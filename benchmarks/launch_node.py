@@ -38,15 +38,13 @@ def main():
     n = int(os.environ["group_size"])
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    # persistent compile cache: the step/burst programs are identical
-    # across node restarts — never pay a mid-serving JIT pause twice
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          "/tmp/rp_jax_cache")
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
-                          "0.2")
     import jax
     if os.environ.get("RP_BENCH_CPU", "1") == "1":
         jax.config.update("jax_platforms", "cpu")
+    # persistent compile cache: the step/burst programs are identical
+    # across node restarts — never pay a mid-serving JIT pause twice
+    from rdma_paxos_tpu.utils.compile_cache import use_compile_cache
+    use_compile_cache()
 
     from rdma_paxos_tpu.config import LogConfig, TimeoutConfig, load_config
     from rdma_paxos_tpu.runtime.node import NodeDaemon

@@ -107,15 +107,13 @@ def main():
         ensure_redis()
     except (FileNotFoundError, RuntimeError) as e:
         raise SystemExit(str(e))
-    # persistent compile cache: burst-tier compiles are seconds each and
-    # identical across runs — never pay them twice on one machine
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          "/tmp/rp_jax_cache")
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
-                          "0.2")
     import jax
     if os.environ.get("RP_BENCH_CPU", "1") == "1":
         jax.config.update("jax_platforms", "cpu")
+    # persistent compile cache: burst-tier compiles are seconds each and
+    # identical across runs — never pay them twice on one machine
+    from rdma_paxos_tpu.utils.compile_cache import use_compile_cache
+    use_compile_cache()
     from rdma_paxos_tpu.config import LogConfig, TimeoutConfig
     from rdma_paxos_tpu.runtime.driver import ClusterDriver
 

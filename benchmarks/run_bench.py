@@ -1002,13 +1002,17 @@ def measure_txn(cfg=None, *, n_replicas=3, n_groups=3, n_probe=12,
                 coordinator=coord)
 
 
-def client_worker(port, n, lat, tid, pipeline=1, retries=5):
+def client_worker(port, n, lat, tid, pipeline=1, retries=5,
+                  completed=None):
     """Pipelined client (the redis-benchmark -P analog): P commands per
     write — the app's read() picks them up as ONE buffer, so they ride a
     single consensus event; latency is measured per pipelined batch.
     A severed connection (a refused event during leadership churn — the
     shim fails fast with -1 and the session drops) reconnects and
-    retries the batch, bounded, exactly as a real client would."""
+    retries the batch, bounded, exactly as a real client would.
+    ``completed[tid]`` tracks the commands ACKNOWLEDGED so far, so a
+    worker that exhausts its retries (and dies with the exception)
+    still leaves an honest count behind."""
     s = socket.create_connection(("127.0.0.1", port), timeout=30)
     f = s.makefile("rb")
     done = 0
@@ -1035,6 +1039,8 @@ def client_worker(port, n, lat, tid, pipeline=1, retries=5):
             continue                 # re-issue the same batch
         lat.append(time.perf_counter() - t0)
         done += k
+        if completed is not None:
+            completed[tid] = done
     s.close()
 
 
@@ -1224,12 +1230,11 @@ def main():
         return shard_main(fwd)
     os.environ.setdefault(
         "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/rp_jax_cache")
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
-                          "0.2")
     import jax
     if os.environ.get("RP_BENCH_CPU", "1") == "1":
         jax.config.update("jax_platforms", "cpu")
+    from rdma_paxos_tpu.utils.compile_cache import use_compile_cache
+    use_compile_cache()
 
     if args.governor:
         # standalone mode (like plain --groups): the governor A/B is
@@ -1357,12 +1362,16 @@ def main():
         return ports[lead]
 
     def run_wave(total: int):
-        """One full client wave; returns (ops/s, sorted latencies)."""
+        """One full client wave; returns (ops/s, seconds, sorted
+        latencies, commands completed) — the rate counts commands
+        ACKNOWLEDGED, not commands asked for."""
         per_w = total // args.clients
         lats_w = [[] for _ in range(args.clients)]
+        completed = [0] * args.clients
         threads = [threading.Thread(target=client_worker,
                                     args=(port_for(i), per_w, lats_w[i],
-                                          i, args.pipeline))
+                                          i, args.pipeline),
+                                    kwargs=dict(completed=completed))
                    for i in range(args.clients)]
         t0_w = time.perf_counter()
         for t in threads:
@@ -1374,7 +1383,24 @@ def main():
         for l in lats_w:
             flat.extend(l)
         flat.sort()
-        return (per_w * args.clients) / dt_w, dt_w, flat
+        return sum(completed) / dt_w, dt_w, flat, sum(completed)
+
+    def die(msg: str):
+        """A dead wave is a failed run: stop what we started, exit 1."""
+        driver.stop()
+        for a in apps:
+            a.kill()
+            a.wait()
+        raise SystemExit(msg)
+
+    def wave_ops() -> float:
+        """ops/s of one full wave for the A/B rounds; a wave in which
+        any client exhausted its retries fails the run instead of
+        scoring a rate."""
+        ops_w, _dt, _lat, got = run_wave(args.requests)
+        if got < args.requests // args.clients * args.clients:
+            die(f"A/B wave died: {got} commands completed")
+        return ops_w
 
     profile_session = None
     if args.profile:
@@ -1384,12 +1410,13 @@ def main():
         profile_session = driver.start_profile(
             seconds=args.profile_secs,
             log_dir=os.path.join(wd, "profile"))
-    ops, dt, lat = run_wave(args.requests)
+    ops, dt, lat, completed = run_wave(args.requests)
     if profile_session is not None:
         driver.stop_profile()
     nb = len(lat)
     n = args.requests // args.clients * args.clients
-    print(f"committed SETs: {n} in {dt:.2f}s -> {n / dt:.0f} ops/s "
+    print(f"committed SETs: {completed} of {n} in {dt:.2f}s -> "
+          f"{ops:.0f} ops/s "
           f"({args.clients} clients, pipeline {args.pipeline}, "
           f"dispatch depth {args.pipeline_depth}"
           f"{', %d groups' % args.groups if sharded_e2e else ''}"
@@ -1399,9 +1426,6 @@ def main():
               f"p95={lat[int(nb * .95)] * 1e3:.2f}ms "
               f"p99={lat[int(nb * .99)] * 1e3:.2f}ms")
     else:
-        # the workload died (all clients exhausted their retries) —
-        # still fall through: the metrics/health export below is
-        # exactly the post-mortem such a run needs
         print("per-batch latency: no completed batches")
 
     # observability export: the registry snapshot (commit-latency
@@ -1420,6 +1444,10 @@ def main():
           f"{len(metrics_snap['histograms'])} histograms)")
     print("METRICS:" + json.dumps(metrics_snap))
     print("HEALTH:" + json.dumps(health))
+    if completed < n:
+        # a client exhausted its retries: the export above is the
+        # post-mortem such a run needs, and the run itself has failed
+        die(f"workload died: {completed} of {n} SETs completed")
 
     trace_detail = None
     if args.trace:
@@ -1516,7 +1544,7 @@ def main():
               f"{merged_path} (one timeline — load in "
               f"https://ui.perfetto.dev)")
 
-    emit("e2e_committed_ops_per_sec", round(n / dt, 1), "ops/s",
+    emit("e2e_committed_ops_per_sec", round(ops, 1), "ops/s",
          detail=dict(
              requests=n, seconds=round(dt, 3),
              clients=args.clients, pipeline=args.pipeline,
@@ -1547,7 +1575,7 @@ def main():
         from benchmarks.reporting import ab_pipeline_rounds
         ab = ab_pipeline_rounds(
             driver, args.ab_pipeline, args.pipeline_depth,
-            lambda: run_wave(args.requests)[0])
+            wave_ops)
         speedup = ab["on"] / max(ab["off"], 1e-9)
         print(f"pipeline A/B: {ab['off']:.0f} ops/s off vs "
               f"{ab['on']:.0f} ops/s on -> {speedup:.2f}x "
@@ -1582,7 +1610,7 @@ def main():
 
         ab = ab_variant_rounds(driver, args.ab_hostpath,
                                apply_variant,
-                               lambda: run_wave(args.requests)[0])
+                               wave_ops)
         speedup = ab["on"] / max(ab["off"], 1e-9)
 
         def us_per_op(ops):

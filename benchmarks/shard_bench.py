@@ -368,10 +368,11 @@ def main(argv=None) -> int:
 
     os.environ.setdefault(
         "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/rp_jax_cache")
     import jax
     if os.environ.get("RP_BENCH_CPU", "1") == "1":
         jax.config.update("jax_platforms", "cpu")
+    from rdma_paxos_tpu.utils.compile_cache import use_compile_cache
+    use_compile_cache()
 
     from benchmarks.reporting import emit
 

@@ -19,6 +19,7 @@
 #include <netinet/in.h>
 #include <poll.h>
 #include <pthread.h>
+#include <signal.h>
 #include <stdio.h>
 #include <stdlib.h>
 #include <string.h>
@@ -157,6 +158,11 @@ static void* conn_main(void* arg) {
 int main(int argc, char** argv) {
   int port = argc > 1 ? atoi(argv[1]) : 7000;
   int threaded = argc > 2 && !strcmp(argv[2], "-t");
+  /* like the servers this stands in for (redis.c setupSignalHandlers,
+   * memcached sigignore): a reply written to a connection the peer — or
+   * the shim's sever of a refused session — already shut down is an
+   * EPIPE to skip, not a signal that kills the whole store */
+  signal(SIGPIPE, SIG_IGN);
   int ls = socket(AF_INET, SOCK_STREAM, 0);
   int one = 1;
   setsockopt(ls, SOL_SOCKET, SO_REUSEADDR, &one, sizeof one);

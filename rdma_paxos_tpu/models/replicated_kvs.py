@@ -59,8 +59,8 @@ class ReplicatedKVS:
         # labels the dedup metric series so per-group dedup pressure
         # is observable — None = unsharded, unlabeled legacy series
         self.group: Optional[int] = None
-        self.tables: List[KVState] = [make_kvs(cap)
-                                      for _ in range(cluster.R)]
+        self.tables: List[KVState] = [self._make_table(cap, r)
+                                      for r in range(cluster.R)]
         self._cursor = [0] * cluster.R
         self._apply_jit = jax.jit(apply_cmd)
         self._get_many_jit = None      # compiled lazily on first batch
@@ -98,6 +98,22 @@ class ReplicatedKVS:
         self.txn_applied: List[int] = [0] * cluster.R
         self.txn_discarded: List[int] = [0] * cluster.R
 
+    def _make_table(self, cap: int, r: int) -> KVState:
+        """An empty table for replica ``r``, on the chip that holds
+        replica ``r``'s log rows when the engine runs one replica per
+        chip (``SimCluster.replica_device``) — a state machine on chip
+        0 next to replica 0's log would make every other replica's
+        apply and read cross chips. Engines without a per-replica
+        placement (vmap rows, group facades) use the default device."""
+        place = getattr(self.c, "replica_device", None)
+        dev = place(r) if place is not None else None
+        if dev is None:
+            return make_kvs(cap)
+        # built on ``dev`` and COMMITTED there: jit follows committed
+        # operands, so applies and reads run where the table lives
+        with jax.default_device(dev):
+            return jax.device_put(make_kvs(cap), dev)
+
     def _spans(self):
         """The cluster's span recorder when causal tracing is on —
         session mutations are span births keyed (client_id, req_id),
@@ -124,7 +140,7 @@ class ReplicatedKVS:
         driver's recovery path). The fold is deterministic, so the
         rebuilt table, registry, and dedup decisions match exactly what
         the pre-crash incarnation derived."""
-        self.tables[r] = make_kvs(int(self.tables[r].cap))
+        self.tables[r] = self._make_table(int(self.tables[r].cap), r)
         self._cursor[r] = 0
         self.last_req[r] = dict()
         self.deduped[r] = 0

@@ -32,6 +32,7 @@ from rdma_paxos_tpu.consensus.log import EntryType
 from rdma_paxos_tpu.obs import trace as obs_trace
 from rdma_paxos_tpu.obs.metrics import default_registry
 from rdma_paxos_tpu.obs.trace import default_ring
+from rdma_paxos_tpu.utils.net import close_listener
 
 OP_HELLO, OP_CONNECT, OP_SEND, OP_CLOSE = 1, 2, 3, 4
 
@@ -249,10 +250,7 @@ class ProxyServer:
 
     def close(self) -> None:
         self._stop.set()
-        try:
-            self._srv.close()
-        except OSError:
-            pass
+        close_listener(self._srv, self._thread)
         for l in self._links:
             try:
                 l.close()

@@ -74,6 +74,7 @@ import numpy as np
 from rdma_paxos_tpu.obs import trace as obs_trace
 from rdma_paxos_tpu.obs.metrics import default_registry
 from rdma_paxos_tpu.obs.trace import default_ring
+from rdma_paxos_tpu.utils.net import close_listener
 
 
 # ---------------------------------------------------------------------------
@@ -548,10 +549,8 @@ class GroupController:
         self._stop.set()
         with self._lock:
             self._lock.notify_all()    # release the cutter promptly
-        try:
-            self._srv.close()
-        except OSError:
-            pass
+        close_listener(self._srv, self._thread)
+        self._cutter.join(timeout=2.0)
 
 
 # ---------------------------------------------------------------------------
@@ -600,7 +599,8 @@ class ElasticSupervisor:
         # registration time (no worker is running then, so the store
         # file is quiescent); donor fetches serve exactly this
         self._offered: Optional[Tuple[dict, bytes, dict]] = None
-        threading.Thread(target=self._serve, daemon=True).start()
+        self._thread = threading.Thread(target=self._serve, daemon=True)
+        self._thread.start()
 
     # ---------------- dump serving (the donor side) ----------------
 
@@ -824,10 +824,7 @@ class ElasticSupervisor:
         if child is not None:
             child.kill()
         self._reap()
-        try:
-            self._srv.close()
-        except OSError:
-            pass
+        close_listener(self._srv, self._thread)
 
 
 def main() -> None:
@@ -843,16 +840,16 @@ def main() -> None:
     ap.add_argument("--cfg-json", default="")
     ap.add_argument("--worker-cpu", action="store_true",
                     help="run worker consensus cores on the CPU backend "
-                         "(sets RP_BENCH_CPU=1 for workers; without this "
-                         "workers inherit the environment's backend — on "
-                         "a TPU host that means the TPU)")
+                         "(sets JAX_PLATFORMS=cpu for workers; without "
+                         "this workers inherit the environment's backend "
+                         "— on a TPU host that means the TPU)")
     args = ap.parse_args()
     sup = ElasticSupervisor(
         host_id=args.host_id, controller=args.controller,
         workdir=args.workdir, port=args.port, app_port=args.app_port,
         app_cmd=args.app_cmd, round_iters=args.round_iters,
         cfg_json=args.cfg_json,
-        worker_env={"RP_BENCH_CPU": "1"} if args.worker_cpu else None)
+        worker_env={"JAX_PLATFORMS": "cpu"} if args.worker_cpu else None)
     print(f"supervisor h{args.host_id} serving on {sup.addr}",
           flush=True)
     try:

@@ -249,16 +249,14 @@ class NodeDaemon:
 
     @property
     def burst_enabled(self) -> bool:
-        """Bursts amortize per-DISPATCH overhead — dominant on real TPU
-        hosts (device launch / tunnel latency per program), negligible
-        on the CPU multi-process test harness where per-collective
-        cross-process syncs dominate and a fused K-step program costs
-        the same collectives as K separate steps. Default: on for TPU,
-        off for CPU; RP_BURST=1/0 overrides (must MATCH on all hosts —
-        burst engagement is part of the collective program schedule).
-        Measured on the 1-core CPU harness: 2000-SET drain 0.14 s
-        without bursts vs 0.62 s with (the collective count is the
-        bottleneck there, not dispatches).
+        """Bursts amortize per-DISPATCH overhead; a fused K-step
+        program still costs the same collectives as K separate steps.
+        Off unless RP_BURST=1 (must MATCH on all hosts — burst
+        engagement is part of the collective program schedule): the
+        only reading of this tier is the 1-core CPU harness, where
+        cross-process collective syncs dominate and bursts lose
+        (2000-SET drain 0.14 s without vs 0.62 s with); on chips it is
+        not measured, so nothing is switched by backend.
 
         Bursts additionally REQUIRE full connectivity: K is agreed via
         the gathered burst_hint (a max over the leaders each replica
@@ -268,14 +266,9 @@ class NodeDaemon:
         configuration (HostReplicaDriver.step refuses psum with any
         masked peer), so bursts are gated on it; under fanout='gather'
         (the partition-simulation mode) bursts stay off regardless of
-        backend or RP_BURST."""
-        if self.hd._fanout != "psum":
-            return False
-        env = os.environ.get("RP_BURST")
-        if env is not None:
-            return env == "1"
-        import jax
-        return jax.default_backend() == "tpu"
+        RP_BURST."""
+        return (self.hd._fanout == "psum"
+                and os.environ.get("RP_BURST") == "1")
 
     def prewarm_burst(self) -> None:
         """COLLECTIVE: compile the burst program before serving (every

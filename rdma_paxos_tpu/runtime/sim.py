@@ -1327,6 +1327,15 @@ class SimCluster:
 
     # ---------------- inspection ----------------
 
+    def replica_device(self, r: int):
+        """The chip holding replica ``r``'s state rows (``mode="spmd"``:
+        one replica per chip), or None when every replica is a vmap
+        row on the default device. Host-side state that belongs to one
+        replica (its state-machine table) is placed by this."""
+        if self._mode != "spmd":
+            return None
+        return self.mesh.devices[r]
+
     def leader(self) -> int:
         assert self.last is not None
         ids = [r for r in range(self.R)

@@ -22,17 +22,6 @@ from rdma_paxos_tpu.consensus.membership import MembershipManager
 from rdma_paxos_tpu.consensus.snapshot import export_row, genesis_row
 from rdma_paxos_tpu.consensus.state import ConfigState, Role
 from rdma_paxos_tpu.runtime.sim import SimCluster
-from tests.conftest import jax_multiprocess_cpu
-
-# the full elastic worlds run one NodeDaemon OS process per host over
-# jax.distributed — impossible on a jaxlib whose CPU backend lacks
-# cross-process collectives (the workers die at boot and the
-# supervisors churn generations until the assertion timeout)
-needs_multiprocess_cpu = pytest.mark.skipif(
-    not jax_multiprocess_cpu(),
-    reason="cross-process CPU collectives unavailable (jaxlib raises "
-           "'Multiprocess computations aren't implemented on the CPU "
-           "backend'); needs jax >= 0.5")
 
 NATIVE = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "native")
@@ -320,7 +309,6 @@ def built_native():
                    capture_output=True)
 
 
-@needs_multiprocess_cpu
 def test_elastic_loss_restart_rejoin(tmp_path, built_native):
     from rdma_paxos_tpu.runtime.elastic import (ElasticSupervisor,
                                                 GroupController)
@@ -329,14 +317,10 @@ def test_elastic_loss_restart_rejoin(tmp_path, built_native):
     # compile cache is machine-stable so later runs are warm
     ctl = GroupController(expect=3, settle=1.2, barrier_timeout=90.0)
     dirs = {h: str(tmp_path / f"h{h}") for h in range(3)}
-    cache = "/tmp/rp_elastic_jaxcache"
-    # tests opt into the CPU backend EXPLICITLY (workers no longer
-    # default to CPU — a silent CPU fallback on a TPU deployment was an
-    # advisor finding); the outer environment may carry an accelerator
-    # JAX_PLATFORMS that must not leak into the worker world
-    wenv = {"JAX_COMPILATION_CACHE_DIR": cache,
-            "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "1",
-            "RP_BENCH_CPU": "1"}
+    # tests opt into the CPU backend EXPLICITLY (workers never default
+    # to CPU — a silent CPU fallback on a TPU deployment was an advisor
+    # finding); the compile cache is the one conftest exports
+    wenv = {"JAX_PLATFORMS": "cpu"}
 
     def mk_sup(h):
         sup = ElasticSupervisor(
@@ -403,7 +387,6 @@ def test_elastic_loss_restart_rejoin(tmp_path, built_native):
         ctl.close()
 
 
-@needs_multiprocess_cpu
 def test_leader_sigkill_under_speculative_load(tmp_path, built_native):
     """The reference's RemoveLeader scenario (reconf_bench.sh:96-123) at
     FULL stack depth with speculative clients in flight: SIGKILL the
@@ -422,10 +405,7 @@ def test_leader_sigkill_under_speculative_load(tmp_path, built_native):
                                                 GroupController)
     ctl = GroupController(expect=3, settle=1.2, barrier_timeout=90.0)
     dirs = {h: str(tmp_path / f"h{h}") for h in range(3)}
-    cache = "/tmp/rp_elastic_jaxcache"
-    wenv = {"JAX_COMPILATION_CACHE_DIR": cache,
-            "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "1",
-            "RP_BENCH_CPU": "1"}
+    wenv = {"JAX_PLATFORMS": "cpu"}
 
     def mk_sup(h):
         sup = ElasticSupervisor(

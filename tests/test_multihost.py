@@ -7,23 +7,12 @@ import os
 import subprocess
 import sys
 
-import pytest
-
-from tests.conftest import jax_multiprocess_cpu
-
-pytestmark = pytest.mark.skipif(
-    not jax_multiprocess_cpu(),
-    reason="cross-process CPU collectives unavailable (jaxlib raises "
-           "'Multiprocess computations aren't implemented on the CPU "
-           "backend'); needs jax >= 0.5")
-
 WORKER = r"""
 import os, sys
 pid, n, port = int(sys.argv[1]), int(sys.argv[2]), sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.pop("XLA_FLAGS", None)    # 1 device per process
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 from rdma_paxos_tpu.config import LogConfig
 from rdma_paxos_tpu.consensus.log import EntryType
@@ -87,7 +76,6 @@ pid, n, port = int(sys.argv[1]), int(sys.argv[2]), sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.pop("XLA_FLAGS", None)    # 1 device per process
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 from rdma_paxos_tpu.config import LogConfig
 from rdma_paxos_tpu.consensus.log import EntryType
@@ -152,7 +140,6 @@ pid, n, port = int(sys.argv[1]), int(sys.argv[2]), sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.pop("XLA_FLAGS", None)    # 1 device per process
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 from rdma_paxos_tpu.config import LogConfig
 from rdma_paxos_tpu.consensus.log import EntryType, M_LEN

@@ -13,14 +13,6 @@ import time
 
 import pytest
 
-from tests.conftest import jax_multiprocess_cpu
-
-pytestmark = pytest.mark.skipif(
-    not jax_multiprocess_cpu(),
-    reason="cross-process CPU collectives unavailable (jaxlib raises "
-           "'Multiprocess computations aren't implemented on the CPU "
-           "backend'); needs jax >= 0.5")
-
 NATIVE = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "native")
 _BASE = 7800 + (os.getpid() % 400)
@@ -150,8 +142,7 @@ def _boot_nodes(wd, iterations=20000, extra_env=None, _retry=True):
 
 def test_deep_queue_drains_through_bursts(tmp_path):
     """Deep pipelined load on the real multihost path WITH BURSTS
-    FORCED ON (RP_BURST=1 — the TPU-default path, off by default on
-    this CPU harness): the leader's submit backlog rides the control
+    ON (RP_BURST=1 — off by default): the leader's submit backlog rides the control
     gather as burst_hint, every host agrees on a fused K-step dispatch,
     and the queue drains through fused bursts. Correctness gate: every
     reply arrives (output commit) and follower state converges
