@@ -376,40 +376,43 @@ def replica_step(
     # During joint consensus, old-config members must still vote (the win
     # condition demands a majority of BOTH configs — dare_server.c:1366-1373)
     i_member = (in_vote[me] > 0) | ((transit > 0) & (in_old[me] > 0))
-    my_lterm = last_term(state.log, state.end)
+    with jax.named_scope("control_gather"):
+        my_lterm = last_term(state.log, state.end)
 
     # ------------------------------------------------------------------
     # Phase A — control gather (terms, roles, offsets, candidacies,
     # apply offsets for pruning).  The analog of reading peers' cached
     # SIDs / ctrl arrays (dare_ibv_rc.c:1182-1280).
     # ------------------------------------------------------------------
-    ctrl = jnp.zeros((C_N,), i32)
-    ctrl = ctrl.at[C_TERM].set(state.term)
-    ctrl = ctrl.at[C_ROLE].set(state.role)
-    ctrl = ctrl.at[C_END].set(state.end)
-    ctrl = ctrl.at[C_COMMIT].set(state.commit)
-    ctrl = ctrl.at[C_LTERM].set(my_lterm)
-    ctrl = ctrl.at[C_APPLY].set(jnp.minimum(inp.apply_done, state.commit))
-    ctrl = ctrl.at[C_TMO].set(inp.timeout_fired)
-    ctrl = ctrl.at[C_VTERM].set(state.voted_term)
-    ctrl = ctrl.at[C_VFOR].set(state.voted_for)
-    ctrl = ctrl.at[C_QDEP].set(inp.queue_depth)
-    ctrl = ctrl.at[C_HEAD].set(state.head)
-    allc = lax.all_gather(ctrl, axis_name)                  # [R, C_N]
+    with jax.named_scope("control_gather"):
+        ctrl = jnp.zeros((C_N,), i32)
+        ctrl = ctrl.at[C_TERM].set(state.term)
+        ctrl = ctrl.at[C_ROLE].set(state.role)
+        ctrl = ctrl.at[C_END].set(state.end)
+        ctrl = ctrl.at[C_COMMIT].set(state.commit)
+        ctrl = ctrl.at[C_LTERM].set(my_lterm)
+        ctrl = ctrl.at[C_APPLY].set(
+            jnp.minimum(inp.apply_done, state.commit))
+        ctrl = ctrl.at[C_TMO].set(inp.timeout_fired)
+        ctrl = ctrl.at[C_VTERM].set(state.voted_term)
+        ctrl = ctrl.at[C_VFOR].set(state.voted_for)
+        ctrl = ctrl.at[C_QDEP].set(inp.queue_depth)
+        ctrl = ctrl.at[C_HEAD].set(state.head)
+        allc = lax.all_gather(ctrl, axis_name)              # [R, C_N]
 
-    g_term, g_end = allc[:, C_TERM], allc[:, C_END]
-    g_lterm, g_apply = allc[:, C_LTERM], allc[:, C_APPLY]
-    g_tmo = allc[:, C_TMO]
+        g_term, g_end = allc[:, C_TERM], allc[:, C_END]
+        g_lterm, g_apply = allc[:, C_LTERM], allc[:, C_APPLY]
+        g_tmo = allc[:, C_TMO]
 
-    # vote-record retention from the control gather (rc_replicate_vote
-    # analog, dare_ibv_rc.c:1049): runs on EVERY step, so a replica that
-    # was partitioned during an election still learns peers' durable vote
-    # pairs once healed — identically in the full and stable paths.
-    rec_upd0 = heard & (allc[:, C_VTERM] > state.vote_rec_term)
-    vote_rec_term1 = jnp.where(rec_upd0, allc[:, C_VTERM],
-                               state.vote_rec_term)
-    vote_rec_for1 = jnp.where(rec_upd0, allc[:, C_VFOR],
-                              state.vote_rec_for)
+        # vote-record retention from the control gather (rc_replicate_vote
+        # analog, dare_ibv_rc.c:1049): runs on EVERY step, so a replica that
+        # was partitioned during an election still learns peers' durable vote
+        # pairs once healed — identically in the full and stable paths.
+        rec_upd0 = heard & (allc[:, C_VTERM] > state.vote_rec_term)
+        vote_rec_term1 = jnp.where(rec_upd0, allc[:, C_VTERM],
+                                   state.vote_rec_term)
+        vote_rec_for1 = jnp.where(rec_upd0, allc[:, C_VFOR],
+                                  state.vote_rec_for)
 
     # ------------------------------------------------------------------
     # Phase B — one-round election (start_election dare_server.c:1264,
@@ -417,103 +420,107 @@ def replica_step(
     # Statically removed in the stable fast path (elections=False).
     # ------------------------------------------------------------------
     if not elections:
-        new_voted_term = state.voted_term
-        new_voted_for = state.voted_for
-        vote_rec_term2 = vote_rec_term1
-        vote_rec_for2 = vote_rec_for1
-        win = jnp.zeros((), bool)
-        became = jnp.zeros((), bool)
-        max_heard = jnp.max(jnp.where(heard, g_term, I32_MIN))
-        new_term = jnp.maximum(state.term, max_heard)
-        role = jnp.where(new_term > state.term, int(Role.FOLLOWER),
-                         state.role).astype(i32)
-        i_lead = role == int(Role.LEADER)
-        leader_id = jnp.where(new_term > state.term, -1,
-                              state.leader_id).astype(i32)
-        log2, end2 = append_batch(
-            state.log, state.end, state.head, inp.batch_data,
-            inp.batch_meta,
-            jnp.where(i_lead, inp.batch_count, 0).astype(i32), new_term)
+        with jax.named_scope("election"):
+            new_voted_term = state.voted_term
+            new_voted_for = state.voted_for
+            vote_rec_term2 = vote_rec_term1
+            vote_rec_for2 = vote_rec_for1
+            win = jnp.zeros((), bool)
+            became = jnp.zeros((), bool)
+            max_heard = jnp.max(jnp.where(heard, g_term, I32_MIN))
+            new_term = jnp.maximum(state.term, max_heard)
+            role = jnp.where(new_term > state.term, int(Role.FOLLOWER),
+                             state.role).astype(i32)
+            i_lead = role == int(Role.LEADER)
+            leader_id = jnp.where(new_term > state.term, -1,
+                                  state.leader_id).astype(i32)
+        with jax.named_scope("append"):
+            log2, end2 = append_batch(
+                state.log, state.end, state.head, inp.batch_data,
+                inp.batch_meta,
+                jnp.where(i_lead, inp.batch_count, 0).astype(i32), new_term)
         end1 = state.end
     else:
-        is_cand = (g_tmo > 0) & (in_vote > 0)               # [R]
-        cand_term = g_term + 1
-        i_cand = is_cand[me] & (state.role != int(Role.LEADER))
+        with jax.named_scope("election"):
+            is_cand = (g_tmo > 0) & (in_vote > 0)               # [R]
+            cand_term = g_term + 1
+            i_cand = is_cand[me] & (state.role != int(Role.LEADER))
 
-        # voter logic (vote durability: the vote all_gather below
-        # replicates the durable (voted_term, voted_for) pair to every
-        # live peer, which RETAINS it in vote_rec_* — the
-        # rc_replicate_vote analog; the host additionally persists the
-        # pair to a HardState file between steps, and recovery restores
-        # max(persisted, peer records) — see consensus/snapshot.py
-        # recover_vote)
-        can_grant = (
-            heard & is_cand
-            & (cand_term >= state.term)
-            & ((cand_term > state.voted_term)
-               | ((cand_term == state.voted_term)
-                  & (jnp.arange(R) == state.voted_for)))
-            & ((g_lterm > my_lterm)
-               | ((g_lterm == my_lterm) & (g_end >= state.end)))
-        )
-        best = _lex_argmax(can_grant, [cand_term, g_lterm, g_end])
-        my_vote = jnp.where(i_cand, me, jnp.where(i_member, best, -1))
-        vote_cast = my_vote >= 0
-        new_voted_term = jnp.where(
-            vote_cast, jnp.maximum(state.voted_term, cand_term[my_vote]),
-            state.voted_term)
-        new_voted_for = jnp.where(vote_cast, my_vote, state.voted_for)
+            # voter logic (vote durability: the vote all_gather below
+            # replicates the durable (voted_term, voted_for) pair to every
+            # live peer, which RETAINS it in vote_rec_* — the
+            # rc_replicate_vote analog; the host additionally persists the
+            # pair to a HardState file between steps, and recovery restores
+            # max(persisted, peer records) — see consensus/snapshot.py
+            # recover_vote)
+            can_grant = (
+                heard & is_cand
+                & (cand_term >= state.term)
+                & ((cand_term > state.voted_term)
+                   | ((cand_term == state.voted_term)
+                      & (jnp.arange(R) == state.voted_for)))
+                & ((g_lterm > my_lterm)
+                   | ((g_lterm == my_lterm) & (g_end >= state.end)))
+            )
+            best = _lex_argmax(can_grant, [cand_term, g_lterm, g_end])
+            my_vote = jnp.where(i_cand, me, jnp.where(i_member, best, -1))
+            vote_cast = my_vote >= 0
+            new_voted_term = jnp.where(
+                vote_cast, jnp.maximum(state.voted_term, cand_term[my_vote]),
+                state.voted_term)
+            new_voted_for = jnp.where(vote_cast, my_vote, state.voted_for)
 
-        vote_msg = jnp.stack([my_vote, new_voted_term, new_voted_for])
-        g_votes = lax.all_gather(vote_msg, axis_name)       # [R, 3]
-        votes = g_votes[:, 0]
-        got = (votes == me) & heard
-        # retain votes CAST THIS STEP immediately (the control-gather
-        # retention above only carries pre-step pairs): the vote gather
-        # doubles as same-step durable replication to every live peer
-        rec_upd = heard & (g_votes[:, 1] > vote_rec_term1)
-        vote_rec_term2 = jnp.where(rec_upd, g_votes[:, 1], vote_rec_term1)
-        vote_rec_for2 = jnp.where(rec_upd, g_votes[:, 2], vote_rec_for1)
-        win = (
-            i_cand
-            & (jnp.sum(got.astype(i32) * in_vote) >= maj_vote)
-            & jnp.where(transit > 0,
-                        jnp.sum(got.astype(i32) * in_old) >= maj_old, True)
-        )
+            vote_msg = jnp.stack([my_vote, new_voted_term, new_voted_for])
+            g_votes = lax.all_gather(vote_msg, axis_name)       # [R, 3]
+            votes = g_votes[:, 0]
+            got = (votes == me) & heard
+            # retain votes CAST THIS STEP immediately (the control-gather
+            # retention above only carries pre-step pairs): the vote gather
+            # doubles as same-step durable replication to every live peer
+            rec_upd = heard & (g_votes[:, 1] > vote_rec_term1)
+            vote_rec_term2 = jnp.where(rec_upd, g_votes[:, 1], vote_rec_term1)
+            vote_rec_for2 = jnp.where(rec_upd, g_votes[:, 2], vote_rec_for1)
+            win = (
+                i_cand
+                & (jnp.sum(got.astype(i32) * in_vote) >= maj_vote)
+                & jnp.where(transit > 0,
+                            jnp.sum(got.astype(i32) * in_old) >= maj_old, True)
+            )
 
-        # term adoption: everyone adopts the max term heard (incl.
-        # candidacies); a deposed leader steps down here — the fencing of
-        # server_to_follower (dare_server.c:2238).
-        my_term1 = jnp.where(i_cand, state.term + 1, state.term)
-        eff_term = jnp.where(is_cand, cand_term, g_term)
-        max_heard = jnp.max(jnp.where(heard, eff_term, I32_MIN))
-        new_term = jnp.maximum(my_term1, max_heard)
+            # term adoption: everyone adopts the max term heard (incl.
+            # candidacies); a deposed leader steps down here — the fencing of
+            # server_to_follower (dare_server.c:2238).
+            my_term1 = jnp.where(i_cand, state.term + 1, state.term)
+            eff_term = jnp.where(is_cand, cand_term, g_term)
+            max_heard = jnp.max(jnp.where(heard, eff_term, I32_MIN))
+            new_term = jnp.maximum(my_term1, max_heard)
 
-        role = jnp.where(
-            win, int(Role.LEADER),
-            jnp.where(new_term > my_term1, int(Role.FOLLOWER),
-                      jnp.where(i_cand, int(Role.CANDIDATE), state.role)),
-        ).astype(i32)
-        became = win & (state.role != int(Role.LEADER))
-        i_lead = role == int(Role.LEADER)
-        leader_id = jnp.where(win, me,
-                              jnp.where(new_term > state.term, -1,
-                                        state.leader_id)).astype(i32)
+            role = jnp.where(
+                win, int(Role.LEADER),
+                jnp.where(new_term > my_term1, int(Role.FOLLOWER),
+                          jnp.where(i_cand, int(Role.CANDIDATE), state.role)),
+            ).astype(i32)
+            became = win & (state.role != int(Role.LEADER))
+            i_lead = role == int(Role.LEADER)
+            leader_id = jnp.where(win, me,
+                                  jnp.where(new_term > state.term, -1,
+                                            state.leader_id)).astype(i32)
 
         # --------------------------------------------------------------
         # Phase C — leader append: NOOP on election (dare_server.c:1487),
         # then the client batch (get_tailq_message → log_append_entry,
         # dare_ibv_ud.c:780-790).
         # --------------------------------------------------------------
-        noop_data = jnp.zeros((1, cfg.slot_words), i32)
-        noop_meta = jnp.zeros((1, META_W), i32).at[0, M_TYPE].set(
-            int(EntryType.NOOP))
-        log1, end1 = append_batch(
-            state.log, state.end, state.head, noop_data, noop_meta,
-            jnp.where(became, 1, 0).astype(i32), new_term)
-        log2, end2 = append_batch(
-            log1, end1, state.head, inp.batch_data, inp.batch_meta,
-            jnp.where(i_lead, inp.batch_count, 0).astype(i32), new_term)
+        with jax.named_scope("append"):
+            noop_data = jnp.zeros((1, cfg.slot_words), i32)
+            noop_meta = jnp.zeros((1, META_W), i32).at[0, M_TYPE].set(
+                int(EntryType.NOOP))
+            log1, end1 = append_batch(
+                state.log, state.end, state.head, noop_data, noop_meta,
+                jnp.where(became, 1, 0).astype(i32), new_term)
+            log2, end2 = append_batch(
+                log1, end1, state.head, inp.batch_data, inp.batch_meta,
+                jnp.where(i_lead, inp.batch_count, 0).astype(i32), new_term)
 
     # ------------------------------------------------------------------
     # Phase D — leader fan-out. Window floored at the minimum reachable
@@ -522,48 +529,49 @@ def replica_step(
     # dare_server.c:2069) and at the leader's own head (pruned entries
     # are gone).
     # ------------------------------------------------------------------
-    others = heard & (in_new > 0) & (jnp.arange(R) != me)
-    min_end = jnp.min(jnp.where(others, g_end, I32_MAX))
-    wstart = jnp.clip(min_end, end2 - W, end2)
-    wstart = jnp.maximum(jnp.maximum(wstart, state.head), 0)
-    wcount = jnp.clip(end2 - wstart, 0, W)
-    wdata, wmeta = extract_window(log2, wstart, W)
-    prev_term = jnp.where(
-        wstart > 0, log2.meta[slot_of(wstart - 1, cfg.n_slots), M_TERM], 0)
+    with jax.named_scope("fanout"):
+        others = heard & (in_new > 0) & (jnp.arange(R) != me)
+        min_end = jnp.min(jnp.where(others, g_end, I32_MAX))
+        wstart = jnp.clip(min_end, end2 - W, end2)
+        wstart = jnp.maximum(jnp.maximum(wstart, state.head), 0)
+        wcount = jnp.clip(end2 - wstart, 0, W)
+        wdata, wmeta = extract_window(log2, wstart, W)
+        prev_term = jnp.where(
+            wstart > 0, log2.meta[slot_of(wstart - 1, cfg.n_slots), M_TERM], 0)
 
-    # pruning input: min apply over reachable members (leader-only use)
-    min_apply = jnp.min(jnp.where(heard & (in_new > 0), g_apply, I32_MAX))
+        # pruning input: min apply over reachable members (leader-only use)
+        min_apply = jnp.min(jnp.where(heard & (in_new > 0), g_apply, I32_MAX))
 
-    msg_scal = jnp.zeros((S_N,), i32)
-    msg_scal = msg_scal.at[S_VALID].set(1)
-    msg_scal = msg_scal.at[S_WSTART].set(wstart)
-    msg_scal = msg_scal.at[S_WCOUNT].set(wcount)
-    msg_scal = msg_scal.at[S_TERM].set(new_term)
-    msg_scal = msg_scal.at[S_PREV].set(prev_term)
-    msg_scal = msg_scal.at[S_COMMIT].set(state.commit)
-    msg_scal = msg_scal.at[S_HEAD].set(state.head)
+        msg_scal = jnp.zeros((S_N,), i32)
+        msg_scal = msg_scal.at[S_VALID].set(1)
+        msg_scal = msg_scal.at[S_WSTART].set(wstart)
+        msg_scal = msg_scal.at[S_WCOUNT].set(wcount)
+        msg_scal = msg_scal.at[S_TERM].set(new_term)
+        msg_scal = msg_scal.at[S_PREV].set(prev_term)
+        msg_scal = msg_scal.at[S_COMMIT].set(state.commit)
+        msg_scal = msg_scal.at[S_HEAD].set(state.head)
 
-    contrib = jnp.where(i_lead, 1, 0)
-    gw_scal = lax.all_gather(msg_scal * contrib, axis_name)  # [R, S_N]
+        contrib = jnp.where(i_lead, 1, 0)
+        gw_scal = lax.all_gather(msg_scal * contrib, axis_name)  # [R, S_N]
 
-    # dominant leader: the highest-term valid claim this replica can hear
-    claim = heard & (gw_scal[:, S_VALID] > 0)
-    dom = _lex_argmax(claim, [gw_scal[:, S_TERM]])
-    has_msg = dom >= 0
-    dsafe = jnp.maximum(dom, 0)
-    m_scal = gw_scal[dsafe]
-    m_term = m_scal[S_TERM]
+        # dominant leader: the highest-term valid claim this replica can hear
+        claim = heard & (gw_scal[:, S_VALID] > 0)
+        dom = _lex_argmax(claim, [gw_scal[:, S_TERM]])
+        has_msg = dom >= 0
+        dsafe = jnp.maximum(dom, 0)
+        m_scal = gw_scal[dsafe]
+        m_term = m_scal[S_TERM]
 
-    if fanout == "psum":
-        # single-contributor broadcast (see docstring for the safety
-        # argument): O(W) bandwidth instead of O(R·W)
-        m_data = lax.psum(wdata * contrib, axis_name)       # [W, sw]
-        m_meta = lax.psum(wmeta * contrib, axis_name)       # [W, MW]
-    else:
-        gw_data = lax.all_gather(wdata * contrib, axis_name)  # [R, W, sw]
-        gw_meta = lax.all_gather(wmeta * contrib, axis_name)  # [R, W, MW]
-        m_data = gw_data[dsafe]
-        m_meta = gw_meta[dsafe]
+        if fanout == "psum":
+            # single-contributor broadcast (see docstring for the safety
+            # argument): O(W) bandwidth instead of O(R·W)
+            m_data = lax.psum(wdata * contrib, axis_name)       # [W, sw]
+            m_meta = lax.psum(wmeta * contrib, axis_name)       # [W, MW]
+        else:
+            gw_data = lax.all_gather(wdata * contrib, axis_name)  # [R, W, sw]
+            gw_meta = lax.all_gather(wmeta * contrib, axis_name)  # [R, W, MW]
+            m_data = gw_data[dsafe]
+            m_meta = gw_meta[dsafe]
 
     # ------------------------------------------------------------------
     # Phase E — absorb (uniform; the leader absorbs its own window as a
@@ -571,49 +579,50 @@ def replica_step(
     # consistency; backoff on mismatch = nextIndex rewind, expressed as
     # data (our advertised end drops, so the next window reaches lower).
     # ------------------------------------------------------------------
-    use = has_msg & (m_scal[S_VALID] > 0) & (m_term >= new_term)
-    new_term2 = jnp.where(use, jnp.maximum(new_term, m_term), new_term)
-    role2 = jnp.where(
-        use & ((m_term > new_term) | (dom != me)),
-        jnp.where(i_lead & (dom == me), role, int(Role.FOLLOWER)),
-        role).astype(i32)
-    leader_id2 = jnp.where(use, dom, leader_id)
-    i_lead2 = role2 == int(Role.LEADER)
+    with jax.named_scope("absorb"):
+        use = has_msg & (m_scal[S_VALID] > 0) & (m_term >= new_term)
+        new_term2 = jnp.where(use, jnp.maximum(new_term, m_term), new_term)
+        role2 = jnp.where(
+            use & ((m_term > new_term) | (dom != me)),
+            jnp.where(i_lead & (dom == me), role, int(Role.FOLLOWER)),
+            role).astype(i32)
+        leader_id2 = jnp.where(use, dom, leader_id)
+        i_lead2 = role2 == int(Role.LEADER)
 
-    m_wstart, m_wcount = m_scal[S_WSTART], m_scal[S_WCOUNT]
-    gap = m_wstart > end2
-    local_prev = jnp.where(
-        m_wstart > 0,
-        log2.meta[slot_of(m_wstart - 1, cfg.n_slots), M_TERM], 0)
-    prev_ok = (m_wstart == 0) | (local_prev == m_scal[S_PREV])
-    can_absorb = use & ~gap & prev_ok
+        m_wstart, m_wcount = m_scal[S_WSTART], m_scal[S_WCOUNT]
+        gap = m_wstart > end2
+        local_prev = jnp.where(
+            m_wstart > 0,
+            log2.meta[slot_of(m_wstart - 1, cfg.n_slots), M_TERM], 0)
+        prev_ok = (m_wstart == 0) | (local_prev == m_scal[S_PREV])
+        can_absorb = use & ~gap & prev_ok
 
-    log3, end3 = absorb_window(
-        log2, end2, m_data, m_meta, m_wstart,
-        jnp.where(can_absorb, m_wcount, 0))
-    # backoff: advertised end rewinds to just before the mismatch (never
-    # below commit — committed entries cannot conflict)
-    end3 = jnp.where(use & ~gap & ~prev_ok,
-                     jnp.maximum(m_wstart - 1, state.commit), end3)
+        log3, end3 = absorb_window(
+            log2, end2, m_data, m_meta, m_wstart,
+            jnp.where(can_absorb, m_wcount, 0))
+        # backoff: advertised end rewinds to just before the mismatch (never
+        # below commit — committed entries cannot conflict)
+        end3 = jnp.where(use & ~gap & ~prev_ok,
+                         jnp.maximum(m_wstart - 1, state.commit), end3)
 
-    # follower commit/head riding the message (lazy, one step behind the
-    # leader's scan — matching the reference's lazy commit push). The
-    # advance is CLAMPED to W per step: the committed-config checkpoint
-    # (Phase G) scans only the W-entry commit-crossing window, so an
-    # unbounded jump (rejoiner with a long matching log but stale
-    # commit) could carry a CONFIG entry past the scan unseen. W per
-    # step is also the host's apply/replay catch-up rate, so the clamp
-    # costs no end-to-end liveness.
-    commit1 = jnp.where(
-        can_absorb & ~i_lead2,
-        jnp.maximum(state.commit,
-                    jnp.minimum(jnp.minimum(m_scal[S_COMMIT], end3),
-                                state.commit + W)),
-        state.commit)
-    head1 = jnp.where(
-        can_absorb,
-        jnp.maximum(state.head, jnp.minimum(m_scal[S_HEAD], commit1)),
-        state.head)
+        # follower commit/head riding the message (lazy, one step behind the
+        # leader's scan — matching the reference's lazy commit push). The
+        # advance is CLAMPED to W per step: the committed-config checkpoint
+        # (Phase G) scans only the W-entry commit-crossing window, so an
+        # unbounded jump (rejoiner with a long matching log but stale
+        # commit) could carry a CONFIG entry past the scan unseen. W per
+        # step is also the host's apply/replay catch-up rate, so the clamp
+        # costs no end-to-end liveness.
+        commit1 = jnp.where(
+            can_absorb & ~i_lead2,
+            jnp.maximum(state.commit,
+                        jnp.minimum(jnp.minimum(m_scal[S_COMMIT], end3),
+                                    state.commit + W)),
+            state.commit)
+        head1 = jnp.where(
+            can_absorb,
+            jnp.maximum(state.head, jnp.minimum(m_scal[S_HEAD], commit1)),
+            state.head)
 
     # ------------------------------------------------------------------
     # CONFIG derivation — Raft's latest-configuration-in-the-log rule,
@@ -639,99 +648,100 @@ def replica_step(
     # dare_server.c:2133-2187). Runs BEFORE the commit scan (joint
     # consensus needs the new quorum rules from append time).
     # ------------------------------------------------------------------
-    wend_abs = m_wstart + m_wcount
-    # invalidation: source truncated away (divergence backoff or
-    # in-window conflict both leave end3 at/below it) …
-    stale_src = state.cfg_src >= end3
-    # … or overwritten by an absorbed window row that is no longer the
-    # same CONFIG entry
-    wp = jnp.clip(state.cfg_src - m_wstart, 0, W - 1)
-    # same gidx + type is NOT enough: a new leader's conflicting CONFIG
-    # at the same index is a different entry — the term disambiguates
-    same_entry = ((m_meta[wp, M_GIDX] == state.cfg_src)
-                  & (m_meta[wp, M_TYPE] == int(EntryType.CONFIG))
-                  & (m_meta[wp, M_TERM] == state.cfg_src_term))
-    replaced = (can_absorb & (state.cfg_src >= m_wstart)
-                & (state.cfg_src < wend_abs) & ~same_entry)
-    cfg_invalid = (state.cfg_src >= 0) & (stale_src | replaced)
+    with jax.named_scope("cfg_rescan"):
+        wend_abs = m_wstart + m_wcount
+        # invalidation: source truncated away (divergence backoff or
+        # in-window conflict both leave end3 at/below it) …
+        stale_src = state.cfg_src >= end3
+        # … or overwritten by an absorbed window row that is no longer the
+        # same CONFIG entry
+        wp = jnp.clip(state.cfg_src - m_wstart, 0, W - 1)
+        # same gidx + type is NOT enough: a new leader's conflicting CONFIG
+        # at the same index is a different entry — the term disambiguates
+        same_entry = ((m_meta[wp, M_GIDX] == state.cfg_src)
+                      & (m_meta[wp, M_TYPE] == int(EntryType.CONFIG))
+                      & (m_meta[wp, M_TERM] == state.cfg_src_term))
+        replaced = (can_absorb & (state.cfg_src >= m_wstart)
+                    & (state.cfg_src < wend_abs) & ~same_entry)
+        cfg_invalid = (state.cfg_src >= 0) & (stale_src | replaced)
 
-    def _cfg_rescan(_):
-        all_gidx = log3.meta[:, M_GIDX]
-        live = ((log3.meta[:, M_TYPE] == int(EntryType.CONFIG))
-                & (all_gidx >= head1) & (all_gidx < end3))
-        pos = _lex_argmax(live, [all_gidx])
-        found = pos >= 0
-        psafe = jnp.maximum(pos, 0)
-        w = log3.data[psafe]
-        return (jnp.where(found, all_gidx[psafe], -1),
-                jnp.where(found, log3.meta[psafe, M_TERM], 0),
-                jnp.where(found, w[0].astype(jnp.uint32), state.ccfg_old),
-                jnp.where(found, w[1].astype(jnp.uint32), state.ccfg_new),
-                jnp.where(found, w[2], state.ccfg_cid),
-                jnp.where(found, w[3], state.ccfg_epoch))
+        def _cfg_rescan(_):
+            all_gidx = log3.meta[:, M_GIDX]
+            live = ((log3.meta[:, M_TYPE] == int(EntryType.CONFIG))
+                    & (all_gidx >= head1) & (all_gidx < end3))
+            pos = _lex_argmax(live, [all_gidx])
+            found = pos >= 0
+            psafe = jnp.maximum(pos, 0)
+            w = log3.data[psafe]
+            return (jnp.where(found, all_gidx[psafe], -1),
+                    jnp.where(found, log3.meta[psafe, M_TERM], 0),
+                    jnp.where(found, w[0].astype(jnp.uint32), state.ccfg_old),
+                    jnp.where(found, w[1].astype(jnp.uint32), state.ccfg_new),
+                    jnp.where(found, w[2], state.ccfg_cid),
+                    jnp.where(found, w[3], state.ccfg_epoch))
 
-    def _cfg_keep(_):
-        return (state.cfg_src, state.cfg_src_term, state.bitmask_old,
-                state.bitmask_new, state.cid_state, state.epoch)
+        def _cfg_keep(_):
+            return (state.cfg_src, state.cfg_src_term, state.bitmask_old,
+                    state.bitmask_new, state.cid_state, state.epoch)
 
-    (base_src, base_sterm, base_old, base_new, base_cid,
-     base_epoch) = lax.cond(cfg_invalid, _cfg_rescan, _cfg_keep, None)
+        (base_src, base_sterm, base_old, base_new, base_cid,
+         base_epoch) = lax.cond(cfg_invalid, _cfg_rescan, _cfg_keep, None)
 
-    # newest CONFIG in the absorbed window (followers learn configs here)
-    w_offs = jnp.arange(W, dtype=i32)
-    w_gidx = m_wstart + w_offs
-    w_is_cfg = (can_absorb & (w_offs < m_wcount)
-                & (m_meta[:, M_TYPE] == int(EntryType.CONFIG))
-                & (m_meta[:, M_GIDX] == w_gidx)
-                & (w_gidx >= head1) & (w_gidx < end3))
-    wpos = _lex_argmax(w_is_cfg, [w_gidx])
-    w_words = m_data[jnp.maximum(wpos, 0)]
-    w_src = jnp.where(wpos >= 0, m_wstart + wpos, -1)
+        # newest CONFIG in the absorbed window (followers learn configs here)
+        w_offs = jnp.arange(W, dtype=i32)
+        w_gidx = m_wstart + w_offs
+        w_is_cfg = (can_absorb & (w_offs < m_wcount)
+                    & (m_meta[:, M_TYPE] == int(EntryType.CONFIG))
+                    & (m_meta[:, M_GIDX] == w_gidx)
+                    & (w_gidx >= head1) & (w_gidx < end3))
+        wpos = _lex_argmax(w_is_cfg, [w_gidx])
+        w_words = m_data[jnp.maximum(wpos, 0)]
+        w_src = jnp.where(wpos >= 0, m_wstart + wpos, -1)
 
-    # newest CONFIG in the just-appended batch (the leader learns its
-    # own submissions here — its fan-out window may trail its end)
-    Bn = inp.batch_meta.shape[0]
-    b_offs = jnp.arange(Bn, dtype=i32)
-    b_is_cfg = ((b_offs < (end2 - end1))
-                & (inp.batch_meta[:, M_TYPE] == int(EntryType.CONFIG))
-                & ((end1 + b_offs) < end3))
-    bpos = _lex_argmax(b_is_cfg, [b_offs])
-    b_words = inp.batch_data[jnp.maximum(bpos, 0)]
-    b_src = jnp.where(bpos >= 0, end1 + bpos, -1)
+        # newest CONFIG in the just-appended batch (the leader learns its
+        # own submissions here — its fan-out window may trail its end)
+        Bn = inp.batch_meta.shape[0]
+        b_offs = jnp.arange(Bn, dtype=i32)
+        b_is_cfg = ((b_offs < (end2 - end1))
+                    & (inp.batch_meta[:, M_TYPE] == int(EntryType.CONFIG))
+                    & ((end1 + b_offs) < end3))
+        bpos = _lex_argmax(b_is_cfg, [b_offs])
+        b_words = inp.batch_data[jnp.maximum(bpos, 0)]
+        b_src = jnp.where(bpos >= 0, end1 + bpos, -1)
 
-    # adopt the candidate with the largest (gidx, term) — an absorbed
-    # window row at the SAME gidx as the base but a newer term is a new
-    # leader's conflicting CONFIG and must win; ties/absences fall back
-    # to the base cache (index 0)
-    w_term = m_meta[jnp.maximum(wpos, 0), M_TERM]
-    cand_src = jnp.stack([base_src, w_src, b_src])
-    cand_sterm = jnp.stack([
-        base_sterm, jnp.where(wpos >= 0, w_term, 0),
-        jnp.where(bpos >= 0, new_term, 0)])
-    cand_old = jnp.stack([base_old, w_words[0].astype(jnp.uint32),
-                          b_words[0].astype(jnp.uint32)])
-    cand_new = jnp.stack([base_new, w_words[1].astype(jnp.uint32),
-                          b_words[1].astype(jnp.uint32)])
-    cand_cid = jnp.stack([base_cid, w_words[2], b_words[2]])
-    cand_epoch = jnp.stack([base_epoch, w_words[3], b_words[3]])
-    pick = _lex_argmax(cand_src >= -1, [cand_src, cand_sterm])
-    pick = jnp.maximum(pick, 0)
-    cfg_src2 = cand_src[pick]
-    cfg_src_term2 = cand_sterm[pick]
-    bm_old2 = cand_old[pick]
-    bm_new2 = cand_new[pick]
-    cid2 = cand_cid[pick]
-    epoch2 = cand_epoch[pick]
-    in_new2 = _popcount_vec(bm_new2, R)
-    in_old2 = _popcount_vec(bm_old2, R)
-    maj_old2 = jnp.sum(in_old2) // 2 + 1
-    transit2 = (cid2 == int(ConfigState.TRANSIT)).astype(i32)
-    # EXTENDED post-absorb: commit quorum on the old config (joiner
-    # replicates but does not count) — same rule as the pre-step masks
-    ext2 = cid2 == int(ConfigState.EXTENDED)
-    q_mask2 = jnp.where(ext2, bm_old2, bm_new2)
-    in_q2 = _popcount_vec(q_mask2, R)
-    maj_q2 = jnp.sum(in_q2) // 2 + 1
+        # adopt the candidate with the largest (gidx, term) — an absorbed
+        # window row at the SAME gidx as the base but a newer term is a new
+        # leader's conflicting CONFIG and must win; ties/absences fall back
+        # to the base cache (index 0)
+        w_term = m_meta[jnp.maximum(wpos, 0), M_TERM]
+        cand_src = jnp.stack([base_src, w_src, b_src])
+        cand_sterm = jnp.stack([
+            base_sterm, jnp.where(wpos >= 0, w_term, 0),
+            jnp.where(bpos >= 0, new_term, 0)])
+        cand_old = jnp.stack([base_old, w_words[0].astype(jnp.uint32),
+                              b_words[0].astype(jnp.uint32)])
+        cand_new = jnp.stack([base_new, w_words[1].astype(jnp.uint32),
+                              b_words[1].astype(jnp.uint32)])
+        cand_cid = jnp.stack([base_cid, w_words[2], b_words[2]])
+        cand_epoch = jnp.stack([base_epoch, w_words[3], b_words[3]])
+        pick = _lex_argmax(cand_src >= -1, [cand_src, cand_sterm])
+        pick = jnp.maximum(pick, 0)
+        cfg_src2 = cand_src[pick]
+        cfg_src_term2 = cand_sterm[pick]
+        bm_old2 = cand_old[pick]
+        bm_new2 = cand_new[pick]
+        cid2 = cand_cid[pick]
+        epoch2 = cand_epoch[pick]
+        in_new2 = _popcount_vec(bm_new2, R)
+        in_old2 = _popcount_vec(bm_old2, R)
+        maj_old2 = jnp.sum(in_old2) // 2 + 1
+        transit2 = (cid2 == int(ConfigState.TRANSIT)).astype(i32)
+        # EXTENDED post-absorb: commit quorum on the old config (joiner
+        # replicates but does not count) — same rule as the pre-step masks
+        ext2 = cid2 == int(ConfigState.EXTENDED)
+        q_mask2 = jnp.where(ext2, bm_old2, bm_new2)
+        in_q2 = _popcount_vec(q_mask2, R)
+        maj_q2 = jnp.sum(in_q2) // 2 + 1
 
     # ------------------------------------------------------------------
     # Phase F — ACK + quorum commit. The ack is the *verified match
@@ -741,82 +751,85 @@ def replica_step(
     # itself is ops/quorum.commit_scan (Pallas on TPU), under the
     # POST-absorb membership config.
     # ------------------------------------------------------------------
-    my_ack = jnp.where(can_absorb, m_wstart + m_wcount, 0).astype(i32)
-    ack_pair = jnp.stack([my_ack, jnp.where(can_absorb, dom, -1)])
-    g_acks = lax.all_gather(ack_pair, axis_name)            # [R, 2]
-    acks_for_me = jnp.where(heard & (g_acks[:, 1] == me), g_acks[:, 0], 0)
-    acks_pad = jnp.zeros((R_PAD,), i32).at[:R].set(acks_for_me)
+    with jax.named_scope("ack_quorum"):
+        my_ack = jnp.where(can_absorb, m_wstart + m_wcount, 0).astype(i32)
+        ack_pair = jnp.stack([my_ack, jnp.where(can_absorb, dom, -1)])
+        g_acks = lax.all_gather(ack_pair, axis_name)            # [R, 2]
+        acks_for_me = jnp.where(heard & (g_acks[:, 1] == me), g_acks[:, 0], 0)
+        acks_pad = jnp.zeros((R_PAD,), i32).at[:R].set(acks_for_me)
 
-    cwin_g = state.commit + jnp.arange(W, dtype=i32)
-    cwin_meta = log3.meta[slot_of(cwin_g, cfg.n_slots)]     # [W, META_W]
-    terms_win = cwin_meta[:, M_TERM]
-    scanned = commit_scan(
-        acks_pad, state.commit, new_term2, end3, terms_win,
-        bm_old2, q_mask2, transit2, maj_old2, maj_q2,
-        use_pallas=use_pallas, interpret=interpret)
-    commit2 = jnp.where(i_lead2, jnp.maximum(state.commit, scanned), commit1)
+        cwin_g = state.commit + jnp.arange(W, dtype=i32)
+        cwin_meta = log3.meta[slot_of(cwin_g, cfg.n_slots)]     # [W, META_W]
+        terms_win = cwin_meta[:, M_TERM]
+        scanned = commit_scan(
+            acks_pad, state.commit, new_term2, end3, terms_win,
+            bm_old2, q_mask2, transit2, maj_old2, maj_q2,
+            use_pallas=use_pallas, interpret=interpret)
+        commit2 = jnp.where(
+            i_lead2, jnp.maximum(state.commit, scanned), commit1)
 
     # ------------------------------------------------------------------
     # Phase G — apply echo, pruning, CONFIG application.
     # ------------------------------------------------------------------
-    apply2 = jnp.clip(jnp.maximum(state.apply, inp.apply_done),
-                      head1, commit2)
-    # Pruning is lazy and pressure-gated, like the reference: the periodic
-    # pruner only trims what every reachable member has applied
-    # (log_pruning P1/P2/P3 invariants, dare_server.c:1996-2067), and only
-    # once the ring is 3/4 full — so a transiently-partitioned laggard can
-    # still catch up from the log; one pruned past must snapshot-recover
-    # (host path), which is exactly the reference's straggler-eviction
-    # semantics.
-    pressure = (end3 - head1) > (3 * cfg.n_slots) // 4
-    head2 = jnp.where(
-        i_lead2 & pressure,
-        jnp.clip(jnp.maximum(head1, min_apply), head1, apply2),
-        head1)
-    # FORCED pruning (force_log_pruning analog, dare_server.c:2069-2122):
-    # a reachable member whose apply is frozen (wedged app) must not
-    # block the ring forever. Under HARD pressure (7/8 full) the leader
-    # advances the head past the laggard, bounded by its OWN applied
-    # offset — every recycled entry is applied + persisted on the leader,
-    # so the left-behind member can snapshot-recover from its store. The
-    # laggard's host detects head > its apply cursor and stops replaying
-    # (recycled slots must never reach the app) — see
-    # SimCluster._replay_committed / need_recovery.
-    hard = (end3 - head1) > (7 * cfg.n_slots) // 8
-    head2 = jnp.where(i_lead2 & hard, jnp.maximum(head2, apply2), head2)
+    with jax.named_scope("apply_prune"):
+        apply2 = jnp.clip(jnp.maximum(state.apply, inp.apply_done),
+                          head1, commit2)
+        # Pruning is lazy and pressure-gated, like the reference: the periodic
+        # pruner only trims what every reachable member has applied
+        # (log_pruning P1/P2/P3 invariants, dare_server.c:1996-2067), and
+        # only once the ring is 3/4 full — so a transiently-partitioned
+        # laggard can still catch up from the log; one pruned past must
+        # snapshot-recover (host path), which is exactly the reference's
+        # straggler-eviction semantics.
+        pressure = (end3 - head1) > (3 * cfg.n_slots) // 4
+        head2 = jnp.where(
+            i_lead2 & pressure,
+            jnp.clip(jnp.maximum(head1, min_apply), head1, apply2),
+            head1)
+        # FORCED pruning (force_log_pruning analog, dare_server.c:2069-2122):
+        # a reachable member whose apply is frozen (wedged app) must not
+        # block the ring forever. Under HARD pressure (7/8 full) the leader
+        # advances the head past the laggard, bounded by its OWN applied
+        # offset — every recycled entry is applied + persisted on the leader,
+        # so the left-behind member can snapshot-recover from its store. The
+        # laggard's host detects head > its apply cursor and stops replaying
+        # (recycled slots must never reach the app) — see
+        # SimCluster._replay_committed / need_recovery.
+        hard = (end3 - head1) > (7 * cfg.n_slots) // 8
+        head2 = jnp.where(i_lead2 & hard, jnp.maximum(head2, apply2), head2)
 
-    # committed-config checkpoint: a CONFIG entry below commit can never
-    # be truncated (backoff floors at commit), so it becomes the
-    # fallback when the ring holds no live CONFIG entry (pruned past, or
-    # every newer CONFIG was truncated). Incremental form: (a) promote
-    # the live cache once its source entry commits; (b) scan the
-    # commit-CROSSING window [state.commit, commit2) — bounded by W —
-    # for an older CONFIG committing while a newer uncommitted one is
-    # cached (two-configs-in-flight; the driver serializes changes so
-    # this is a churn-replay corner). Newest-wins by epoch (epochs are
-    # strictly increasing along the committed config order by
-    # construction — MembershipManager bumps per change, and elastic
-    # genesis re-types old-world CONFIGs to NOOP).
-    crossed = ((cwin_meta[:, M_TYPE] == int(EntryType.CONFIG))
-               & (cwin_meta[:, M_GIDX] == cwin_g)
-               & (cwin_g < commit2))
-    xpos = _lex_argmax(crossed, [cwin_g])
-    xw = log3.data[slot_of(state.commit + jnp.maximum(xpos, 0),
-                           cfg.n_slots)]
-    x_found = xpos >= 0
-    cc1_old = jnp.where(x_found & (xw[3] > state.ccfg_epoch),
-                        xw[0].astype(jnp.uint32), state.ccfg_old)
-    cc1_new = jnp.where(x_found & (xw[3] > state.ccfg_epoch),
-                        xw[1].astype(jnp.uint32), state.ccfg_new)
-    cc1_cid = jnp.where(x_found & (xw[3] > state.ccfg_epoch),
-                        xw[2], state.ccfg_cid)
-    cc1_epoch = jnp.where(x_found & (xw[3] > state.ccfg_epoch),
-                          xw[3], state.ccfg_epoch)
-    promote = (cfg_src2 >= 0) & (cfg_src2 < commit2) & (epoch2 > cc1_epoch)
-    ccfg_old2 = jnp.where(promote, bm_old2, cc1_old)
-    ccfg_new2 = jnp.where(promote, bm_new2, cc1_new)
-    ccfg_cid2 = jnp.where(promote, cid2, cc1_cid)
-    ccfg_epoch2 = jnp.where(promote, epoch2, cc1_epoch)
+        # committed-config checkpoint: a CONFIG entry below commit can never
+        # be truncated (backoff floors at commit), so it becomes the
+        # fallback when the ring holds no live CONFIG entry (pruned past, or
+        # every newer CONFIG was truncated). Incremental form: (a) promote
+        # the live cache once its source entry commits; (b) scan the
+        # commit-CROSSING window [state.commit, commit2) — bounded by W —
+        # for an older CONFIG committing while a newer uncommitted one is
+        # cached (two-configs-in-flight; the driver serializes changes so
+        # this is a churn-replay corner). Newest-wins by epoch (epochs are
+        # strictly increasing along the committed config order by
+        # construction — MembershipManager bumps per change, and elastic
+        # genesis re-types old-world CONFIGs to NOOP).
+        crossed = ((cwin_meta[:, M_TYPE] == int(EntryType.CONFIG))
+                   & (cwin_meta[:, M_GIDX] == cwin_g)
+                   & (cwin_g < commit2))
+        xpos = _lex_argmax(crossed, [cwin_g])
+        xw = log3.data[slot_of(state.commit + jnp.maximum(xpos, 0),
+                               cfg.n_slots)]
+        x_found = xpos >= 0
+        cc1_old = jnp.where(x_found & (xw[3] > state.ccfg_epoch),
+                            xw[0].astype(jnp.uint32), state.ccfg_old)
+        cc1_new = jnp.where(x_found & (xw[3] > state.ccfg_epoch),
+                            xw[1].astype(jnp.uint32), state.ccfg_new)
+        cc1_cid = jnp.where(x_found & (xw[3] > state.ccfg_epoch),
+                            xw[2], state.ccfg_cid)
+        cc1_epoch = jnp.where(x_found & (xw[3] > state.ccfg_epoch),
+                              xw[3], state.ccfg_epoch)
+        promote = (cfg_src2 >= 0) & (cfg_src2 < commit2) & (epoch2 > cc1_epoch)
+        ccfg_old2 = jnp.where(promote, bm_old2, cc1_old)
+        ccfg_new2 = jnp.where(promote, bm_new2, cc1_new)
+        ccfg_cid2 = jnp.where(promote, cid2, cc1_cid)
+        ccfg_epoch2 = jnp.where(promote, epoch2, cc1_epoch)
 
     # ------------------------------------------------------------------
     # Silent-divergence audit digests (audit=True only; statically
@@ -840,17 +853,18 @@ def replica_step(
     # ring retains at most n_slots - 1 live entries).
     audit_start = audit_digest = audit_terms = None
     if audit:
-        u32 = jnp.uint32
-        a_g = (commit2 - W) + jnp.arange(W, dtype=i32)
-        audit_start = jnp.maximum(jnp.maximum(commit2 - W, head2), 0)
-        a_valid = a_g >= audit_start
-        a_rows = log3.buf[slot_of(a_g, cfg.n_slots)].astype(u32)
-        # the fold lives in digest_fold — shared with the range
-        # re-digest program and the host-side snapshot verification,
-        # so no digest producer can drift from another
-        audit_digest = jnp.where(a_valid, digest_fold(a_rows), u32(0))
-        audit_terms = jnp.where(
-            a_valid, a_rows[:, cfg.slot_words + M_TERM].astype(i32), 0)
+        with jax.named_scope("audit_digest"):
+            u32 = jnp.uint32
+            a_g = (commit2 - W) + jnp.arange(W, dtype=i32)
+            audit_start = jnp.maximum(jnp.maximum(commit2 - W, head2), 0)
+            a_valid = a_g >= audit_start
+            a_rows = log3.buf[slot_of(a_g, cfg.n_slots)].astype(u32)
+            # the fold lives in digest_fold — shared with the range
+            # re-digest program and the host-side snapshot verification,
+            # so no digest producer can drift from another
+            audit_digest = jnp.where(a_valid, digest_fold(a_rows), u32(0))
+            audit_terms = jnp.where(
+                a_valid, a_rows[:, cfg.slot_words + M_TERM].astype(i32), 0)
 
     # ------------------------------------------------------------------
     # Device telemetry (telemetry=True only; statically removed
@@ -865,26 +879,27 @@ def replica_step(
     # ------------------------------------------------------------------
     telemetry_vec = None
     if telemetry:
-        if elections:
-            t_elec = i_cand.astype(i32)
-            # granted = voted for ANOTHER replica's candidacy this
-            # step; denied = heard candidacies (own excluded) that did
-            # not get this replica's vote
-            t_grant = (vote_cast & (my_vote != me)).astype(i32)
-            n_cand = jnp.sum((is_cand & heard).astype(i32))
-            t_deny = jnp.maximum(n_cand - t_elec - t_grant, 0)
-        else:
-            t_elec = t_grant = t_deny = jnp.zeros((), i32)
-        telemetry_vec = jnp.stack([
-            t_elec,
-            t_grant,
-            t_deny,
-            (end2 - end1).astype(i32),
-            (commit2 - state.commit).astype(i32),
-            (R - jnp.sum(heard.astype(i32))).astype(i32),
-            jnp.sum((heard & (g_acks[:, 1] == me)).astype(i32)),
-            ((cfg.n_slots - 1) - (end3 - head2)).astype(i32),
-        ]).astype(jnp.uint32)
+        with jax.named_scope("telemetry"):
+            if elections:
+                t_elec = i_cand.astype(i32)
+                # granted = voted for ANOTHER replica's candidacy this
+                # step; denied = heard candidacies (own excluded) that did
+                # not get this replica's vote
+                t_grant = (vote_cast & (my_vote != me)).astype(i32)
+                n_cand = jnp.sum((is_cand & heard).astype(i32))
+                t_deny = jnp.maximum(n_cand - t_elec - t_grant, 0)
+            else:
+                t_elec = t_grant = t_deny = jnp.zeros((), i32)
+            telemetry_vec = jnp.stack([
+                t_elec,
+                t_grant,
+                t_deny,
+                (end2 - end1).astype(i32),
+                (commit2 - state.commit).astype(i32),
+                (R - jnp.sum(heard.astype(i32))).astype(i32),
+                jnp.sum((heard & (g_acks[:, 1] == me)).astype(i32)),
+                ((cfg.n_slots - 1) - (end3 - head2)).astype(i32),
+            ]).astype(jnp.uint32)
 
     # ------------------------------------------------------------------
     # Cross-group transaction prepare-vote lane (txn=True only;
@@ -1074,4 +1089,5 @@ def fetch_window(log: Log, start: jax.Array, *, window_slots: int):
     used by the driver to read newly committed payloads for replay/persist
     (the analog of apply_committed_entries walking the log,
     ``dare_server.c:1815-1974``)."""
-    return extract_window(log, start, window_slots)
+    with jax.named_scope("replay_fetch"):
+        return extract_window(log, start, window_slots)

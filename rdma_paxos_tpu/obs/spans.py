@@ -23,15 +23,58 @@ inside the optional fencing path):
   keys are dictionary misses.
 
 * :class:`StepPhaseProfiler` — attributes driver/daemon hot-loop wall
-  time to phases (host encode, device dispatch, device sync, quorum
-  wait, apply, ack release) and feeds the existing histogram registry
-  (``step_phase_us{phase=...}``). Device sync is measured via explicit
+  time to nested phases (exact ``(count, total, max)`` sums in
+  ``acc``; the seven coarse ones — host encode, device dispatch, device
+  sync, quorum wait, apply, ack release, apply/replay/ack — also feed
+  the histogram registry, ``step_phase_us{phase=...}``) and closes the
+  account of each loop cycle with a residual. Device sync is measured via explicit
   ``jax.block_until_ready`` fencing — OFF by default, because without
   a fence the dispatch phase deliberately conflates enqueue with
   device time (the async-dispatch norm) and fencing serializes the
   pipeline; with ``fence=True`` the sync cost lands in its own
   ``device_sync`` series. Fencing changes no compiled programs
   (``tests/test_spans.py`` guards compiled-step cache keys).
+
+  What a benchmark's probe reads of it (``perfbench``'s
+  ``DriverDeployment.probe`` exports every ``acc`` key as
+  ``phase.<name>.count`` / ``phase.<name>.total_us`` and every registry
+  counter, summed over its labels, as ``counter.<name>``). Totals are
+  INCLUSIVE; indentation is containment on the serial loop:
+
+  ==================== ==============================================
+  ``cycle``            one loop iteration (or one ticket on the
+                       readback thread); never in the event ring
+    ``dispatch_gate``  ``_pipeline_ready``, ``_can_idle_skip``, ``_busy``
+    ``pipeline_wait``  dispatch thread waiting for the readback thread
+    ``idle_wait``      parked on ``_wake`` (idle park, ``period`` wait)
+    ``admin_pump``     ``_drain_admin`` + ``_pump_submitq``
+    ``host_encode``    batch pack (holds ``input_transfer`` on the
+                       single-step path)
+    ``device_dispatch`` program enqueue (holds ``input_transfer`` on the
+                       burst path)
+    ``device_sync``    ``fence=True`` only
+    ``quorum_wait``    block on the step, then ``readback_rest``: every
+                       output read after the first
+    ``post_readback``  audit ingest, telemetry, stamps, requeue
+    ``apply``          ``replay_fetch`` (fetch bind + both host reads),
+                       ``replay_decode`` (``decode_window``)
+    ``finish_tail``    flight record, rebase, spans, leases, reads
+    ``post_step_rules`` ``_post_step`` but for the two below
+    ``apply_replay_ack`` ``store_append``, ``replay_send``,
+                       ``replay_drain``, ``ack_release``
+    ``observe``        ``_observe_step`` + the alert/health cadences
+    ``profiler``       this class's bookkeeping round the above
+    ``unattributed``   the rest of the cycle
+  ``intake_to_ack``    per operation: ``PendingEvent.t0`` -> ack release
+  ``intake_queue_wait`` per operation: ``t0`` -> the pump that dispatches
+  ==================== ==============================================
+
+  Counters: ``readback_arrays_total`` (device-to-host reads in
+  ``quorum_wait``), ``replay_applies_total`` (calls into
+  ``ReplayEngine.apply``), ``phase_stalls_total`` and
+  ``phase_stall_us_total{phase}`` (a phase instance longer than
+  ``TimeoutConfig.elec_timeout_low``; each also leaves one
+  ``phase_stall`` event in the trace ring).
 
 * Chrome trace-event export — :func:`to_chrome_trace` merges one or
   more span dumps (aligned on the shared
@@ -59,8 +102,12 @@ import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from rdma_paxos_tpu.config import TimeoutConfig
 from rdma_paxos_tpu.obs.clock import anchor as clock_anchor
 from rdma_paxos_tpu.obs.metrics import LATENCY_BUCKETS_US
+from rdma_paxos_tpu.obs.trace import PHASE_STALL
+
+_now_ns = time.perf_counter_ns
 
 # ---------------------------------------------------------------------------
 # span phases (the causal chain of one client command)
@@ -533,7 +580,12 @@ def active_recorder(obs) -> Optional[SpanRecorder]:
 # step-phase profiler
 # ---------------------------------------------------------------------------
 
-# the attributable hot-loop phases (one histogram series per phase)
+# the attributable hot-loop phases. The first seven are the coarse
+# account (one ``step_phase_us{phase=}`` histogram each); the rest
+# split them and name what lay between them, and live only in the exact
+# ``acc`` sums and the event ring (a histogram is 18 series in the
+# 0.25 s sampling store: the ``observe`` phase would pay for its own
+# detail).
 PHASE_HOST_ENCODE = "host_encode"        # batch pack / input build
 PHASE_DEVICE_DISPATCH = "device_dispatch"  # program enqueue (async)
 PHASE_DEVICE_SYNC = "device_sync"        # explicit fence (opt-in)
@@ -544,6 +596,30 @@ PHASE_APPLY_REPLAY_ACK = "apply_replay_ack"  # driver store/replay/ack
                                          # sweep (whole-batch, per
                                          # replica) — the host_path
                                          # A/B attribution phase
+PHASE_CYCLE = "cycle"                    # one _dispatch_loop iteration
+PHASE_UNATTRIBUTED = "unattributed"      # cycle minus its direct children
+PHASE_PROFILER = "profiler"              # of a cycle: start/stop bookkeeping
+                                         # round its direct children
+PHASE_ADMIN_PUMP = "admin_pump"          # _drain_admin + _pump_submitq
+PHASE_DISPATCH_GATE = "dispatch_gate"    # _pipeline_ready / drain /
+                                         # _can_idle_skip / _busy
+PHASE_IDLE_WAIT = "idle_wait"            # parked on _wake
+PHASE_PIPELINE_WAIT = "pipeline_wait"    # dispatch thread waits for the
+                                         # readback thread (drain / depth)
+PHASE_INPUT_TRANSFER = "input_transfer"  # jnp.asarray of step inputs
+PHASE_READBACK_REST = "readback_rest"    # output reads after the first
+PHASE_POST_READBACK = "post_readback"    # finish: quorum_wait -> apply
+PHASE_REPLAY_FETCH = "replay_fetch"      # fetch bind + both host reads
+PHASE_REPLAY_DECODE = "replay_decode"    # decode_window
+PHASE_FINISH_TAIL = "finish_tail"        # finish: after apply
+PHASE_STORE_APPEND = "store_append"      # framed blobs -> StableStore
+PHASE_REPLAY_SEND = "replay_send"        # ReplayEngine.apply loop
+PHASE_REPLAY_DRAIN = "replay_drain"      # ReplayEngine.drain_responses
+PHASE_POST_STEP_RULES = "post_step_rules"  # _post_step minus apply/observe
+PHASE_OBSERVE = "observe"                # _observe_step + cadences
+# per operation, credited (no start/stop, no ring entry)
+OP_INTAKE_TO_ACK = "intake_to_ack"       # PendingEvent.t0 -> release
+OP_INTAKE_QUEUE_WAIT = "intake_queue_wait"  # PendingEvent.t0 -> pump
 
 
 class StepPhaseProfiler:
@@ -559,25 +635,69 @@ class StepPhaseProfiler:
     shrinks to the readback. Fencing serializes the dispatch pipeline —
     it is a profiling mode, off by default, and changes no compiled
     programs (cache-key guarded).
+
+    Phases NEST, per thread: ``start`` pushes on the calling thread's
+    stack, ``stop`` pops, and every total in ``acc`` is INCLUSIVE of
+    the phases nested in it. ``cycle`` is the one container (one
+    iteration of the dispatch loop, or one ticket's finish and post-step
+    on the readback thread of the pipelined loop): at its stop, ``profiler`` is credited with this class's own bookkeeping
+    round the cycle's direct children and ``unattributed`` with the
+    rest of the cycle that no direct child covers, so direct children
+    + ``profiler`` + ``unattributed`` = ``cycle``; it never enters the
+    event ring (a span over everything would name every device-idle
+    gap), nor does ``pipeline_wait``, which spans the other thread's
+    whole cycle. A phase instance
+    that runs (waits apart) longer than :attr:`STALL_US` leaves one
+    ``phase_stall`` trace event (innermost phase only) and adds to ``phase_stalls_total`` /
+    ``phase_stall_us_total{phase}``.
     """
 
     BUCKETS_US = LATENCY_BUCKETS_US
     PHASES = (PHASE_HOST_ENCODE, PHASE_DEVICE_DISPATCH,
               PHASE_DEVICE_SYNC, PHASE_QUORUM_WAIT, PHASE_APPLY,
               PHASE_ACK_RELEASE, PHASE_APPLY_REPLAY_ACK)
+    _HISTOGRAMS = frozenset(PHASES)
+    DETAIL = (PHASE_CYCLE, PHASE_UNATTRIBUTED, PHASE_PROFILER,
+              PHASE_ADMIN_PUMP, PHASE_DISPATCH_GATE, PHASE_IDLE_WAIT,
+              PHASE_PIPELINE_WAIT, PHASE_INPUT_TRANSFER,
+              PHASE_READBACK_REST, PHASE_POST_READBACK,
+              PHASE_REPLAY_FETCH, PHASE_REPLAY_DECODE, PHASE_FINISH_TAIL,
+              PHASE_STORE_APPEND, PHASE_REPLAY_SEND, PHASE_REPLAY_DRAIN,
+              PHASE_POST_STEP_RULES, PHASE_OBSERVE, OP_INTAKE_TO_ACK,
+              OP_INTAKE_QUEUE_WAIT)
+    COUNTERS = ("readback_arrays_total", "replay_applies_total")
+    # a thread waiting by design: its length counts towards no stall,
+    # its own or of the phase it waits in
+    WAITS = (PHASE_IDLE_WAIT, PHASE_PIPELINE_WAIT)
+    # spans over (nearly) everything another thread does: in the event
+    # ring they would name every device-idle gap
+    NOT_IN_RING = (PHASE_CYCLE, PHASE_PIPELINE_WAIT)
+    # the length at which a stall of the loop starts costing elections
+    STALL_US = TimeoutConfig().elec_timeout_low * 1e6
 
     def __init__(self, metrics=None, *, fence: bool = False,
-                 replica: int = -1):
+                 replica: int = -1, trace=None, step_index=None):
         self.metrics = metrics           # MetricsRegistry or None
         self.fence = fence
         self.replica = replica
-        self.acc: Dict[str, Tuple[int, float, float]] = {}
-        self._open: Dict[str, int] = {}
+        self.trace = trace               # obs.trace.TraceRing or None
+        self.step_index = step_index     # () -> int, stamps stall events
+        # every key exists from the start: a reader's dict(acc) never
+        # sees the dict change size, and a phase that never ran reads 0
+        self.acc: Dict[str, Tuple[int, float, float]] = {
+            p: (0, 0.0, 0.0) for p in self.PHASES + self.DETAIL}
+        self._lock = threading.Lock()    # acc read-modify-write
+        self._tls = threading.local()    # .stack: one per thread
         # opt-in timestamped phase slices (enable_events): the
         # host-phase TRACK of the merged device timeline
         # (obs.device.merge_timeline) — histograms alone cannot place
         # a phase on a wall-clock axis
         self.events: Optional[collections.deque] = None
+        if metrics is not None:
+            for name in self.COUNTERS:
+                metrics.inc(name, 0)
+            metrics.inc("phase_stalls_total", 0)
+            metrics.inc("phase_stall_us_total", 0, phase=PHASE_CYCLE)
 
     def enable_events(self, capacity: int = 65536) -> None:
         """Record ``(phase, t0_monotonic, t1_monotonic)`` triples in a
@@ -585,23 +705,95 @@ class StepPhaseProfiler:
         extra clock read per stop)."""
         self.events = collections.deque(maxlen=capacity)
 
+    def _stack(self) -> list:
+        try:
+            return self._tls.stack
+        except AttributeError:
+            self._tls.stack = []
+            return self._tls.stack
+
     def start(self, phase: str) -> None:
-        self._open[phase] = time.perf_counter_ns()
+        t0 = _now_ns()
+        try:
+            stack = self._tls.stack
+        except AttributeError:           # this thread's first phase
+            stack = self._tls.stack = []
+        for i, frame in enumerate(stack):
+            if frame[0] == phase:
+                # no phase nests in itself: this one was abandoned by
+                # an exception between its start and stop
+                del stack[i:]
+                break
+        # [phase, t0, a child already stalled, ns spent waiting by
+        #  design (WAITS), and for a cycle: its direct children's ns
+        #  with their stop() bookkeeping, of which bookkeeping]
+        stack.append([phase, t0, False, 0, 0, 0])
 
     def stop(self, phase: str) -> None:
-        t0 = self._open.pop(phase, None)
-        if t0 is None:
-            return
-        us = (time.perf_counter_ns() - t0) / 1e3
-        n, tot, mx = self.acc.get(phase, (0, 0.0, 0.0))
-        self.acc[phase] = (n + 1, tot + us, max(mx, us))
-        if self.events is not None:
+        now = _now_ns()
+        stack = self._stack()
+        i = len(stack) - 1
+        while i >= 0 and stack[i][0] != phase:
+            i -= 1
+        if i < 0:
+            return                       # never started: as before
+        _, t0, child_stalled, wait_ns, child_ns, own_ns = stack[i]
+        del stack[i:]                    # frames above it were abandoned
+        ns = now - t0
+        us = ns / 1e3
+        self.credit(phase, us)
+        if phase == PHASE_CYCLE:
+            # the account closes: named children + this method's own
+            # adds round them + the residual
+            self.credit(PHASE_PROFILER, own_ns / 1e3)
+            self.credit(PHASE_UNATTRIBUTED, max(ns - child_ns, 0) / 1e3)
+        if self.events is not None and phase not in self.NOT_IN_RING:
             t1m = time.monotonic()
             self.events.append((phase, t1m - us / 1e6, t1m))
-        if self.metrics is not None:
+        if phase in self.WAITS:
+            wait_ns = ns                 # waiting is no stall
+        if stack:
+            stack[-1][3] += wait_ns
+        if ns - wait_ns >= self.STALL_US * 1e3:
+            if stack:
+                stack[-1][2] = True
+            if not child_stalled:        # a parent's stall is its child's
+                self._stall(phase, (ns - wait_ns) / 1e3)
+        if self.metrics is not None and phase in self._HISTOGRAMS:
             self.metrics.observe("step_phase_us", us,
                                  buckets=self.BUCKETS_US, phase=phase,
                                  replica=self.replica)
+        if len(stack) == 1 and stack[0][0] == PHASE_CYCLE:
+            # seen from its cycle this phase lasted until here: what
+            # that exceeds ``ns`` by is the profiler's own cost, never
+            # the cycle's residual
+            span = _now_ns() - t0
+            stack[0][4] += span
+            stack[0][5] += span - ns
+
+    def credit(self, name: str, total_us: float, n: int = 1) -> None:
+        """Add ``n`` samples totalling ``total_us`` to ``acc[name]``
+        with no clock read and no ring entry: how a per-operation sum
+        (``intake_to_ack``) costs one add an operation."""
+        with self._lock:
+            cnt, tot, mx = self.acc.get(name, (0, 0.0, 0.0))
+            self.acc[name] = (cnt + n, tot + total_us,
+                              max(mx, total_us / n))
+
+    def count(self, counter: str, n: int) -> None:
+        """Bump one of :attr:`COUNTERS` (work done inside a phase)."""
+        if self.metrics is not None:
+            self.metrics.inc(counter, n)
+
+    def _stall(self, phase: str, us: float) -> None:
+        if self.metrics is not None:
+            self.metrics.inc("phase_stalls_total")
+            self.metrics.inc("phase_stall_us_total", us, phase=phase)
+        if self.trace is not None:
+            self.trace.record(
+                PHASE_STALL, phase=phase, us=round(us, 1),
+                step=(int(self.step_index())
+                      if self.step_index is not None else -1))
 
     def sync(self, outputs) -> None:
         """Explicit device fence: block until ``outputs`` are ready,
@@ -615,22 +807,23 @@ class StepPhaseProfiler:
         self.stop(PHASE_DEVICE_SYNC)
 
     def sums(self) -> Dict[str, dict]:
-        """Per-phase ``{n, total_us, max_us}`` sums with zero-sample
-        phases SUPPRESSED — the one exporter benches embed in their
-        detail rows, so A/B tables never carry dead columns (e.g. a
-        ``device_sync`` row when ``fence=`` is off)."""
+        """THE public read: per-phase ``{n, total_us, max_us}``,
+        inclusive of nested phases, with zero-sample phases SUPPRESSED
+        — benches embed it in their detail rows, so A/B tables never
+        carry dead columns (e.g. a ``device_sync`` row when ``fence=``
+        is off). ``acc`` itself stays readable for the benchmark's
+        probe, which wants the zero rows."""
+        with self._lock:
+            acc = dict(self.acc)
         return {p: dict(n=a[0], total_us=round(a[1], 1),
                         max_us=round(a[2], 1))
-                for p, a in sorted(self.acc.items()) if a[0] > 0}
+                for p, a in sorted(acc.items()) if a[0] > 0}
 
     def report(self) -> str:
-        lines = []
-        for phase, (n, tot, mx) in sorted(self.acc.items()):
-            if n == 0:
-                continue          # zero-sample phases carry no signal
-            lines.append(f"{phase}: n={n} mean={tot / max(n, 1):.1f}us "
-                         f"max={mx:.1f}us")
-        return "\n".join(lines)
+        return "\n".join(
+            f"{phase}: n={s['n']} mean={s['total_us'] / s['n']:.1f}us "
+            f"max={s['max_us']:.1f}us"
+            for phase, s in self.sums().items())
 
 
 # ---------------------------------------------------------------------------

@@ -69,6 +69,7 @@ GOVERNOR_TIER = "governor_tier"          # dispatch tier changed
 GOVERNOR_SHED = "governor_shed"          # SLO burn pager dropped tier to serial
 GOVERNOR_RESUME = "governor_resume"      # shed latch cleared (pager resolved)
 IDLE_QUIESCE = "idle_quiesce"            # poll loop entered idle quiescence
+PHASE_STALL = "phase_stall"              # a loop phase outlasted elec_timeout_low
 TOPOLOGY_PROPOSED = "topology_proposed"  # policy proposed a split/merge
 TOPOLOGY_SEEDED = "topology_seeded"      # migrating range copied to targets
 TOPOLOGY_VERIFIED = "topology_verified"  # range digests matched pre-cutover

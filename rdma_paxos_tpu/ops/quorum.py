@@ -61,32 +61,37 @@ def _scan_math(ends, commit, my_term, my_end, terms_win, bm_old, bm_new,
                transit, maj_old, maj_new, W):
     """Shared scan body: ends [R_PAD] i32 (non-members already zeroed) ->
     new commit (scalar i32, >= commit)."""
-    j = jax.lax.broadcasted_iota(jnp.int32, (W, R_PAD), 0)    # entry row
-    r = jax.lax.broadcasted_iota(jnp.int32, (W, R_PAD), 1)    # replica col
+    with jax.named_scope("commit_scan"):
+        j = jax.lax.broadcasted_iota(jnp.int32, (W, R_PAD), 0)  # entry row
+        r = jax.lax.broadcasted_iota(jnp.int32, (W, R_PAD), 1)  # replica col
 
-    in_old = jnp.bitwise_and(
-        jnp.right_shift(bm_old, r.astype(jnp.uint32)), 1).astype(jnp.int32)
-    in_new = jnp.bitwise_and(
-        jnp.right_shift(bm_new, r.astype(jnp.uint32)), 1).astype(jnp.int32)
+        in_old = jnp.bitwise_and(
+            jnp.right_shift(bm_old, r.astype(jnp.uint32)),
+            1).astype(jnp.int32)
+        in_new = jnp.bitwise_and(
+            jnp.right_shift(bm_new, r.astype(jnp.uint32)),
+            1).astype(jnp.int32)
 
-    ack = (ends[None, :] > commit + j).astype(jnp.int32)      # [W, R_PAD]
-    cnt_old = jnp.sum(ack * in_old, axis=1)                   # [W]
-    cnt_new = jnp.sum(ack * in_new, axis=1)
+        ack = (ends[None, :] > commit + j).astype(jnp.int32)  # [W, R_PAD]
+        cnt_old = jnp.sum(ack * in_old, axis=1)               # [W]
+        cnt_new = jnp.sum(ack * in_new, axis=1)
 
-    jcol = jnp.arange(W, dtype=jnp.int32)
-    ok = (cnt_new >= maj_new) & (commit + jcol < my_end)
-    # boolean algebra, not where-on-bool (Mosaic can't legalize i1 selects)
-    ok = ok & ((transit <= 0) | (cnt_old >= maj_old))
+        jcol = jnp.arange(W, dtype=jnp.int32)
+        ok = (cnt_new >= maj_new) & (commit + jcol < my_end)
+        # boolean algebra, not where-on-bool (Mosaic can't legalize i1
+        # selects)
+        ok = ok & ((transit <= 0) | (cnt_old >= maj_old))
 
-    # contiguous committed prefix length = first False position (plain min
-    # reduction — integer arg-reductions don't lower on the TPU VPU)
-    prefix = jnp.min(jnp.where(ok, W, jcol))
+        # contiguous committed prefix length = first False position (plain
+        # min reduction — integer arg-reductions don't lower on the TPU VPU)
+        prefix = jnp.min(jnp.where(ok, W, jcol))
 
-    # Raft term guard: commit only up to the last current-term entry in the
-    # prefix (entries of older terms commit transitively below it).
-    eligible = (jcol < prefix) & (terms_win == my_term)
-    lastj = jnp.max(jnp.where(eligible, jcol, -1))
-    return jnp.where(lastj >= 0, commit + lastj + 1, commit).astype(jnp.int32)
+        # Raft term guard: commit only up to the last current-term entry in
+        # the prefix (entries of older terms commit transitively below it).
+        eligible = (jcol < prefix) & (terms_win == my_term)
+        lastj = jnp.max(jnp.where(eligible, jcol, -1))
+        return jnp.where(lastj >= 0, commit + lastj + 1,
+                         commit).astype(jnp.int32)
 
 
 def commit_scan_ref(

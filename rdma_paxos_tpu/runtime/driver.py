@@ -49,7 +49,7 @@ from rdma_paxos_tpu.proxy.stablestore import (
 from rdma_paxos_tpu.runtime.hostpath import plan_segment
 from rdma_paxos_tpu.runtime.sim import SimCluster
 from rdma_paxos_tpu.runtime.timers import ElectionTimer
-from rdma_paxos_tpu.utils.debug import ReplicaLog, StepTimer
+from rdma_paxos_tpu.utils.debug import ReplicaLog
 from rdma_paxos_tpu.utils.codec import fragment
 
 
@@ -157,14 +157,14 @@ class ClusterDriver:
         # nothing below may run inside jitted code, and tests verify
         # compiled-step cache keys are unchanged by it.
         self.obs = obs if obs is not None else Observability()
-        self._timer_obs = StepTimer(metrics=self.obs.metrics)
         # step-phase wall-time attribution (obs.spans profiler). fence
         # keeps its default (False) in production: fencing blocks on
         # the step's outputs right after dispatch so device time lands
         # in its own device_sync histogram — a profiling mode that
         # serializes the dispatch pipeline, never the serving default.
-        self._phase_prof = StepPhaseProfiler(metrics=self.obs.metrics,
-                                             fence=fence)
+        self._phase_prof = StepPhaseProfiler(
+            metrics=self.obs.metrics, fence=fence, trace=self.obs.trace,
+            step_index=lambda: self.cluster.step_index)
         self._health = (HealthReporter(workdir, period=health_period)
                         if workdir else None)
         # bounded recovery: optional app-level snapshot hook tuple
@@ -373,6 +373,11 @@ class ClusterDriver:
         # per-replica queues of (etype, conn_id, fragment_bytes, seq)
         self._submitq: List[List[Tuple[int, int, bytes, int]]]
         self._submitq = [[] for _ in range(n_replicas)]  # guarded-by: _lock
+        # operations queued since the last pump and the sum of their
+        # intake stamps (PendingEvent.t0): the pump credits their queue
+        # wait with one multiply, not one clock read an operation
+        self._intake_n = 0          # guarded-by: _lock
+        self._intake_t0_sum = 0.0   # guarded-by: _lock
         # advisory leader view: written under the lock on the readback
         # thread; lock-free reads (poll/app threads) tolerate one step
         # of staleness by design  # guarded-by: _lock [writes]
@@ -580,6 +585,8 @@ class ClusterDriver:
             self._submitq[r].append((etype, conn_id, f,
                                      rt.submit_seq))
         rt.inflight.append((ev, rt.submit_seq))
+        self._intake_n += 1
+        self._intake_t0_sum += ev.t0
         self.obs.metrics.inc("proxy_events_total", replica=r)
         self.obs.trace.record(obs_trace.PROXY_ENQUEUE,
                               replica=r, etype=etype,
@@ -662,14 +669,23 @@ class ClusterDriver:
                         r, [(etype, conn, seq, frag)
                             for etype, conn, frag, seq in q])
                     q.clear()
+            # intake_queue_wait: intake to this pump, summed over the
+            # operations queued since the last one
+            n = self._intake_n
+            if n:
+                wait = n * time.perf_counter() - self._intake_t0_sum
+                self._phase_prof.credit("intake_queue_wait", wait * 1e6, n)
+                self._intake_n, self._intake_t0_sum = 0, 0.0
 
     def step(self) -> Dict:
         """One host-loop iteration (public for deterministic tests).
         Serial: dispatch + readback fused — the pipelined run loop
         splits the same work into begin_* on the dispatch thread and
         ``_post_step`` on the readback thread."""
+        self._phase_prof.start("admin_pump")
         self._drain_admin()
         self._pump_submitq()
+        self._phase_prof.stop("admin_pump")
 
         # a flagged (force-pruned) leader never heals on its own: it
         # acks windows and heartbeats normally, so nothing deposes it,
@@ -712,10 +728,8 @@ class ClusterDriver:
                 and not (self.cluster.txn is not None
                          and self.cluster.txn.wants_serial())
                 and (dec is None or dec.max_k > 1)):
-            self._timer_obs.start("device_step")
             res = self.cluster.step_burst(
                 max_k=dec.max_k if dec is not None else None)
-            self._timer_obs.stop("device_step")
         else:
             timeouts = []
             last = self.cluster.last
@@ -740,9 +754,7 @@ class ClusterDriver:
                         rt.fired_leader = (int(last["leader_id"][r])
                                            if last is not None else -1)
                         rt.fired_countdown = 50
-            self._timer_obs.start("device_step")
             res = self.cluster.step(timeouts=timeouts)
-            self._timer_obs.stop("device_step")
         return self._post_step(res)
 
     def _backlog(self) -> int:
@@ -765,7 +777,12 @@ class ClusterDriver:
         release, detectors, recovery drive, and observability export.
         Serial ``step()`` runs it inline; the pipelined loop runs it on
         the READBACK thread, so none of this work — observability
-        included — can serialize the dispatch path it measures."""
+        included — can serialize the dispatch path it measures.
+        ``post_step_rules`` is everything here but the replay/ack sweep
+        and the observe pass, which are phases of their own: it stops
+        round each ``_apply_new_entries``."""
+        prof = self._phase_prof
+        prof.start("post_step_rules")
         self._update_leader_view(res)
 
         for r, rt in enumerate(self.runtimes):
@@ -785,7 +802,9 @@ class ClusterDriver:
                     # timeout -> widen adaptively (to_adjust_cb analog)
                     rt.timer.false_positive()
                     rt.fired_countdown = 0
+            prof.stop("post_step_rules")
             self._apply_new_entries(r, rt)
+            prof.start("post_step_rules")
             if res["role"][r] != int(Role.LEADER):
                 with self._lock:
                     # lost leadership with blocked app threads: fail them
@@ -843,7 +862,10 @@ class ClusterDriver:
                     rt.app_dirty = True
                     rt.log.info_wtime("AUTO-RECOVERY FAILED: %s" % exc)
                 self.cluster.need_recovery.discard(r)
+        prof.stop("post_step_rules")
+        prof.start("observe")
         self._observe_step(res)
+        prof.stop("observe")
         return res
 
     # ------------------------------------------------------------------
@@ -1525,7 +1547,8 @@ class ClusterDriver:
         n = len(stream)
         if rt.replay_cursor >= n:
             return
-        self._phase_prof.start("apply_replay_ack")
+        prof = self._phase_prof
+        prof.start("apply_replay_ack")
         # the engine's decode left the new entries as COLUMNAR batches
         # (hostpath.ReplayBatch): the replay/ack sweep below touches
         # Python O(1) per window, not O(1) per entry
@@ -1538,32 +1561,39 @@ class ClusterDriver:
             # (SimCluster.collect_frames); one syscall appends the batch
             blobs = self.cluster.frames[r]
             if blobs:
+                prof.start("store_append")
                 self.cluster.frames[r] = []
                 for b in blobs:
                     rt.store.append_framed(b)
+                prof.stop("store_append")
         # a dirty app's state diverged: keep persisting (the store stays
         # the complete committed stream) but feed the app nothing until
         # reset_app rebuilds it
         replaying = rt.replay is not None and not rt.app_dirty
         own_max = -1
-        n_replayed = 0
+        n_applies = 0
 
         def own_of(conns, _gens):
             return conn_origin(conns) == r
 
+        prof.start("replay_send")
         for seg in segs:
-            seg_max, ops, n_rem = plan_segment(seg, own_of,
-                                               want_ops=replaying)
+            seg_max, ops, _n_rem = plan_segment(seg, own_of,
+                                                want_ops=replaying)
             own_max = max(own_max, seg_max)
-            n_replayed += n_rem
             if replaying:
                 # remote SEND runs arrive coalesced per connection
                 # (one loopback write per run — byte-stream identical
                 # for the app); CONNECT/CLOSE apply individually
                 for etype, conn, payload in ops:
                     rt.replay.apply(etype, conn, payload)
+                n_applies += len(ops)
+        prof.stop("replay_send")
         if replaying:
+            prof.count("replay_applies_total", n_applies)
+            prof.start("replay_drain")
             rt.replay.drain_responses()
+            prof.stop("replay_drain")
         if rt.store is not None:
             # The WRITE precedes the ack (store_record runs inside the
             # reference's apply, before the proxy releases the client,
@@ -1578,14 +1608,11 @@ class ClusterDriver:
             if now - rt.last_sync > self.sync_period:
                 rt.store.sync()
                 rt.last_sync = now
-        if replaying and n_replayed:
-            self.obs.metrics.inc("replayed_entries_total",
-                                 n_replayed, replica=r)
         if own_max >= 0:
             # ack release by sequence: every own-origin entry carries
             # the fragment seq in req_id (monotone in commit order), so
             # commits are matched exactly even across leadership churn
-            self._phase_prof.start("ack_release")
+            prof.start("ack_release")
             releases = []
             with self._lock:
                 while rt.inflight and rt.inflight[0][1] <= own_max:
@@ -1601,8 +1628,10 @@ class ClusterDriver:
                 sampled = {req: conn for conn, req
                            in self.obs.spans.ack_release(r, own_max)}
             now = time.perf_counter()
+            t0_sum = 0.0
             for ev, seq in releases:
                 ev.release(0)
+                t0_sum += ev.t0
                 # intake→release is the client-visible commit latency
                 # (the spin at proxy.c:160, measured instead of spun)
                 self.obs.metrics.observe(
@@ -1611,8 +1640,13 @@ class ClusterDriver:
                     exemplar=(span_trace_id(sampled[seq], seq)
                               if seq in sampled else None),
                     replica=r)
-            self._phase_prof.stop("ack_release")
-        self._phase_prof.stop("apply_replay_ack")
+            if releases:
+                # the same intake -> release, as an exact sum
+                prof.credit("intake_to_ack",
+                            (len(releases) * now - t0_sum) * 1e6,
+                            len(releases))
+            prof.stop("ack_release")
+        prof.stop("apply_replay_ack")
 
     # ------------------------------------------------------------------
     # lifecycle
@@ -1801,11 +1835,16 @@ class ClusterDriver:
         if self._idle_backoff <= 0.001:
             # once per quiescence episode, not per beat
             self.obs.trace.record(obs_trace.IDLE_QUIESCE)
+        prof = self._phase_prof
+        prof.start("observe")
         self._cadence_observe()
+        prof.stop("observe")
         wait = min(self._idle_backoff, self._idle_margin() / 2)
         self._idle_backoff = min(self._idle_backoff * 2,
                                  self._idle_backoff_max)
+        prof.start("idle_wait")
         self._wake.wait(timeout=max(wait, 0.0005))
+        prof.stop("idle_wait")
         self._wake.clear()
 
     def _drain_pipeline(self) -> bool:
@@ -1830,6 +1869,8 @@ class ClusterDriver:
             ticket = self._pl_queue.get()
             if ticket is None:
                 return
+            # this thread's unit of the phase account: one ticket
+            self._phase_prof.start("cycle")
             try:
                 res = self.cluster.finish(ticket)
                 self._post_step(res)
@@ -1839,84 +1880,117 @@ class ClusterDriver:
                     self._pl_pending = 0
                     self._pl_cv.notify_all()
                 return
+            finally:
+                self._phase_prof.stop("cycle")
             with self._pl_cv:
                 self._pl_pending -= 1
                 self._pl_cv.notify_all()
 
     def _dispatch_loop(self, period: float) -> None:
-        while not self._stop.is_set():
-            if self.loop_error is not None:
-                return
-            if not (self.pipeline >= 2 and self._pipeline_ready()):
-                # serial iteration (elections / admin / recovery /
-                # rebase / idle heartbeat): drain first — the engine's
-                # FIFO finish contract forbids a fused step() while
-                # tickets are in flight
-                if not self._drain_pipeline():
-                    return
-                if self._stop.is_set():
-                    return
-                # the idle-skip check and the step share one crash
-                # handler: a raised skip-path bug must fail blocked
-                # waiters loudly, never park the loop dead silently
-                try:
-                    if self._can_idle_skip():
-                        # idle quiescence: nothing needs the device —
-                        # skip the dispatch, keep the cadences live
-                        self._idle_park()
-                        continue
-                    self._idle_backoff = 0.001  # re-arm the backoff
-                    self.step()
-                except Exception as exc:  # noqa: BLE001
-                    self._handle_loop_crash(exc)
-                    return
-                if not self._busy() and period:
-                    self._wake.wait(timeout=period)
-                self._wake.clear()
-                continue
-            # ---- pipelined fast path: encode + dispatch only ----
-            with self._pl_cv:
-                if self._pl_pending >= self.pipeline:
-                    self._pl_cv.wait(timeout=0.05)
-                    continue
-            self._pump_submitq()
-            dec = (self.governor.decision if self.governor is not None
-                   else None)
-            if (dec is not None and dec.coalesce_us > 0
-                    and self._backlog()):
-                # bounded admission-coalescing wait (governor): at a
-                # high arrival rate with a window still filling, a
-                # beat of patience ships fuller windows — strictly
-                # bounded, never applied while shedding
-                time.sleep(dec.coalesce_us / 1e6)
-                self.obs.metrics.observe(
-                    "governor_coalesce_us", dec.coalesce_us,
-                    buckets=LATENCY_BUCKETS_US)
-                self._pump_submitq()
+        """One ``cycle`` of the phase profiler per iteration: whatever
+        an iteration spends outside a named phase is its
+        ``unattributed``. (The readback thread makes a cycle of each
+        ticket; while it does, this thread's is in ``pipeline_wait``.)"""
+        prof = self._phase_prof
+        while not self._stop.is_set() and self.loop_error is None:
+            prof.start("cycle")
             try:
-                self._timer_obs.start("device_step")
-                # dec.max_k can flip to 1 (SLO shed) between
-                # _pipeline_ready and here: honor it with a no-take
-                # heartbeat dispatch — never a burst; the next
-                # iteration sees pipeline disengaged and drains to
-                # the serial path
-                if self._backlog() and (dec is None or dec.max_k > 1):
-                    ticket = self.cluster.begin_burst(
-                        max_k=dec.max_k if dec is not None else None)
-                else:
-                    # waiters with empty queues: quorum/commit trails
-                    # the last append by a step — advance it (no batch
-                    # take: pipelined appends ride capacity-clamped
-                    # bursts only, so shortfall requeues cannot reorder
-                    # against in-flight dispatches)
-                    ticket = self.cluster.begin_step(take_batch=False)
-                self._timer_obs.stop("device_step")
+                if not self._loop_once(period):
+                    return
+            finally:
+                prof.stop("cycle")
+
+    def _loop_once(self, period: float) -> bool:
+        """One iteration of the dispatch loop; False ends the loop."""
+        prof = self._phase_prof
+        prof.start("dispatch_gate")
+        pipelined = self.pipeline >= 2 and self._pipeline_ready()
+        prof.stop("dispatch_gate")
+        if not pipelined:
+            # serial iteration (elections / admin / recovery / rebase /
+            # idle heartbeat): drain first — the engine's FIFO finish
+            # contract forbids a fused step() while tickets are in
+            # flight
+            prof.start("pipeline_wait")
+            drained = self._drain_pipeline()
+            prof.stop("pipeline_wait")
+            if not drained:
+                return False
+        if self._stop.is_set():
+            return False
+        if not pipelined:
+            # the idle-skip check and the step share one crash
+            # handler: a raised skip-path bug must fail blocked
+            # waiters loudly, never park the loop dead silently
+            try:
+                prof.start("dispatch_gate")
+                idle = self._can_idle_skip()
+                prof.stop("dispatch_gate")
+                if idle:
+                    # idle quiescence: nothing needs the device —
+                    # skip the dispatch, keep the cadences live
+                    self._idle_park()
+                    return True
+                self._idle_backoff = 0.001  # re-arm the backoff
+                self.step()
             except Exception as exc:  # noqa: BLE001
                 self._handle_loop_crash(exc)
-                return
-            with self._pl_cv:
-                self._pl_pending += 1
-            self._pl_queue.put(ticket)
+                return False
+            prof.start("dispatch_gate")
+            busy = self._busy()
+            prof.stop("dispatch_gate")
+            if not busy and period:
+                prof.start("idle_wait")
+                self._wake.wait(timeout=period)
+                prof.stop("idle_wait")
+            self._wake.clear()
+            return True
+        # ---- pipelined fast path: encode + dispatch only ----
+        with self._pl_cv:
+            if self._pl_pending >= self.pipeline:
+                prof.start("pipeline_wait")
+                self._pl_cv.wait(timeout=0.05)
+                prof.stop("pipeline_wait")
+                return True
+        prof.start("admin_pump")
+        self._pump_submitq()
+        prof.stop("admin_pump")
+        dec = (self.governor.decision if self.governor is not None
+               else None)
+        if (dec is not None and dec.coalesce_us > 0
+                and self._backlog()):
+            # bounded admission-coalescing wait (governor): at a
+            # high arrival rate with a window still filling, a
+            # beat of patience ships fuller windows — strictly
+            # bounded, never applied while shedding
+            time.sleep(dec.coalesce_us / 1e6)
+            self.obs.metrics.observe(
+                "governor_coalesce_us", dec.coalesce_us,
+                buckets=LATENCY_BUCKETS_US)
+            self._pump_submitq()
+        try:
+            # dec.max_k can flip to 1 (SLO shed) between
+            # _pipeline_ready and here: honor it with a no-take
+            # heartbeat dispatch — never a burst; the next
+            # iteration sees pipeline disengaged and drains to
+            # the serial path
+            if self._backlog() and (dec is None or dec.max_k > 1):
+                ticket = self.cluster.begin_burst(
+                    max_k=dec.max_k if dec is not None else None)
+            else:
+                # waiters with empty queues: quorum/commit trails
+                # the last append by a step — advance it (no batch
+                # take: pipelined appends ride capacity-clamped
+                # bursts only, so shortfall requeues cannot reorder
+                # against in-flight dispatches)
+                ticket = self.cluster.begin_step(take_batch=False)
+        except Exception as exc:  # noqa: BLE001
+            self._handle_loop_crash(exc)
+            return False
+        with self._pl_cv:
+            self._pl_pending += 1
+        self._pl_queue.put(ticket)
+        return True
 
     def run(self, period: float = 0.0) -> None:
         """Run the polling loop in background threads. While client work

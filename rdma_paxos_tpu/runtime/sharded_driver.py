@@ -375,14 +375,10 @@ class ShardedClusterDriver(ClusterDriver):
                 and self._backlog()
                 and not (c.txn is not None and c.txn.wants_serial())
                 and (dec is None or dec.max_k > 1)):
-            self._timer_obs.start("device_step")
             res = c.step_burst(max_k=dec.max_k if dec is not None
                                else None)
-            self._timer_obs.stop("device_step")
         else:
-            self._timer_obs.start("device_step")
             res = c.step(timeouts=timeouts)
-            self._timer_obs.stop("device_step")
         return self._post_step(res)
 
     def _pipeline_ready(self) -> bool:

@@ -680,6 +680,8 @@ class SimCluster:
         tmo = np.zeros((R,), np.int32)
         for r in timeouts:
             tmo[r] = 1
+        if prof is not None:
+            prof.start("input_transfer")
         inp = StepInput(
             batch_data=jnp.asarray(bufs["data"]),
             batch_meta=jnp.asarray(bufs["meta"]),
@@ -697,6 +699,8 @@ class SimCluster:
                 txn_term=jnp.full((R,), self._txn_wterm, jnp.int32),
             ) if self._txn else {}),
         )
+        if prof is not None:
+            prof.stop("input_transfer")
         # no timer fired ⟹ Phase B is provably a no-op: dispatch the
         # stable step (bit-identical outputs, one fewer collective)
         fn = (self._build_step(elections=False)
@@ -776,12 +780,14 @@ class SimCluster:
         if prof is not None:
             prof.stop("host_encode")
             prof.start("device_dispatch")
+            prof.start("input_transfer")
+        args = (jnp.asarray(bufs["data"]), jnp.asarray(bufs["meta"]),
+                jnp.asarray(count), jnp.asarray(mask),
+                jnp.asarray(applied), jnp.asarray(qdepth))
+        if prof is not None:
+            prof.stop("input_transfer")
         with self._host_lock:
-            self.state, outs = fn(
-                self.state, jnp.asarray(bufs["data"]),
-                jnp.asarray(bufs["meta"]), jnp.asarray(count),
-                jnp.asarray(mask), jnp.asarray(applied),
-                jnp.asarray(qdepth))
+            self.state, outs = fn(self.state, *args)
             ticket = StepTicket("scan" if scan else "burst", outs,
                                 taken, (), K, bufs,
                                 applied0=applied if scan else None)
@@ -816,6 +822,8 @@ class SimCluster:
         if prof is not None:
             prof.sync(out)              # fenced device_sync (opt-in)
             prof.start("quorum_wait")
+        # the FIRST read blocks on the program; every read after it
+        # (``readback_rest``) is one small device-to-host transfer
         if scan:
             # consolidated minimal readback: ONE scalar matrix (final
             # step's row; ``accepted`` is cumulative in-program) plus
@@ -823,21 +831,33 @@ class SimCluster:
             scal = np.asarray(out["scal"])[-1]           # [R, NS]
             res = {k: scal[:, i] for i, k in enumerate(SCAN_KEYS)
                    if k in self.RES_KEYS}
-            res["peer_acked"] = np.asarray(out["peer_acked"])[-1]
-        elif burst:
-            res = {k: np.asarray(getattr(out, k))[-1]
-                   for k in self.RES_KEYS if k != "accepted"}
-            acc = np.asarray(out.accepted).sum(axis=0)       # [R]
-            res["accepted"] = acc
+            rest = {"peer_acked": out["peer_acked"]}
         else:
-            res = {k: np.asarray(getattr(out, k))
-                   for k in self.RES_KEYS}
-            if self._txn and out.txn_vote is not None:
+            first, *keys = self.RES_KEYS
+            res = {first: np.asarray(getattr(out, first))}
+            rest = {k: getattr(out, k) for k in keys}
+            if (not burst and self._txn
+                    and out.txn_vote is not None):
                 # serial dispatches only: the txn lane never rides
                 # burst/scan programs (their keys stay untouched)
-                res["txn_vote"] = np.asarray(out.txn_vote)
+                rest["txn_vote"] = out.txn_vote
+        if prof is not None:
+            prof.start("readback_rest")
+        for k, v in rest.items():
+            res[k] = np.asarray(v)
+        if prof is not None:
+            prof.stop("readback_rest")
+            prof.count("readback_arrays_total", 1 + len(rest))
+        if burst:
+            # [K, R] per fused step: the last step's row, but the
+            # accepted counts summed over the burst
+            res = {k: (v.sum(axis=0) if k == "accepted" else v[-1])
+                   for k, v in res.items()}
+        elif scan:
+            res["peer_acked"] = res["peer_acked"][-1]
         if prof is not None:
             prof.stop("quorum_wait")
+            prof.start("post_readback")
         if self._audit:
             # ingest BEFORE _maybe_rebase: the emitted indices are raw
             # (pre-rollover), consistent with the current rebased_total
@@ -899,12 +919,14 @@ class SimCluster:
         for note in txn_notes:
             self.txn.note_appends(*note)
         if prof is not None:
+            prof.stop("post_readback")
             prof.start("apply")
         self._replay_committed(
             res, scan_rows=((out["replay_data"], out["replay_meta"],
                              ticket.applied0) if scan else None))
         if prof is not None:
             prof.stop("apply")
+            prof.start("finish_tail")
         if self._audit:
             self._record_flight(res, ticket.taken, ticket.timeouts,
                                 burst_k=ticket.K)
@@ -942,6 +964,8 @@ class SimCluster:
         else:
             self._staging.release(ticket.bufs, [
                 ((r,), len(t)) for r, t in enumerate(ticket.taken)])
+        if prof is not None:
+            prof.stop("finish_tail")
         return res
 
     def drain(self) -> Optional[Dict[str, np.ndarray]]:
@@ -1299,6 +1323,9 @@ class SimCluster:
             if not todo:
                 return
             starts = jnp.asarray(self.applied.astype(np.int32))
+            prof = self.profiler
+            if prof is not None:
+                prof.start("replay_fetch")
             # bind the fetch's log argument UNDER the host lock: the
             # pipelined dispatch thread donates the current state
             # buffers into the next step's dispatch, and a fetch bound
@@ -1312,7 +1339,12 @@ class SimCluster:
             # runs outside it so the dispatch path never stalls.
             with self._host_lock:
                 wd_fut, wm_fut = self._fetch_all(self.state.log, starts)
+            # wm is read last: a wrapper over _fetch_all (the
+            # benchmark's span) ends inside its conversion
             wd_all, wm_all = np.asarray(wd_fut), np.asarray(wm_fut)
+            if prof is not None:
+                prof.stop("replay_fetch")
+                prof.start("replay_decode")
             for r in todo:
                 commit = int(res["commit"][r])
                 n = int(min(commit - self.applied[r], W))
@@ -1324,6 +1356,8 @@ class SimCluster:
                               self.frames[r], self.collect_frames,
                               rebase=self.rebased_total)
                 self.applied[r] += n
+            if prof is not None:
+                prof.stop("replay_decode")
 
     # ---------------- inspection ----------------
 

@@ -23,6 +23,7 @@ import collections
 import json
 import os
 import threading
+import time
 
 import pytest
 
@@ -526,3 +527,332 @@ def test_reporting_emit_line_and_snapshot(tmp_path, capsys):
     assert filed["metrics"]["counters"]["ops_total{replica=0}"] == 5
     assert set(filed["anchor"]) == {"monotonic", "wall"}
     assert row["metrics"] == filed["metrics"]
+
+
+# ---------------------------------------------------------------------------
+# the closed account of the dispatch cycle (nested phases, the residual,
+# per-operation sums, stalls, device scopes)
+# ---------------------------------------------------------------------------
+
+ACCT_CFG = LogConfig(n_slots=128, slot_bytes=64, window_slots=32,
+                     batch_slots=8)
+# of a cycle on the serial loop; the rest nest in one of these
+CYCLE_CHILDREN = ("profiler", "dispatch_gate", "idle_wait",
+                  "pipeline_wait", "observe", "admin_pump",
+                  "host_encode", "device_dispatch",
+                  "device_sync", "quorum_wait", "post_readback", "apply",
+                  "finish_tail", "post_step_rules", "apply_replay_ack")
+NEW_PHASES = ("cycle", "unattributed", "profiler", "admin_pump",
+              "dispatch_gate", "idle_wait", "pipeline_wait",
+              "input_transfer", "readback_rest", "post_readback", "replay_fetch",
+              "replay_decode", "finish_tail", "store_append",
+              "replay_send", "replay_drain", "post_step_rules", "observe",
+              "intake_to_ack", "intake_queue_wait")
+
+
+def _sink_port():
+    """A TCP server that reads and drops: the 'app' the followers'
+    ReplayEngine replays into."""
+    import socket
+    srv = socket.socket()
+    srv.bind(("127.0.0.1", 0))
+    srv.listen(16)
+
+    def drain(c):
+        try:
+            while c.recv(65536):
+                pass
+        except OSError:
+            pass
+
+    def accept():
+        while True:
+            try:
+                c, _ = srv.accept()
+            except OSError:
+                return
+            threading.Thread(target=drain, args=(c,), daemon=True).start()
+    threading.Thread(target=accept, daemon=True).start()
+    return srv
+
+
+def _closed_loop_sets(d, n_clients, n_sets):
+    """``n_clients`` threads, one SET outstanding each, through the
+    shim's handler; -> events acknowledged."""
+    handler = d._make_handler(0)
+    done = []
+
+    def client(c):
+        conn = (0 << 24) | (100 + c)
+        evs = [handler(int(EntryType.CONNECT), conn, b"")]
+        assert evs[0].done.wait(30)
+        for i in range(n_sets):
+            ev = handler(int(EntryType.SEND), conn, b"SET k%d v\n" % i)
+            assert ev.done.wait(30), (c, i)
+            evs.append(ev)
+        done.append(len(evs))
+    threads = [threading.Thread(target=client, args=(c,))
+               for c in range(n_clients)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+        assert not t.is_alive()
+    return sum(done)
+
+
+def _account_driver(tmp_path, pipeline, bench_wrapper=False):
+    """A led ClusterDriver with stores and replay sinks, its fetches
+    counted; with ``bench_wrapper`` the benchmark's own span is
+    installed over ``cluster._fetch_all`` as a traced run installs it."""
+    srv = _sink_port()
+    d = ClusterDriver(ACCT_CFG, 3, workdir=str(tmp_path),
+                      app_ports=[srv.getsockname()[1]] * 3,
+                      timeout_cfg=TO, pipeline=pipeline)
+    d.cluster.run_until_elected(0)
+    d.step()
+    assert d.leader() == 0
+    fetches = []
+    jitted = d.cluster._fetch_all
+
+    def counted(log, starts):
+        fetches.append(1)
+        return jitted(log, starts)
+    d.cluster._fetch_all = counted
+    dep = None
+    if bench_wrapper:
+        from perfbench.deployments._driver_common import (
+            DriverDeployment, SpanAcc)
+        dep = DriverDeployment.__new__(DriverDeployment)
+        dep.driver = d
+        dep.bench_spans = {"replay_fetch": SpanAcc()}
+        dep.enable_tracing()
+    else:
+        d._phase_prof.enable_events()
+    return d, srv, fetches, dep
+
+
+def _delta(d, base):
+    return {p: (a[0] - base[p][0], a[1] - base[p][1])
+            for p, a in d._phase_prof.acc.items()}
+
+
+@pytest.fixture(scope="module")
+def serial_account(tmp_path_factory):
+    d, srv, fetches, _ = _account_driver(
+        tmp_path_factory.mktemp("acct"), pipeline=0)
+    try:
+        # compile what the traffic runs BEFORE the loop starts, so that
+        # the account below starts and ends on cycle boundaries
+        handler = d._make_handler(0)
+        warm = [handler(int(EntryType.CONNECT), (0 << 24) | c, b"")
+                for c in (1, 2)]
+        assert _step_until(d, lambda: all(e.done.is_set() for e in warm))
+        base = dict(d._phase_prof.acc)
+        n_fetch0 = len(fetches)
+        d.run()
+        acked = _closed_loop_sets(d, n_clients=2, n_sets=150)
+        time.sleep(0.2)             # the loop parks: idle_wait
+        d.stop()
+        assert d.loop_error is None
+        return dict(acc=_delta(d, base), acked=acked,
+                    fetches=len(fetches) - n_fetch0,
+                    events=list(d._phase_prof.events),
+                    counters=d.obs.metrics.snapshot()["counters"])
+    finally:
+        d.stop()
+        srv.close()
+
+
+def test_cycle_account_closes_on_the_serial_loop(serial_account):
+    acc = serial_account["acc"]
+    n_cycles, cycle_us = acc["cycle"]
+    assert n_cycles >= 150
+    for phase in NEW_PHASES:
+        assert acc[phase][0] > 0, f"{phase} never recorded"
+    # direct children + the residual ARE the cycle (float adds apart)
+    parts = sum(acc[p][1] for p in CYCLE_CHILDREN) + acc["unattributed"][1]
+    assert abs(parts - cycle_us) < 1e-6 * cycle_us + 1.0
+    # the program names all but a sliver of its working time
+    working = cycle_us - acc["idle_wait"][1]
+    assert acc["unattributed"][1] < 0.05 * working, (
+        acc["unattributed"], working)
+    # totals are inclusive: what nests never exceeds what contains it
+    # (input_transfer: host_encode on the step path, device_dispatch on
+    # the burst path)
+    assert acc["input_transfer"][1] <= (acc["host_encode"][1]
+                                        + acc["device_dispatch"][1])
+    assert acc["readback_rest"][1] <= acc["quorum_wait"][1]
+    assert (acc["replay_fetch"][1] + acc["replay_decode"][1]
+            <= acc["apply"][1])
+    assert (acc["store_append"][1] + acc["replay_send"][1]
+            + acc["replay_drain"][1] + acc["ack_release"][1]
+            <= acc["apply_replay_ack"][1])
+    # a container over everything would name every device-idle gap
+    names = {e[0] for e in serial_account["events"]}
+    assert names and not names & {"cycle", "pipeline_wait",
+                                  "unattributed", "profiler",
+                                  "intake_to_ack", "intake_queue_wait"}
+    assert serial_account["counters"]["readback_arrays_total"] >= \
+        16 * acc["quorum_wait"][0]
+    assert serial_account["counters"]["replay_applies_total"] > 0
+
+
+def test_intake_sums_are_one_sample_an_operation(serial_account):
+    acc = serial_account["acc"]
+    assert acc["intake_to_ack"][0] == serial_account["acked"]
+    assert acc["intake_queue_wait"][0] == serial_account["acked"]
+    assert 0 < acc["intake_queue_wait"][1] <= acc["intake_to_ack"][1]
+
+
+@pytest.mark.parametrize("bench_wrapper", [False, True],
+                         ids=["bare", "benchmark_span_installed"])
+def test_replay_fetch_phase_counts_fetch_dispatches(tmp_path,
+                                                    bench_wrapper):
+    d, srv, fetches, dep = _account_driver(tmp_path, pipeline=0,
+                                           bench_wrapper=bench_wrapper)
+    try:
+        base = dict(d._phase_prof.acc)
+        n0 = len(fetches)
+        d.run()
+        _closed_loop_sets(d, n_clients=1, n_sets=40)
+        d.stop()
+        assert d.loop_error is None
+        acc = _delta(d, base)
+        assert acc["replay_fetch"][0] == len(fetches) - n0 > 0
+        if dep is not None:
+            # the benchmark's span brackets the same fetches from
+            # outside (it ends inside the last array's conversion)
+            span = dep.bench_spans["replay_fetch"]
+            assert span.count == len(fetches) - n0
+            assert span.total_us <= acc["replay_fetch"][1] * 1.05 + 50
+    finally:
+        d.stop()
+        srv.close()
+
+
+def test_phase_stall_leaves_one_trace_event_and_one_count():
+    reg, ring = MetricsRegistry(), TraceRing()
+    prof = StepPhaseProfiler(metrics=reg, trace=ring,
+                             step_index=lambda: 42)
+    low = TimeoutConfig().elec_timeout_low
+    assert prof.STALL_US == low * 1e6
+    prof.start("cycle")
+    prof.start("apply")
+    prof.start("replay_fetch")
+    time.sleep(low + 0.02)
+    prof.stop("replay_fetch")
+    prof.stop("apply")                  # as long, but its child's stall
+    prof.start("idle_wait")
+    time.sleep(low + 0.02)              # waiting for work is no stall
+    prof.stop("idle_wait")
+    prof.start("observe")
+    prof.stop("observe")                # short
+    prof.stop("cycle")
+    prof.start("cycle")                 # long only by its waiting
+    prof.start("pipeline_wait")
+    time.sleep(low * 0.7)
+    prof.stop("pipeline_wait")
+    prof.start("observe")
+    time.sleep(low * 0.7)
+    prof.stop("observe")
+    prof.stop("cycle")
+    evs = ring.events(kind="phase_stall")
+    assert len(evs) == 1
+    assert evs[0].fields["phase"] == "replay_fetch"
+    assert evs[0].fields["step"] == 42
+    assert evs[0].fields["us"] >= low * 1e6
+    counters = reg.snapshot()["counters"]
+    assert counters["phase_stalls_total"] == 1
+    assert counters["phase_stall_us_total{phase=replay_fetch}"] >= low * 1e6
+    assert sum(v for k, v in counters.items()
+               if k.startswith("phase_stall_us_total")) == \
+        counters["phase_stall_us_total{phase=replay_fetch}"]
+
+
+def test_profiler_survives_an_abandoned_phase_and_reads_zero_rows():
+    prof = StepPhaseProfiler()
+    # every key exists before any phase ran: a reader's dict(acc) never
+    # sees the dict grow, and sums() still hides the dead rows
+    assert set(NEW_PHASES) <= set(prof.acc) and prof.sums() == {}
+    prof.start("cycle")
+    prof.start("apply")
+    prof.start("replay_fetch")          # raised before its stop
+    prof.stop("apply")                  # pops the abandoned frame too
+    prof.start("apply")
+    prof.stop("apply")
+    prof.stop("cycle")
+    assert prof.acc["apply"][0] == 2 and prof.acc["replay_fetch"][0] == 0
+    assert prof._stack() == []
+    prof.stop("never_started")          # as before: ignored
+    prof.credit("intake_to_ack", 300.0, 3)
+    assert prof.sums()["intake_to_ack"] == dict(n=3, total_us=300.0,
+                                                max_us=100.0)
+
+
+def test_pipelined_loop_loses_no_phase_with_two_threads(tmp_path):
+    """tests/test_pipeline.py's set-up: a record longer than one burst
+    queued BEFORE the loop starts, so dispatch and readback overlap and
+    both threads are in the profiler at once."""
+    d, srv, _fetches, _ = _account_driver(tmp_path, pipeline=2)
+    try:
+        handler = d._make_handler(0)
+        conns = [(0 << 24) | 11, (0 << 24) | 12]
+        evs = [handler(int(EntryType.CONNECT), c, b"") for c in conns]
+        evs += [handler(int(EntryType.SEND), conns[i % 2], b"w%03d" % i)
+                for i in range(200)]
+        base = dict(d._phase_prof.acc)
+        d.run(period=0.001)
+        for ev in evs:
+            assert ev.done.wait(30)
+        time.sleep(0.1)
+        d.stop()
+        assert d.loop_error is None
+        assert d.cluster.max_inflight_dispatches >= 2
+        acc = _delta(d, base)
+        # every dispatch was encoded, read back, applied and tailed:
+        # no start or stop went missing between the two threads
+        n = acc["device_dispatch"][0]
+        assert n > 0
+        for phase in ("host_encode", "quorum_wait", "post_readback",
+                      "apply", "finish_tail"):
+            assert acc[phase][0] == n, (phase, acc[phase], n)
+        assert acc["observe"][0] >= n       # idle parks observe too
+        assert acc["intake_to_ack"][0] == len(evs)
+        # a cycle an iteration of the dispatch loop AND a cycle a
+        # ticket on the readback thread: both threads' accounts close
+        assert acc["cycle"][0] > n
+        parts = (sum(acc[p][1] for p in CYCLE_CHILDREN)
+                 + acc["unattributed"][1])
+        assert abs(parts - acc["cycle"][1]) < 1e-6 * acc["cycle"][1] + 1.0
+        assert acc["pipeline_wait"][1] > 0
+    finally:
+        d.stop()
+        srv.close()
+
+
+STEP_SCOPES = ("control_gather", "election", "append", "fanout", "absorb",
+               "cfg_rescan", "ack_quorum", "commit_scan", "apply_prune",
+               "audit_digest", "telemetry")
+
+
+@pytest.fixture(scope="module")
+def lowered_texts():
+    import jax
+    import jax.numpy as jnp
+
+    from rdma_paxos_tpu.consensus.step import make_step_input
+    c = SimCluster(ACCT_CFG, 3, audit=True, telemetry=True)
+    inp = jax.vmap(lambda _: make_step_input(ACCT_CFG, 3))(jnp.arange(3))
+    step = c._build_step(elections=True).lower(c.state, inp)
+    fetch = c._fetch_all.lower(c.state.log, jnp.zeros((3,), jnp.int32))
+    return dict(step=step.as_text(debug_info=True),
+                fetch=fetch.as_text(debug_info=True))
+
+
+@pytest.mark.parametrize("scope", STEP_SCOPES + ("replay_fetch",))
+def test_lowered_program_carries_each_scope(lowered_texts, scope):
+    import re
+    text = lowered_texts["fetch" if scope == "replay_fetch" else "step"]
+    # the path a device trace shows: jit(replica_step)/vmap(append)/...
+    assert re.search(r"[/(]%s[/)]" % scope, text), scope
