@@ -59,10 +59,11 @@ dominant leader's row.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from rdma_paxos_tpu.config import LogConfig
@@ -183,6 +184,13 @@ class StepOutput:
     # the default program — txn=False steps stay byte-identical
     # (cache-key guarded by tests/test_txn.py).
     txn_vote: Optional[jax.Array] = None
+    # --- packed readback row (:func:`scan_scalars`) ---
+    # [len(SCAN_KEYS) + R] i32: every scalar above that the host rules
+    # consume, the replica's config view and the ``peer_acked`` row, so
+    # one dispatch costs ONE device->host read. Filled by the builders
+    # of ``parallel/mesh.py`` (:func:`with_scalars`); None out of
+    # ``replica_step`` itself.
+    scal: Optional[jax.Array] = None
 
 
 def make_step_input(cfg: LogConfig, n_replicas: int) -> StepInput:
@@ -1040,40 +1048,61 @@ def group_step(
 # device-resident K-window scan: the consolidated minimal readback
 # ---------------------------------------------------------------------------
 
-# per-replica scalar outputs the host rules actually consume, packed
-# into ONE [..., len(SCAN_KEYS)] i32 matrix by :func:`scan_scalars` so
-# a K-step scan dispatch returns a single consolidated array instead
-# of one device->host transfer per field. ``accepted`` carries the
-# CUMULATIVE accepted count across the scan (the burst-sum semantics,
-# computed in-program). Order is part of the host contract
-# (runtime/sim.py unpacks by index) — append only.
+# per-replica scalars the host rules actually consume, packed into ONE
+# [..., len(SCAN_KEYS) + R] i32 row by :func:`scan_scalars`, so every
+# dispatch (serial step, fused burst, K-window scan) hands the host a
+# single array instead of one device->host transfer per field. The
+# named columns come first; the ``peer_acked`` row (R columns) follows
+# them. ``accepted`` carries the CUMULATIVE accepted count across a
+# fused dispatch (the burst-sum semantics, computed in-program). The
+# :data:`CONFIG_VIEW_KEYS` columns are the replica's config view,
+# taken from the POST-step state — the view of the same step as the ``peer_acked``
+# row the failure detector judges it with. Order is part of the host
+# contract (:func:`unpack_scalars` reads by index) — append only.
+CONFIG_VIEW_KEYS = ("bitmask_old", "bitmask_new", "cid_state", "epoch")
 SCAN_KEYS = ("term", "role", "leader_id", "voted_term", "voted_for",
              "head", "apply", "commit", "end", "hb_seen",
              "became_leader", "acked", "accepted",
-             "leadership_verified", "rebase_delta", "burst_hint")
+             "leadership_verified", "rebase_delta", "burst_hint",
+             ) + CONFIG_VIEW_KEYS
 
 
-def scan_scalars(out: StepOutput, accepted_total: jax.Array
-                 ) -> jax.Array:
-    """Stack one step's :data:`SCAN_KEYS` outputs along a trailing
-    axis (``[..., len(SCAN_KEYS)]`` i32) — the scan tier's one-array
-    scalar readback. ``accepted_total`` substitutes the cumulative
-    accepted count for the per-step ``accepted`` field."""
-    cols = [accepted_total if k == "accepted" else getattr(out, k)
+def scan_scalars(out: StepOutput, accepted_total: jax.Array,
+                 state: ReplicaState) -> jax.Array:
+    """One step's packed readback row: the :data:`SCAN_KEYS` columns
+    then ``out.peer_acked``, along a trailing axis
+    (``[..., len(SCAN_KEYS) + R]`` i32). ``accepted_total`` substitutes
+    the cumulative accepted count for the per-step ``accepted`` field;
+    ``state`` is the POST-step state the config view is read from (the
+    u32 bitmasks travel bit-for-bit)."""
+    cols = [accepted_total if k == "accepted"
+            else getattr(state if k in CONFIG_VIEW_KEYS else out, k)
             for k in SCAN_KEYS]
-    return jnp.stack([c.astype(jnp.int32) for c in cols], axis=-1)
+    cols = [c if c.dtype == jnp.int32
+            else lax.bitcast_convert_type(c, jnp.int32) for c in cols]
+    return jnp.concatenate([jnp.stack(cols, axis=-1), out.peer_acked],
+                           axis=-1)
 
 
-def scan_readback(out: StepOutput, accepted_total: jax.Array, *,
-                  audit: bool, telemetry: bool) -> dict:
+def with_scalars(out: StepOutput, accepted_total: jax.Array,
+                 state: ReplicaState) -> StepOutput:
+    """``out`` carrying its packed readback row (``out.scal``) — what
+    the step and burst builders return, so the host reads ONE array a
+    dispatch while the field-by-field outputs stay for callers that
+    want them (an output nobody reads costs no transfer)."""
+    return dataclasses.replace(
+        out, scal=scan_scalars(out, accepted_total, state))
+
+
+def scan_readback(out: StepOutput, accepted_total: jax.Array,
+                  state: ReplicaState, *, audit: bool,
+                  telemetry: bool) -> dict:
     """One scan step's readback dict — the SINGLE assembly rule every
     scan builder uses (sim, group, spmd, spmd-group), so the
     consolidated-readback contract can never drift between engines:
-    the :func:`scan_scalars` matrix + ``peer_acked``, plus the
-    per-step audit windows / telemetry vector only when those
-    variants are compiled."""
-    ys = dict(scal=scan_scalars(out, accepted_total),
-              peer_acked=out.peer_acked)
+    the :func:`scan_scalars` row, plus the per-step audit windows /
+    telemetry vector only when those variants are compiled."""
+    ys = dict(scal=scan_scalars(out, accepted_total, state))
     if audit:
         ys.update(audit_start=out.audit_start,
                   audit_digest=out.audit_digest,
@@ -1082,6 +1111,17 @@ def scan_readback(out: StepOutput, accepted_total: jax.Array, *,
     if telemetry:
         ys["telemetry"] = out.telemetry
     return ys
+
+
+def unpack_scalars(rows: np.ndarray) -> Dict[str, np.ndarray]:
+    """Host inverse of :func:`scan_scalars`: the ``res`` dict of one
+    step's rows (``[..., len(SCAN_KEYS) + R]``, already on the host) —
+    THE unpack routine of step, burst and scan readbacks."""
+    res = {k: rows[..., i] for i, k in enumerate(SCAN_KEYS)}
+    for k in ("bitmask_old", "bitmask_new"):
+        res[k] = res[k].astype(np.uint32)
+    res["peer_acked"] = rows[..., len(SCAN_KEYS):]
+    return res
 
 
 def fetch_window(log: Log, start: jax.Array, *, window_slots: int):

@@ -53,8 +53,9 @@ inside the optional fencing path):
     ``device_dispatch`` program enqueue (holds ``input_transfer`` on the
                        burst path)
     ``device_sync``    ``fence=True`` only
-    ``quorum_wait``    block on the step, then ``readback_rest``: every
-                       output read after the first
+    ``quorum_wait``    block on the step's ONE packed read, then
+                       ``readback_rest``: the reads compiled only on
+                       request (none in the default programs)
     ``post_readback``  audit ingest, telemetry, stamps, requeue
     ``apply``          ``replay_fetch`` (fetch bind + both host reads),
                        ``replay_decode`` (``decode_window``)
@@ -607,7 +608,7 @@ PHASE_IDLE_WAIT = "idle_wait"            # parked on _wake
 PHASE_PIPELINE_WAIT = "pipeline_wait"    # dispatch thread waits for the
                                          # readback thread (drain / depth)
 PHASE_INPUT_TRANSFER = "input_transfer"  # jnp.asarray of step inputs
-PHASE_READBACK_REST = "readback_rest"    # output reads after the first
+PHASE_READBACK_REST = "readback_rest"    # reads after the packed one
 PHASE_POST_READBACK = "post_readback"    # finish: quorum_wait -> apply
 PHASE_REPLAY_FETCH = "replay_fetch"      # fetch bind + both host reads
 PHASE_REPLAY_DECODE = "replay_decode"    # decode_window

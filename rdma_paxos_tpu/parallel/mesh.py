@@ -25,7 +25,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from rdma_paxos_tpu.config import LogConfig
 from rdma_paxos_tpu.consensus.state import ReplicaState, make_replica_state
-from rdma_paxos_tpu.consensus.step import StepInput, replica_step
+from rdma_paxos_tpu.consensus.step import (
+    StepInput, replica_step, scan_readback, with_scalars)
 
 REPLICA_AXIS = "replica"
 GROUP_AXIS = "group"
@@ -97,6 +98,17 @@ def stack_group_states(cfg: LogConfig, n_groups: int, n_replicas: int,
         lambda x: jnp.broadcast_to(x, (n_groups,) + x.shape), one)
 
 
+def _with_step_scalars(step):
+    """A batched single step whose output carries its packed readback
+    row. ``wraps`` keeps the step's name, which names the compiled
+    program (``jit_replica_step``) in device traces."""
+    @functools.wraps(step)
+    def stepped(state_b, inp_b):
+        st, out = step(state_b, inp_b)
+        return st, with_scalars(out, out.accepted, st)
+    return stepped
+
+
 def _squeeze(tree):
     return jax.tree.map(lambda x: x[0], tree)
 
@@ -126,7 +138,8 @@ def build_spmd_step(cfg: LogConfig, n_replicas: int, mesh: Mesh, *,
 
     def per_device(state_b, inp_b):
         st, out = core(_squeeze(state_b), _squeeze(inp_b))
-        return _unsqueeze(st), _unsqueeze(out)
+        return (_unsqueeze(st),
+                _unsqueeze(with_scalars(out, out.accepted, st)))
 
     mapped = _shard_map(
         per_device, mesh=mesh,
@@ -154,8 +167,10 @@ def build_sim_burst(cfg: LogConfig, n_replicas: int, *,
     mid-burst), so pruning advances at most to the pre-burst applied
     offsets; the caller's capacity sizing must fit the whole burst in
     the pre-burst free space. K is the leading axis of the stacked
-    inputs; returns the final state plus per-step stacked outputs for
-    exact host accounting."""
+    inputs; returns the final state plus the per-step stacked outputs,
+    whose ``scal`` (``[K, R, len(SCAN_KEYS) + R]``, ``accepted``
+    cumulative in-program) is the ONE array the host reads: row
+    ``[-1]`` is the burst's result."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -178,15 +193,19 @@ def build_sim_burst(cfg: LogConfig, n_replicas: int, *,
         # qdepth [R] = the host backlog REMAINING beyond this burst, so
         # the final step's gathered burst_hint keeps bursts back-to-back
         # under sustained load instead of resetting to zero
-        def body(st, xs):
+        def body(carry, xs):
+            st, acc = carry
             d, m, c = xs
             inp = StepInput(
                 batch_data=d, batch_meta=m, batch_count=c,
                 timeout_fired=zeros_r, peer_mask=peer_mask,
                 apply_done=applied, queue_depth=qdepth)
             st, out = vstep(st, inp)
-            return st, out
-        return lax.scan(body, state_b, (datas, metas, counts))
+            acc = acc + out.accepted
+            return (st, acc), with_scalars(out, acc, st)
+        (st, _acc), outs = lax.scan(body, (state_b, zeros_r),
+                                    (datas, metas, counts))
+        return st, outs
     return jax.jit(burst, donate_argnums=(0,) if donate else ())
 
 
@@ -200,9 +219,9 @@ def build_sim_scan(cfg: LogConfig, n_replicas: int, *,
     consolidated minimal readback instead of the full per-step output
     stacks — only what the host rules consume:
 
-    * ``scal`` ``[K, R, len(SCAN_KEYS)]`` i32 — the per-step scalar
-      matrix (``accepted`` cumulative; the host reads row ``[-1]``),
-    * ``peer_acked`` ``[K, R, R]`` — the failure detector's input,
+    * ``scal`` ``[K, R, len(SCAN_KEYS) + R]`` i32 — the per-step
+      packed rows (``accepted`` cumulative; config view and the
+      ``peer_acked`` row included; the host reads row ``[-1]``),
     * ``replay_data``/``replay_meta`` — ``replay_slots`` committed
       rows per replica starting at the host's PRE-scan apply cursors,
       extracted from the post-scan log INSIDE the same dispatch, so
@@ -219,7 +238,6 @@ def build_sim_scan(cfg: LogConfig, n_replicas: int, *,
     import jax.numpy as jnp
     from jax import lax
     from rdma_paxos_tpu.consensus.log import extract_window
-    from rdma_paxos_tpu.consensus.step import scan_readback
 
     core = functools.partial(
         replica_step, cfg=cfg, n_replicas=n_replicas,
@@ -243,7 +261,7 @@ def build_sim_scan(cfg: LogConfig, n_replicas: int, *,
                 apply_done=applied, queue_depth=qdepth)
             st, out = vstep(st, inp)
             acc = acc + out.accepted
-            ys = scan_readback(out, acc, audit=audit,
+            ys = scan_readback(out, acc, st, audit=audit,
                                telemetry=telemetry)
             return (st, acc), ys
 
@@ -269,7 +287,7 @@ def build_sim_group_scan(cfg: LogConfig, n_replicas: int, *,
     import jax.numpy as jnp
     from jax import lax
     from rdma_paxos_tpu.consensus.log import extract_window
-    from rdma_paxos_tpu.consensus.step import group_step, scan_readback
+    from rdma_paxos_tpu.consensus.step import group_step
 
     gstep = group_step(cfg=cfg, n_replicas=n_replicas,
                        axis_name=REPLICA_AXIS, use_pallas=use_pallas,
@@ -292,7 +310,7 @@ def build_sim_group_scan(cfg: LogConfig, n_replicas: int, *,
                 apply_done=applied, queue_depth=qdepth)
             st, out = gstep(st, inp)
             acc = acc + out.accepted
-            ys = scan_readback(out, acc, audit=audit,
+            ys = scan_readback(out, acc, st, audit=audit,
                                telemetry=telemetry)
             return (st, acc), ys
 
@@ -321,7 +339,6 @@ def build_spmd_group_scan(cfg: LogConfig, n_replicas: int, mesh: Mesh,
     import jax.numpy as jnp
     from jax import lax
     from rdma_paxos_tpu.consensus.log import Log, extract_window
-    from rdma_paxos_tpu.consensus.step import scan_readback
 
     core = functools.partial(
         replica_step, cfg=cfg, n_replicas=n_replicas,
@@ -345,7 +362,7 @@ def build_spmd_group_scan(cfg: LogConfig, n_replicas: int, mesh: Mesh,
                 queue_depth=qdepth_b[:, 0])
             s, out = vcore(s, inp)
             acc = acc + out.accepted
-            ys = scan_readback(out, acc, audit=audit,
+            ys = scan_readback(out, acc, s, audit=audit,
                                telemetry=telemetry)
             return (s, acc), ys
 
@@ -361,7 +378,7 @@ def build_spmd_group_scan(cfg: LogConfig, n_replicas: int, mesh: Mesh,
         return (jax.tree.map(lambda x: x[:, None], st), out)
 
     spec_k = P(None, GROUP_AXIS, REPLICA_AXIS)
-    out_spec = dict(scal=spec_k, peer_acked=spec_k,
+    out_spec = dict(scal=spec_k,
                     replay_data=P(GROUP_AXIS, REPLICA_AXIS),
                     replay_meta=P(GROUP_AXIS, REPLICA_AXIS))
     if audit:
@@ -396,7 +413,6 @@ def build_spmd_scan(cfg: LogConfig, n_replicas: int, mesh: Mesh, *,
     import jax.numpy as jnp
     from jax import lax
     from rdma_paxos_tpu.consensus.log import extract_window
-    from rdma_paxos_tpu.consensus.step import scan_readback
 
     core = functools.partial(
         replica_step, cfg=cfg, n_replicas=n_replicas,
@@ -418,7 +434,7 @@ def build_spmd_scan(cfg: LogConfig, n_replicas: int, mesh: Mesh, *,
                 queue_depth=qdepth_b[0])
             s, out = core(s, inp)
             acc = acc + out.accepted
-            ys = scan_readback(out, acc, audit=audit,
+            ys = scan_readback(out, acc, s, audit=audit,
                                telemetry=telemetry)
             return (s, acc), ys
 
@@ -433,7 +449,7 @@ def build_spmd_scan(cfg: LogConfig, n_replicas: int, mesh: Mesh, *,
         return _unsqueeze(st), out
 
     spec_k = P(None, REPLICA_AXIS)
-    out_spec = dict(scal=spec_k, peer_acked=spec_k,
+    out_spec = dict(scal=spec_k,
                     replay_data=P(REPLICA_AXIS),
                     replay_meta=P(REPLICA_AXIS))
     if audit:
@@ -456,7 +472,8 @@ def build_spmd_burst(cfg: LogConfig, n_replicas: int, mesh: Mesh, *,
                      audit: bool = False,
                      telemetry: bool = False):
     """:func:`build_sim_burst` over a real device mesh (``shard_map`` with
-    the K-step scan inside the per-device program)."""
+    the K-step scan inside the per-device program); the same stacked
+    outputs, ``scal`` the one array the host reads."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -470,7 +487,8 @@ def build_spmd_burst(cfg: LogConfig, n_replicas: int, mesh: Mesh, *,
                    applied_b, qdepth_b):
         st = _squeeze(state_b)
 
-        def body(s, xs):
+        def body(carry, xs):
+            s, acc = carry
             d, m, c = xs
             inp = StepInput(
                 batch_data=d[0], batch_meta=m[0], batch_count=c[0],
@@ -480,8 +498,11 @@ def build_spmd_burst(cfg: LogConfig, n_replicas: int, mesh: Mesh, *,
                 # the final burst_hint sustains back-to-back bursts
                 queue_depth=qdepth_b[0])
             s, out = core(s, inp)
-            return s, out
-        st, outs = lax.scan(body, st, (datas_b, metas_b, counts_b))
+            acc = acc + out.accepted
+            return (s, acc), with_scalars(out, acc, s)
+        (st, _acc), outs = lax.scan(
+            body, (st, jnp.zeros((), jnp.int32)),
+            (datas_b, metas_b, counts_b))
         return (_unsqueeze(st),
                 jax.tree.map(lambda x: x[:, None], outs))   # [K, 1, ...]
 
@@ -505,12 +526,13 @@ def build_sim_group_step(cfg: LogConfig, n_replicas: int, *,
     independent; only the replica axis carries collectives — so one
     dispatch steps every group (the sharded-cluster hot path)."""
     from rdma_paxos_tpu.consensus.step import group_step
-    mapped = group_step(cfg=cfg, n_replicas=n_replicas,
-                        axis_name=REPLICA_AXIS, use_pallas=use_pallas,
-                        interpret=interpret, fanout=fanout,
-                        elections=elections, audit=audit,
-                        telemetry=telemetry, txn=txn)
-    return jax.jit(mapped, donate_argnums=(0,) if donate else ())
+    gstep = group_step(cfg=cfg, n_replicas=n_replicas,
+                       axis_name=REPLICA_AXIS, use_pallas=use_pallas,
+                       interpret=interpret, fanout=fanout,
+                       elections=elections, audit=audit,
+                       telemetry=telemetry, txn=txn)
+    return jax.jit(_with_step_scalars(gstep),
+                   donate_argnums=(0,) if donate else ())
 
 
 def build_sim_group_burst(cfg: LogConfig, n_replicas: int, *,
@@ -540,14 +562,19 @@ def build_sim_group_burst(cfg: LogConfig, n_replicas: int, *,
     def burst(state_gb, datas, metas, counts, peer_mask, applied, qdepth):
         zeros_gr = jnp.zeros_like(counts[0])
 
-        def body(st, xs):
+        def body(carry, xs):
+            st, acc = carry
             d, m, c = xs
             inp = StepInput(
                 batch_data=d, batch_meta=m, batch_count=c,
                 timeout_fired=zeros_gr, peer_mask=peer_mask,
                 apply_done=applied, queue_depth=qdepth)
-            return gstep(st, inp)
-        return lax.scan(body, state_gb, (datas, metas, counts))
+            st, out = gstep(st, inp)
+            acc = acc + out.accepted
+            return (st, acc), with_scalars(out, acc, st)
+        (st, _acc), outs = lax.scan(body, (state_gb, zeros_gr),
+                                    (datas, metas, counts))
+        return st, outs
     return jax.jit(burst, donate_argnums=(0,) if donate else ())
 
 
@@ -583,6 +610,7 @@ def build_spmd_group_step(cfg: LogConfig, n_replicas: int, mesh: Mesh,
     def per_device(state_b, inp_b):
         st, out = vcore(jax.tree.map(lambda x: x[:, 0], state_b),
                         jax.tree.map(lambda x: x[:, 0], inp_b))
+        out = with_scalars(out, out.accepted, st)
         return (jax.tree.map(lambda x: x[:, None], st),
                 jax.tree.map(lambda x: x[:, None], out))
 
@@ -625,15 +653,19 @@ def build_spmd_group_burst(cfg: LogConfig, n_replicas: int, mesh: Mesh,
         st = jax.tree.map(lambda x: x[:, 0], state_b)   # [Gl, ...]
         zeros_g = jnp.zeros_like(counts_b[0, :, 0])     # [Gl]
 
-        def body(s, xs):
+        def body(carry, xs):
+            s, acc = carry
             d, m, c = xs                # d: [Gl, 1, B, sw] etc.
             inp = StepInput(
                 batch_data=d[:, 0], batch_meta=m[:, 0],
                 batch_count=c[:, 0], timeout_fired=zeros_g,
                 peer_mask=peer_b[:, 0], apply_done=applied_b[:, 0],
                 queue_depth=qdepth_b[:, 0])
-            return vcore(s, inp)
-        st, outs = lax.scan(body, st, (datas_b, metas_b, counts_b))
+            s, out = vcore(s, inp)
+            acc = acc + out.accepted
+            return (s, acc), with_scalars(out, acc, s)
+        (st, _acc), outs = lax.scan(body, (st, zeros_g),
+                                    (datas_b, metas_b, counts_b))
         return (jax.tree.map(lambda x: x[:, None], st),
                 jax.tree.map(lambda x: x[:, :, None], outs))
 
@@ -663,5 +695,6 @@ def build_sim_step(cfg: LogConfig, n_replicas: int, *,
         axis_name=REPLICA_AXIS, use_pallas=use_pallas, interpret=interpret,
         fanout=fanout, elections=elections, audit=audit,
         telemetry=telemetry, txn=txn)
-    mapped = jax.vmap(core, in_axes=(0, 0), axis_name=REPLICA_AXIS)
-    return jax.jit(mapped, donate_argnums=(0,) if donate else ())
+    vstep = jax.vmap(core, in_axes=(0, 0), axis_name=REPLICA_AXIS)
+    return jax.jit(_with_step_scalars(vstep),
+                   donate_argnums=(0,) if donate else ())

@@ -348,12 +348,11 @@ class ClusterDriver:
         self.auto_evict = auto_evict
         self.fail_threshold = fail_threshold
         self.fail_count = np.zeros(n_replicas, np.int64)
+        # the failure detector takes the membership view from each
+        # step's own ``res`` (the packed row carries it); the manager's
+        # device-state reads serve the rare paths only, and only while
+        # nothing is in flight (see _drive_config_change)
         self._mm = MembershipManager(self.cluster)
-        # last known membership view (device-state reads are unsafe —
-        # and pipeline-serializing — while dispatches are in flight;
-        # see _member_view_cached)
-        self._member_cur = dict(bitmask_new=(1 << n_replicas) - 1,
-                                epoch=0, cid_state=0)
         # (phase, new_mask, epoch, steps_left) — steps_left bounds a change
         # wedged by leader churn losing the CONFIG entry; on expiry the
         # phase resets so eviction/request can be re-issued
@@ -1190,25 +1189,16 @@ class ClusterDriver:
                 with self._lock:
                     self._fail_inflight_locked(rt, "step-down")
 
-    def _member_view_cached(self, lead: int) -> dict:
-        """The current config view (bitmask/epoch/cid_state), refreshed
-        from device state only while NOTHING is in flight (a device
-        read under in-flight dispatches both races state donation and
-        serializes the pipeline). Config changes drain the pipeline
-        (see _pipeline_ready), so the cache is stale at most for the
-        duration of one drained transition."""
-        with self.cluster._host_lock:
-            if not self.cluster._tickets:
-                self._member_cur = self._mm.current(lead)
-        return self._member_cur
-
     def _failure_detector(self, res) -> None:
+        """Count the steps each member failed to ack the leader's
+        window, against the leader's config view of the SAME step
+        (both ride the packed readback row: no device read here)."""
         lead = self._leader_view
         if lead < 0:
             self.fail_count[:] = 0
             return
-        cur = self._member_view_cached(lead)
-        mask = cur["bitmask_new"]
+        mask = int(res["bitmask_new"][lead])
+        epoch = int(res["epoch"][lead])
         acked = res["peer_acked"][lead]
         for r in range(self.R):
             if not (mask >> r) & 1 or r == lead:
@@ -1229,15 +1219,12 @@ class ClusterDriver:
             # of live nodes would permanently shrink fault tolerance
             survivors = bin(new_mask).count("1")
             if survivors > bin(mask).count("1") // 2:
-                self._mm.submit_transit(lead, mask, new_mask,
-                                        cur["epoch"] + 1)
-                self._config_phase = ("transit", new_mask,
-                                      cur["epoch"] + 1, 500)
+                self._mm.submit_transit(lead, mask, new_mask, epoch + 1)
+                self._config_phase = ("transit", new_mask, epoch + 1, 500)
                 self.obs.metrics.inc("evictions_total", len(dead))
                 self.obs.trace.record(obs_trace.MEMBERSHIP_CHANGE,
                                       phase="evict_transit", dead=dead,
-                                      new_mask=new_mask,
-                                      epoch=cur["epoch"] + 1)
+                                      new_mask=new_mask, epoch=epoch + 1)
 
     def _drive_config_change(self) -> None:
         """Advance a two-phase (joint-consensus) config change one poll

@@ -37,16 +37,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from rdma_paxos_tpu.config import LogConfig
 from rdma_paxos_tpu.consensus.log import EntryType, META_W
-from rdma_paxos_tpu.consensus.step import StepInput, fetch_window
+from rdma_paxos_tpu.consensus.step import (
+    SCAN_KEYS, StepInput, fetch_window, unpack_scalars)
 from rdma_paxos_tpu.parallel.mesh import (
     REPLICA_AXIS, build_spmd_step, stack_states)
-
-# per-replica scalar outputs extracted from a step/burst (ONE list so the
-# single-step and burst paths can never drift)
-OUT_KEYS = ("term", "role", "leader_id", "voted_term", "voted_for",
-            "head", "apply", "commit", "end", "hb_seen", "became_leader",
-            "acked", "accepted", "leadership_verified", "burst_hint",
-            "rebase_delta")
 
 
 class HostReplicaDriver:
@@ -244,18 +238,32 @@ class HostReplicaDriver:
         same loop iteration. Returns THIS replica's scalar outputs."""
         inp = self.make_input(**kw)
         self.state, out = self._step(self.state, inp)
-        res = {}
-        keys = OUT_KEYS + (("audit_start", "audit_digest",
-                            "audit_term") if self._audit else ())
-        for k in keys:
-            arr = getattr(out, k)
-            # a 1-wide replica axis (single-host world) shards as
-            # slice(None), whose .start is None — that shard IS
-            # replica 0's
-            local = [s for s in arr.addressable_shards
-                     if (s.index[0].start or 0) == self.me]
-            res[k] = np.asarray(local[0].data[0]) if local else None
+        res = self._local_scalars(out.scal, 0)
+        for k in (("audit_start", "audit_digest", "audit_term")
+                  if self._audit else ()):
+            local = self._local_shard(getattr(out, k), 0)
+            res[k] = np.asarray(local[0]) if local is not None else None
         return res
+
+    def _local_shard(self, arr, axis: int):
+        """THIS replica's shard of a global array sharded on ``axis``
+        (None on a host that holds none). A 1-wide replica axis
+        (single-host world) shards as slice(None), whose .start is
+        None — that shard IS replica 0's."""
+        sh = [s for s in arr.addressable_shards
+              if (s.index[axis].start or 0) == self.me]
+        return sh[0].data if sh else None
+
+    def _local_scalars(self, scal, axis: int) -> Dict[str, np.ndarray]:
+        """THIS replica's final-step scalars out of a dispatch's packed
+        rows (``[R, N]`` of a step, ``[K, R, N]`` of a burst or scan,
+        replica axis ``axis``): ONE local read, unpacked by the rule
+        every engine shares (``accepted`` cumulative over a burst)."""
+        local = self._local_shard(scal, axis)
+        if local is None:
+            return {k: None for k in SCAN_KEYS}
+        rows = np.asarray(local)
+        return unpack_scalars(rows.reshape(-1, rows.shape[-1])[-1])
 
     def _kglobal(self, local_k: np.ndarray, fill=0) -> jax.Array:
         """[K, R, ...] global array sharded on axis 1; this host provides
@@ -321,17 +329,7 @@ class HostReplicaDriver:
         self.state, outs = fn(self.state, self._kglobal(data),
                               self._kglobal(meta), self._kglobal(count),
                               pm, ap, qd)
-        res = {}
-        for k in OUT_KEYS:
-            arr = getattr(outs, k)            # [K, R, ...]
-            local = [s for s in arr.addressable_shards
-                     if (s.index[1].start or 0) == self.me]
-            res[k] = (np.asarray(local[0].data[-1, 0])
-                      if local else None)
-        if res["accepted"] is not None:
-            acc = [s for s in outs.accepted.addressable_shards
-                   if (s.index[1].start or 0) == self.me]
-            res["accepted"] = np.asarray(acc[0].data[:, 0]).sum()
+        res = self._local_scalars(outs.scal, 1)
         if self._audit:
             # audit windows for EVERY fused step (not just the last) —
             # the daemon ingests them in order so the digest-chain
@@ -339,11 +337,10 @@ class HostReplicaDriver:
             # matching per-step commit frontiers
             for k in ("audit_start", "audit_digest", "audit_term",
                       "commit"):
-                arr = getattr(outs, k)          # [K, R, ...]
-                local = [s for s in arr.addressable_shards
-                         if (s.index[1].start or 0) == self.me]
+                local = self._local_shard(getattr(outs, k), 1)
                 res["audit_commit" if k == "commit" else k] = (
-                    np.asarray(local[0].data[:, 0]) if local else None)
+                    np.asarray(local[:, 0]) if local is not None
+                    else None)   # [K, ...]
         return res
 
     def _scan_fn(self):
@@ -400,28 +397,15 @@ class HostReplicaDriver:
                               self._kglobal(meta),
                               self._kglobal(count), pm, ap, qd)
 
-        def local_of(arr, axis):
-            sh = [s for s in arr.addressable_shards
-                  if (s.index[axis].start or 0) == self.me]
-            return sh[0].data if sh else None
-
-        from rdma_paxos_tpu.consensus.step import SCAN_KEYS
-        scal = local_of(outs["scal"], 1)        # [K, 1, NS]
-        res: Dict[str, np.ndarray] = {}
-        if scal is not None:
-            row = np.asarray(scal[-1, 0])
-            for i, k in enumerate(SCAN_KEYS):
-                res[k] = row[i]
-        else:
-            res = {k: None for k in SCAN_KEYS}
-        if self._audit and scal is not None:
+        res = self._local_scalars(outs["scal"], 1)
+        if self._audit and res["term"] is not None:
             for k in ("audit_start", "audit_digest", "audit_term",
                       "audit_commit"):
-                loc = local_of(outs[k], 1)      # [K, 1, ...]
+                loc = self._local_shard(outs[k], 1)     # [K, 1, ...]
                 res[k] = (np.asarray(loc[:, 0]) if loc is not None
                           else None)
-        wd = local_of(outs["replay_data"], 0)   # [1, W, sw]
-        wm = local_of(outs["replay_meta"], 0)
+        wd = self._local_shard(outs["replay_data"], 0)   # [1, W, sw]
+        wm = self._local_shard(outs["replay_meta"], 0)
         rows = (np.asarray(wd[0]) if wd is not None else None,
                 np.asarray(wm[0]) if wm is not None else None)
         return res, rows
@@ -445,9 +429,7 @@ class HostReplicaDriver:
         from rdma_paxos_tpu.consensus.state import ReplicaState
 
         def local(arr):
-            sh = [s for s in arr.addressable_shards
-                  if (s.index[0].start or 0) == self.me]
-            return np.asarray(sh[0].data[0])
+            return np.asarray(self._local_shard(arr, 0)[0])
 
         out = {"log_buf": local(self.state.log.buf)}
         for f in _dc.fields(ReplicaState):
@@ -460,8 +442,7 @@ class HostReplicaDriver:
         """Read ``window_slots`` entries beginning at ``start`` from THIS
         replica's log. Host-local (no collective): call freely, on any
         host, only when needed."""
-        sh = [s for s in self.state.log.buf.addressable_shards
-              if (s.index[0].start or 0) == self.me][0]
-        wd, wm = self._local_fetch(sh.data[0],
+        wd, wm = self._local_fetch(
+            self._local_shard(self.state.log.buf, 0)[0],
                                    jnp.asarray(start, jnp.int32))
         return np.asarray(wd), np.asarray(wm)

@@ -25,7 +25,7 @@ from rdma_paxos_tpu.consensus.log import (
     EntryType, M_CONN, M_GIDX, M_LEN, M_REQID, M_TYPE, META_W)
 from rdma_paxos_tpu.consensus.state import Role
 from rdma_paxos_tpu.consensus.step import (
-    SCAN_KEYS, StepInput, fetch_window)
+    StepInput, fetch_window, unpack_scalars)
 from rdma_paxos_tpu.parallel.mesh import (
     build_sim_burst, build_sim_scan, build_sim_step, build_spmd_burst,
     build_spmd_scan, build_spmd_step, make_replica_mesh, stack_states)
@@ -235,6 +235,20 @@ class StepTicket:
         self.applied0 = applied0
 
 
+def read_scalars(ticket: StepTicket) -> Dict[str, np.ndarray]:
+    """The ONE device-to-host read of a dispatch, which blocks on the
+    program: its packed rows (``[..., R, NS + R]``, with a leading K
+    from a fused dispatch, whose result is the final step's rows with
+    ``accepted`` cumulative in-program), unpacked into the ``res``
+    dict — every scalar the host rules consume, the config view and
+    ``peer_acked`` included. Shared by both engines."""
+    out = ticket.out
+    if ticket.kind == "step":
+        return unpack_scalars(np.asarray(out.scal))
+    return unpack_scalars(np.asarray(
+        out["scal"] if ticket.kind == "scan" else out.scal)[-1])
+
+
 class StagingPool:
     """Persistent, reusable host staging buffers for window encode.
 
@@ -324,10 +338,10 @@ class SimCluster:
         self.cfg = cfg
         # device-resident K-window scan tier (hostpath PR): with
         # scan=True, begin_burst dispatches the fused-scan program —
-        # same protocol computation as the burst, but the readback is
-        # ONE consolidated minimal transfer (scalar matrix + in-
-        # dispatch replay rows) instead of per-field stacks plus a
-        # separate fetch dispatch. Mutable at runtime (A/B benches
+        # same protocol computation as the burst (and the same packed
+        # scalar rows), but the committed rows are extracted INSIDE
+        # the dispatch instead of by a separate fetch dispatch, and no
+        # per-field stacks are returned. Mutable at runtime (A/B benches
         # flip it); scan-off clusters never build a scan program, so
         # their STEP_CACHE keys are untouched (tests pin it).
         self.scan = bool(scan)
@@ -612,12 +626,6 @@ class SimCluster:
     # (bounded recompiles) and padded with zero-count steps
     K_TIERS = (2, 4, 8, 16)
 
-    # step() result keys pulled to host numpy each dispatch
-    RES_KEYS = ("term", "role", "leader_id", "voted_term", "voted_for",
-                "head", "apply", "commit", "end", "hb_seen",
-                "became_leader", "acked", "accepted", "peer_acked",
-                "leadership_verified", "rebase_delta")
-
     def _step_bufs(self) -> dict:
         cfg, R, B = self.cfg, self.R, self.cfg.batch_slots
         return self._staging.acquire(
@@ -822,40 +830,20 @@ class SimCluster:
         if prof is not None:
             prof.sync(out)              # fenced device_sync (opt-in)
             prof.start("quorum_wait")
-        # the FIRST read blocks on the program; every read after it
-        # (``readback_rest``) is one small device-to-host transfer
-        if scan:
-            # consolidated minimal readback: ONE scalar matrix (final
-            # step's row; ``accepted`` is cumulative in-program) plus
-            # peer_acked — the replay rows are consumed lazily below
-            scal = np.asarray(out["scal"])[-1]           # [R, NS]
-            res = {k: scal[:, i] for i, k in enumerate(SCAN_KEYS)
-                   if k in self.RES_KEYS}
-            rest = {"peer_acked": out["peer_acked"]}
-        else:
-            first, *keys = self.RES_KEYS
-            res = {first: np.asarray(getattr(out, first))}
-            rest = {k: getattr(out, k) for k in keys}
-            if (not burst and self._txn
-                    and out.txn_vote is not None):
-                # serial dispatches only: the txn lane never rides
-                # burst/scan programs (their keys stay untouched)
-                rest["txn_vote"] = out.txn_vote
+        res = read_scalars(ticket)
+        # what is compiled only on request keeps a read of its own
+        # (``readback_rest``): none in the default programs
+        reads = 1
         if prof is not None:
             prof.start("readback_rest")
-        for k, v in rest.items():
-            res[k] = np.asarray(v)
+        if not (burst or scan) and self._txn and out.txn_vote is not None:
+            # serial dispatches only: the txn lane never rides
+            # burst/scan programs (their keys stay untouched)
+            res["txn_vote"] = np.asarray(out.txn_vote)
+            reads += 1
         if prof is not None:
             prof.stop("readback_rest")
-            prof.count("readback_arrays_total", 1 + len(rest))
-        if burst:
-            # [K, R] per fused step: the last step's row, but the
-            # accepted counts summed over the burst
-            res = {k: (v.sum(axis=0) if k == "accepted" else v[-1])
-                   for k, v in res.items()}
-        elif scan:
-            res["peer_acked"] = res["peer_acked"][-1]
-        if prof is not None:
+            prof.count("readback_arrays_total", reads)
             prof.stop("quorum_wait")
             prof.start("post_readback")
         if self._audit:

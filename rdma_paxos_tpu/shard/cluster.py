@@ -62,7 +62,7 @@ from rdma_paxos_tpu.consensus.log import (
     EntryType, Log, M_CONN, M_GIDX, M_LEN, M_REQID, M_TYPE, META_W)
 from rdma_paxos_tpu.consensus.state import Role
 from rdma_paxos_tpu.consensus.step import (
-    SCAN_KEYS, StepInput, fetch_window)
+    StepInput, fetch_window)
 from rdma_paxos_tpu.parallel.mesh import (
     GROUP_AXIS, REPLICA_AXIS, build_mesh_2d, build_sim_group_burst,
     build_sim_group_scan, build_sim_group_step, build_spmd_group_burst,
@@ -71,16 +71,9 @@ from rdma_paxos_tpu.parallel.mesh import (
 from rdma_paxos_tpu.runtime.hostpath import LazyReplayStream
 from rdma_paxos_tpu.runtime.sim import (
     STEP_CACHE, SimCluster, StagingPool, StepTicket, cap_tiers,
-    clamp_burst_take, decode_window, pack_rows, rebase_delta_of,
-    requeue_shortfall, require_drained)
+    clamp_burst_take, decode_window, pack_rows, read_scalars,
+    rebase_delta_of, requeue_shortfall, require_drained)
 from rdma_paxos_tpu.shard.router import KeyRouter
-
-# step() result keys pulled to host numpy each dispatch — the same set
-# SimCluster materializes, so per-group slices are drop-in res dicts
-_RES_KEYS = ("term", "role", "leader_id", "voted_term", "voted_for",
-             "head", "apply", "commit", "end", "hb_seen",
-             "became_leader", "acked", "accepted", "peer_acked",
-             "leadership_verified", "rebase_delta")
 
 TimeoutsLike = Union[None, Dict[int, Sequence[int]],
                      Sequence[Tuple[int, int]]]
@@ -719,23 +712,11 @@ class ShardedCluster:
         if prof is not None:
             prof.sync(out)              # fenced device_sync (opt-in)
             prof.start("quorum_wait")
-        if scan:
-            # consolidated minimal readback (see SimCluster.finish)
-            scal = np.asarray(out["scal"])[-1]       # [G, R, NS]
-            res = {k: scal[..., i] for i, k in enumerate(SCAN_KEYS)
-                   if k in _RES_KEYS}
-            res["peer_acked"] = np.asarray(out["peer_acked"])[-1]
-        elif burst:
-            res = {k: np.asarray(getattr(out, k))[-1]
-                   for k in _RES_KEYS if k != "accepted"}
-            acc = np.asarray(out.accepted).sum(axis=0)       # [G, R]
-            res["accepted"] = acc
-        else:
-            res = {k: np.asarray(getattr(out, k)) for k in _RES_KEYS}
-            if self._txn and out.txn_vote is not None:
-                # serial dispatches only: the txn lane never rides
-                # burst/scan programs (their keys stay untouched)
-                res["txn_vote"] = np.asarray(out.txn_vote)
+        res = read_scalars(ticket)               # [G, R] per key
+        if not (burst or scan) and self._txn and out.txn_vote is not None:
+            # serial dispatches only: the txn lane never rides
+            # burst/scan programs (their keys stay untouched)
+            res["txn_vote"] = np.asarray(out.txn_vote)
         if prof is not None:
             prof.stop("quorum_wait")
         if self._audit:

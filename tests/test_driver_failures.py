@@ -39,6 +39,36 @@ def test_auto_eviction_of_dead_member():
     d.stop()
 
 
+def test_eviction_steps_unchanged_with_view_taken_from_res():
+    """The failure detector judges each step's ``peer_acked`` row with
+    the config view of that step's own ``res`` (no device read): the
+    dead follower is evicted at the step counts the device-state view
+    gave (TRANSIT submitted on the fail_threshold-th silent step,
+    adopted on the next, STABLE on the one after), and every step's
+    view equals the device state's."""
+    d = make_driver(auto_evict=True, fail_threshold=5)
+    d.runtimes[0].timer.beat = lambda: None
+    d.cluster.run_until_elected(0)
+    d.step()
+    d.cluster.partition([[0, 1, 2, 3], [4]])
+    changes, prev = [], None
+    for n in range(1, 13):
+        r = d.step()
+        view = {k: int(r[k][0]) for k in d._mm.current(0)}
+        assert view == d._mm.current(0), n
+        row = (d._config_phase and d._config_phase[0],
+               view["bitmask_new"], view["cid_state"], view["epoch"])
+        if row != prev:
+            changes.append((n,) + row)
+        prev = row
+    assert changes == [
+        (1, None, 0b11111, int(ConfigState.STABLE), 0),
+        (5, "transit", 0b11111, int(ConfigState.STABLE), 0),
+        (6, "stable", 0b01111, int(ConfigState.TRANSIT), 1),
+        (7, None, 0b01111, int(ConfigState.STABLE), 2)], changes
+    d.stop()
+
+
 def test_driver_snapshot_recovery_path():
     d = make_driver()
     d.cluster.run_until_elected(0)

@@ -17,6 +17,7 @@ import jax
 from rdma_paxos_tpu.config import LogConfig
 from rdma_paxos_tpu.consensus.state import Role
 from rdma_paxos_tpu.runtime.sim import SimCluster
+from tests.readback_ref import assert_same, drive
 
 CFG = LogConfig(n_slots=64, slot_bytes=32, window_slots=16, batch_slots=8)
 
@@ -204,3 +205,24 @@ def test_burst_shortfall_requeues_instead_of_raising():
     c.step()
     assert [p for (_, _, _, p) in c.replayed[0]] == \
         [b"s%02d" % i for i in range(30)]
+
+
+# entries a dispatch: 5 ride a serial step, 12 the tier K=2, 100 the
+# tier K=16 (batch_slots=8)
+PACKED_CFG = LogConfig(n_slots=512, slot_bytes=32, window_slots=16,
+                       batch_slots=8)
+
+
+@pytest.mark.parametrize("n,K", [(5, 1), (12, 2), (100, 16)],
+                         ids=["step", "burst_k2", "burst_k16"])
+def test_packed_row_unpacks_to_fieldwise_readback(n, K):
+    """The ONE array a dispatch reads back unpacks to exactly what the
+    per-field reads returned: the final step's row of every StepOutput
+    field the host rules consume, ``accepted`` summed over the burst,
+    and the config view of the post-step state."""
+    c = SimCluster(PACKED_CFG, 3)
+    seen = drive(c, n, fused=K > 1)
+    assert max(k for _, k, _, _ in seen) == K
+    for kind, _, res, ref in seen:
+        assert_same(res, ref)
+    assert c.last["commit"][0] == 1 + 3 * n

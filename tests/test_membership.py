@@ -180,3 +180,38 @@ def test_full_join_ladder_extended_transit_stable():
     c.step()
     stream3 = [p for (_, _, _, p) in c.replayed[3]]
     assert b"history" in stream3
+
+
+@pytest.mark.parametrize("kind", ["change", "join"])
+def test_config_view_columns_equal_device_state_every_step(kind):
+    """The packed readback row carries each replica's config view of
+    the post-step state: at every step of a TRANSIT -> STABLE change
+    and of a join (EXTENDED -> TRANSIT -> STABLE) its four columns are
+    what ``MembershipManager.current(r)`` reads off the device."""
+    c = SimCluster(CFG, 8, group_size=3)
+    mm = MembershipManager(c)
+    c.run_until_elected(0)
+    c.submit(0, b"before")
+    step, states = c.step, []
+
+    def checked_step(*a, **kw):
+        res = step(*a, **kw)
+        for r in range(c.R):
+            cur = mm.current(r)
+            assert {k: int(res[k][r]) for k in cur} == cur, (
+                len(states), r)
+        states.append((int(res["cid_state"][0]), int(res["epoch"][0])))
+        return res
+
+    c.step = checked_step
+    if kind == "change":
+        mm.change(0, 0b11111)
+        ladder = [ConfigState.TRANSIT, ConfigState.STABLE]
+    else:
+        mm.join(0, 3)
+        ladder = [ConfigState.EXTENDED, ConfigState.TRANSIT,
+                  ConfigState.STABLE]
+    seen = [s for i, s in enumerate(states) if i == 0 or s != states[i - 1]]
+    assert seen == [(int(s), e + 1) for e, s in enumerate(ladder)], seen
+    assert c.last["bitmask_new"][0] == (0b11111 if kind == "change"
+                                        else 0b1111)

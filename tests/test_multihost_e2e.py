@@ -45,7 +45,10 @@ def wait_kv(port, key, want, timeout=30.0):
 
 def test_full_stack_multiprocess(tmp_path):
     wd = str(tmp_path)
-    procs, leader, ports = _boot_nodes(wd, iterations=4000)
+    # the iteration count is the daemons' lifetime: an idle iteration
+    # is ~5 ms (one packed readback a step), so 8000 leave the body
+    # ~40 s after the leader line even on a loaded box
+    procs, leader, ports = _boot_nodes(wd, iterations=8000)
     try:
         s = socket.create_connection(("127.0.0.1", ports[leader]),
                                      timeout=20)

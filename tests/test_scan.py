@@ -18,6 +18,7 @@ import pytest
 from rdma_paxos_tpu.config import LogConfig, TimeoutConfig
 from rdma_paxos_tpu.runtime.driver import ClusterDriver
 from rdma_paxos_tpu.runtime.sim import STEP_CACHE, SimCluster
+from tests.readback_ref import assert_same, drive
 
 CFG = LogConfig(n_slots=128, slot_bytes=64, window_slots=32,
                 batch_slots=8)
@@ -60,6 +61,24 @@ def test_engine_scan_bit_identical_to_burst():
     # every burst's replay rode the staged rows (commit deltas fit
     # the replay window on this workload)
     assert cs.applied.min() > 0
+
+
+@pytest.mark.parametrize("mode", ["sim", "spmd"])
+@pytest.mark.parametrize("n,K", [(12, 2), (100, 16)],
+                         ids=["k2", "k16"])
+def test_scan_packed_row_unpacks_to_fieldwise_readback(mode, n, K):
+    """The scan tier's ONE matrix (``peer_acked`` and the config view
+    now ride it) unpacks to the field-by-field readback of the same
+    drive through bursts, dispatch for dispatch."""
+    cfg = LogConfig(n_slots=512, slot_bytes=32, window_slots=16,
+                    batch_slots=8)
+    scans = drive(SimCluster(cfg, 3, mode=mode, scan=True), n)
+    bursts = drive(SimCluster(cfg, 3, mode=mode), n)
+    assert [(k, n) for k, n, _, _ in scans] == [
+        ("scan" if k == "burst" else k, n) for k, n, _, _ in bursts]
+    assert max(n for _, n, _, _ in scans) == K
+    for (_, _, res, _), (_, _, _, ref) in zip(scans, bursts):
+        assert_same(res, ref)
 
 
 def test_scan_equals_k_serial_steps():

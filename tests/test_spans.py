@@ -619,6 +619,14 @@ def _account_driver(tmp_path, pipeline, bench_wrapper=False):
         fetches.append(1)
         return jitted(log, starts)
     d.cluster._fetch_all = counted
+    # reads of the membership view off the device state, counted
+    d.member_reads = []
+    current = d._mm.current
+
+    def counted_current(r=0):
+        d.member_reads.append(r)
+        return current(r)
+    d._mm.current = counted_current
     dep = None
     if bench_wrapper:
         from perfbench.deployments._driver_common import (
@@ -650,15 +658,22 @@ def serial_account(tmp_path_factory):
         assert _step_until(d, lambda: all(e.done.is_set() for e in warm))
         base = dict(d._phase_prof.acc)
         n_fetch0 = len(fetches)
+        arrays0 = d.obs.metrics.snapshot()["counters"][
+            "readback_arrays_total"]
+        del d.member_reads[:]
         d.run()
         acked = _closed_loop_sets(d, n_clients=2, n_sets=150)
         time.sleep(0.2)             # the loop parks: idle_wait
         d.stop()
         assert d.loop_error is None
+        counters = d.obs.metrics.snapshot()["counters"]
         return dict(acc=_delta(d, base), acked=acked,
                     fetches=len(fetches) - n_fetch0,
                     events=list(d._phase_prof.events),
-                    counters=d.obs.metrics.snapshot()["counters"])
+                    counters=counters,
+                    readback_arrays=(counters["readback_arrays_total"]
+                                     - arrays0),
+                    member_reads=list(d.member_reads))
     finally:
         d.stop()
         srv.close()
@@ -693,9 +708,31 @@ def test_cycle_account_closes_on_the_serial_loop(serial_account):
     assert names and not names & {"cycle", "pipeline_wait",
                                   "unattributed", "profiler",
                                   "intake_to_ack", "intake_queue_wait"}
-    assert serial_account["counters"]["readback_arrays_total"] >= \
-        16 * acc["quorum_wait"][0]
     assert serial_account["counters"]["replay_applies_total"] > 0
+
+
+def test_one_readback_array_a_dispatch(serial_account):
+    """The default programs hand the host ONE array a dispatch: the
+    counter the benchmark's ``readback_arrays_per_dispatch`` reads rises
+    by exactly 1 a dispatch, and ``readback_rest`` (the reads after the
+    first: none) is still recorded every dispatch, at next to nothing,
+    so ``readback_rest_us`` reads about 0 and not ``null``."""
+    acc = serial_account["acc"]
+    n = acc["device_dispatch"][0]
+    assert n >= 150 and acc["quorum_wait"][0] == n
+    assert serial_account["readback_arrays"] == n
+    assert acc["readback_rest"][0] == n
+    assert acc["readback_rest"][1] < 0.02 * acc["quorum_wait"][1]
+    assert acc["readback_rest"][1] < 50.0 * n        # us
+
+
+def test_steady_serving_reads_no_membership_state(serial_account):
+    """Over 150 loaded dispatches the failure detector took the
+    membership view from each step's ``res``: not one
+    ``MembershipManager.current`` (four index programs and four
+    blocking reads of the device state) on a serving cycle."""
+    assert serial_account["acc"]["post_step_rules"][0] >= 150
+    assert serial_account["member_reads"] == []
 
 
 def test_intake_sums_are_one_sample_an_operation(serial_account):
@@ -802,6 +839,9 @@ def test_pipelined_loop_loses_no_phase_with_two_threads(tmp_path):
         evs += [handler(int(EntryType.SEND), conns[i % 2], b"w%03d" % i)
                 for i in range(200)]
         base = dict(d._phase_prof.acc)
+        arrays0 = d.obs.metrics.snapshot()["counters"][
+            "readback_arrays_total"]
+        del d.member_reads[:]
         d.run(period=0.001)
         for ev in evs:
             assert ev.done.wait(30)
@@ -810,6 +850,11 @@ def test_pipelined_loop_loses_no_phase_with_two_threads(tmp_path):
         assert d.loop_error is None
         assert d.cluster.max_inflight_dispatches >= 2
         acc = _delta(d, base)
+        # one array a dispatch and no membership read, with dispatches
+        # in flight too (where the cached view used to stand in)
+        assert d.obs.metrics.snapshot()["counters"][
+            "readback_arrays_total"] - arrays0 == acc["quorum_wait"][0]
+        assert d.member_reads == []
         # every dispatch was encoded, read back, applied and tailed:
         # no start or stop went missing between the two threads
         n = acc["device_dispatch"][0]

@@ -9,6 +9,7 @@ import pytest
 from rdma_paxos_tpu.config import LogConfig
 from rdma_paxos_tpu.consensus.state import Role
 from rdma_paxos_tpu.runtime.sim import SimCluster
+from tests.readback_ref import assert_same, drive
 
 CFG = LogConfig(n_slots=64, slot_bytes=32, window_slots=16, batch_slots=8)
 
@@ -86,3 +87,20 @@ def test_spmd_failover():
     c.submit(3, b"post")
     res = c.step()
     assert res["commit"][3] == 4
+
+
+@pytest.mark.parametrize("n,K", [(5, 1), (12, 2), (100, 16)],
+                         ids=["step", "burst_k2", "burst_k16"])
+def test_spmd_packed_row_unpacks_to_fieldwise_readback(n, K):
+    """One replica per device: the packed row is assembled per device
+    and read back as one (sharded) array — same values as the
+    per-field reads, the failure detector's ``peer_acked`` row and the
+    config view included."""
+    cfg = LogConfig(n_slots=512, slot_bytes=32, window_slots=16,
+                    batch_slots=8)
+    c = SimCluster(cfg, 3, mode="spmd", fanout="psum")
+    seen = drive(c, n, fused=K > 1)
+    assert max(k for _, k, _, _ in seen) == K
+    for _, _, res, ref in seen:
+        assert_same(res, ref)
+    assert c.last["commit"][0] == 1 + 3 * n
