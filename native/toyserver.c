@@ -3,10 +3,15 @@
  * Plays the role of the reference's pristine Redis/memcached builds
  * (apps/redis/mk): the e2e tests replicate it via LD_PRELOAD=interpose.so
  * without it knowing. Protocol (newline-framed, one request per line):
- *   SET <key> <value>\n  -> +OK\n
+ *   SET <key> <value>\n  -> +OK\n (-ERR full when the table is)
  *   GET <key>\n          -> <value>\n or -\n
  *   DEL <key>\n          -> +OK\n
- *   COUNT\n              -> <n>\n
+ *   COUNT\n              -> <n>\n (string keys and hash records)
+ *   HMSET <key> <field> <value> [<field> <value> ...]\n -> +OK\n
+ *   HGETALL <key>\n      -> <field> <value> [<field> <value> ...]\n or -\n
+ * HMSET creates the record or updates the named fields (redis's hash
+ * type, as YCSB's Redis binding uses it); HGETALL answers on ONE line,
+ * the fields in the order they were first set. Values hold no space.
  * Uses accept()/read()/write()/close() directly — the exact syscall
  * surface the shim hooks. Two serving modes:
  *   toyserver <port>      poll-based single thread (redis-style)
@@ -53,22 +58,23 @@ static const char* kv_get(const char* k) {
   int i = kv_find(k);
   return i < 0 ? NULL : vals[i];
 }
-static void kv_set(const char* k, const char* v) {
+static int kv_set(const char* k, const char* v) {   /* 0, or -1: full */
   for (unsigned i = kv_hash(k), n = 0; n < MAXKV;
        i = (i + 1) & (MAXKV - 1), n++) {
     if (used[i] && !strcmp(keys[i], k)) {
       snprintf(vals[i], 256, "%s", v);
-      return;
+      return 0;
     }
     if (!used[i]) {
-      if (nkv >= MAXKV - 1) return;      /* table full: drop */
+      if (nkv >= MAXKV - 1) return -1;
       used[i] = 1;
       snprintf(keys[i], 64, "%s", k);
       snprintf(vals[i], 256, "%s", v);
       nkv++;
-      return;
+      return 0;
     }
   }
+  return -1;
 }
 static void kv_del(const char* k) {
   int i = kv_find(k);
@@ -87,16 +93,87 @@ static void kv_del(const char* k) {
   }
 }
 
+/* Hash records (HMSET/HGETALL): a table of their own, same probing, no
+ * delete. A record holds up to MAXF fields in the order first set. */
+#define MAXH 16384              /* power of two */
+#define MAXF 16
+struct hrec {
+  char key[64];
+  int nf;
+  char field[MAXF][32], val[MAXF][256];
+};
+static struct hrec hrecs[MAXH];
+static unsigned char hused[MAXH];
+static int nh = 0;
+
+static struct hrec* h_find(const char* k, int create) {
+  for (unsigned i = kv_hash(k) & (MAXH - 1), n = 0; n < MAXH;
+       i = (i + 1) & (MAXH - 1), n++) {
+    if (hused[i] && !strcmp(hrecs[i].key, k)) return &hrecs[i];
+    if (!hused[i]) {
+      if (!create || nh >= MAXH - 1) return NULL;
+      hused[i] = 1;
+      snprintf(hrecs[i].key, 64, "%s", k);
+      hrecs[i].nf = 0;
+      nh++;
+      return &hrecs[i];
+    }
+  }
+  return NULL;
+}
+/* "<field> <value> <field> <value> ..." into the record, all of it or
+ * none; returns the reply */
+static const char* h_mset(const char* k, char* pairs) {
+  char* tok[2 * MAXF];
+  int nt = 0;
+  char* save;
+  for (char* t = strtok_r(pairs, " ", &save); t;
+       t = strtok_r(NULL, " ", &save)) {
+    if (nt == 2 * MAXF) return "-ERR full\n";
+    tok[nt++] = t;
+  }
+  if (!nt || nt % 2) return "-ERR\n";
+  if (kv_find(k) >= 0) return "-ERR wrongtype\n";
+  struct hrec* h = h_find(k, 0);
+  int fresh = 0;                /* fields the record does not have yet */
+  for (int t = 0; t < nt; t += 2) {
+    int j, seen = 0;
+    for (j = 0; h && j < h->nf && strcmp(h->field[j], tok[t]); j++) {}
+    for (int u = 0; u < t && !seen; u += 2) seen = !strcmp(tok[u], tok[t]);
+    fresh += !seen && (!h || j == h->nf);
+  }
+  if ((h ? h->nf : 0) + fresh > MAXF) return "-ERR full\n";
+  if (!h && !(h = h_find(k, 1))) return "-ERR full\n";
+  for (int t = 0; t < nt; t += 2) {
+    int j;
+    for (j = 0; j < h->nf && strcmp(h->field[j], tok[t]); j++) {}
+    if (j == h->nf) snprintf(h->field[h->nf++], 32, "%s", tok[t]);
+    snprintf(h->val[j], 256, "%s", tok[t + 1]);
+  }
+  return "+OK\n";
+}
+
 struct conn { int fd; char buf[BUFSZ]; int len; };
 
 static pthread_mutex_t kv_mu = PTHREAD_MUTEX_INITIALIZER;
 
 static void handle_line(int fd, char* line) {
-  char out[512], k[64], v[256];
+  char out[MAXF * 290 + 8], k[64], v[256];
+  int at = 0;
   pthread_mutex_lock(&kv_mu);
   if (sscanf(line, "SET %63s %255[^\n]", k, v) == 2) {
-    kv_set(k, v);
-    snprintf(out, sizeof out, "+OK\n");
+    snprintf(out, sizeof out, "%s",
+             h_find(k, 0) ? "-ERR wrongtype\n"
+             : kv_set(k, v) ? "-ERR full\n" : "+OK\n");
+  } else if (sscanf(line, "HMSET %63s %n", k, &at) == 1 && at) {
+    snprintf(out, sizeof out, "%s", h_mset(k, line + at));
+  } else if (sscanf(line, "HGETALL %63s", k) == 1) {
+    struct hrec* h = h_find(k, 0);
+    int n = 0;
+    for (int j = 0; h && j < h->nf; j++)
+      n += snprintf(out + n, sizeof out - (size_t)n, "%s%s %s",
+                    j ? " " : "", h->field[j], h->val[j]);
+    snprintf(out + n, sizeof out - (size_t)n, "%s\n", h ? "" : "-");
   } else if (sscanf(line, "GET %63s", k) == 1) {
     const char* r = kv_get(k);
     snprintf(out, sizeof out, "%s\n", r ? r : "-");
@@ -109,11 +186,13 @@ static void handle_line(int fd, char* line) {
      * replies to earlier pipelined commands */
     snprintf(out, sizeof out, "=%s\n", v);
   } else if (!strncmp(line, "COUNT", 5)) {
-    snprintf(out, sizeof out, "%d\n", nkv);
+    snprintf(out, sizeof out, "%d\n", nkv + nh);
   } else if (!strncmp(line, "DUMPALL", 7)) {
     /* full-state listing: "<key> <value>\n" per pair, "." terminator —
      * the app-level snapshot hook bounded recovery uses (the analog of
-     * redis BGSAVE producing an RDB: app state without event history) */
+     * redis BGSAVE producing an RDB: app state without event history).
+     * String keys ONLY: hash records are not listed, so an app that
+     * holds them is not rebuilt from this listing */
     for (unsigned i = 0; i < MAXKV; i++) {
       if (!used[i]) continue;
       char lineb[512];

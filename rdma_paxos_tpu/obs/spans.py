@@ -72,7 +72,13 @@ inside the optional fencing path):
 
   Counters: ``readback_arrays_total`` (device-to-host reads in
   ``quorum_wait``), ``replay_applies_total`` (calls into
-  ``ReplayEngine.apply``), ``phase_stalls_total`` and
+  ``ReplayEngine.apply``), ``replay_followers_total`` (followers a
+  dispatch replayed to: ``replay_send`` + ``replay_drain`` over it is
+  the pass per follower), ``replay_reply_bytes_total`` (app output read
+  off the replay sockets), ``intake_fragments_total`` and
+  ``intake_payload_bytes_total`` (log entries and bytes admitted at
+  intake: an operation larger than a slot is several entries),
+  ``phase_stalls_total`` and
   ``phase_stall_us_total{phase}`` (a phase instance longer than
   ``TimeoutConfig.elec_timeout_low``; each also leaves one
   ``phase_stall`` event in the trace ring).
@@ -666,7 +672,9 @@ class StepPhaseProfiler:
               PHASE_STORE_APPEND, PHASE_REPLAY_SEND, PHASE_REPLAY_DRAIN,
               PHASE_POST_STEP_RULES, PHASE_OBSERVE, OP_INTAKE_TO_ACK,
               OP_INTAKE_QUEUE_WAIT)
-    COUNTERS = ("readback_arrays_total", "replay_applies_total")
+    COUNTERS = ("readback_arrays_total", "replay_applies_total",
+                "replay_followers_total", "replay_reply_bytes_total",
+                "intake_fragments_total", "intake_payload_bytes_total")
     # a thread waiting by design: its length counts towards no stall,
     # its own or of the phase it waits in
     WAITS = (PHASE_IDLE_WAIT, PHASE_PIPELINE_WAIT)

@@ -285,11 +285,45 @@ def replay_store_into(store, replay: "ReplayEngine",
 
 class ReplayEngine:
     """Replays committed remote-origin events into the local app over
-    loopback TCP (the follower half of the reference proxy)."""
+    loopback TCP (the follower half of the reference proxy).
+
+    IN LOG ORDER ACROSS CONNECTIONS: each connection has a socket of its
+    own, and an event-loop app that finds two of them readable serves
+    them in ITS order (toyserver and redis: by slot or fd), not in the
+    order they were written. Two clients writing one key would then
+    leave the replicas with different values. So before bytes go to
+    another connection than the last one, :meth:`apply` waits for the
+    app to answer on the last one (any bytes: a single-threaded app has
+    by then consumed that request; the answer is read and dropped, which
+    is also all the draining the sockets need); and before more bytes go
+    to the SAME connection too, unless the last write left a request
+    unfinished (it did not end in a newline: a fragment), so that at
+    most one whole request is ever unanswered and an answer proves it
+    done. A request that is never answered (``noreply``) costs
+    ``ORDER_WAIT_S`` once and is counted in ``order_timeouts``; after it
+    the order is the app's. An app that leaves ``GIVE_UP_AFTER``
+    requests in a row unanswered (a sink) is not waited for again until
+    it does answer.
+    """
+
+    # how long a write to another connection waits for the app's answer
+    # on the last one
+    ORDER_WAIT_S = 0.05
+    GIVE_UP_AFTER = 3
+    # ORDER_WAIT_S as the kernel's receive timeout (a struct timeval),
+    # not Python's: the socket stays blocking, so a wait for the app's
+    # answer is ONE recv
+    _RCVTIMEO = struct.pack("ll", 0, int(ORDER_WAIT_S * 1e6))
 
     def __init__(self, app_host: str, app_port: int):
         self.addr = (app_host, app_port)
         self.conns: Dict[int, socket.socket] = {}
+        self._awaiting: Optional[socket.socket] = None
+        self._whole = False         # the last write there ended a request
+        self._reply_bytes = 0       # app output read since the last drain
+        self._sink = bytearray(65536)   # where that output is read to
+        self._unanswered = 0        # waits in a row that timed out
+        self.order_timeouts = 0
         # local (ephemeral) ports of our replay sockets: the driver uses
         # these to recognize its own replayed connections arriving back
         # through the app's interposition shim
@@ -321,8 +355,38 @@ class ReplayEngine:
             self.local_ports.discard(port)
             s.close()
             raise
+        s.setsockopt(socket.SOL_SOCKET, socket.SO_RCVTIMEO, self._RCVTIMEO)
         self.conns[conn_id] = s
         return s
+
+    def _settle(self, wait: bool = True) -> None:
+        """Read away the app's answer on the connection last written
+        to: waiting for it (at most ``ORDER_WAIT_S``) before bytes go to
+        another connection, or only if it is there already."""
+        s = self._awaiting
+        if s is None:
+            return
+        sink = self._sink
+        try:
+            n = s.recv_into(sink, 0, 0 if wait else socket.MSG_DONTWAIT)
+        except BlockingIOError:
+            if wait:                        # never answered
+                self.order_timeouts += 1
+                self._unanswered += 1
+                self._awaiting = None
+            return                          # not waiting: still owed
+        except OSError:
+            self._awaiting = None
+            return
+        self._awaiting = None
+        self._unanswered = 0
+        self._reply_bytes += n
+        try:
+            while n == len(sink):           # there may be more
+                n = s.recv_into(sink, 0, socket.MSG_DONTWAIT)
+                self._reply_bytes += n
+        except OSError:
+            pass
 
     def apply(self, etype: int, conn_id: int, payload: bytes) -> None:
         if etype == int(EntryType.CONNECT):
@@ -331,10 +395,16 @@ class ReplayEngine:
             s = self.conns.get(conn_id)
             if s is None:       # joined mid-stream: open lazily
                 s = self._connect(conn_id)
+            if self._awaiting is not s or self._whole:
+                self._settle(wait=self._unanswered < self.GIVE_UP_AFTER)
             s.sendall(payload)
+            self._awaiting = s
+            self._whole = payload.endswith(b"\n")
         elif etype == int(EntryType.CLOSE):
             s = self.conns.pop(conn_id, None)
             if s is not None:
+                if self._awaiting is s:
+                    self._settle()  # its last request, before the EOF
                 try:
                     self.local_ports.discard(s.getsockname()[1])
                     s.close()
@@ -376,6 +446,7 @@ class ReplayEngine:
         connection before the probe (TCP ordering + in-order reads), so
         probing every replay connection proves all delivered records
         were consumed."""
+        self._settle()
         for s in list(self.conns.values()):
             s.settimeout(timeout)
             try:
@@ -519,20 +590,17 @@ class ReplayEngine:
                 return False
             _time.sleep(0.002)
 
-    def drain_responses(self) -> None:
+    def drain_responses(self) -> int:
         """The local app writes responses to replayed connections; nobody
-        reads them (the reference's follower likewise discards app output
-        — only the leader's app talks to real clients). Drain so the app
-        never blocks on a full socket buffer."""
-        for s in self.conns.values():
-            s.setblocking(False)
-            try:
-                while s.recv(65536):
-                    pass
-            except (BlockingIOError, OSError):
-                pass
-            finally:
-                s.setblocking(True)
+        uses them (the reference's follower likewise discards app output
+        — only the leader's app talks to real clients). ``apply`` reads
+        each away before it writes to another connection, so what is
+        left is the last connection's: read if it is there already, else
+        by the next ``apply``. -> the bytes of app output read since the
+        last call."""
+        self._settle(wait=False)
+        n, self._reply_bytes = self._reply_bytes, 0
+        return n
 
     def close(self) -> None:
         for s in self.conns.values():

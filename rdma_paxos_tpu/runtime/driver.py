@@ -20,6 +20,7 @@ committed → run election timers (heartbeat = the step itself).
 from __future__ import annotations
 
 import collections
+import itertools
 import os
 import queue as _queue
 import threading
@@ -94,8 +95,8 @@ class _ReplicaRuntime:
                                   # into the entry's req_id so ack release
                                   # is exact across leadership churn
         self.replay_cursor = 0    # index into cluster.replayed[idx]
-        self.replicated_conns: set = set()   # conns whose events replicate
         self.passthrough_conns: set = set()  # our own replay connections
+        self.replicated_conns: set = set()   # conns whose events replicate
         self.timer = ElectionTimer(timeout_cfg, seed=seed)
         # false-positive detection for the adaptive timeout (to_adjust_cb
         # analog): if the SAME leader heartbeats again shortly after we
@@ -377,6 +378,8 @@ class ClusterDriver:
         # wait with one multiply, not one clock read an operation
         self._intake_n = 0          # guarded-by: _lock
         self._intake_t0_sum = 0.0   # guarded-by: _lock
+        self._intake_frags = 0      # guarded-by: _lock
+        self._intake_bytes = 0      # guarded-by: _lock
         # advisory leader view: written under the lock on the readback
         # thread; lock-free reads (poll/app threads) tolerate one step
         # of staleness by design  # guarded-by: _lock [writes]
@@ -586,6 +589,8 @@ class ClusterDriver:
         rt.inflight.append((ev, rt.submit_seq))
         self._intake_n += 1
         self._intake_t0_sum += ev.t0
+        self._intake_frags += len(frags)
+        self._intake_bytes += len(payload)
         self.obs.metrics.inc("proxy_events_total", replica=r)
         self.obs.trace.record(obs_trace.PROXY_ENQUEUE,
                               replica=r, etype=etype,
@@ -675,6 +680,13 @@ class ClusterDriver:
                 wait = n * time.perf_counter() - self._intake_t0_sum
                 self._phase_prof.credit("intake_queue_wait", wait * 1e6, n)
                 self._intake_n, self._intake_t0_sum = 0, 0.0
+                # log entries and bytes admitted since the last pump
+                # (an operation over slot_bytes is several entries)
+                self._phase_prof.count("intake_fragments_total",
+                                       self._intake_frags)
+                self._phase_prof.count("intake_payload_bytes_total",
+                                       self._intake_bytes)
+                self._intake_frags = self._intake_bytes = 0
 
     def step(self) -> Dict:
         """One host-loop iteration (public for deterministic tests).
@@ -784,6 +796,7 @@ class ClusterDriver:
         prof.start("post_step_rules")
         self._update_leader_view(res)
 
+        replays: list = []
         for r, rt in enumerate(self.runtimes):
             if rt.hard is not None:
                 rt.hard.save(int(res["term"][r]),
@@ -802,7 +815,7 @@ class ClusterDriver:
                     rt.timer.false_positive()
                     rt.fired_countdown = 0
             prof.stop("post_step_rules")
-            self._apply_new_entries(r, rt)
+            self._apply_new_entries(r, rt, replays)
             prof.start("post_step_rules")
             if res["role"][r] != int(Role.LEADER):
                 with self._lock:
@@ -812,6 +825,9 @@ class ClusterDriver:
                     # replicated may still commit later; seq-stamped acks
                     # make those late applies harmless no-ops.
                     self._fail_inflight_locked(rt, "deposition")
+        prof.stop("post_step_rules")
+        self._replay_in_turns(replays)
+        prof.start("post_step_rules")
 
         self._step_down_detector(res)
         self._failure_detector(res)
@@ -1529,7 +1545,13 @@ class ClusterDriver:
             replay_store_into(rrt.store, rrt.replay,
                               start=0 if app_fresh else old_len)
 
-    def _apply_new_entries(self, r: int, rt: _ReplicaRuntime) -> None:
+    def _apply_new_entries(self, r: int, rt: _ReplicaRuntime,
+                           replays: list) -> None:
+        """Persist replica ``r``'s newly committed entries, release the
+        acks of its own ones and plan what its app is to be replayed:
+        ``(engine, ops)`` is appended to ``replays``, which
+        :meth:`_replay_in_turns` delivers once every replica's turn is
+        done."""
         stream = self.cluster.replayed[r]
         n = len(stream)
         if rt.replay_cursor >= n:
@@ -1558,29 +1580,23 @@ class ClusterDriver:
         # reset_app rebuilds it
         replaying = rt.replay is not None and not rt.app_dirty
         own_max = -1
-        n_applies = 0
+        remote: list = []
 
         def own_of(conns, _gens):
             return conn_origin(conns) == r
 
         prof.start("replay_send")
         for seg in segs:
+            # remote SEND runs arrive coalesced per connection (one
+            # loopback write per run — byte-stream identical for the
+            # app); CONNECT/CLOSE apply individually
             seg_max, ops, _n_rem = plan_segment(seg, own_of,
                                                 want_ops=replaying)
             own_max = max(own_max, seg_max)
-            if replaying:
-                # remote SEND runs arrive coalesced per connection
-                # (one loopback write per run — byte-stream identical
-                # for the app); CONNECT/CLOSE apply individually
-                for etype, conn, payload in ops:
-                    rt.replay.apply(etype, conn, payload)
-                n_applies += len(ops)
+            remote.extend(ops)
         prof.stop("replay_send")
-        if replaying:
-            prof.count("replay_applies_total", n_applies)
-            prof.start("replay_drain")
-            rt.replay.drain_responses()
-            prof.stop("replay_drain")
+        if remote:
+            replays.append((rt.replay, remote))
         if rt.store is not None:
             # The WRITE precedes the ack (store_record runs inside the
             # reference's apply, before the proxy releases the client,
@@ -1633,6 +1649,32 @@ class ClusterDriver:
                             (len(releases) * now - t0_sum) * 1e6,
                             len(releases))
             prof.stop("ack_release")
+        prof.stop("apply_replay_ack")
+
+    def _replay_in_turns(self, replays: list) -> None:
+        """Deliver each follower's planned operations to its app, one
+        operation a follower in turn, and count what was delivered and
+        what the apps answered. A ``ReplayEngine`` waits for its app's
+        answer before its next write (log order across connections is
+        what keeps the apps equal); taken in turns, that answer is
+        produced while the other followers are written to."""
+        if not replays:
+            return
+        prof = self._phase_prof
+        prof.start("apply_replay_ack")
+        prof.start("replay_send")
+        for turn in itertools.zip_longest(*(ops for _, ops in replays)):
+            for (engine, _), op in zip(replays, turn):
+                if op is not None:
+                    engine.apply(*op)
+        prof.stop("replay_send")
+        prof.count("replay_applies_total",
+                   sum(len(ops) for _, ops in replays))
+        prof.count("replay_followers_total", len(replays))
+        prof.start("replay_drain")
+        reply_bytes = sum(engine.drain_responses() for engine, _ in replays)
+        prof.stop("replay_drain")
+        prof.count("replay_reply_bytes_total", reply_bytes)
         prof.stop("apply_replay_ack")
 
     # ------------------------------------------------------------------

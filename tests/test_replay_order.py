@@ -1,0 +1,157 @@
+"""Followers replay in LOG order across connections.
+
+Each replicated connection is a socket of its own into the follower's
+app, and an event-loop app that finds several readable serves them in
+its own order. ``ReplayEngine`` therefore waits for the app's answer on
+one connection before it writes to another: two clients that write one
+key leave every replica with the same value."""
+
+import selectors
+import socket
+import threading
+import time
+
+import pytest
+
+from rdma_paxos_tpu.consensus.log import EntryType
+from rdma_paxos_tpu.proxy.proxy import ReplayEngine
+
+SEND, CONNECT, CLOSE = (int(EntryType.SEND), int(EntryType.CONNECT),
+                        int(EntryType.CLOSE))
+
+
+class LateApp(threading.Thread):
+    """A single-threaded event-loop server that wakes late (``nap`` s
+    before each look at its sockets) and serves the readable ones in
+    REVERSE order of acceptance: whatever was written to two connections
+    while it slept is served out of order. One ``+OK`` a line
+    (``answers``), or nothing at all (a sink)."""
+
+    def __init__(self, nap=0.01, answers=True):
+        super().__init__(daemon=True)
+        self.nap, self.answers = nap, answers
+        self.srv = socket.socket()
+        self.srv.bind(("127.0.0.1", 0))
+        self.srv.listen(64)
+        self.port = self.srv.getsockname()[1]
+        self.served = []            # lines, in the order served
+        self.stop = False
+        self.start()
+
+    def run(self):
+        sel = selectors.DefaultSelector()
+        sel.register(self.srv, selectors.EVENT_READ)
+        conns, bufs = [], {}
+        while not self.stop:
+            time.sleep(self.nap)
+            ready = {k.fileobj for k, _ in sel.select(timeout=0.05)}
+            if self.srv in ready:
+                c, _ = self.srv.accept()
+                conns.append(c)
+                bufs[c] = b""
+                sel.register(c, selectors.EVENT_READ)
+            for c in reversed(conns):
+                if c not in ready:
+                    continue
+                try:
+                    data = c.recv(65536)
+                except OSError:
+                    data = b""
+                if not data:
+                    sel.unregister(c)
+                    conns.remove(c)
+                    continue
+                bufs[c] += data
+                while b"\n" in bufs[c]:
+                    line, bufs[c] = bufs[c].split(b"\n", 1)
+                    self.served.append(line)
+                    if self.answers:
+                        try:
+                            c.sendall(b"+OK\n")
+                        except OSError:
+                            pass
+
+
+@pytest.fixture()
+def late_app():
+    apps = []
+
+    def make(**kw):
+        apps.append(LateApp(**kw))
+        return apps[-1]
+    yield make
+    for a in apps:
+        a.stop = True
+        a.srv.close()
+
+
+def test_connections_are_served_in_log_order(late_app):
+    app = late_app()
+    eng = ReplayEngine("127.0.0.1", app.port)
+    conns = [101, 102, 103, 104]
+    for c in conns:
+        eng.apply(CONNECT, c, b"")
+    sent = [b"SET k v%d" % i for i in range(60)]
+    for i, line in enumerate(sent):
+        eng.apply(SEND, conns[(i * 7) % 4], line + b"\n")
+    deadline = time.time() + 10
+    while len(app.served) < len(sent) and time.time() < deadline:
+        time.sleep(0.01)
+    assert app.served == sent
+    assert eng.order_timeouts == 0
+    # every answer but the last connection's was read away on the way
+    assert eng.drain_responses() >= 4 * (len(sent) - 1)
+    eng.close()
+
+
+def test_a_run_on_one_connection_is_not_waited_for(late_app):
+    """Bytes to the SAME connection need no answer in between: TCP keeps
+    their order (a fragmented request is several writes and one line)."""
+    app = late_app()
+    eng = ReplayEngine("127.0.0.1", app.port)
+    t0 = time.perf_counter()
+    for part in (b"SET big ", b"aaaa", b"bbbb\n"):
+        eng.apply(SEND, 7, part)
+    assert time.perf_counter() - t0 < ReplayEngine.ORDER_WAIT_S
+    eng.apply(SEND, 8, b"SET other 1\n")
+    eng.apply(CLOSE, 8, b"")            # waits for its answer, then EOF
+    assert app.served == [b"SET big aaaabbbb", b"SET other 1"]
+    assert eng.order_timeouts == 0
+    eng.close()
+
+
+def test_an_app_that_never_answers_is_given_up_on(late_app):
+    """A sink costs ``GIVE_UP_AFTER`` waits, not one a write."""
+    app = late_app(nap=0.0, answers=False)
+    eng = ReplayEngine("127.0.0.1", app.port)
+    t0 = time.perf_counter()
+    for i in range(40):
+        eng.apply(SEND, 200 + i % 2, b"line %d\n" % i)
+    took = time.perf_counter() - t0
+    assert eng.order_timeouts == ReplayEngine.GIVE_UP_AFTER
+    assert took < (ReplayEngine.GIVE_UP_AFTER + 2) * ReplayEngine.ORDER_WAIT_S
+    assert eng.drain_responses() == 0
+    eng.close()
+
+
+def test_a_second_request_on_one_connection_waits_for_the_first(late_app):
+    """At most ONE whole request is ever unanswered: were a connection
+    written to twice in a row, the answer to its first request would
+    pass for the second's, and the next connection's bytes could be
+    served before it (the last operation of one dispatch and the first
+    of the next are often one connection's)."""
+    app = late_app(nap=0.0)
+    eng = ReplayEngine("127.0.0.1", app.port)
+    sent = []
+    for i in range(60):
+        conn = 10 if i % 3 else 9           # 9, 10, 10, 9, 10, 10, ...
+        sent.append(b"line%d" % i)
+        eng.apply(SEND, conn, sent[-1] + b"\n")
+    eng.apply(CLOSE, 9, b"")
+    eng.apply(CLOSE, 10, b"")
+    deadline = time.time() + 10
+    while len(app.served) < len(sent) and time.time() < deadline:
+        time.sleep(0.01)
+    assert app.served == sent
+    assert eng.order_timeouts == 0
+    eng.close()
