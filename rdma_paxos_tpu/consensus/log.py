@@ -98,8 +98,18 @@ class Log:
     one ``[n_slots, slot_words + META_W]`` array so every ring gather /
     scatter in the replication hot path touches a single array (the
     dominant step cost scales with the number of these ops, measured ~2x
-    win over separate data/meta arrays). ``data`` / ``meta`` are computed
-    column views — XLA fuses the slices away."""
+    win over separate data/meta arrays).
+
+    Device code reads ROWS FIRST (:func:`rows_at`, :func:`extract_window`:
+    index ``buf`` by slot, then take the columns of what was gathered).
+    ``data`` / ``meta`` are column views of the WHOLE ring and on a device
+    they are not free: the v5e does not fuse such a slice into the gather
+    that follows it, it materialises ``[n_slots, 8 | slot_words]`` for
+    every replica, 0.6 ms a view and step at 3 x 131072 slots even where
+    ONE row of it is read (four of them were half of a 9.6 ms dispatch;
+    PERF.md section 6, PR 30). They stay for host-side callers, tests, and
+    the step's config rescan, which must see every slot and runs only on
+    a step where a cached config source was invalidated."""
 
     buf: jax.Array    # [..., n_slots, slot_words + META_W] int32
 
@@ -138,11 +148,22 @@ def slot_of(g: jax.Array, n_slots: int) -> jax.Array:
     return jnp.bitwise_and(g, n_slots - 1)
 
 
+def rows_at(log: Log, g: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """``(data, meta)`` of the entries at global indices ``g`` (a scalar
+    or ``[N]``): ONE gather of ``buf`` by row, the columns taken from
+    what was gathered — O(rows read) of the ring, never a view of it.
+    The read every device-side caller goes through."""
+    w = log.buf[slot_of(g, log.n_slots)]
+    return w[..., :log.slot_words], w[..., log.slot_words:]
+
+
 def last_term(log: Log, end: jax.Array) -> jax.Array:
-    """Term of the last entry (0 for an empty log) — used for the election
-    up-to-date check (reference ``dare_server.c:1596-1652``)."""
-    t = log.meta[slot_of(end - 1, log.n_slots), M_TERM]
-    return jnp.where(end > 0, t, 0)
+    """Term of the last entry of a log that ends at ``end``, i.e. of entry
+    ``end - 1`` (0 for an empty log) — the election up-to-date check
+    (reference ``dare_server.c:1596-1652``) and the AppendEntries
+    prev-term of a window that starts at ``end``."""
+    _, m = rows_at(log, end - 1)
+    return jnp.where(end > 0, m[M_TERM], 0)
 
 
 # ---------------------------------------------------------------------------
@@ -204,10 +225,7 @@ def extract_window(
     ``dare_ibv_rc.c:1526-1642``); the ring wrap that costs the reference two
     RDMA sends (``:1539-1545``) is absorbed by the modular gather.
     """
-    idx = slot_of(start + jnp.arange(window_slots, dtype=jnp.int32),
-                  log.n_slots)
-    w = log.buf[idx]                         # ONE gather for data + meta
-    return w[:, :log.slot_words], w[:, log.slot_words:]
+    return rows_at(log, start + jnp.arange(window_slots, dtype=jnp.int32))
 
 
 def absorb_window(
@@ -253,7 +271,7 @@ def absorb_window(
     accept = wstart <= my_end
 
     # --- divergence scan over the overlap ---
-    local_terms = log.meta[slot_of(g, n_slots), M_TERM]
+    local_terms = rows_at(log, g)[1][:, M_TERM]
     in_overlap = valid & (g < my_end)
     mismatch = in_overlap & (local_terms != wmeta[:, M_TERM])
     any_conflict = jnp.any(mismatch)

@@ -69,7 +69,8 @@ from jax import lax
 from rdma_paxos_tpu.config import LogConfig
 from rdma_paxos_tpu.consensus.log import (
     EntryType, Log, M_GIDX, M_TERM, M_TYPE, META_W,
-    append_batch, absorb_window, extract_window, last_term, slot_of,
+    append_batch, absorb_window, extract_window, last_term, rows_at,
+    slot_of,
 )
 from rdma_paxos_tpu.consensus.state import ConfigState, ReplicaState, Role
 from rdma_paxos_tpu.ops.quorum import R_PAD, commit_scan
@@ -164,6 +165,11 @@ class StepOutput:
                               # NodeDaemon applies it collectively; the
                               # in-process drivers use their omniscient
                               # min-head instead (partition-safe).
+    cfg_rescanned: jax.Array  # 1 when the full-ring config rescan RAN this
+                              # step (some replica's cached config source
+                              # was invalidated); the same on every replica
+                              # of the group. 0 in every steady step: the
+                              # proof that the conditional is not taken.
     # --- correctness-observability digest chain (audit=True only) ---
     # None in the default program: None leaves add no pytree nodes, so
     # the audit=False step is BYTE-IDENTICAL to the pre-audit program
@@ -544,8 +550,7 @@ def replica_step(
         wstart = jnp.maximum(jnp.maximum(wstart, state.head), 0)
         wcount = jnp.clip(end2 - wstart, 0, W)
         wdata, wmeta = extract_window(log2, wstart, W)
-        prev_term = jnp.where(
-            wstart > 0, log2.meta[slot_of(wstart - 1, cfg.n_slots), M_TERM], 0)
+        prev_term = last_term(log2, wstart)     # of entry wstart - 1
 
         # pruning input: min apply over reachable members (leader-only use)
         min_apply = jnp.min(jnp.where(heard & (in_new > 0), g_apply, I32_MAX))
@@ -599,9 +604,7 @@ def replica_step(
 
         m_wstart, m_wcount = m_scal[S_WSTART], m_scal[S_WCOUNT]
         gap = m_wstart > end2
-        local_prev = jnp.where(
-            m_wstart > 0,
-            log2.meta[slot_of(m_wstart - 1, cfg.n_slots), M_TERM], 0)
+        local_prev = last_term(log2, m_wstart)
         prev_ok = (m_wstart == 0) | (local_prev == m_scal[S_PREV])
         can_absorb = use & ~gap & prev_ok
 
@@ -645,13 +648,27 @@ def replica_step(
     # the committed checkpoint — so truncating an uncommitted CONFIG
     # still rolls the config back (no abandoned-config trap).
     #
-    # Cost honesty: under ``shard_map`` (the real multi-chip path) the
-    # predicate is a per-device scalar and the rescan truly only runs on
-    # invalidation; under ``vmap`` (single-chip simulation) a batched-
-    # predicate cond lowers to select_n and BOTH branches execute, so
-    # the sim still pays one full-ring scan per step — the same cost as
-    # the pre-incremental code, no worse. The committed-checkpoint scan
-    # below was removed outright on every path. CONFIG entries take
+    # Cost honesty (what the v5e trace showed; PERF.md section 6, PR 30):
+    # the rescan is the only ring-sized work of the step, so its
+    # conditional has to be real in every mapping. On a replica's OWN
+    # flag it was not: under ``vmap`` a batched predicate lowers
+    # ``lax.cond`` to ``select_n`` and BOTH branches ran every step (4.1
+    # ms of a 9.6 ms dispatch at 3 x 131072 slots, 9.4 of 22.0 ms at 7;
+    # 39 us on four chips, where the flag is a device's own scalar). So
+    # the branch is gated on "ANY replica of the group invalid": each
+    # replica's flag rides the ack gather of Phase F, issued before the
+    # rescan (its inputs are all known by then; no new collective), and
+    # a value reduced over ``axis_name`` is UNBATCHED under
+    # ``vmap(axis_name=...)``, so the ``cond`` stays a ``cond``. Inside
+    # the taken branch each replica keeps ``where(cfg_invalid, rescanned,
+    # kept)``: bit for bit the per-replica rule. Unheard peers' flags
+    # count too: the flag decides only whether the expensive branch
+    # runs, never what a replica adopts. On a mesh a rare rescan runs on
+    # every chip at once, which is harmless. Under the group engines'
+    # second, UNNAMED ``vmap`` (:func:`group_step`) the predicate is
+    # batched over groups again and the select comes back: no worse than
+    # before, and no benchmark cell runs them. ``StepOutput.
+    # cfg_rescanned`` says whether the branch ran. CONFIG entries take
     # effect from append/absorb time (poll_config_entries,
     # dare_server.c:2133-2187). Runs BEFORE the commit scan (joint
     # consensus needs the new quorum rules from append time).
@@ -673,27 +690,45 @@ def replica_step(
                     & (state.cfg_src < wend_abs) & ~same_entry)
         cfg_invalid = (state.cfg_src >= 0) & (stale_src | replaced)
 
-        def _cfg_rescan(_):
-            all_gidx = log3.meta[:, M_GIDX]
-            live = ((log3.meta[:, M_TYPE] == int(EntryType.CONFIG))
-                    & (all_gidx >= head1) & (all_gidx < end3))
-            pos = _lex_argmax(live, [all_gidx])
-            found = pos >= 0
-            psafe = jnp.maximum(pos, 0)
-            w = log3.data[psafe]
-            return (jnp.where(found, all_gidx[psafe], -1),
-                    jnp.where(found, log3.meta[psafe, M_TERM], 0),
-                    jnp.where(found, w[0].astype(jnp.uint32), state.ccfg_old),
-                    jnp.where(found, w[1].astype(jnp.uint32), state.ccfg_new),
-                    jnp.where(found, w[2], state.ccfg_cid),
-                    jnp.where(found, w[3], state.ccfg_epoch))
+    # the ack gather of Phase F, issued here: it carries ``cfg_invalid``
+    # to the rescan's predicate below
+    with jax.named_scope("ack_quorum"):
+        my_ack = jnp.where(can_absorb, m_wstart + m_wcount, 0).astype(i32)
+        ack_msg = jnp.stack([my_ack, jnp.where(can_absorb, dom, -1),
+                             cfg_invalid.astype(i32)])
+        g_acks = lax.all_gather(ack_msg, axis_name)             # [R, 3]
+
+    with jax.named_scope("cfg_rescan"):
+        any_invalid = jnp.any(g_acks[:, 2] > 0)
 
         def _cfg_keep(_):
             return (state.cfg_src, state.cfg_src_term, state.bitmask_old,
                     state.bitmask_new, state.cid_state, state.epoch)
 
+        def _cfg_rescan(_):
+            # the one place that must see every slot: a column view of the
+            # whole ring (Log.meta) is what it costs; the row found is
+            # then read as a row
+            all_meta = log3.meta
+            all_gidx = all_meta[:, M_GIDX]
+            live = ((all_meta[:, M_TYPE] == int(EntryType.CONFIG))
+                    & (all_gidx >= head1) & (all_gidx < end3))
+            pos = _lex_argmax(live, [all_gidx])
+            found = pos >= 0
+            psafe = jnp.maximum(pos, 0)
+            w, wm = rows_at(log3, psafe)    # psafe < n_slots: its own slot
+            scanned = (
+                jnp.where(found, all_gidx[psafe], -1),
+                jnp.where(found, wm[M_TERM], 0),
+                jnp.where(found, w[0].astype(jnp.uint32), state.ccfg_old),
+                jnp.where(found, w[1].astype(jnp.uint32), state.ccfg_new),
+                jnp.where(found, w[2], state.ccfg_cid),
+                jnp.where(found, w[3], state.ccfg_epoch))
+            return tuple(jnp.where(cfg_invalid, a, b)
+                         for a, b in zip(scanned, _cfg_keep(None)))
+
         (base_src, base_sterm, base_old, base_new, base_cid,
-         base_epoch) = lax.cond(cfg_invalid, _cfg_rescan, _cfg_keep, None)
+         base_epoch) = lax.cond(any_invalid, _cfg_rescan, _cfg_keep, None)
 
         # newest CONFIG in the absorbed window (followers learn configs here)
         w_offs = jnp.arange(W, dtype=i32)
@@ -760,14 +795,11 @@ def replica_step(
     # POST-absorb membership config.
     # ------------------------------------------------------------------
     with jax.named_scope("ack_quorum"):
-        my_ack = jnp.where(can_absorb, m_wstart + m_wcount, 0).astype(i32)
-        ack_pair = jnp.stack([my_ack, jnp.where(can_absorb, dom, -1)])
-        g_acks = lax.all_gather(ack_pair, axis_name)            # [R, 2]
         acks_for_me = jnp.where(heard & (g_acks[:, 1] == me), g_acks[:, 0], 0)
         acks_pad = jnp.zeros((R_PAD,), i32).at[:R].set(acks_for_me)
 
         cwin_g = state.commit + jnp.arange(W, dtype=i32)
-        cwin_meta = log3.meta[slot_of(cwin_g, cfg.n_slots)]     # [W, META_W]
+        cwin_data, cwin_meta = rows_at(log3, cwin_g)            # [W, ...]
         terms_win = cwin_meta[:, M_TERM]
         scanned = commit_scan(
             acks_pad, state.commit, new_term2, end3, terms_win,
@@ -822,8 +854,7 @@ def replica_step(
                    & (cwin_meta[:, M_GIDX] == cwin_g)
                    & (cwin_g < commit2))
         xpos = _lex_argmax(crossed, [cwin_g])
-        xw = log3.data[slot_of(state.commit + jnp.maximum(xpos, 0),
-                               cfg.n_slots)]
+        xw = cwin_data[jnp.maximum(xpos, 0)]    # the row of entry commit+xpos
         x_found = xpos >= 0
         cc1_old = jnp.where(x_found & (xw[3] > state.ccfg_epoch),
                             xw[0].astype(jnp.uint32), state.ccfg_old)
@@ -986,6 +1017,7 @@ def replica_step(
                     ~(cfg.n_slots - 1)),
                 0),
             0).astype(i32),
+        cfg_rescanned=any_invalid.astype(i32),
         audit_start=audit_start,
         audit_digest=audit_digest,
         audit_term=audit_terms,
@@ -1064,7 +1096,7 @@ SCAN_KEYS = ("term", "role", "leader_id", "voted_term", "voted_for",
              "head", "apply", "commit", "end", "hb_seen",
              "became_leader", "acked", "accepted",
              "leadership_verified", "rebase_delta", "burst_hint",
-             ) + CONFIG_VIEW_KEYS
+             ) + CONFIG_VIEW_KEYS + ("cfg_rescanned",)
 
 
 def scan_scalars(out: StepOutput, accepted_total: jax.Array,

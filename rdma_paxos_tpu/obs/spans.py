@@ -71,7 +71,10 @@ inside the optional fencing path):
   ==================== ==============================================
 
   Counters: ``readback_arrays_total`` (device-to-host reads in
-  ``quorum_wait``), ``replay_applies_total`` (calls into
+  ``quorum_wait``), ``cfg_rescans_total`` (protocol steps whose
+  full-ring config rescan ran: ``StepOutput.cfg_rescanned`` off the
+  packed row; 0 while no config source is invalidated),
+  ``replay_applies_total`` (calls into
   ``ReplayEngine.apply``), ``replay_followers_total`` (followers a
   dispatch replayed to: ``replay_send`` + ``replay_drain`` over it is
   the pass per follower), ``replay_reply_bytes_total`` (app output read
@@ -672,7 +675,8 @@ class StepPhaseProfiler:
               PHASE_STORE_APPEND, PHASE_REPLAY_SEND, PHASE_REPLAY_DRAIN,
               PHASE_POST_STEP_RULES, PHASE_OBSERVE, OP_INTAKE_TO_ACK,
               OP_INTAKE_QUEUE_WAIT)
-    COUNTERS = ("readback_arrays_total", "replay_applies_total",
+    COUNTERS = ("readback_arrays_total", "cfg_rescans_total",
+                "replay_applies_total",
                 "replay_followers_total", "replay_reply_bytes_total",
                 "intake_fragments_total", "intake_payload_bytes_total")
     # a thread waiting by design: its length counts towards no stall,

@@ -25,7 +25,7 @@ from rdma_paxos_tpu.consensus.log import (
     EntryType, M_CONN, M_GIDX, M_LEN, M_REQID, M_TYPE, META_W)
 from rdma_paxos_tpu.consensus.state import Role
 from rdma_paxos_tpu.consensus.step import (
-    StepInput, fetch_window, unpack_scalars)
+    SCAN_KEYS, StepInput, fetch_window, unpack_scalars)
 from rdma_paxos_tpu.parallel.mesh import (
     build_sim_burst, build_sim_scan, build_sim_step, build_spmd_burst,
     build_spmd_scan, build_spmd_step, make_replica_mesh, stack_states)
@@ -241,12 +241,17 @@ def read_scalars(ticket: StepTicket) -> Dict[str, np.ndarray]:
     from a fused dispatch, whose result is the final step's rows with
     ``accepted`` cumulative in-program), unpacked into the ``res``
     dict — every scalar the host rules consume, the config view and
-    ``peer_acked`` included. Shared by both engines."""
+    ``peer_acked`` included. ``cfg_rescanned`` alone is summed over a
+    fused dispatch's steps here: in how many of them the full-ring
+    config rescan ran. Shared by both engines."""
     out = ticket.out
     if ticket.kind == "step":
         return unpack_scalars(np.asarray(out.scal))
-    return unpack_scalars(np.asarray(
-        out["scal"] if ticket.kind == "scan" else out.scal)[-1])
+    rows = np.asarray(out["scal"] if ticket.kind == "scan" else out.scal)
+    res = unpack_scalars(rows[-1])
+    res["cfg_rescanned"] = rows[
+        ..., SCAN_KEYS.index("cfg_rescanned")].sum(axis=0)
+    return res
 
 
 class StagingPool:
@@ -844,6 +849,7 @@ class SimCluster:
         if prof is not None:
             prof.stop("readback_rest")
             prof.count("readback_arrays_total", reads)
+            prof.count("cfg_rescans_total", int(res["cfg_rescanned"].max()))
             prof.stop("quorum_wait")
             prof.start("post_readback")
         if self._audit:

@@ -1,0 +1,154 @@
+"""The step touches the rows it needs, not the ring.
+
+In a steady step nothing reads or writes more than O(window_slots)
+rows of a replica's ring: every read goes rows first
+(``consensus.log.rows_at``), and the one full-ring pass, the config
+rescan, sits in a branch of a REAL ``cond`` in every mapping (under
+``vmap`` too: its predicate is reduced over the replica axis, so it is
+unbatched). Checked on the jaxpr of each builder the benchmark's cells
+and the other engines use, so no chip is needed: outside a ``cond``
+branch the ring may only be gathered from, scattered into or reshaped
+whole.
+"""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from rdma_paxos_tpu.config import LogConfig
+from rdma_paxos_tpu.consensus.log import META_W
+from rdma_paxos_tpu.consensus.step import make_step_input
+from rdma_paxos_tpu.parallel import mesh as pm
+
+# n_slots appears in no other dimension of any program below
+CFG = LogConfig(n_slots=512, slot_bytes=64, window_slots=16, batch_slots=4)
+N = CFG.n_slots
+K = 2
+
+# what may touch the ring outside a cond branch: row-indexed reads and
+# writes. A size-preserving slice / reshape / broadcast is the mappings'
+# own unit-axis bookkeeping (``x[0]``, ``x[None]``), not a view.
+ROW_OPS = {"gather", "scatter", "dynamic_slice", "dynamic_update_slice"}
+WHOLE_OPS = {"slice", "squeeze", "reshape", "broadcast_in_dim",
+             "expand_dims", "copy"}
+# the rescan's signature: a reduction over every slot
+RESCAN_OPS = {"reduce_max", "reduce_or", "argmax"}
+
+
+def _subjaxprs(eqn):
+    for v in eqn.params.values():
+        for x in (v if isinstance(v, (tuple, list)) else (v,)):
+            if hasattr(x, "jaxpr") and hasattr(x, "consts"):
+                yield x.jaxpr
+            elif hasattr(x, "eqns"):
+                yield x
+
+
+def _is_ring(v):
+    return N in getattr(getattr(v, "aval", None), "shape", ())
+
+
+def _walk(jaxpr, in_cond, seen):
+    """Every leaf equation with a ring-sized operand, as ``(inside a
+    cond branch, primitive, operand shapes, output shapes)``."""
+    for e in jaxpr.eqns:
+        subs = list(_subjaxprs(e))
+        ring = [v for v in e.invars if _is_ring(v)]
+        if not subs and ring:
+            seen.append((in_cond, e.primitive.name,
+                         [v.aval.shape for v in ring],
+                         [v.aval.shape for v in e.outvars]))
+        for s in subs:
+            _walk(s, in_cond or e.primitive.name == "cond", seen)
+
+
+def _conds(jaxpr, found):
+    for e in jaxpr.eqns:
+        if e.primitive.name == "cond":
+            found.append(e)
+        for s in _subjaxprs(e):
+            _conds(s, found)
+
+
+def _state(R):
+    return jax.eval_shape(lambda: pm.stack_states(CFG, R, R))
+
+
+def _step_args(R):
+    inp = jax.eval_shape(lambda: jax.tree.map(
+        lambda x: jnp.broadcast_to(x, (R,) + x.shape),
+        make_step_input(CFG, R)))
+    return _state(R), inp
+
+
+def _burst_args(R):
+    i32 = jnp.int32
+    sds = jax.ShapeDtypeStruct
+    return (_state(R),
+            sds((K, R, CFG.batch_slots, CFG.slot_words), i32),
+            sds((K, R, CFG.batch_slots, META_W), i32),
+            sds((K, R), i32), sds((R, R), i32), sds((R,), i32),
+            sds((R,), i32))
+
+
+def _program(kind, R):
+    """``(jitted program, abstract arguments)`` of one builder."""
+    if kind == "sim_step":
+        return pm.build_sim_step(CFG, R, fanout="psum"), _step_args(R)
+    if kind == "sim_stable_step":
+        return (pm.build_sim_step(CFG, R, fanout="psum", elections=False),
+                _step_args(R))
+    if kind == "sim_burst":
+        return pm.build_sim_burst(CFG, R, fanout="psum"), _burst_args(R)
+    if kind == "sim_scan":
+        return (pm.build_sim_scan(CFG, R, replay_slots=32, fanout="psum"),
+                _burst_args(R))
+    mesh = pm.make_replica_mesh(R)
+    if kind == "spmd_step":
+        return (pm.build_spmd_step(CFG, R, mesh, fanout="psum",
+                                   elections=False), _step_args(R))
+    assert kind == "spmd_burst", kind
+    return pm.build_spmd_burst(CFG, R, mesh, fanout="psum"), _burst_args(R)
+
+
+KINDS = ("sim_step", "sim_stable_step", "sim_burst", "sim_scan",
+         "spmd_step", "spmd_burst")
+
+
+@pytest.mark.parametrize("R", [3, 7])
+@pytest.mark.parametrize("kind", KINDS)
+def test_steady_program_reads_rows_and_rescans_under_a_cond(kind, R):
+    fn, args = _program(kind, R)
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+
+    seen = []
+    _walk(jaxpr, False, seen)
+    assert any(not c and p in ("gather", "dynamic_slice")
+               for c, p, _i, _o in seen), "the walk found no ring read"
+    for in_cond, prim, ins, outs in seen:
+        if in_cond or prim in ROW_OPS:
+            continue
+        whole = (prim in WHOLE_OPS and len(outs) == 1
+                 and math.prod(outs[0]) == math.prod(ins[0]))
+        assert whole, (
+            f"{kind} R={R}: `{prim}` over the whole ring {ins} -> {outs} "
+            "outside a cond branch: read rows first (log.rows_at)")
+
+    # the conditional is real in this mapping, and the rescan is in it
+    conds = []
+    _conds(jaxpr, conds)
+    rescans = []
+    for e in conds:
+        for br in e.params["branches"]:
+            inside = []
+            _walk(br.jaxpr, True, inside)
+            if {p for _c, p, _i, _o in inside} >= RESCAN_OPS:
+                rescans.append(e)
+    assert len(rescans) == 1, (
+        f"{kind} R={R}: {len(conds)} cond(s), {len(rescans)} holding the "
+        "full-ring config rescan: a batched predicate turns it into a "
+        "select_n that runs every step")
+    # and nothing else in the program reduces over every slot
+    assert not [p for c, p, _i, _o in seen if not c and p in RESCAN_OPS]
