@@ -45,6 +45,11 @@
 //                                  op: 1=HELLO 2=CONNECT 3=SEND 4=CLOSE
 //   response: [u32 seq][i32 status]   >=0 ok / pass; <0 drop connection
 //   HELLO carries one payload byte: bit0 = speculative mode.
+//   A CONNECT's status decides the connection ONCE: <0 sever, 0 track, 1
+//   pass and forget (the driver's own replay connection: no read of it is
+//   forwarded, no reply on it held, no close of it reported). A driver
+//   that only ever answers 0 tracks such a connection as it did; a shim
+//   from before the 1 reads it as "ok" and tracks it: both still work.
 //
 // Build: make -C native  ->  interpose.so
 
@@ -500,11 +505,14 @@ void on_accepted(int fd) {
       memcpy(info, &sa.sin_addr.s_addr, 4);
       memcpy(info + 4, &sa.sin_port, 2);  // network byte order
     }
-    if (proxy_call(OP_CONNECT, fd, info, 6) < 0) {
+    int32_t verdict = proxy_call(OP_CONNECT, fd, info, 6);
+    if (verdict < 0) {
       // driver refused the connection (e.g. replicated session on a
       // deposed leader): sever it so the client reconnects elsewhere
       tracked[fd] = 0;
       shutdown(fd, SHUT_RDWR);
+    } else if (verdict == 1) {
+      tracked[fd] = 0;                // the driver's own: forget it
     }
   }
 }

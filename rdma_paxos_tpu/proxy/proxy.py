@@ -17,6 +17,7 @@ The shim ↔ driver wire protocol is defined in ``native/interpose.cpp``.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import os
@@ -122,13 +123,21 @@ class ProxyServer:
     ``proxy.c:114-160``). Per-fd event order is preserved end-to-end:
     the shim serializes writes under its send mutex and this server
     reads them in order into the driver's submit queue.
+
+    A CONNECT's verdict decides the connection once: ``<0`` sever, ``0``
+    track, ``1`` pass and forget: the shim drops the fd from its table
+    and no event of it follows. The 1 is this server's own answer, for a
+    peer that ``claim`` (``ReplayEngine.claim``) knows as the driver's
+    own replay connection; ``on_event`` never sees such a connection.
     """
 
     def __init__(self, sock_path: str, node_id: int,
                  on_event: Callable[[int, int, bytes],
                                     Optional[PendingEvent]],
-                 conn_ctr_start: int = 0, obs=None):
+                 conn_ctr_start: int = 0, obs=None,
+                 claim: Optional[Callable[[bytes], bool]] = None):
         self.sock_path = sock_path
+        self.claim = claim
         # Observability facade (rdma_paxos_tpu.obs) — link threads
         # count wire events per op so replication throughput and shim
         # pressure export with every snapshot
@@ -224,6 +233,9 @@ class ProxyServer:
                     respond(seq, 0)
                     continue
                 if op == OP_CONNECT:
+                    if self.claim is not None and self.claim(payload):
+                        respond(seq, 1)     # the driver's own: forgotten
+                        continue
                     self.conn_of_fd[(lid, fd)] = self.next_conn_id()
                 conn_id = self.conn_of_fd.get((lid, fd), 0)
                 if op == OP_CLOSE:
@@ -314,6 +326,14 @@ class ReplayEngine:
     # not Python's: the socket stays blocking, so a wait for the app's
     # answer is ONE recv
     _RCVTIMEO = struct.pack("ll", 0, int(ORDER_WAIT_S * 1e6))
+    # a port of ours is claimable from its bind until this long after
+    # its socket's close (an app that accepts late reports a connection
+    # closed already; a later client that draws the port is a client),
+    # and of the never-claimed (an app without the shim reports no
+    # connection) the oldest are forgotten past UNCLAIMED_MAX
+    CLAIM_WAIT_S = 1.0
+    UNCLAIMED_MAX = 1024
+    _LOOPBACK = socket.inet_aton("127.0.0.1")
 
     def __init__(self, app_host: str, app_port: int):
         self.addr = (app_host, app_port)
@@ -326,8 +346,11 @@ class ReplayEngine:
         self.order_timeouts = 0
         # local (ephemeral) ports of our replay sockets: the driver uses
         # these to recognize its own replayed connections arriving back
-        # through the app's interposition shim
-        self.local_ports: set = set()
+        # through the app's interposition shim. port -> None while its
+        # socket is open, then the time of the close; read by the link
+        # threads (``claim``), hence the lock
+        self.local_ports: collections.OrderedDict = collections.OrderedDict()
+        self._ports_lock = threading.Lock()
 
     def _connect(self, conn_id: int) -> socket.socket:
         # a CONNECT for an id we already track means the id wrapped
@@ -335,29 +358,54 @@ class ReplayEngine:
         # one — reset rather than interleave bytes into a stale socket
         old = self.conns.pop(conn_id, None)
         if old is not None:
-            try:
-                self.local_ports.discard(old.getsockname()[1])
-                old.close()
-            except OSError:
-                pass
+            self._shut(old)
+        s = self._open()
+        s.setsockopt(socket.SOL_SOCKET, socket.SO_RCVTIMEO, self._RCVTIMEO)
+        self.conns[conn_id] = s
+        return s
+
+    def _open(self) -> socket.socket:
         s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         # bind first so the local port is REGISTERED before the app can
         # possibly observe the connection: a hot-polling app accepts and
         # reports CONNECT to the driver concurrently with (even before)
         # our connect() returning, and the driver must never misclassify
-        # our own replay connection as a client session
+        # our own replay connection as a client session. Registered until
+        # the verdict (``claim``), not until the close: the app may
+        # accept the connection after that, and its CONNECT is still ours
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]
-        self.local_ports.add(port)
+        with self._ports_lock:
+            self.local_ports[port] = None
+            if len(self.local_ports) > self.UNCLAIMED_MAX:
+                self.local_ports.popitem(last=False)
         try:
             s.connect(self.addr)
         except OSError:
-            self.local_ports.discard(port)
+            with self._ports_lock:
+                self.local_ports.pop(port, None)    # the app never saw it
             s.close()
             raise
-        s.setsockopt(socket.SOL_SOCKET, socket.SO_RCVTIMEO, self._RCVTIMEO)
-        self.conns[conn_id] = s
         return s
+
+    def _shut(self, s: socket.socket) -> None:
+        port = s.getsockname()[1]
+        with self._ports_lock:
+            if port in self.local_ports:        # not claimed yet
+                self.local_ports[port] = time.monotonic()
+        s.close()
+
+    def claim(self, peer: bytes) -> bool:
+        """True, ONCE, for the peer of a connection of our own, as a
+        CONNECT's payload names it (4 address bytes, the port in network
+        byte order): the verdict 1 on what the app's shim reports."""
+        if peer[:4] != self._LOOPBACK:
+            return False
+        with self._ports_lock:
+            closed = self.local_ports.pop(
+                int.from_bytes(peer[4:6], "big"), float("-inf"))
+        return (closed is None
+                or time.monotonic() - closed < self.CLAIM_WAIT_S)
 
     def _settle(self, wait: bool = True) -> None:
         """Read away the app's answer on the connection last written
@@ -405,33 +453,19 @@ class ReplayEngine:
             if s is not None:
                 if self._awaiting is s:
                     self._settle()  # its last request, before the EOF
-                try:
-                    self.local_ports.discard(s.getsockname()[1])
-                    s.close()
-                except OSError:
-                    pass
+                self._shut(s)
 
     @contextlib.contextmanager
     def raw_conn(self):
         """Context manager: a passthrough-registered connection to the
         local app for OUT-OF-BAND operations (app checkpoint dump /
         restore). Bound before connecting so the driver always
-        classifies it as our own (never replicates its traffic); the
-        port registration is dropped on exit so a later real client
-        reusing the ephemeral port cannot be misclassified."""
-        s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-        s.bind(("127.0.0.1", 0))
-        port = s.getsockname()[1]
-        self.local_ports.add(port)
+        classifies it as our own (never replicates its traffic)."""
+        s = self._open()
         try:
-            s.connect(self.addr)
             yield s
         finally:
-            self.local_ports.discard(port)
-            try:
-                s.close()
-            except OSError:
-                pass
+            self._shut(s)
 
     def barrier(self, probe_fn, timeout: float = 10.0) -> None:
         """PROCESSED-INPUT barrier: replay input is delivered over

@@ -70,11 +70,12 @@ class _ReplicaRuntime:
                  log_path: Optional[str] = None, obs=None):
         self.idx = idx
         self.log = ReplicaLog(log_path, replica=idx, obs=obs)
-        self.proxy = (ProxyServer(sock_path, idx, on_event, obs=obs)
-                      if sock_path else None)
         self.app_port = app_port
         self.replay = (ReplayEngine("127.0.0.1", app_port)
                        if app_port else None)
+        self.proxy = (ProxyServer(sock_path, idx, on_event, obs=obs,
+                                  claim=self._claim)
+                      if sock_path else None)
         # a SPECULATIVE app (shim HELLO flag) consumed input that was
         # failed at deposition — its state may have diverged from the
         # committed stream. While dirty: committed entries still persist
@@ -95,7 +96,6 @@ class _ReplicaRuntime:
                                   # into the entry's req_id so ack release
                                   # is exact across leadership churn
         self.replay_cursor = 0    # index into cluster.replayed[idx]
-        self.passthrough_conns: set = set()  # our own replay connections
         self.replicated_conns: set = set()   # conns whose events replicate
         self.timer = ElectionTimer(timeout_cfg, seed=seed)
         # false-positive detection for the adaptive timeout (to_adjust_cb
@@ -103,6 +103,11 @@ class _ReplicaRuntime:
         # fired, the timeout was premature -> widen it
         self.fired_leader = -1
         self.fired_countdown = 0
+
+    def _claim(self, peer: bytes) -> bool:
+        # the proxy server's question on a CONNECT, whatever engine
+        # ``replay`` is by then (``reset_app`` replaces it)
+        return self.replay is not None and self.replay.claim(peer)
 
 
 class ClusterDriver:
@@ -506,16 +511,11 @@ class ClusterDriver:
                     # fast so the app severs and the client retries
                     return refuse_send()
                 if etype == int(EntryType.CONNECT):
-                    # our own replay connections (recognized by peer port)
-                    # stay local; so do client connections on non-leaders
+                    # our own replay connections never come here (the
+                    # proxy server answers them, _ReplicaRuntime._claim);
+                    # client connections on non-leaders stay local
                     # (stale local reads — the reference's followers serve
                     # the same way, proxy.c:230-239 is_leader gate)
-                    port = (int.from_bytes(payload[4:6], "big")
-                            if len(payload) >= 6 else 0)
-                    if (rt.replay is not None
-                            and port in rt.replay.local_ports):
-                        rt.passthrough_conns.add(conn_id)
-                        return None
                     if rt.app_dirty:
                         # a dirty (mis-speculated) app must not serve
                         # clients — not even stale local reads
@@ -529,10 +529,6 @@ class ClusterDriver:
                         return -1
                     rt.replicated_conns.add(conn_id)
                     payload = b""
-                elif conn_id in rt.passthrough_conns:
-                    if etype == int(EntryType.CLOSE):
-                        rt.passthrough_conns.discard(conn_id)
-                    return None
                 elif conn_id not in rt.replicated_conns:
                     return None          # never-replicated local session
                 elif r in self.stepped_down:

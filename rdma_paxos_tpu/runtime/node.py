@@ -104,13 +104,14 @@ class NodeDaemon:
         # (reset_app / generation bootstrap_from_store)
         self.app_dirty = False
         self.replicated_conns: set = set()
-        self.passthrough_conns: set = set()
         self.sock_path = os.path.join(workdir, f"proxy{self.me}.sock")
-        self.proxy = ProxyServer(self.sock_path, self.host_id,
-                                 self._on_event,
-                                 conn_ctr_start=(gen % 16) << 20)
         self.replay = (ReplayEngine("127.0.0.1", app_port)
                        if app_port else None)
+        self.proxy = ProxyServer(
+            self.sock_path, self.host_id, self._on_event,
+            conn_ctr_start=(gen % 16) << 20,
+            claim=lambda peer: (self.replay is not None
+                                and self.replay.claim(peer)))
         # stable files are keyed by the PERSISTENT host id: a restarted
         # host finds its own history regardless of which slot the new
         # generation assigns it
@@ -289,12 +290,8 @@ class NodeDaemon:
     def _on_event(self, etype: int, conn_id: int, payload: bytes):
         with self._lock:
             if etype == int(EntryType.CONNECT):
-                port = (int.from_bytes(payload[4:6], "big")
-                        if len(payload) >= 6 else 0)
-                if (self.replay is not None
-                        and port in self.replay.local_ports):
-                    self.passthrough_conns.add(conn_id)
-                    return None
+                # (our own replay connections never come here: the
+                # proxy server answers them)
                 if self.app_dirty:
                     # a dirty (mis-speculated) app serves nothing —
                     # not even stale local reads
@@ -303,10 +300,6 @@ class NodeDaemon:
                     return None
                 self.replicated_conns.add(conn_id)
                 payload = b""
-            elif conn_id in self.passthrough_conns:
-                if etype == int(EntryType.CLOSE):
-                    self.passthrough_conns.discard(conn_id)
-                return None
             elif conn_id not in self.replicated_conns:
                 return None
             elif self.app_dirty:

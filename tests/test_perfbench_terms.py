@@ -12,6 +12,8 @@ its keys.
 import glob
 import json
 import os
+import socket
+import struct
 
 import pytest
 
@@ -40,11 +42,19 @@ def probe_terms(spec: dict) -> list:
 
 
 @pytest.fixture(scope="module")
-def probe():
+def probe(tmp_path_factory):
     from perfbench.deployments._driver_common import (
         DriverDeployment, SpanAcc)
-    d = ClusterDriver(CFG, 3, timeout_cfg=TO)
+    workdir = str(tmp_path_factory.mktemp("terms"))
+    d = ClusterDriver(CFG, 3, timeout_cfg=TO, workdir=workdir)
     try:
+        # a shim's HELLO up replica 0's link, as an interposed app's
+        # first word: the link threads count what they take in
+        with socket.socket(socket.AF_UNIX) as link:
+            link.connect(os.path.join(workdir, "proxy0.sock"))
+            link.sendall(struct.pack("<BIiIB", 1, 1, 0, 1, 1))
+            assert link.recv(8, socket.MSG_WAITALL) == struct.pack(
+                "<Ii", 1, 0)
         d.runtimes[0].timer._deadline = 0.0     # replica 0 times out
         d.step()
         assert d.leader() == 0

@@ -22,6 +22,7 @@ These tests pin the two sides of that contract:
 
 import os
 import socket
+import struct
 import subprocess
 import time
 
@@ -53,6 +54,7 @@ def spawn_app(tmp_path, r, port):
 
 class Client:
     def __init__(self, port):
+        self.s_port = port
         self.s = socket.create_connection(("127.0.0.1", port), timeout=10)
         self.f = self.s.makefile("rb")
 
@@ -280,3 +282,210 @@ def test_driver_death_severs_without_fabricated_acks(stack):
     except OSError:
         refused = True
     assert refused, "diverged app served a session after driver death"
+
+
+# ---------------------------------------------------------------------
+# The CONNECT verdict decides a connection once (<0 sever, 0 track,
+# 1 pass and forget): the shim against a driver's side of the link that
+# the test plays itself, so what the link carries, and what waits for
+# it, is seen exactly.
+# ---------------------------------------------------------------------
+
+OP_HELLO, OP_CONNECT, OP_SEND, OP_CLOSE = 1, 2, 3, 4
+
+
+class FakeLink:
+    """The driver's end of one shim link: reads events, answers only
+    what the test tells it to."""
+
+    def __init__(self, path):
+        self.srv = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
+        self.srv.bind(path)
+        self.srv.listen(1)
+        self.link = None
+
+    def event(self, timeout=10.0):
+        """-> (op, seq, fd, payload), or None if the link stays silent
+        for ``timeout`` seconds."""
+        if self.link is None:
+            self.srv.settimeout(timeout)
+            self.link, _ = self.srv.accept()
+        self.link.settimeout(timeout)
+        try:
+            hdr = self.link.recv(13, socket.MSG_WAITALL)
+        except socket.timeout:
+            return None
+        if len(hdr) < 13:
+            return None
+        op, seq, fd, ln = struct.unpack("<BIiI", hdr)
+        payload = self.link.recv(ln, socket.MSG_WAITALL) if ln else b""
+        return op, seq, fd, payload
+
+    def answer(self, seq, status):
+        self.link.sendall(struct.pack("<Ii", seq, status))
+
+    def close(self):
+        for s in (self.link, self.srv):
+            if s is not None:
+                s.close()
+
+
+def hang_up(c):
+    """``Client.close`` leaves the connection open while its reading
+    file object lives: close both, so the app reads the end of it."""
+    c.f.close()
+    c.close()
+
+
+def free_port():
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+@pytest.fixture()
+def shimmed_app(tmp_path):
+    """-> start(spec): toyserver under the shim, its link served by a
+    ``FakeLink`` that has answered the HELLO."""
+    made = []
+
+    def start(spec):
+        link = FakeLink(os.path.join(str(tmp_path), "proxy.sock"))
+        port = free_port()
+        env = dict(os.environ)
+        env["LD_PRELOAD"] = os.path.join(NATIVE, "interpose.so")
+        env["RP_PROXY_SOCK"] = os.path.join(str(tmp_path), "proxy.sock")
+        env["RP_SPEC"] = spec
+        app = subprocess.Popen(
+            [os.path.join(NATIVE, "toyserver"), str(port)], env=env,
+            stderr=subprocess.DEVNULL)
+        made.extend([link.close, app.wait, app.kill])
+        op, seq, _pid, flags = link.event()
+        assert op == OP_HELLO and flags == bytes([spec == "1"])
+        link.answer(seq, 0)
+        deadline = time.time() + 10
+        while True:
+            try:
+                return link, Client(port)
+            except OSError:
+                assert time.time() < deadline, "app never listened"
+                time.sleep(0.02)
+    yield start
+    for undo in reversed(made):
+        undo()
+
+
+@pytest.mark.parametrize("spec", ["1", "0"])
+def test_connect_answered_1_is_forgotten_by_the_shim(shimmed_app, spec):
+    """No read of a forgotten connection is forwarded, no reply on it
+    held, no close of it reported: the app's answer reaches the peer
+    while the driver's side of the link says nothing at all."""
+    link, c = shimmed_app(spec)
+    op, seq, fd, info = link.event()
+    assert op == OP_CONNECT
+    assert int.from_bytes(info[4:6], "big") == c.s.getsockname()[1]
+    link.answer(seq, 1)
+    for i in range(20):             # nothing acks these: nothing waits
+        assert c.cmd(f"SET k{i} v{i}") == b"+OK"
+    assert c.cmd("GET k19") == b"v19"
+    assert link.event(timeout=0.3) is None, "a read was forwarded"
+    hang_up(c)
+    assert link.event(timeout=0.5) is None, "the close was reported"
+    # the fd number, reused by the next connection, is tracked afresh
+    c2 = Client(c.s_port)
+    op, seq, fd2, _ = link.event()
+    assert (op, fd2) == (OP_CONNECT, fd)
+    link.answer(seq, 0)
+    c2.send_only("GET k0")
+    assert link.event()[0] == OP_SEND
+    hang_up(c2)
+
+
+@pytest.mark.parametrize("spec", ["1", "0"])
+def test_connect_answered_0_is_tracked_as_before(shimmed_app, spec):
+    """Every read is an event, the reply waits for its ack (held by the
+    shim, or the app held inside read()), the close is reported."""
+    link, c = shimmed_app(spec)
+    op, seq, fd, _ = link.event()
+    assert op == OP_CONNECT
+    link.answer(seq, 0)
+    c.send_only("SET a 1")
+    op, seq, efd, payload = link.event()
+    assert (op, efd, payload) == (OP_SEND, fd, b"SET a 1\n")
+    c.s.settimeout(0.3)
+    with pytest.raises(socket.timeout):
+        c.s.recv(16)                # no ack yet: no reply
+    link.answer(seq, 0)
+    c.s.settimeout(10)
+    assert c.f.readline().strip() == b"+OK"
+    hang_up(c)
+    op, seq, efd, _ = link.event()
+    assert (op, efd) == (OP_CLOSE, fd)
+    link.answer(seq, 0)
+
+
+def wire_events(driver, r):
+    """-> {op: count} of the wire events replica ``r``'s link threads
+    have taken in (``proxy_wire_events_total``)."""
+    return {op: int(driver.obs.metrics.get("proxy_wire_events_total",
+                                           replica=r, op=op))
+            for op in (OP_HELLO, OP_CONNECT, OP_SEND, OP_CLOSE)}
+
+
+def test_followers_report_nothing_of_the_replay(stack):
+    """After N replicated SETs from three clients a follower's link has
+    carried its HELLO and the CONNECTs of the driver's own connections,
+    each answered 1, and nothing else: no read and no close of them.
+    The replay still goes through ``replay.apply``, where the
+    benchmark's must-fail controls stand, and the apps are equal."""
+    driver, _apps, _tmp = stack
+    lead = driver.leader()
+    fols = [r for r in range(3) if r != lead]
+    seen = {r: [] for r in fols}
+    for r in fols:              # replaced as perfbench's `inject` does
+        replay = driver.runtimes[r].replay
+
+        def spy(etype, conn, payload, _apply=replay.apply, _r=r):
+            seen[_r].append((etype, payload))
+            return _apply(etype, conn, payload)
+        replay.apply = spy
+    n, clients = 60, [Client(PORTS[lead]) for _ in range(3)]
+    for i in range(n):
+        assert clients[i % 3].cmd(f"SET key{i} val{i}") == b"+OK"
+    for c in clients:
+        hang_up(c)
+    sets = [b"SET key%d val%d" % (i, i) for i in range(n)]
+    for r in fols:
+        assert wait_kv(PORTS[r], f"key{n - 1}", f"val{n - 1}".encode())
+        deadline = time.time() + 10     # the three CLOSEs, replayed
+        while (sum(e == 4 for e, _ in seen[r]) < 3
+               and time.time() < deadline):
+            time.sleep(0.05)
+        sent = b"".join(p for e, p in seen[r] if e == 3)
+        assert sent.split(b"\n")[:-1] == sets, "an operation went round"
+    for r in fols:
+        ev = wire_events(driver, r)
+        # the three replayed sessions, plus wait_kv's own (untouched by
+        # this PR: a client's session on a follower stays tracked)
+        assert ev[OP_HELLO] == 1
+        ours = sum(e == 2 for e, _ in seen[r])
+        assert ours == 3
+        probes = ev[OP_CONNECT] - ours
+        assert probes >= 1
+        assert ev[OP_SEND] == probes, ev    # wait_kv's one GET each
+        assert ev[OP_CLOSE] <= probes, ev
+        assert not driver.runtimes[r].replay.local_ports
+        assert len(driver.runtimes[r].proxy.conn_of_fd) <= probes
+    for i in (0, n // 2, n - 1):
+        vals = set()
+        for p in PORTS:
+            c = Client(p)
+            vals.add(c.cmd(f"GET key{i}"))
+            hang_up(c)
+        assert vals == {f"val{i}".encode()}
+    counts = set()
+    for p in PORTS:
+        c = Client(p)
+        counts.add(c.cmd("COUNT"))
+        hang_up(c)
+    assert counts == {str(n).encode()}
