@@ -32,7 +32,9 @@
 #include <unistd.h>
 
 #define MAXKV 131072            /* open-addressing table, power of two */
-#define MAXC 64
+#define MAXC 256                /* poll-mode connections; a front-end of a
+                                 * G-group deployment holds its clients' and
+                                 * (G - 1) groups' replayed ones */
 #define BUFSZ 65536
 
 /* Open-addressing hash KVS (linear probing, tombstone-free deletes by
@@ -250,7 +252,7 @@ int main(int argc, char** argv) {
   a.sin_addr.s_addr = htonl(INADDR_LOOPBACK);
   a.sin_port = htons((unsigned short)port);
   if (bind(ls, (struct sockaddr*)&a, sizeof a) != 0) { perror("bind"); return 1; }
-  listen(ls, 64);
+  listen(ls, MAXC);
   fprintf(stderr, "toyserver listening on %d%s\n", port,
           threaded ? " (threaded)" : "");
 
@@ -271,7 +273,7 @@ int main(int argc, char** argv) {
     }
   }
 
-  struct conn cs[MAXC];
+  static struct conn cs[MAXC];     /* 16 MB: not on the stack */
   for (int i = 0; i < MAXC; i++) cs[i].fd = -1;
 
   for (;;) {

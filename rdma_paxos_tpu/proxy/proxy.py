@@ -311,7 +311,11 @@ class ReplayEngine:
     to the SAME connection too, unless the last write left a request
     unfinished (it did not end in a newline: a fragment), so that at
     most one whole request is ever unanswered and an answer proves it
-    done. A request that is never answered (``noreply``) costs
+    done. Bytes for ANOTHER connection after an unfinished request (two
+    groups' streams into one app: within one log a request's fragments
+    are neighbours) go out at once: nothing can answer yet, and the
+    rest of the request waits its turn like any other write. A request
+    that is never answered (``noreply``) costs
     ``ORDER_WAIT_S`` once and is counted in ``order_timeouts``; after it
     the order is the app's. An app that leaves ``GIVE_UP_AFTER``
     requests in a row unanswered (a sink) is not waited for again until
@@ -444,7 +448,11 @@ class ReplayEngine:
             if s is None:       # joined mid-stream: open lazily
                 s = self._connect(conn_id)
             if self._awaiting is not s or self._whole:
-                self._settle(wait=self._unanswered < self.GIVE_UP_AFTER)
+                # an unfinished request has no answer to wait for (a
+                # replica that follows several groups is handed another
+                # group's operation between one group's fragments)
+                self._settle(wait=self._whole
+                             and self._unanswered < self.GIVE_UP_AFTER)
             s.sendall(payload)
             self._awaiting = s
             self._whole = payload.endswith(b"\n")

@@ -583,10 +583,7 @@ class ClusterDriver:
             self._submitq[r].append((etype, conn_id, f,
                                      rt.submit_seq))
         rt.inflight.append((ev, rt.submit_seq))
-        self._intake_n += 1
-        self._intake_t0_sum += ev.t0
-        self._intake_frags += len(frags)
-        self._intake_bytes += len(payload)
+        self._note_intake(ev, len(frags))
         self.obs.metrics.inc("proxy_events_total", replica=r)
         self.obs.trace.record(obs_trace.PROXY_ENQUEUE,
                               replica=r, etype=etype,
@@ -597,6 +594,32 @@ class ClusterDriver:
         self.obs.spans.begin(conn_id, rt.submit_seq, r)
         self._wake.set()
         return ev
+
+    # holds-lock: _lock
+    def _note_intake(self, ev: PendingEvent, n_frags: int) -> None:
+        """One operation admitted: its intake stamp, log entries and
+        bytes join the sums the next pump credits."""
+        self._intake_n += 1
+        self._intake_t0_sum += ev.t0
+        self._intake_frags += n_frags
+        self._intake_bytes += len(ev.payload)
+
+    # holds-lock: _lock
+    def _credit_intake(self) -> None:
+        """intake_queue_wait: intake to this pump, summed over the
+        operations queued since the last one."""
+        n = self._intake_n
+        if n:
+            wait = n * time.perf_counter() - self._intake_t0_sum
+            self._phase_prof.credit("intake_queue_wait", wait * 1e6, n)
+            self._intake_n, self._intake_t0_sum = 0, 0.0
+            # log entries and bytes admitted since the last pump
+            # (an operation over slot_bytes is several entries)
+            self._phase_prof.count("intake_fragments_total",
+                                   self._intake_frags)
+            self._phase_prof.count("intake_payload_bytes_total",
+                                   self._intake_bytes)
+            self._intake_frags = self._intake_bytes = 0
 
     # ------------------------------------------------------------------
     # the polling loop
@@ -669,20 +692,7 @@ class ClusterDriver:
                         r, [(etype, conn, seq, frag)
                             for etype, conn, frag, seq in q])
                     q.clear()
-            # intake_queue_wait: intake to this pump, summed over the
-            # operations queued since the last one
-            n = self._intake_n
-            if n:
-                wait = n * time.perf_counter() - self._intake_t0_sum
-                self._phase_prof.credit("intake_queue_wait", wait * 1e6, n)
-                self._intake_n, self._intake_t0_sum = 0, 0.0
-                # log entries and bytes admitted since the last pump
-                # (an operation over slot_bytes is several entries)
-                self._phase_prof.count("intake_fragments_total",
-                                       self._intake_frags)
-                self._phase_prof.count("intake_payload_bytes_total",
-                                       self._intake_bytes)
-                self._intake_frags = self._intake_bytes = 0
+            self._credit_intake()
 
     def step(self) -> Dict:
         """One host-loop iteration (public for deterministic tests).
@@ -1552,22 +1562,36 @@ class ClusterDriver:
         n = len(stream)
         if rt.replay_cursor >= n:
             return
+        cur, rt.replay_cursor = rt.replay_cursor, n
+        remote: list = []
+        self._apply_stream(r, rt, stream, cur, self.cluster.frames,
+                           rt.inflight, r, remote)
+        if remote:
+            replays.append((rt.replay, remote))
+
+    def _apply_stream(self, r: int, rt: _ReplicaRuntime, stream, cur: int,
+                      frames: list, inflight: collections.deque,
+                      span_rep: int, remote: list) -> int:
+        """One committed stream's entries from ``cur`` on, as replica
+        ``r`` holds them (the group's, or one of the sharded driver's G
+        a replica): ``frames[r]`` to the store, the acks of its own
+        entries released off ``inflight``, what its app is to be
+        replayed appended to ``remote``. -> acks released."""
         prof = self._phase_prof
         prof.start("apply_replay_ack")
         # the engine's decode left the new entries as COLUMNAR batches
         # (hostpath.ReplayBatch): the replay/ack sweep below touches
         # Python O(1) per window, not O(1) per entry
-        segs = (stream.segments_from(rt.replay_cursor)
+        segs = (stream.segments_from(cur)
                 if hasattr(stream, "segments_from")
-                else [stream[rt.replay_cursor:]])
-        rt.replay_cursor = n
+                else [stream[cur:]])
         if rt.store is not None:
             # frames were assembled vectorized during the window decode
             # (SimCluster.collect_frames); one syscall appends the batch
-            blobs = self.cluster.frames[r]
+            blobs = frames[r]
             if blobs:
                 prof.start("store_append")
-                self.cluster.frames[r] = []
+                frames[r] = []
                 for b in blobs:
                     rt.store.append_framed(b)
                 prof.stop("store_append")
@@ -1576,7 +1600,6 @@ class ClusterDriver:
         # reset_app rebuilds it
         replaying = rt.replay is not None and not rt.app_dirty
         own_max = -1
-        remote: list = []
 
         def own_of(conns, _gens):
             return conn_origin(conns) == r
@@ -1591,8 +1614,7 @@ class ClusterDriver:
             own_max = max(own_max, seg_max)
             remote.extend(ops)
         prof.stop("replay_send")
-        if remote:
-            replays.append((rt.replay, remote))
+        released = 0
         if rt.store is not None:
             # The WRITE precedes the ack (store_record runs inside the
             # reference's apply, before the proxy releases the client,
@@ -1614,9 +1636,10 @@ class ClusterDriver:
             prof.start("ack_release")
             releases = []
             with self._lock:
-                while rt.inflight and rt.inflight[0][1] <= own_max:
-                    ev, seq = rt.inflight.popleft()
+                while inflight and inflight[0][1] <= own_max:
+                    ev, seq = inflight.popleft()
                     releases.append((ev, seq))
+            released = len(releases)
             # spans first so the latency observe below can attach the
             # SAMPLED releases' span ids as histogram exemplars
             sampled = {}
@@ -1625,7 +1648,8 @@ class ClusterDriver:
                                       replica=r, count=len(releases),
                                       submit_seq=own_max)
                 sampled = {req: conn for conn, req
-                           in self.obs.spans.ack_release(r, own_max)}
+                           in self.obs.spans.ack_release(span_rep,
+                                                         own_max)}
             now = time.perf_counter()
             t0_sum = 0.0
             for ev, seq in releases:
@@ -1646,6 +1670,7 @@ class ClusterDriver:
                             len(releases))
             prof.stop("ack_release")
         prof.stop("apply_replay_ack")
+        return released
 
     def _replay_in_turns(self, replays: list) -> None:
         """Deliver each follower's planned operations to its app, one

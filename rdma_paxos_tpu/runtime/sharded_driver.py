@@ -29,6 +29,23 @@ threaded through the real proxy/shim/app path:
     g's waiters, so groups committing at different rates can never
     reorder or cross-release acks.
 
+**The client contract (with interposed apps).** Every writer of a key
+reaches it through the SAME front-end: the app of the replica that
+leads the key's group, on a connection whose keys all belong to that
+group (a cluster-aware client holds one connection a group, as
+``JedisCluster`` holds one a master). That app executes the request,
+its reply is held until the group commits, and the other replicas' apps
+get it replayed in that group's log order; keys of different groups
+never meet, so the groups' streams into one app need no order between
+them. A front-end handed a request for ANOTHER group's key does not
+refuse it: ``_enqueue_locked`` pins the connection to the key's group
+and the entry is forwarded to that group's leader's log. Its own app,
+though, executed the request when it arrived, not where the log put
+it: two front-ends executing writes to one key before the log orders
+them can leave their apps different. No fencing stops that today, and
+the honest alternative, a non-leader front-end refusing a write, is
+left to an issue of its own (PERF.md sec. 7).
+
 The pipelined dispatch loop (double-buffered ``begin_*``/``finish``,
 readback thread) is inherited unchanged — the engines share one
 ticket contract. Operator surfaces that are single-group by design
@@ -40,7 +57,6 @@ detection) are not supported in sharded mode and raise; ROADMAP item 4
 from __future__ import annotations
 
 import collections
-import time
 from typing import Dict, List, Optional
 
 from rdma_paxos_tpu.config import LogConfig
@@ -48,12 +64,9 @@ from rdma_paxos_tpu.consensus.log import EntryType
 from rdma_paxos_tpu.consensus.state import Role
 from rdma_paxos_tpu.obs import trace as obs_trace
 from rdma_paxos_tpu.obs.health import make_snapshot
-from rdma_paxos_tpu.obs.metrics import LATENCY_BUCKETS_S
-from rdma_paxos_tpu.obs.spans import span_trace_id
 from rdma_paxos_tpu.obs.tracectx import health_blame as _health_blame
 from rdma_paxos_tpu.proxy.proxy import PendingEvent
-from rdma_paxos_tpu.runtime.driver import ClusterDriver, conn_origin
-from rdma_paxos_tpu.runtime.hostpath import plan_segment
+from rdma_paxos_tpu.runtime.driver import ClusterDriver
 from rdma_paxos_tpu.runtime.timers import GroupStepTimer
 from rdma_paxos_tpu.shard.cluster import ShardedCluster
 from rdma_paxos_tpu.shard.router import KeyRouter
@@ -142,6 +155,11 @@ class ShardedClusterDriver(ClusterDriver):
                                         hi=group_timer_hi)
                          for g in range(self.G)]
         self._elect_round = [0] * self.G
+        # what the deployment adds to the single group's counters: read
+        # from the first probe on, so they exist before any dispatch
+        self.obs.metrics.inc("group_appends_total", 0)
+        for g in range(self.G):
+            self.obs.metrics.inc("group_acks_total", 0, group=g)
         # elastic-topology cutover hook: the controller calls this on
         # the driver thread right after the atomic router swap
         self.cluster._on_topology_cutover = self._on_topology_cutover
@@ -265,6 +283,7 @@ class ShardedClusterDriver(ClusterDriver):
             self._conn_group.pop(conn_id, None)
         self._submitq[r].extend(rows)
         self._inflight_g[r][g].append((ev, rt.submit_seq))
+        self._note_intake(ev, len(rows))     # a held CONNECT's too
         self.obs.metrics.inc("proxy_events_total", replica=r)
         self.obs.trace.record(obs_trace.PROXY_ENQUEUE, replica=r,
                               etype=etype, conn=conn_id, group=g,
@@ -300,6 +319,7 @@ class ShardedClusterDriver(ClusterDriver):
                     q = views[g] if views[g] >= 0 else 0
                     self.cluster.submit_many(g, q, rows)
                 self._submitq[r].clear()
+            self._credit_intake()
 
     # ------------------------------------------------------------------
     # stepping
@@ -335,8 +355,10 @@ class ShardedClusterDriver(ClusterDriver):
         """One host-loop iteration: elections for leaderless groups
         ride the same dispatch as every other group's step; any
         backlog rides a fused all-groups burst."""
+        self._phase_prof.start("admin_pump")
         self._drain_admin()
         self._pump_submitq()
+        self._phase_prof.stop("admin_pump")
         c = self.cluster
         timeouts: Dict[int, list] = {}
         if c.last is not None:
@@ -521,99 +543,66 @@ class ShardedClusterDriver(ClusterDriver):
                                   replica=rt.idx, count=n, site=site)
 
     def _post_step(self, res) -> Dict:
+        """The sharded loop's post-readback host rules, in the phases
+        of ``ClusterDriver._post_step``: ``post_step_rules`` is all of
+        it but the per-(replica, group) store/ack sweep, the replay to
+        the apps and the observe pass, which are phases of their own."""
+        prof = self._phase_prof
+        prof.start("post_step_rules")
         self._update_leader_view(res)
         for g in range(self.G):
             if self._group_views[g] >= 0:
                 self._gtimers[g].beat()
+        prof.stop("post_step_rules")
+        replays: list = []
         for r, rt in enumerate(self.runtimes):
-            self._apply_new_entries(r, rt)
+            self._apply_new_entries(r, rt, replays)
+        # every replica's app follows G - 1 groups: its operations of
+        # all of them are one list, delivered in turns with the other
+        # replicas' (log order within a group is what keeps the apps
+        # equal; groups own disjoint keys)
+        self._replay_in_turns(replays)
+        prof.start("post_step_rules")
         # self-healing observation (same contract as the base driver's
         # _post_step): quarantine new findings / advance probation on
         # every finished step — the surgery itself waits for a drained
         # serial iteration (_drain_admin → repair.drive)
         if self.repair is not None:
             self.repair.observe()
+        prof.stop("post_step_rules")
+        prof.start("observe")
         self._observe_step(res)
+        prof.stop("observe")
         return res
 
     # ------------------------------------------------------------------
     # apply / ack release (per group)
     # ------------------------------------------------------------------
 
-    def _apply_new_entries(self, r: int, rt) -> None:
+    def _apply_new_entries(self, r: int, rt, replays: list) -> None:
+        """Replica ``r``'s newly committed entries of every group, each
+        through :meth:`ClusterDriver._apply_stream` (store, ack release
+        of its own entries off that group's waiters); what its app is to
+        be replayed, of all the groups it follows, is ONE entry of
+        ``replays``."""
         c = self.cluster
-        progressed = False
-        releases: list = []
-        sampled: set = set()      # (conn, req) span keys acked now
-        replaying = rt.replay is not None and not rt.app_dirty
-
-        def own_of(conns, _gens):
-            return conn_origin(conns) == r
-
-        self._phase_prof.start("apply_replay_ack")
+        remote: list = []
         for g in range(self.G):
             stream = c.replayed[g][r]
             n = len(stream)
             cur = self._replay_cursor[r][g]
             if cur >= n:
                 continue
-            # columnar batch consumption — Python O(1) per decoded
-            # window (see ClusterDriver._apply_new_entries)
-            segs = (stream.segments_from(cur)
-                    if hasattr(stream, "segments_from")
-                    else [stream[cur:]])
             self._replay_cursor[r][g] = n
-            progressed = True
-            if rt.store is not None:
-                blobs = c.frames[g][r]
-                if blobs:
-                    c.frames[g][r] = []
-                    for b in blobs:
-                        rt.store.append_framed(b)
-            own_max = -1
-            for seg in segs:
-                seg_max, ops, _n_rem = plan_segment(
-                    seg, own_of, want_ops=replaying)
-                own_max = max(own_max, seg_max)
-                if replaying:
-                    for etype, conn, payload in ops:
-                        rt.replay.apply(etype, conn, payload)
-            if own_max >= 0:
-                self._phase_prof.start("ack_release")
-                with self._lock:
-                    dq = self._inflight_g[r][g]
-                    while dq and dq[0][1] <= own_max:
-                        ev, seq = dq.popleft()
-                        releases.append((ev, seq))
-                # span acks live on the GROUP-NAMESPACED track the
-                # enqueue-side begin() used — (group, term, index)
-                # correlation closes here; sampled keys feed the
-                # latency histogram's exemplars below
-                sampled.update(
-                    self.obs.spans.ack_release(self._span_rep(g, r),
-                                               own_max))
-                self._phase_prof.stop("ack_release")
-        self._phase_prof.stop("apply_replay_ack")
-        if progressed and replaying:
-            rt.replay.drain_responses()
-        if progressed and rt.store is not None:
-            now = time.monotonic()
-            if now - rt.last_sync > self.sync_period:
-                rt.store.sync()
-                rt.last_sync = now
-        if releases:
-            acked = {req: conn for conn, req in sampled}
-            now = time.perf_counter()
-            for ev, seq in releases:
-                ev.release(0)
-                self.obs.metrics.observe(
-                    "commit_latency_seconds", now - ev.t0,
-                    buckets=LATENCY_BUCKETS_S,
-                    exemplar=(span_trace_id(acked[seq], seq)
-                              if seq in acked else None),
-                    replica=r)
-            self.obs.trace.record(obs_trace.PROXY_ACK_RELEASE,
-                                  replica=r, count=len(releases))
+            with self._lock:
+                waiters = self._inflight_g[r][g]
+            acked = self._apply_stream(
+                r, rt, stream, cur, c.frames[g], waiters,
+                self._span_rep(g, r), remote)
+            if acked:
+                self.obs.metrics.inc("group_acks_total", acked, group=g)
+        if remote:
+            replays.append((rt.replay, remote))
 
     # ------------------------------------------------------------------
     # observability / health

@@ -566,6 +566,8 @@ class ShardedCluster:
         for g, rs in tmo.items():
             for r in rs:
                 tmo_arr[g, r] = 1
+        if prof is not None:
+            prof.start("input_transfer")
         inp = StepInput(
             batch_data=jnp.asarray(bufs["data"]),
             batch_meta=jnp.asarray(bufs["meta"]),
@@ -587,6 +589,8 @@ class ShardedCluster:
                     (G, R)).astype(np.int32)),
             ) if self._txn else {}),
         )
+        if prof is not None:
+            prof.stop("input_transfer")
         # no timer fired in ANY group ⟹ Phase B is provably a no-op
         # for every group: dispatch the stable step (bit-identical)
         if self._stable_fast_path and not tmo:
@@ -672,12 +676,14 @@ class ShardedCluster:
         if prof is not None:
             prof.stop("host_encode")
             prof.start("device_dispatch")
+            prof.start("input_transfer")
+        args = (jnp.asarray(bufs["data"]), jnp.asarray(bufs["meta"]),
+                jnp.asarray(count), jnp.asarray(mask),
+                jnp.asarray(applied), jnp.asarray(qdepth))
+        if prof is not None:
+            prof.stop("input_transfer")
         with self._host_lock:
-            self.state, outs = fn(
-                self.state, jnp.asarray(bufs["data"]),
-                jnp.asarray(bufs["meta"]), jnp.asarray(count),
-                jnp.asarray(mask), jnp.asarray(applied),
-                jnp.asarray(qdepth))
+            self.state, outs = fn(self.state, *args)
             ticket = StepTicket("scan" if scan else "burst", outs,
                                 taken, {}, K, bufs,
                                 applied0=applied if scan else None)
@@ -713,12 +719,25 @@ class ShardedCluster:
             prof.sync(out)              # fenced device_sync (opt-in)
             prof.start("quorum_wait")
         res = read_scalars(ticket)               # [G, R] per key
+        # what is compiled only on request keeps a read of its own
+        # (``readback_rest``): none in the default programs
+        reads = 1
+        if prof is not None:
+            prof.start("readback_rest")
         if not (burst or scan) and self._txn and out.txn_vote is not None:
             # serial dispatches only: the txn lane never rides
             # burst/scan programs (their keys stay untouched)
             res["txn_vote"] = np.asarray(out.txn_vote)
+            reads += 1
         if prof is not None:
+            prof.stop("readback_rest")
+            prof.count("readback_arrays_total", reads)
+            # protocol steps whose full-ring rescan ran, summed over the
+            # groups (the same on every replica of one group)
+            prof.count("cfg_rescans_total",
+                       int(res["cfg_rescanned"].max(axis=-1).sum()))
             prof.stop("quorum_wait")
+            prof.start("post_readback")
         if self._audit:
             if burst or scan:
                 get = (out.__getitem__ if scan
@@ -754,12 +773,14 @@ class ShardedCluster:
             _device.accumulate(self.device_counters, res["telemetry"])
             _device.ingest(self.obs, res["telemetry"])
         txn_notes = []
+        appended = 0        # groups whose leader appended in this dispatch
         with self._host_lock:
             for g in range(G):
                 for r in range(R):
                     take = ticket.taken[g][r]
                     if take and res["role"][g, r] == int(Role.LEADER):
                         acc_gr = int(res["accepted"][g, r])
+                        appended += acc_gr > 0
                         self._stamp_appends(g, r, take, acc_gr, res)
                         if ((self.txn is not None
                              or self.topology is not None)
@@ -783,12 +804,15 @@ class ShardedCluster:
             if self.topology is not None:
                 self.topology.note_appends(*note)
         if prof is not None:
+            prof.count("group_appends_total", appended)
+            prof.stop("post_readback")
             prof.start("apply")
         self._replay_committed(
             res, scan_rows=((out["replay_data"], out["replay_meta"],
                              ticket.applied0) if scan else None))
         if prof is not None:
             prof.stop("apply")
+            prof.start("finish_tail")
         if self._audit:
             self._record_flight(res, ticket.taken, ticket.timeouts,
                                 burst_k=ticket.K)
@@ -827,6 +851,8 @@ class ShardedCluster:
             self._staging.release(ticket.bufs, [
                 ((g, r), len(ticket.taken[g][r]))
                 for g in range(G) for r in range(R)])
+        if prof is not None:
+            prof.stop("finish_tail")
         return res
 
     def drain(self) -> Optional[Dict[str, np.ndarray]]:
@@ -914,12 +940,20 @@ class ShardedCluster:
             if not todo:
                 break
             starts = jnp.asarray(self.applied.astype(np.int32))
+            prof = self.profiler
+            if prof is not None:
+                prof.start("replay_fetch")
             # bind under the host lock (donation hazard — see
             # SimCluster._replay_committed); block on results outside it
             with self._host_lock:
                 wd_fut, wm_fut = self._fetch_all(self.state.log, starts)
             self.fetch_dispatches += 1
+            # wm is read last: a wrapper over _fetch_all (the
+            # benchmark's span) ends inside its conversion
             wd_all, wm_all = np.asarray(wd_fut), np.asarray(wm_fut)
+            if prof is not None:
+                prof.stop("replay_fetch")
+                prof.start("replay_decode")
             for g, r in todo:
                 t0 = _time.perf_counter_ns()
                 commit = int(res["commit"][g, r])
@@ -934,6 +968,8 @@ class ShardedCluster:
                 self.applied[g, r] += n
                 t_group[g] = (t_group.get(g, 0)
                               + _time.perf_counter_ns() - t0)
+            if prof is not None:
+                prof.stop("replay_decode")
         if (t_group and self.obs is not None
                 and self.profiler is not None):
             from rdma_paxos_tpu.obs.metrics import LATENCY_BUCKETS_US

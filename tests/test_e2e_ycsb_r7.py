@@ -40,6 +40,20 @@ def free_ports(n):
     return ports
 
 
+def spawn_apps(apps, ports, workdir):
+    """One toyserver under the shim a replica, appended to ``apps`` as
+    it starts (the caller's ``finally`` kills what is there)."""
+    for r, port in enumerate(ports):
+        env = dict(os.environ,
+                   LD_PRELOAD=os.path.join(NATIVE, "interpose.so"),
+                   RP_PROXY_SOCK=os.path.join(str(workdir),
+                                              f"proxy{r}.sock"))
+        apps.append(subprocess.Popen(
+            [os.path.join(NATIVE, "toyserver"), str(port)], env=env,
+            stderr=subprocess.DEVNULL))
+    time.sleep(0.3)                     # let the apps bind
+
+
 @pytest.fixture()
 def group(tmp_path):
     subprocess.run(["make", "-C", NATIVE], check=True, capture_output=True)
@@ -50,15 +64,7 @@ def group(tmp_path):
             CFG, R, workdir=str(tmp_path), app_ports=ports, fanout="psum",
             timeout_cfg=TimeoutConfig(elec_timeout_low=1.0,
                                       elec_timeout_high=2.0))
-        for r, port in enumerate(ports):
-            env = dict(os.environ,
-                       LD_PRELOAD=os.path.join(NATIVE, "interpose.so"),
-                       RP_PROXY_SOCK=os.path.join(str(tmp_path),
-                                                  f"proxy{r}.sock"))
-            apps.append(subprocess.Popen(
-                [os.path.join(NATIVE, "toyserver"), str(port)], env=env,
-                stderr=subprocess.DEVNULL))
-        time.sleep(0.3)
+        spawn_apps(apps, ports, tmp_path)
         driver.run(period=0.002)
         deadline = time.time() + 120
         while driver.leader() < 0 and time.time() < deadline:

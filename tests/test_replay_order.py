@@ -155,3 +155,21 @@ def test_a_second_request_on_one_connection_waits_for_the_first(late_app):
     assert app.served == sent
     assert eng.order_timeouts == 0
     eng.close()
+
+
+def test_an_unfinished_request_is_not_waited_for(late_app):
+    """An app that follows two groups is handed the other group's
+    operation between one group's fragments: no answer can come for
+    half a request, so none is waited for, and the rest of the request
+    still waits for the answer to what went out in between."""
+    app = late_app(nap=0.0)
+    eng = ReplayEngine("127.0.0.1", app.port)
+    t0 = time.perf_counter()
+    eng.apply(SEND, 21, b"SET big aaaa")        # group A, fragment 1
+    eng.apply(SEND, 22, b"SET other 1\n")       # group B, whole
+    eng.apply(SEND, 21, b"bbbb\n")              # group A, the rest
+    eng.apply(CLOSE, 21, b"")
+    assert time.perf_counter() - t0 < ReplayEngine.ORDER_WAIT_S
+    assert app.served == [b"SET other 1", b"SET big aaaabbbb"]
+    assert eng.order_timeouts == 0
+    eng.close()

@@ -38,6 +38,7 @@ from rdma_paxos_tpu.obs.spans import (
     to_chrome_trace)
 from rdma_paxos_tpu.obs.trace import TraceRing
 from rdma_paxos_tpu.runtime.driver import ClusterDriver
+from rdma_paxos_tpu.runtime.sharded_driver import ShardedClusterDriver
 from rdma_paxos_tpu.runtime.sim import SimCluster
 
 CFG = LogConfig(n_slots=64, slot_bytes=32, window_slots=16, batch_slots=8)
@@ -550,9 +551,10 @@ NEW_PHASES = ("cycle", "unattributed", "profiler", "admin_pump",
               "intake_to_ack", "intake_queue_wait")
 
 
-def _sink_port():
+def _sink_port(answers=False):
     """A TCP server that reads and drops: the 'app' the followers'
-    ReplayEngine replays into."""
+    ReplayEngine replays into. With ``answers`` it says ``+OK`` to
+    whatever it reads, as an app would."""
     import socket
     srv = socket.socket()
     srv.bind(("127.0.0.1", 0))
@@ -561,7 +563,8 @@ def _sink_port():
     def drain(c):
         try:
             while c.recv(65536):
-                pass
+                if answers:
+                    c.sendall(b"+OK\n")
         except OSError:
             pass
 
@@ -578,16 +581,29 @@ def _sink_port():
 
 def _closed_loop_sets(d, n_clients, n_sets):
     """``n_clients`` threads, one SET outstanding each, through the
-    shim's handler; -> events acknowledged."""
-    handler = d._make_handler(0)
+    shim's handler (the sharded driver: client ``c`` through replica
+    ``c % R``'s, and its CONNECT is held, not an event); -> events
+    acknowledged."""
+    sharded = isinstance(d, ShardedClusterDriver)
     done = []
 
     def client(c):
-        conn = (0 << 24) | (100 + c)
+        r = c % d.R if sharded else 0
+        handler = d._make_handler(r)
+        conn = (r << 24) | (100 + c)
         evs = [handler(int(EntryType.CONNECT), conn, b"")]
-        assert evs[0].done.wait(30)
+        if sharded:
+            assert evs.pop() == 0
+        else:
+            assert evs[0].done.wait(30)
+        # the sharded driver routes by key prefix: client c keeps to a
+        # prefix of group c % G, so every group is served
+        stem = b"k" if not sharded else next(
+            k for k in (b"s%d" % j for j in range(1000))
+            if d.router.group_of(k) == c % d.G) + b"-"
         for i in range(n_sets):
-            ev = handler(int(EntryType.SEND), conn, b"SET k%d v\n" % i)
+            ev = handler(int(EntryType.SEND), conn,
+                         b"SET %s%d v\n" % (stem, i))
             assert ev.done.wait(30), (c, i)
             evs.append(ev)
         done.append(len(evs))
@@ -640,37 +656,73 @@ def _account_driver(tmp_path, pipeline, bench_wrapper=False):
     return d, srv, fetches, dep
 
 
+def _sharded_account_driver(tmp_path, pipeline):
+    """``_account_driver``'s sharded twin: three groups on three
+    replicas, group g led by replica g, stores and replay 'apps' that
+    answer; the same four things are handed back."""
+    srv = _sink_port(answers=True)
+    d = ShardedClusterDriver(ACCT_CFG, 3, 3, workdir=str(tmp_path),
+                             app_ports=[srv.getsockname()[1]] * 3,
+                             pipeline=pipeline)
+    assert d.cluster.place_leaders("round_robin") == [0, 1, 2]
+    d.step()
+    assert d.leaders() == [0, 1, 2]
+    fetches = []
+    jitted = d.cluster._fetch_all
+
+    def counted(log, starts):
+        fetches.append(1)
+        return jitted(log, starts)
+    d.cluster._fetch_all = counted
+    d.member_reads = []         # the sharded loop has no membership view
+    d._phase_prof.enable_events()
+    return d, srv, fetches, None
+
+
 def _delta(d, base):
     return {p: (a[0] - base[p][0], a[1] - base[p][1])
             for p, a in d._phase_prof.acc.items()}
 
 
-@pytest.fixture(scope="module")
-def serial_account(tmp_path_factory):
-    d, srv, fetches, _ = _account_driver(
+@pytest.fixture(scope="module", params=["single_group", "sharded"])
+def serial_account(request, tmp_path_factory):
+    sharded = request.param == "sharded"
+    d, srv, fetches, _ = (_sharded_account_driver if sharded
+                          else _account_driver)(
         tmp_path_factory.mktemp("acct"), pipeline=0)
     try:
         # compile what the traffic runs BEFORE the loop starts, so that
         # the account below starts and ends on cycle boundaries
-        handler = d._make_handler(0)
-        warm = [handler(int(EntryType.CONNECT), (0 << 24) | c, b"")
-                for c in (1, 2)]
+        if sharded:
+            warm = []
+            for r in range(d.R):
+                handler = d._make_handler(r)
+                for c in (1, 2, 3, 4):
+                    conn = (r << 24) | c
+                    assert handler(int(EntryType.CONNECT), conn, b"") == 0
+                    warm.append(handler(int(EntryType.SEND), conn,
+                                        b"SET w%d%d v\n" % (r, c)))
+        else:
+            handler = d._make_handler(0)
+            warm = [handler(int(EntryType.CONNECT), (0 << 24) | c, b"")
+                    for c in (1, 2)]
         assert _step_until(d, lambda: all(e.done.is_set() for e in warm))
         base = dict(d._phase_prof.acc)
         n_fetch0 = len(fetches)
-        arrays0 = d.obs.metrics.snapshot()["counters"][
-            "readback_arrays_total"]
+        counters0 = d.obs.metrics.snapshot()["counters"]
+        arrays0 = counters0["readback_arrays_total"]
         del d.member_reads[:]
         d.run()
-        acked = _closed_loop_sets(d, n_clients=2, n_sets=150)
+        acked = _closed_loop_sets(d, n_clients=3 if sharded else 2,
+                                  n_sets=150)
         time.sleep(0.2)             # the loop parks: idle_wait
         d.stop()
         assert d.loop_error is None
         counters = d.obs.metrics.snapshot()["counters"]
-        return dict(acc=_delta(d, base), acked=acked,
+        return dict(acc=_delta(d, base), acked=acked, sharded=sharded,
                     fetches=len(fetches) - n_fetch0,
                     events=list(d._phase_prof.events),
-                    counters=counters,
+                    counters=counters, counters0=counters0,
                     readback_arrays=(counters["readback_arrays_total"]
                                      - arrays0),
                     member_reads=list(d.member_reads))
@@ -709,6 +761,37 @@ def test_cycle_account_closes_on_the_serial_loop(serial_account):
                                   "unattributed", "profiler",
                                   "intake_to_ack", "intake_queue_wait"}
     assert serial_account["counters"]["replay_applies_total"] > 0
+
+
+# what ISSUE 38 asked of the sharded loop, beyond NEW_PHASES
+SHARDED_COUNTERS = ("replay_applies_total", "replay_followers_total",
+                    "replay_reply_bytes_total", "intake_fragments_total",
+                    "intake_payload_bytes_total", "readback_arrays_total",
+                    "group_appends_total")
+
+
+def test_sharded_dispatch_records_every_phase_and_counter(serial_account):
+    """Every phase and counter the benchmark's per-layer metrics read
+    is recorded by the sharded loop too, by dispatches that append in
+    every group and replay to every replica's app."""
+    if not serial_account["sharded"]:
+        pytest.skip("the sharded driver's account")
+    acc = serial_account["acc"]
+    counters = {k: v - serial_account["counters0"].get(k, 0)
+                for k, v in serial_account["counters"].items()}
+    for phase in NEW_PHASES + ("ack_release", "apply", "host_encode",
+                               "device_dispatch", "quorum_wait"):
+        assert acc[phase][0] > 0, f"{phase} never recorded"
+    for name in SHARDED_COUNTERS:
+        assert counters[name] > 0, name
+    assert "cfg_rescans_total" in counters
+    n = acc["device_dispatch"][0]
+    # at most G groups append in a dispatch, and every group was served
+    assert n <= counters["group_appends_total"] <= 3 * n
+    acks = [counters["group_acks_total{group=%d}" % g] for g in range(3)]
+    assert all(acks) and sum(acks) >= serial_account["acked"]
+    # a replica follows two groups: ONE list of its operations a dispatch
+    assert counters["replay_followers_total"] <= 3 * n
 
 
 def test_one_readback_array_a_dispatch(serial_account):
@@ -827,17 +910,25 @@ def test_profiler_survives_an_abandoned_phase_and_reads_zero_rows():
                                                 max_us=100.0)
 
 
-def test_pipelined_loop_loses_no_phase_with_two_threads(tmp_path):
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["single_group", "sharded"])
+def test_pipelined_loop_loses_no_phase_with_two_threads(tmp_path, sharded):
     """tests/test_pipeline.py's set-up: a record longer than one burst
     queued BEFORE the loop starts, so dispatch and readback overlap and
     both threads are in the profiler at once."""
-    d, srv, _fetches, _ = _account_driver(tmp_path, pipeline=2)
+    d, srv, _fetches, _ = (_sharded_account_driver if sharded
+                           else _account_driver)(tmp_path, pipeline=2)
     try:
         handler = d._make_handler(0)
         conns = [(0 << 24) | 11, (0 << 24) | 12]
         evs = [handler(int(EntryType.CONNECT), c, b"") for c in conns]
+        if sharded:             # held with the connection's first SEND
+            assert evs == [0, 0]
+            evs = []
+        # (the sharded driver pins each connection to its first key's
+        # group: as many again, so that ONE group's queue is as long)
         evs += [handler(int(EntryType.SEND), conns[i % 2], b"w%03d" % i)
-                for i in range(200)]
+                for i in range(400 if sharded else 200)]
         base = dict(d._phase_prof.acc)
         arrays0 = d.obs.metrics.snapshot()["counters"][
             "readback_arrays_total"]
