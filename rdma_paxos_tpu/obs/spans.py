@@ -630,6 +630,15 @@ PHASE_OBSERVE = "observe"                # _observe_step + cadences
 # per operation, credited (no start/stop, no ring entry)
 OP_INTAKE_TO_ACK = "intake_to_ack"       # PendingEvent.t0 -> release
 OP_INTAKE_QUEUE_WAIT = "intake_queue_wait"  # PendingEvent.t0 -> pump
+# a membership change and a replica's recovery: rare, recorded only when
+# they run. The first two nest where they run (admin_pump, or
+# post_step_rules for the auto-recovery); the last two span many cycles
+# and are credited whole when they end, one sample each
+PHASE_CHECKPOINT = "checkpoint"          # _do_checkpoint
+PHASE_RECOVER = "recover"                # snapshot take/verify/install +
+#                                          the store's transfer
+SPAN_CONFIG_CHANGE = "config_change"     # TRANSIT submitted -> STABLE seen
+SPAN_APP_REBUILD = "app_rebuild"         # a fresh app fed the history
 
 
 class StepPhaseProfiler:
@@ -674,11 +683,14 @@ class StepPhaseProfiler:
               PHASE_REPLAY_FETCH, PHASE_REPLAY_DECODE, PHASE_FINISH_TAIL,
               PHASE_STORE_APPEND, PHASE_REPLAY_SEND, PHASE_REPLAY_DRAIN,
               PHASE_POST_STEP_RULES, PHASE_OBSERVE, OP_INTAKE_TO_ACK,
-              OP_INTAKE_QUEUE_WAIT)
+              OP_INTAKE_QUEUE_WAIT, PHASE_CHECKPOINT, PHASE_RECOVER,
+              SPAN_CONFIG_CHANGE, SPAN_APP_REBUILD)
     COUNTERS = ("readback_arrays_total", "cfg_rescans_total",
                 "replay_applies_total",
                 "replay_followers_total", "replay_reply_bytes_total",
-                "intake_fragments_total", "intake_payload_bytes_total")
+                "intake_fragments_total", "intake_payload_bytes_total",
+                "recover_bytes_total", "recover_entries_total",
+                "replay_reconnects_total")
     # a thread waiting by design: its length counts towards no stall,
     # its own or of the phase it waits in
     WAITS = (PHASE_IDLE_WAIT, PHASE_PIPELINE_WAIT)

@@ -272,16 +272,46 @@ class ProxyServer:
             os.unlink(self.sock_path)
 
 
+_DUMP_MAGIC = 0x52505353544F5231     # native/stablestore.cpp kMagic
+
+
+def dump_records(blob: bytes):
+    """-> (base, iterator over the records) of a ``StableStore.dump()``
+    blob, parsed here (an optional 16-byte ``[magic, base]`` header,
+    then ``[u32 length][record]`` after ``[u32 length][record]``): a
+    joiner's app is fed the history from the blob that brought it,
+    not read back out of the store record by record."""
+    off = base = 0
+    if len(blob) >= 16:
+        magic, b = struct.unpack_from("<QQ", blob, 0)
+        if magic == _DUMP_MAGIC:
+            off, base = 16, b
+
+    def records():
+        at, end = off, len(blob)
+        while at + 4 <= end:
+            (n,) = struct.unpack_from("<I", blob, at)
+            yield blob[at + 4:at + 4 + n]
+            at += 4 + n
+    return base, records()
+
+
+def apply_record(replay: "ReplayEngine", rec: bytes) -> None:
+    """One store record (1-byte etype + 4-byte little-endian conn id +
+    payload) into the app: the single decoder of the layout."""
+    replay.apply(rec[0], int.from_bytes(rec[1:5], "little"), rec[5:])
+
+
 def replay_store_into(store, replay: "ReplayEngine",
-                      start: int = 0) -> None:
+                      start: int = 0, stop: Optional[int] = None,
+                      cap: int = 1 << 20) -> None:
     """Replay the stable store's event history from record ``start``
-    into the local app (``proxy_apply_db_snapshot`` analog,
-    ``proxy.c:306-339``) — the single decoder of the store record layout
-    (1-byte etype + 4-byte little-endian conn id + payload). ``start=0``
-    rebuilds a FRESH app; a nonzero ``start`` delivers only the delta to
-    a LIVE app that already executed the prefix (store streams are
-    prefix-consistent: every store is a prefix of the committed event
-    order)."""
+    (up to ``stop``, default the store's end) into the local app
+    (``proxy_apply_db_snapshot`` analog, ``proxy.c:306-339``).
+    ``start=0`` rebuilds a FRESH app; a nonzero ``start`` delivers only
+    the delta to a LIVE app that already executed the prefix (store
+    streams are prefix-consistent: every store is a prefix of the
+    committed event order). ``cap`` bounds one record's read buffer."""
     if replay is None:
         return
     base = getattr(store, "base", 0)
@@ -289,9 +319,8 @@ def replay_store_into(store, replay: "ReplayEngine",
         # records below base were compacted away; their effects must
         # already be covered by a restored app-state checkpoint
         start = base
-    for i in range(start, len(store)):
-        rec = store.read(i)
-        replay.apply(rec[0], int.from_bytes(rec[1:5], "little"), rec[5:])
+    for i in range(start, len(store) if stop is None else stop):
+        apply_record(replay, store.read(i, cap))
     replay.drain_responses()
 
 

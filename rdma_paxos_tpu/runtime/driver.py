@@ -44,7 +44,8 @@ from rdma_paxos_tpu.obs.metrics import (
 from rdma_paxos_tpu.obs.spans import StepPhaseProfiler, span_trace_id
 from rdma_paxos_tpu.obs.tracectx import health_blame as _health_blame
 from rdma_paxos_tpu.proxy.proxy import (
-    PendingEvent, ProxyServer, ReplayEngine, spec_send_refused_dirty)
+    PendingEvent, ProxyServer, ReplayEngine, apply_record, dump_records,
+    replay_store_into, spec_send_refused_dirty)
 from rdma_paxos_tpu.proxy.stablestore import (
     HardState, StableStore, atomic_write)
 from rdma_paxos_tpu.runtime.hostpath import plan_segment
@@ -83,6 +84,11 @@ class _ReplicaRuntime:
         # is replayed into the app and new client sessions are severed;
         # the operator restarts the app and calls reset_app().
         self.app_dirty = False
+        # (store cursor, perf_counter at its start) of a fresh app's
+        # background rebuild that has come within a few records of the
+        # store's end and waits for the poll loop to finish it
+        # (ClusterDriver._rebuild_app); ``app_dirty`` all the while
+        self.rebuild: Optional[Tuple[int, float]] = None
         self.last_sync = 0.0      # cadenced store fdatasync bookkeeping
         self.store = StableStore(store_path) if store_path else None
         # durable (term, voted_term, voted_for) — persisted every step the
@@ -363,7 +369,18 @@ class ClusterDriver:
         # wedged by leader churn losing the CONFIG entry; on expiry the
         # phase resets so eviction/request can be re-issued
         self._config_phase: Optional[Tuple[str, int, int, int]] = None
+        self._config_t0 = 0.0     # perf_counter at its TRANSIT's submit
         self.config_changes_abandoned = 0
+        # present at 0 from the start, so that a reader of deltas finds
+        # them before the first change as after it
+        for name in ("evictions_total", "config_changes_total",
+                     "checkpoints_total"):
+            self.obs.metrics.inc(name, 0)
+        # replicas whose machine is lost (fail_replica) until a
+        # replacement is recovered into their row
+        self._lost: set = set()
+        # (thread, replica, exception box) of each app rebuild started
+        self._rebuilders: List[Tuple[threading.Thread, int, list]] = []
         # recovery requests execute inside the poll loop (never racing
         # the stepping thread over cluster.state): (replica, donor,
         # done_event, exception_box) — failures surface to the caller,
@@ -1243,6 +1260,7 @@ class ClusterDriver:
             if survivors > bin(mask).count("1") // 2:
                 self._mm.submit_transit(lead, mask, new_mask, epoch + 1)
                 self._config_phase = ("transit", new_mask, epoch + 1, 500)
+                self._config_t0 = time.perf_counter()
                 self.obs.metrics.inc("evictions_total", len(dead))
                 self.obs.trace.record(obs_trace.MEMBERSHIP_CHANGE,
                                       phase="evict_transit", dead=dead,
@@ -1300,6 +1318,9 @@ class ClusterDriver:
                 if (cur["epoch"] >= epoch
                         and cur["cid_state"] == int(ConfigState.STABLE)):
                     self._config_phase = None
+                    self._phase_prof.credit(
+                        "config_change",
+                        (time.perf_counter() - self._config_t0) * 1e6)
                     self.obs.metrics.inc("config_changes_total")
                     self.obs.trace.record(obs_trace.MEMBERSHIP_CHANGE,
                                           phase="complete",
@@ -1308,25 +1329,92 @@ class ClusterDriver:
     def request_membership(self, new_mask: int) -> None:
         """Operator API: start a two-phase change to ``new_mask`` (join /
         upsize / downsize); the polling loop drives it to completion."""
-        lead = self._leader_view
-        if lead < 0:
+        lead, last = self._leader_view, self.cluster.last
+        if lead < 0 or last is None:
             raise RuntimeError("no leader")
-        cur = self._mm.current(lead)
-        self._mm.submit_transit(lead, cur["bitmask_new"], new_mask,
-                                cur["epoch"] + 1)
-        self._config_phase = ("transit", new_mask, cur["epoch"] + 1, 500)
+        if self._config_phase is not None:
+            raise RuntimeError("a membership change is being driven")
+        # the leader's view as the last finished step's packed row has
+        # it (with no change in flight it is the current one): the
+        # device state itself may be donated to a dispatch in flight,
+        # and this is an operator's thread
+        epoch = int(last["epoch"][lead]) + 1
+        self._mm.submit_transit(lead, int(last["bitmask_new"][lead]),
+                                new_mask, epoch)
+        self._config_phase = ("transit", new_mask, epoch, 500)
+        self._config_t0 = time.perf_counter()
         self.obs.trace.record(obs_trace.MEMBERSHIP_CHANGE,
                               phase="transit_requested",
-                              new_mask=new_mask, epoch=cur["epoch"] + 1)
+                              new_mask=new_mask, epoch=epoch)
+
+    def membership(self) -> Optional[Dict]:
+        """The leader's configuration as of the last finished step
+        (its packed row: no device read): ``mask`` of the members,
+        ``stable`` (no joint phase), ``epoch``, and ``changing`` while
+        the loop still drives a change. None without a leader."""
+        lead, last = self._leader_view, self.cluster.last
+        if lead < 0 or last is None:
+            return None
+        return dict(mask=int(last["bitmask_new"][lead]),
+                    stable=(int(last["cid_state"][lead])
+                            == int(ConfigState.STABLE)),
+                    epoch=int(last["epoch"][lead]),
+                    changing=self._config_phase is not None)
+
+    def fail_replica(self, r: int) -> None:
+        """Replica ``r``'s machine is lost, as far as one process can
+        render it: from the next dispatch on its row hears nobody and
+        nobody hears it (``cluster.partition``), and its election timer
+        stops, as a dead machine's does (left running, its candidacies
+        would raise a term that deposes the leader the moment the row
+        is heard again). The group is told nothing: the leader's
+        failure detector finds out (``auto_evict``). Its app is the
+        caller's to kill; ``recover_replica`` brings a replacement into
+        the row."""
+        self._cut_off(self._lost | {r})
+        self.runtimes[r].timer.stop()
+        self.runtimes[r].log.info_wtime("LOST: row cut off, timer stopped")
+
+    def _cut_off(self, lost: set) -> None:
+        """Every replica of ``lost`` alone, the others together (all
+        heard again where ``lost`` is empty); ``_lost`` follows only
+        where the engine took the split."""
+        if lost:
+            self.cluster.partition(
+                [[p for p in range(self.R) if p not in lost]]
+                + [[p] for p in sorted(lost)])
+        else:
+            self.cluster.heal()
+        self._lost = lost
+
+    def prewarm_recovery(self) -> None:
+        """Load the programs that a membership change and a snapshot
+        recovery run (the config view's reads, the determinant's, the
+        vote records', the install), so that the first one under load
+        compiles nothing. Call before ``run()``; the state is left as
+        it was."""
+        self._mm.current(0)
+        recover_vote(self.cluster.state, 0)
+        # index 1 whatever was applied: the determinant's term is read
+        # off the ring only for an index above 0
+        snap = take_snapshot(self.cluster.state, 0, index=1)
+        install_snapshot(self.cluster.state, 0, snap)   # result dropped
 
     def recover_replica(self, r: int, donor: Optional[int] = None,
-                        timeout: float = 60.0) -> None:
+                        timeout: float = 60.0,
+                        wait_app: bool = True) -> None:
         """Snapshot-recover replica ``r`` from ``donor`` (default: current
         leader): install the consensus determinant and transfer the event
         history into r's stable store (reset first — never duplicated).
         The app instance behind r must be fresh (restarted) — its state is
         rebuilt by replaying the store. Executes inside the poll loop so
-        it never races the stepping thread over cluster state."""
+        it never races the stepping thread over cluster state; under a
+        running loop the app is fed by a thread of its own
+        (:meth:`_rebuild_app`) while the loop serves, and this call
+        returns when the app holds the history, or with
+        ``wait_app=False`` as soon as the consensus state and the store
+        are in (a joiner must be asked into the configuration before it
+        falls a window behind: ``wait_app_rebuilt`` is for afterwards)."""
         done = threading.Event()
         box: list = []
         with self._lock:
@@ -1340,6 +1428,20 @@ class ClusterDriver:
             raise TimeoutError("recovery did not run (loop stalled?)")
         if box:
             raise box[0]
+        if wait_app:
+            self.wait_app_rebuilt(r, timeout)
+
+    def wait_app_rebuilt(self, r: int, timeout: float = 60.0) -> None:
+        """Return once no rebuild of replica ``r``'s app is under way
+        (:meth:`_rebuild_app`), raising what ended it if it failed."""
+        for t, rr, box in list(self._rebuilders):
+            if rr != r:
+                continue
+            t.join(timeout)
+            if t.is_alive():
+                raise TimeoutError("the app's rebuild did not end")
+            if box:
+                raise box[0]
 
     def reset_app(self, r: int, timeout: float = 60.0) -> None:
         """Exit mis-speculation quarantine: the operator has restarted
@@ -1398,6 +1500,13 @@ class ClusterDriver:
             raise box[0]
 
     def _do_checkpoint(self, r: int) -> None:
+        self._phase_prof.start("checkpoint")
+        try:
+            self._checkpoint(r)
+        finally:
+            self._phase_prof.stop("checkpoint")
+
+    def _checkpoint(self, r: int) -> None:
         import struct
         rt = self.runtimes[r]
         if self.app_snapshot is None:
@@ -1447,6 +1556,21 @@ class ClusterDriver:
 
     def _do_reset_app(self, r: int) -> None:
         rt = self.runtimes[r]
+        if rt.rebuild is not None:
+            # a background rebuild's last leg: the few records that
+            # reached the store since its worker looked, fed here,
+            # where nothing appends to the store meanwhile (the
+            # pipeline is drained); live replay takes over from the
+            # next committed entry
+            (cur, t0), rt.rebuild = rt.rebuild, None
+            self._feed_store(rt, cur, len(rt.store))
+            rt.app_dirty = False
+            prof = self._phase_prof
+            prof.credit("app_rebuild", (time.perf_counter() - t0) * 1e6)
+            prof.count("replay_reconnects_total", len(rt.replay.conns))
+            rt.log.info_wtime("APP REBUILT: holds the store's %d records"
+                              % len(rt.store))
+            return
         if rt.replay is not None:
             rt.replay.close()
             rt.replay = ReplayEngine("127.0.0.1", rt.app_port)
@@ -1461,7 +1585,6 @@ class ClusterDriver:
                         "store compacted to %d but no matching app "
                         "checkpoint to rebuild from" % rt.store.base)
                 self._restore_ckpt(rt, ckpt)
-            from rdma_paxos_tpu.proxy.proxy import replay_store_into
             replay_store_into(rt.store, rt.replay, start=0)
         rt.app_dirty = False
         rt.log.info_wtime("APP RESET: rebuilt from committed store")
@@ -1477,6 +1600,14 @@ class ClusterDriver:
         carries the donor's audit-chain position and the install
         refuses a donor contradicting the ledger majority — raising
         BEFORE any state (device, store, or app) is touched."""
+        self._phase_prof.start("recover")
+        try:
+            self._recover(r, donor, app_fresh, ledger, min_verified)
+        finally:
+            self._phase_prof.stop("recover")
+
+    def _recover(self, r: int, donor: Optional[int], app_fresh: bool,
+                 ledger, min_verified: int) -> None:
         donor = self._leader_view if donor is None else donor
         if donor < 0:
             raise RuntimeError("no donor available")
@@ -1513,10 +1644,18 @@ class ClusterDriver:
             # undrained frames predate the snapshot load: appending
             # them to the freshly loaded store would duplicate history
             self.cluster.frames[r] = []
+            if r in self._lost:
+                # a replacement stands in the lost machine's row: heard
+                # again only now that nothing of the old one is left
+                self._cut_off(self._lost - {r})
+                rrt.timer.beat()
         if rrt.store is not None and snap.store_blob:
             old_len = len(rrt.store)
             rrt.store.reset()
-            rrt.store.load(snap.store_blob)
+            n_loaded = rrt.store.load(snap.store_blob)
+            self._phase_prof.count("recover_bytes_total",
+                                   len(snap.store_blob))
+            self._phase_prof.count("recover_entries_total", n_loaded)
             base = rrt.store.base
             if base > 0:
                 # the donor's store was compacted behind its app
@@ -1543,13 +1682,91 @@ class ClusterDriver:
                         "live app executed only %d records but the "
                         "donor history now starts at %d — restart the "
                         "app and use reset_app" % (old_len, base))
-            from rdma_paxos_tpu.proxy.proxy import replay_store_into
             # fresh app: rebuild checkpoint + full retained history;
             # live app (auto recovery): deliver only the records beyond
             # the prefix it already executed — its own old store (a
             # prefix of the donor's, both being the committed order)
-            replay_store_into(rrt.store, rrt.replay,
-                              start=0 if app_fresh else old_len)
+            if not app_fresh:
+                replay_store_into(rrt.store, rrt.replay, start=old_len)
+            elif rrt.replay is not None:
+                # the old engine's sockets were the old app's
+                rrt.replay.close()
+                rrt.replay = ReplayEngine("127.0.0.1", rrt.app_port)
+                if threading.current_thread() is self._thread:
+                    self._start_rebuild(r, rrt, snap.store_blob)
+                else:
+                    self._feed_blob(rrt, snap.store_blob)
+                    rrt.replay.drain_responses()
+
+    # a rebuild's worker hands over to the poll loop once the store is
+    # at most this many records ahead of what it has fed the app
+    REBUILD_HANDOVER = 64
+
+    def _feed_blob(self, rt: _ReplicaRuntime, blob: bytes) -> int:
+        """A fresh app fed the history a joiner's snapshot brought, from
+        the blob itself; -> the store index after its last record."""
+        base, records = dump_records(blob)
+        n = 0
+        for n, rec in enumerate(records, 1):
+            apply_record(rt.replay, rec)
+        return base + n
+
+    def _feed_store(self, rt: _ReplicaRuntime, start: int,
+                    stop: int) -> None:
+        """Records ``[start, stop)`` of ``rt``'s own store into its app
+        (what committed since the blob was taken)."""
+        replay_store_into(rt.store, rt.replay, start=start, stop=stop,
+                          cap=self.cfg.slot_bytes + 64)
+
+    def _start_rebuild(self, r: int, rt: _ReplicaRuntime,
+                       blob: bytes) -> None:
+        """Under a running loop a fresh app is fed by a thread of its
+        own: the whole history at an answer a request would hold the
+        loop, and with it every client, for seconds. Until it holds
+        the history the app is quarantined (``app_dirty``: the store
+        keeps every committed entry, the app is replayed nothing and
+        serves nobody)."""
+        rt.app_dirty = True
+        box: list = []
+        t = threading.Thread(target=self._rebuild_app,
+                             args=(r, rt, blob, box), daemon=True)
+        self._rebuilders = [e for e in self._rebuilders
+                            if e[0].is_alive()] + [(t, r, box)]
+        t.start()
+
+    def _rebuild_app(self, r: int, rt: _ReplicaRuntime, blob: bytes,
+                     box: list) -> None:
+        """The worker: the blob, then what the store has gained since,
+        again and again until it is within ``REBUILD_HANDOVER`` records
+        of the store's end; the last leg is the poll loop's
+        (``_do_reset_app``, asked for like an operator's reset), since
+        only there nothing appends meanwhile. Ends when that is done."""
+        t0 = time.perf_counter()
+        try:
+            cur = self._feed_blob(rt, blob)
+            while not self._stop.is_set():
+                end = len(rt.store)
+                if end - cur <= self.REBUILD_HANDOVER:
+                    break
+                self._feed_store(rt, cur, end)
+                cur = end
+            rt.rebuild = (cur, t0)
+            done = threading.Event()
+            while not self._stop.is_set():
+                with self._lock:
+                    if self._reset_req is None:
+                        self._reset_req = (r, done, box)
+                        break
+                time.sleep(0.001)
+            self._wake.set()
+            while not done.wait(0.05):
+                if self._stop.is_set() or self.loop_error is not None:
+                    raise RuntimeError("the loop ended under the rebuild")
+        except Exception as exc:  # noqa: BLE001 — reported to the waiter
+            # the app stays quarantined; reset_app starts over
+            rt.rebuild = None
+            box.append(exc)
+            rt.log.info_wtime("APP REBUILD FAILED: %r" % (exc,))
 
     def _apply_new_entries(self, r: int, rt: _ReplicaRuntime,
                            replays: list) -> None:
@@ -1687,7 +1904,10 @@ class ClusterDriver:
         for turn in itertools.zip_longest(*(ops for _, ops in replays)):
             for (engine, _), op in zip(replays, turn):
                 if op is not None:
-                    engine.apply(*op)
+                    try:
+                        engine.apply(*op)
+                    except OSError as exc:
+                        self._replay_lost(engine, exc)
         prof.stop("replay_send")
         prof.count("replay_applies_total",
                    sum(len(ops) for _, ops in replays))
@@ -1697,6 +1917,22 @@ class ClusterDriver:
         prof.stop("replay_drain")
         prof.count("replay_reply_bytes_total", reply_bytes)
         prof.stop("apply_replay_ack")
+
+    def _replay_lost(self, engine: ReplayEngine, exc: OSError) -> None:
+        """A follower's app would not take a replayed operation (it
+        died, or reset the connection): the pass goes on for the
+        others, and this app is quarantined like a mis-speculated one
+        (``app_dirty``): its store keeps every committed entry, it is
+        replayed nothing more, and ``recover_replica`` / ``reset_app``
+        rebuild a fresh one. Never the loop's death: the group serves
+        on without it."""
+        for rt in self.runtimes:
+            if rt.replay is engine and not rt.app_dirty:
+                rt.app_dirty = True
+                engine.close()
+                self.obs.metrics.inc("replay_errors_total", replica=rt.idx)
+                rt.log.info_wtime("APP LOST: replay failed (%r); "
+                                  "quarantined until rebuilt" % (exc,))
 
     # ------------------------------------------------------------------
     # lifecycle
@@ -2137,6 +2373,8 @@ class ClusterDriver:
         if self._rb_thread is not None:
             self._pl_queue.put(None)
             self._rb_thread.join(timeout=join_timeout)
+        for t, _r, _box in self._rebuilders:
+            t.join(timeout=join_timeout)
         # release commit waiters that were already inflight at stop —
         # nothing will ever step again, so they must fail, not hang
         # (queued reads the same: no step will ever confirm them)

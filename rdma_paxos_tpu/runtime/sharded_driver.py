@@ -706,9 +706,14 @@ class ShardedClusterDriver(ClusterDriver):
             "membership changes are single-group only (ROADMAP: "
             "elastic resharding)")
 
-    def recover_replica(self, r, donor=None, timeout: float = 60.0):
+    def recover_replica(self, r, donor=None, timeout: float = 60.0,
+                        wait_app: bool = True):
         raise NotImplementedError(
             "snapshot recovery is single-group only")
+
+    def fail_replica(self, r: int) -> None:
+        raise NotImplementedError(
+            "a lost machine is modelled for a single group only")
 
     def reset_app(self, r: int, timeout: float = 60.0) -> None:
         raise NotImplementedError("app reset is single-group only")

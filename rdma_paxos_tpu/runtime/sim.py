@@ -432,6 +432,8 @@ class SimCluster:
         # under the lock  # guarded-by: _host_lock [writes]
         self.applied = np.zeros(n_replicas, np.int64)
         self.peer_mask = np.ones((n_replicas, n_replicas), np.int32)
+        # a split that partition() found sound under the psum fan-out
+        self._psum_split = False
         # guarded-by: _host_lock
         self.pending: List[List[Tuple[int, int, int, bytes]]] = [
             [] for _ in range(n_replicas)]
@@ -588,24 +590,52 @@ class SimCluster:
         self._txn_wterm = 0
 
     def partition(self, groups: Sequence[Sequence[int]]) -> None:
-        """Split the cluster: replicas hear only same-group peers."""
+        """Split the cluster: replicas hear only same-group peers. One
+        rebind of the matrix: a dispatch on another thread reads the
+        old one or the new one, never a half-written one."""
         if self._fanout == "psum":
-            # the O(W) psum fan-out assumes at most one self-claimed
-            # leader (full connectivity); two partitioned leaders would
-            # SUM their windows into followers' logs — reject loudly
-            # (see replica_step's fanout docstring)
-            raise ValueError(
-                "partitions cannot be modeled with fanout='psum'; "
-                "build the cluster with fanout='gather'")
-        self.peer_mask[:] = 0
+            self._check_psum_split(groups)
+        mask = np.zeros_like(self.peer_mask)
         for g in groups:
             for i in g:
                 for j in g:
-                    self.peer_mask[i, j] = 1
-        np.fill_diagonal(self.peer_mask, 1)
+                    mask[i, j] = 1
+        np.fill_diagonal(mask, 1)
+        self.peer_mask = mask
+        self._psum_split = self._fanout == "psum"
+
+    def _check_psum_split(self, groups) -> None:
+        """The O(W) psum fan-out SUMS every self-claimed leader's
+        window (see replica_step's fanout docstring), so under it a
+        split is sound only while a second leader cannot come to be:
+        every leader of now is in ONE group, and no other group holds
+        a majority of that leader's configuration, old or new (a
+        member cut off alone: a lost machine). Anything else is
+        rejected loudly."""
+        last = self.last
+        leaders = ([] if last is None else
+                   [r for r in range(self.R)
+                    if last["role"][r] == int(Role.LEADER)])
+        home = [set(g) for g in groups if leaders and leaders[0] in g]
+        ok = len(home) == 1 and home[0].issuperset(leaders)
+        if ok:
+            lead = leaders[0]
+            for key in ("bitmask_old", "bitmask_new"):
+                mask = int(last[key][lead])
+                members = {r for r in range(self.R) if (mask >> r) & 1}
+                ok = ok and all(
+                    len(members & set(g)) <= len(members) // 2
+                    for g in groups if set(g) != home[0])
+        if not ok:
+            raise ValueError(
+                "partitions cannot be modeled with fanout='psum' "
+                "unless every leader stays in one group and no other "
+                "group could elect one; build the cluster with "
+                "fanout='gather'")
 
     def heal(self) -> None:
-        self.peer_mask[:] = 1
+        self.peer_mask = np.ones_like(self.peer_mask)
+        self._psum_split = False
 
     def wedge_apply(self, r: int) -> None:
         """Freeze replica ``r``'s apply progress (models a wedged app:
@@ -671,7 +701,8 @@ class SimCluster:
             prof.start("host_encode")
         cfg, R, B = self.cfg, self.R, self.cfg.batch_slots
         mask = self._effective_mask()
-        if self._fanout == "psum" and not mask.all():
+        if (self._fanout == "psum" and not self._psum_split
+                and not mask.all()):
             raise ValueError(
                 "psum fan-out requires full connectivity; use "
                 "fanout='gather' to model partitions")
@@ -753,7 +784,8 @@ class SimCluster:
         if prof is not None:
             prof.start("host_encode")
         mask = self._effective_mask()
-        if self._fanout == "psum" and not mask.all():
+        if (self._fanout == "psum" and not self._psum_split
+                and not mask.all()):
             raise ValueError(
                 "psum fan-out requires full connectivity; use "
                 "fanout='gather' to model partitions")

@@ -43,6 +43,11 @@ class ElectionTimer:
     def beat(self) -> None:
         self._deadline = self._clock() + self._draw()
 
+    def stop(self) -> None:
+        """Never fire again until the next ``beat()``: the timer of a
+        machine that is gone."""
+        self._deadline = float("inf")
+
     def expired(self) -> bool:
         return self._clock() >= self._deadline
 

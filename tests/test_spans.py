@@ -725,7 +725,11 @@ def serial_account(request, tmp_path_factory):
                     counters=counters, counters0=counters0,
                     readback_arrays=(counters["readback_arrays_total"]
                                      - arrays0),
-                    member_reads=list(d.member_reads))
+                    member_reads=list(d.member_reads),
+                    replacement=dict(
+                        auto_evict=d.auto_evict,
+                        config_phase=d._config_phase,
+                        rebuilders=len(d._rebuilders), lost=len(d._lost)))
     finally:
         d.stop()
         srv.close()
@@ -846,6 +850,117 @@ def test_replay_fetch_phase_counts_fetch_dispatches(tmp_path,
             span = dep.bench_spans["replay_fetch"]
             assert span.count == len(fetches) - n0
             assert span.total_us <= acc["replay_fetch"][1] * 1.05 + 50
+    finally:
+        d.stop()
+        srv.close()
+
+
+# a membership change and a replica's recovery (ISSUE 43): recorded
+# only when they run
+REPLACEMENT_PHASES = ("config_change", "checkpoint", "recover",
+                      "app_rebuild")
+REPLACEMENT_COUNTERS = ("recover_bytes_total", "recover_entries_total",
+                        "replay_reconnects_total", "evictions_total",
+                        "config_changes_total", "checkpoints_total")
+
+
+def test_a_serving_driver_records_nothing_of_a_replacement(serial_account):
+    """A driver built WITHOUT ``auto_evict`` and handed no membership
+    change, over 150 loaded dispatches: not one sample of the four
+    phases, not one count, not one read of the membership view, no
+    rebuild worker: the cells that never ask for a replacement run
+    nothing new (the phases and counters are there, at 0, so that a
+    reader of deltas finds them)."""
+    acc = serial_account["acc"]
+    for phase in REPLACEMENT_PHASES:
+        assert acc[phase] == (0, 0.0), phase
+    before, after = serial_account["counters0"], serial_account["counters"]
+    for name in REPLACEMENT_COUNTERS:
+        assert after[name] == before[name] == 0, name
+    assert serial_account["member_reads"] == []
+    assert serial_account["replacement"] == dict(
+        auto_evict=False, config_phase=None, rebuilders=0, lost=0)
+
+
+@pytest.mark.parametrize("pipeline", [0, 2], ids=["serial", "pipelined"])
+def test_replacement_phases_nest_in_the_cycle_account(tmp_path, pipeline):
+    """A checkpoint, a lost follower's eviction, its recovery with a
+    fresh app and its way back in, on a running loop under load:
+    ``checkpoint`` and ``recover`` nest in ``admin_pump`` (a drained
+    serial iteration), ``config_change`` and ``app_rebuild`` span many
+    cycles and are credited whole, one sample each; the cycle's
+    account closes as it did."""
+    srv = _sink_port(answers=True)
+    d = ClusterDriver(ACCT_CFG, 3, workdir=str(tmp_path),
+                      app_ports=[srv.getsockname()[1]] * 3,
+                      timeout_cfg=TO, pipeline=pipeline, auto_evict=True,
+                      fail_threshold=5,
+                      app_snapshot=(lambda s: b"", lambda s, blob: None,
+                                    lambda s: None))
+    try:
+        d.cluster.prewarm()
+        d.prewarm_recovery()
+        d.cluster.run_until_elected(0)
+        d.step()
+        base = dict(d._phase_prof.acc)
+        d.run()
+        stop = threading.Event()
+        acked = []
+
+        def client():
+            handler = d._make_handler(0)
+            conn = (0 << 24) | 77
+            assert handler(int(EntryType.CONNECT), conn, b"").done.wait(30)
+            while not stop.is_set():
+                ev = handler(int(EntryType.SEND), conn,
+                             b"SET k%d v\n" % len(acked))
+                assert ev.done.wait(30) and ev.status == 0
+                acked.append(ev)
+        t = threading.Thread(target=client, daemon=True)
+        t.start()
+
+        def until(cond, what):
+            deadline = time.monotonic() + 60
+            while not cond():
+                assert time.monotonic() < deadline and t.is_alive(), what
+                time.sleep(0.005)
+
+        def stands_on(mask):
+            m = d.membership()
+            return (m is not None and m["mask"] == mask and m["stable"]
+                    and not m["changing"])
+        until(lambda: len(acked) > 20, "no load")
+        d.checkpoint_app(1)
+        d.fail_replica(2)
+        until(lambda: stands_on(0b011), "never evicted")
+        n = len(acked)
+        until(lambda: len(acked) > n + 20, "two members do not serve")
+        d.recover_replica(2)            # waits for the app's rebuild
+        d.request_membership(0b111)
+        until(lambda: stands_on(0b111), "never back in")
+        n = len(acked)
+        until(lambda: len(acked) > n + 20, "three members do not serve")
+        stop.set()
+        t.join(30)
+        assert not t.is_alive()
+        d.stop()
+        assert d.loop_error is None
+        acc = _delta(d, base)
+        assert [acc[p][0] for p in REPLACEMENT_PHASES] == [2, 1, 1, 1]
+        assert all(acc[p][1] > 0 for p in REPLACEMENT_PHASES)
+        parts = (sum(acc[p][1] for p in CYCLE_CHILDREN)
+                 + acc["unattributed"][1])
+        assert abs(parts - acc["cycle"][1]) < 1e-6 * acc["cycle"][1] + 1.0
+        # both ran inside a serial iteration's admin_pump
+        assert (acc["checkpoint"][1] + acc["recover"][1]
+                <= acc["admin_pump"][1])
+        counters = d.obs.metrics.snapshot()["counters"]
+        assert counters["evictions_total"] == 1
+        assert counters["config_changes_total"] == 2
+        assert counters["checkpoints_total{replica=1}"] == 1
+        assert counters["replay_reconnects_total"] == 1
+        assert counters["recover_entries_total"] > 20
+        assert not d.runtimes[2].app_dirty
     finally:
         d.stop()
         srv.close()
