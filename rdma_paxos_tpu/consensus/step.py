@@ -308,6 +308,7 @@ def replica_step(
     audit: bool = False,
     telemetry: bool = False,
     txn: bool = False,
+    group_batch_axis: Optional[str] = None,
 ) -> Tuple[ReplicaState, StepOutput]:
     """One protocol step for this replica (call under ``shard_map`` over the
     ``replica`` mesh axis, or under ``vmap(axis_name=...)`` for single-chip
@@ -365,6 +366,14 @@ def replica_step(
     end-to-end check — so bit corruption of replicated state is silent
     without it. ``audit=False`` (the default) is byte-identical to the
     pre-audit program.
+
+    ``group_batch_axis`` is given by the group mappings alone
+    (:func:`vmap_groups`): the name of the ``vmap`` axis that batches
+    independent groups into one program. The config rescan's predicate
+    is reduced over it too, so its ``lax.cond`` stays a conditional
+    there (see the cost note at the rescan). ``None`` (every
+    single-group mapping) traces the program without it, equation for
+    equation.
     """
     assert fanout in ("gather", "psum"), fanout
     i32 = jnp.int32
@@ -664,10 +673,17 @@ def replica_step(
     # kept)``: bit for bit the per-replica rule. Unheard peers' flags
     # count too: the flag decides only whether the expensive branch
     # runs, never what a replica adopts. On a mesh a rare rescan runs on
-    # every chip at once, which is harmless. Under the group engines'
-    # second, UNNAMED ``vmap`` (:func:`group_step`) the predicate is
-    # batched over groups again and the select comes back: no worse than
-    # before, and no benchmark cell runs them. ``StepOutput.
+    # every chip at once, which is harmless. The group engines batch
+    # this step over groups with a second ``vmap`` (:func:`group_step`,
+    # the ``build_spmd_group_*`` builders), under which a per-group
+    # predicate is batched again and the select came back (3.5 ms of a
+    # 12.9 ms dispatch at 3 groups x 3 replicas, PERF.md section 6, PR
+    # 44). That ``vmap`` is NAMED (:func:`vmap_groups`) and the
+    # predicate is reduced over it as well, by one ``pmax`` of one
+    # scalar, to "ANY replica of ANY group batched here": unbatched in
+    # every mapping. The same argument holds across groups: a group
+    # whose caches are all valid keeps them, bit for bit, through a
+    # step in which another group rescanned. ``StepOutput.
     # cfg_rescanned`` says whether the branch ran. CONFIG entries take
     # effect from append/absorb time (poll_config_entries,
     # dare_server.c:2133-2187). Runs BEFORE the commit scan (joint
@@ -700,6 +716,9 @@ def replica_step(
 
     with jax.named_scope("cfg_rescan"):
         any_invalid = jnp.any(g_acks[:, 2] > 0)
+        if group_batch_axis is not None:
+            any_invalid = lax.pmax(any_invalid.astype(i32),
+                                   group_batch_axis) > 0
 
         def _cfg_keep(_):
             return (state.cfg_src, state.cfg_src_term, state.bitmask_old,
@@ -1027,6 +1046,22 @@ def replica_step(
     return new_state, out
 
 
+# the LOCAL batch axis of the ``vmap`` that puts independent groups
+# into one program. Not the mesh's ``GROUP_AXIS`` (``parallel/mesh.py``):
+# on a 2-D mesh a reduction over this name stays on the chip.
+GROUP_BATCH_AXIS = "group_batch"
+
+
+def vmap_groups(fn):
+    """``fn(state, inp)`` batched over a leading axis of independent
+    groups, the axis named :data:`GROUP_BATCH_AXIS`: the one place the
+    group mappings (:func:`group_step`, the ``build_spmd_group_*``
+    builders of ``parallel/mesh.py``) take their outer ``vmap`` from.
+    The :func:`replica_step` inside is given the same name as
+    ``group_batch_axis``."""
+    return jax.vmap(fn, in_axes=(0, 0), axis_name=GROUP_BATCH_AXIS)
+
+
 def group_step(
     *,
     cfg: LogConfig,
@@ -1044,14 +1079,22 @@ def group_step(
     advanced by ONE program.
 
     :func:`replica_step` is documented as vmappable over the replica
-    axis; sharding the keyspace across G groups adds a second,
-    *unnamed* leading ``group`` batch axis. Groups are fully
-    independent state machines — no collective may ever cross the
-    group axis — so the outer ``vmap`` carries no axis name and XLA
-    simply widens every tensor op and every replica-axis collective by
-    a factor of G. G groups therefore replicate in ONE compiled
-    dispatch instead of G (the sharded-throughput win
-    ``benchmarks/shard_bench.py`` measures).
+    axis; sharding the keyspace across G groups adds a second leading
+    ``group`` batch axis (:func:`vmap_groups`). Groups are fully
+    independent state machines: no protocol state may ever cross the
+    group axis, and XLA simply widens every tensor op and every
+    replica-axis collective by a factor of G. G groups therefore
+    replicate in ONE compiled dispatch instead of G (the
+    sharded-throughput win ``benchmarks/shard_bench.py`` measures).
+
+    The one exception, and why the axis has a name: the scalar that
+    GATES the full-ring config rescan is reduced over the groups too
+    ("some replica of some group here must rescan"), because only an
+    unbatched predicate keeps ``lax.cond`` a conditional. It decides
+    whether work runs, carries no protocol state, and inside the
+    branch every replica still adopts on its own flag: a group whose
+    config caches are valid leaves the step bit for bit as if it had
+    run alone (``tests/test_membership_rescan.py``).
 
     Takes/returns pytrees with leading axes ``[group, replica, ...]``.
 
@@ -1071,9 +1114,10 @@ def group_step(
         replica_step, cfg=cfg, n_replicas=n_replicas,
         axis_name=axis_name, use_pallas=use_pallas,
         interpret=interpret, fanout=fanout, elections=elections,
-        audit=audit, telemetry=telemetry, txn=txn)
+        audit=audit, telemetry=telemetry, txn=txn,
+        group_batch_axis=GROUP_BATCH_AXIS)
     vstep = jax.vmap(core, in_axes=(0, 0), axis_name=axis_name)
-    return jax.vmap(vstep, in_axes=(0, 0))
+    return vmap_groups(vstep)
 
 
 # ---------------------------------------------------------------------------

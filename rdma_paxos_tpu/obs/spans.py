@@ -71,9 +71,11 @@ inside the optional fencing path):
   ==================== ==============================================
 
   Counters: ``readback_arrays_total`` (device-to-host reads in
-  ``quorum_wait``), ``cfg_rescans_total`` (protocol steps whose
-  full-ring config rescan ran: ``StepOutput.cfg_rescanned`` off the
-  packed row; 0 while no config source is invalidated),
+  ``quorum_wait``), ``cfg_rescans_total`` (program steps whose
+  full-ring config rescan branch ran: ``StepOutput.cfg_rescanned`` off
+  the packed row, which reads alike on every replica of every group of
+  one program, so the sharded engine counts a step once and not once a
+  group; 0 while no config source is invalidated),
   ``replay_applies_total`` (calls into
   ``ReplayEngine.apply``), ``replay_followers_total`` (followers a
   dispatch replayed to: ``replay_send`` + ``replay_drain`` over it is

@@ -26,7 +26,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 from rdma_paxos_tpu.config import LogConfig
 from rdma_paxos_tpu.consensus.state import ReplicaState, make_replica_state
 from rdma_paxos_tpu.consensus.step import (
-    StepInput, replica_step, scan_readback, with_scalars)
+    GROUP_BATCH_AXIS, StepInput, replica_step, scan_readback, vmap_groups,
+    with_scalars)
 
 REPLICA_AXIS = "replica"
 GROUP_AXIS = "group"
@@ -344,8 +345,9 @@ def build_spmd_group_scan(cfg: LogConfig, n_replicas: int, mesh: Mesh,
         replica_step, cfg=cfg, n_replicas=n_replicas,
         axis_name=REPLICA_AXIS, use_pallas=use_pallas,
         interpret=interpret, fanout=fanout, elections=False,
-        audit=audit, telemetry=telemetry)
-    vcore = jax.vmap(core, in_axes=(0, 0))      # local groups, unnamed
+        audit=audit, telemetry=telemetry,
+        group_batch_axis=GROUP_BATCH_AXIS)
+    vcore = vmap_groups(core)                   # the device's own groups
 
     def per_device(state_b, datas_b, metas_b, counts_b, peer_b,
                    applied_b, qdepth_b):
@@ -522,9 +524,10 @@ def build_sim_group_step(cfg: LogConfig, n_replicas: int, *,
                          telemetry: bool = False, txn: bool = False):
     """Compile the G-group × R-replica protocol step as ONE program on
     one device (:func:`rdma_paxos_tpu.consensus.step.group_step` under
-    ``jit``). The group axis is an unnamed batch axis — groups are
-    independent; only the replica axis carries collectives — so one
-    dispatch steps every group (the sharded-cluster hot path)."""
+    ``jit``). The group axis is a batch axis — groups are independent;
+    only the replica axis carries protocol collectives (the group axis
+    is named for one scalar, the rescan's gate: ``group_step``) — so
+    one dispatch steps every group (the sharded-cluster hot path)."""
     from rdma_paxos_tpu.consensus.step import group_step
     gstep = group_step(cfg=cfg, n_replicas=n_replicas,
                        axis_name=REPLICA_AXIS, use_pallas=use_pallas,
@@ -593,19 +596,21 @@ def build_spmd_group_step(cfg: LogConfig, n_replicas: int, mesh: Mesh,
     ``P(group, replica)`` — each device holds ``G / group_shards``
     whole group rows of exactly one replica column. Inside the
     per-device program the replica axis (local size 1) is squeezed and
-    the local group rows ride an *unnamed* ``vmap``, so every
-    collective in :func:`replica_step` binds the ``replica`` MESH axis
-    only: quorum traffic crosses the R chips of one replica ring,
-    never the group axis. The compiled program is polymorphic in the
+    the local group rows ride a ``vmap`` whose axis name is LOCAL
+    (``vmap_groups``: the rescan's gate is reduced over a device's own
+    groups, on the chip), so every collective in :func:`replica_step`
+    that leaves the chip binds the ``replica`` MESH axis only: quorum
+    traffic crosses the R chips of one replica ring, never the
+    ``group`` mesh axis. The compiled program is polymorphic in the
     local group count, so the cache key carries the mesh — not G
     (``tests/test_mesh.py`` pins the single-compile property)."""
     core = functools.partial(
         replica_step, cfg=cfg, n_replicas=n_replicas,
         axis_name=REPLICA_AXIS, use_pallas=use_pallas,
         interpret=interpret, fanout=fanout, elections=elections,
-        audit=audit,
-        telemetry=telemetry, txn=txn)
-    vcore = jax.vmap(core, in_axes=(0, 0))      # local groups, unnamed
+        audit=audit, telemetry=telemetry, txn=txn,
+        group_batch_axis=GROUP_BATCH_AXIS)
+    vcore = vmap_groups(core)                   # the device's own groups
 
     def per_device(state_b, inp_b):
         st, out = vcore(jax.tree.map(lambda x: x[:, 0], state_b),
@@ -644,9 +649,9 @@ def build_spmd_group_burst(cfg: LogConfig, n_replicas: int, mesh: Mesh,
         replica_step, cfg=cfg, n_replicas=n_replicas,
         axis_name=REPLICA_AXIS, use_pallas=use_pallas,
         interpret=interpret, fanout=fanout, elections=False,
-        audit=audit,
-        telemetry=telemetry)
-    vcore = jax.vmap(core, in_axes=(0, 0))      # local groups, unnamed
+        audit=audit, telemetry=telemetry,
+        group_batch_axis=GROUP_BATCH_AXIS)
+    vcore = vmap_groups(core)                   # the device's own groups
 
     def per_device(state_b, datas_b, metas_b, counts_b, peer_b,
                    applied_b, qdepth_b):

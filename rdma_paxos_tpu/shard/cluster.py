@@ -5,7 +5,7 @@ ONE compiled dispatch per step.
 partitions the keyspace across many. This engine stacks G independent
 ``(Log, HardState, peer_mask, timers)`` pytrees along a leading
 ``group`` axis and steps ALL of them with the group-batched protocol
-step (:func:`rdma_paxos_tpu.consensus.step.group_step` — an unnamed
+step (:func:`rdma_paxos_tpu.consensus.step.group_step` — a second
 ``vmap`` over groups around the named replica-axis ``vmap``), the way
 SmartNIC replication stacks multiplex many replicated partitions onto
 one device (PAPERS.md, arXiv:2503.18093). Device work per step is one
@@ -15,7 +15,7 @@ frontiers, replay, requeue, rebase, leader tracking) stays per-group.
 Two execution engines behind ONE host-bookkeeping implementation:
 
 * ``mesh=None`` (default) — the single-device engine: the group axis
-  is an unnamed ``vmap`` batch axis, all G×R state on one chip.
+  is a ``vmap`` batch axis, all G×R state on one chip.
 * ``mesh=(group_shards, R)`` (or a prebuilt 2-D ``Mesh``) — the
   MULTI-CHIP engine: state is sharded ``P(group, replica)`` over a
   real ``(group, replica)`` device mesh
@@ -732,10 +732,10 @@ class ShardedCluster:
         if prof is not None:
             prof.stop("readback_rest")
             prof.count("readback_arrays_total", reads)
-            # protocol steps whose full-ring rescan ran, summed over the
-            # groups (the same on every replica of one group)
-            prof.count("cfg_rescans_total",
-                       int(res["cfg_rescanned"].max(axis=-1).sum()))
+            # program steps whose full-ring rescan branch ran: its
+            # predicate is reduced over the groups of one program, so
+            # the column reads alike in all of them (sim.py's meaning)
+            prof.count("cfg_rescans_total", int(res["cfg_rescanned"].max()))
             prof.stop("quorum_wait")
             prof.start("post_readback")
         if self._audit:

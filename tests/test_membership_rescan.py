@@ -7,10 +7,19 @@ drive it with exactly ONE replica invalid while the others keep their
 cache, under ``vmap`` and under ``shard_map`` on CPU devices, and hold
 every step's state and the packed row's ``cfg_rescanned`` column to a
 plain NumPy rendering of the rule: newest CONFIG in ``[head, end)``,
-else the committed checkpoint."""
+else the committed checkpoint.
 
+The group engine batches groups into the same program and reduces the
+branch's predicate over them too (``consensus.step.vmap_groups``), so
+the same scenarios run in ONE group of three while the other two serve
+steady traffic: every group must leave every step as the same group run
+alone through ``SimCluster`` does, leaf for leaf, and the step is
+counted once."""
+
+import functools
 import types
 
+import jax
 import numpy as np
 import pytest
 
@@ -20,7 +29,10 @@ from rdma_paxos_tpu.consensus.log import (
 from rdma_paxos_tpu.consensus.membership import MembershipManager
 from rdma_paxos_tpu.consensus.state import ConfigState, Role
 from rdma_paxos_tpu.consensus.step import SCAN_KEYS
+from rdma_paxos_tpu.obs.metrics import MetricsRegistry
+from rdma_paxos_tpu.obs.spans import StepPhaseProfiler
 from rdma_paxos_tpu.runtime.sim import SimCluster, StepTicket, read_scalars
+from rdma_paxos_tpu.shard.cluster import ShardedCluster
 
 CFG = LogConfig(n_slots=64, slot_bytes=32, window_slots=16, batch_slots=8)
 SW = CFG.slot_words
@@ -29,9 +41,8 @@ VIEW = ("cfg_src", "cfg_src_term", "bitmask_old", "bitmask_new",
         "ccfg_epoch", "head", "end")
 
 
-def _host(c):
-    """The device state's config cache, offsets and ring, on the host."""
-    st = c.state
+def _host(st):
+    """A device state's config cache, offsets and ring, on the host."""
     snap = {k: np.asarray(getattr(st, k)).astype(np.int64) for k in VIEW}
     snap["buf"] = np.asarray(st.log.buf)
     return snap
@@ -67,6 +78,21 @@ def _derived(pre, post, r):
             row[2], row[3])
 
 
+def _held_to_rule(pre, post, R, at):
+    """One group's step held to the NumPy rule; returns which replicas'
+    cached source went in it."""
+    gone = [_source_gone(pre, post, r) for r in range(R)]
+    for r in range(R):
+        # a replica that holds no CONFIG and never had one keeps its
+        # genesis view: only the rule's first clause has a say there
+        want = _derived(pre, post, r)
+        if want[0] < 0 and not gone[r]:
+            continue
+        got = tuple(post[k][r] for k in VIEW[:6])
+        assert got == tuple(int(x) for x in want), (at, r, got, want)
+    return gone
+
+
 class Checked:
     """``c.step`` with every step held to the NumPy rule."""
 
@@ -76,19 +102,9 @@ class Checked:
 
     def __call__(self, *a, **kw):
         c = self.c
-        pre = _host(c)
+        pre = _host(c.state)
         res = self.step(*a, **kw)
-        post = _host(c)
-        gone = [_source_gone(pre, post, r) for r in range(c.R)]
-        for r in range(c.R):
-            # a replica that holds no CONFIG and never had one keeps its
-            # genesis view: only the rule's first clause has a say there
-            want = _derived(pre, post, r)
-            if want[0] < 0 and not gone[r]:
-                continue
-            got = tuple(post[k][r] for k in VIEW[:6])
-            assert got == tuple(int(x) for x in want), (
-                len(self.steps), r, got, want)
+        gone = _held_to_rule(pre, _host(c.state), c.R, len(self.steps))
         # the row's column: the branch ran iff some replica's source
         # went, and says so on every replica alike
         flag = res["cfg_rescanned"]
@@ -98,10 +114,93 @@ class Checked:
         return res
 
 
-def _backoff(mode):
+class OneGroupOfThree:
+    """Group :attr:`G0` of a three-group ``ShardedCluster`` behind
+    ``SimCluster``'s surface, as far as the scenarios use it. Every
+    ``step`` steps the whole engine once, with one entry submitted to
+    the leader of each other group, and each group's twin, a
+    ``SimCluster`` given the same events, once; then holds every group
+    of the engine to its twin and to the NumPy rule."""
+
+    G, G0 = 3, 1
+
+    def __init__(self, R, **kw):
+        self.R = R
+        self.sh = ShardedCluster(CFG, R, self.G, **kw)
+        self.alone = [SimCluster(CFG, R, **kw) for _ in range(self.G)]
+        self.metrics = MetricsRegistry()
+        self.sh.profiler = StepPhaseProfiler(metrics=self.metrics)
+        self.ran = 0        # engine steps whose rescan branch ran
+        self.n = 0
+        for g in self.others:
+            self.step(_group=g, timeouts=[g])
+            assert self.sh.leader(g) == g
+
+    @property
+    def others(self):
+        return [g for g in range(self.G) if g != self.G0]
+
+    @property
+    def state(self):
+        return jax.tree.map(lambda x: x[self.G0], self.sh.state)
+
+    def submit(self, r, payload, etype=EntryType.SEND):
+        self.sh.submit(self.G0, r, payload, etype)
+        self.alone[self.G0].submit(r, payload, etype)
+
+    def partition(self, groups):
+        self.sh.partition(self.G0, groups)
+        self.alone[self.G0].partition(groups)
+
+    def heal(self):
+        self.sh.heal(self.G0)
+        self.alone[self.G0].heal()
+
+    def run_until_elected(self, r):
+        res = self.step(timeouts=[r])
+        assert res["role"][r] == int(Role.LEADER)
+
+    def step(self, timeouts=(), _group=None):
+        sh, at = self.sh, self.n
+        tg = self.G0 if _group is None else _group
+        for g in self.others:
+            if sh.last is not None and sh.leader_hint(g) >= 0:
+                sh.submit(g, sh.leader_hint(g), b"steady%d-%d" % (g, at))
+                self.alone[g].submit(sh.leader_hint(g),
+                                     b"steady%d-%d" % (g, at))
+        pre = [_host(jax.tree.map(lambda x: x[g], sh.state))
+               for g in range(self.G)]
+        res = sh.step(timeouts={tg: list(timeouts)} if timeouts else ())
+        twins = [c.step(timeouts=timeouts if g == tg else ())
+                 for g, c in enumerate(self.alone)]
+        ran = []
+        for g, (c, twin) in enumerate(zip(self.alone, twins)):
+            mine = jax.tree.map(lambda x: x[g], sh.state)
+            for a, b in zip(jax.tree.leaves(mine),
+                            jax.tree.leaves(c.state)):
+                assert np.array_equal(np.asarray(a), np.asarray(b)), (
+                    at, g, "differs from the group run alone")
+            for k in twin.keys() - {"cfg_rescanned"}:
+                assert np.array_equal(twin[k], res[k][g]), (at, g, k)
+            gone = _held_to_rule(pre[g], _host(mine), self.R, at)
+            assert twin["cfg_rescanned"].tolist() == (
+                [int(any(gone))] * self.R)
+            ran.append(any(gone))
+        # only G0 ever rescans; the engine's column says that the
+        # branch ran in EVERY group, and the counter counts one step
+        assert not any(ran[g] for g in self.others), (at, ran)
+        assert res["cfg_rescanned"].tolist() == (
+            [[int(any(ran))] * self.R] * self.G), (at, ran)
+        self.ran += any(ran)
+        assert self.metrics.get("cfg_rescans_total") == self.ran
+        self.n += 1
+        return {k: v[self.G0] for k, v in res.items()}
+
+
+def _backoff(make):
     """Replica 0's uncommitted CONFIG is truncated by divergence
     backoff; 1, 2 and 3 keep the committed CONFIG they cached."""
-    c = SimCluster(CFG, 5, group_size=3, mode=mode)
+    c = make(5, group_size=3)
     mm = MembershipManager(c)
     c.run_until_elected(0)
     mm.change(0, 0b1111)            # a CONFIG every member caches
@@ -123,11 +222,11 @@ def _backoff(mode):
     return ck
 
 
-def _overwritten(mode):
+def _overwritten(make):
     """Replica 0's CONFIG is overwritten INSIDE an absorbed window by
     the new leader's CONFIG of a newer term at the same index (a
     laggard floors the window below it, so nothing backs off first)."""
-    c = SimCluster(CFG, 5, mode=mode)
+    c = make(5)
     mm = MembershipManager(c)
     c.run_until_elected(0)
     c.step()
@@ -154,12 +253,45 @@ def _overwritten(mode):
 @pytest.mark.parametrize("mode", ["sim", "spmd"])
 @pytest.mark.parametrize("scenario", [_backoff, _overwritten])
 def test_rescan_with_one_replica_invalid(scenario, mode):
-    ck = scenario(mode)
+    ck = scenario(functools.partial(SimCluster, CFG, mode=mode))
     ran = [gone for gone in ck.steps if any(gone)]
     assert ran, "the scenario never invalidated a config source"
     # exactly replica 0, every time: the others kept their cache through
     # a step in which the branch ran
     assert all(gone == [True] + [False] * 4 for gone in ran), ran
+
+
+@pytest.mark.parametrize("scenario", [_backoff, _overwritten])
+def test_rescan_in_one_group_of_three(scenario):
+    """One replica of ONE group invalid: the branch runs for the whole
+    program, is counted once, and the other two groups come out of
+    that step as they do alone (``OneGroupOfThree.step`` holds every
+    step to that)."""
+    ck = scenario(OneGroupOfThree)
+    ran = [gone for gone in ck.steps if any(gone)]
+    assert ran and all(gone == [True] + [False] * 4 for gone in ran), ran
+    assert ck.c.metrics.get("cfg_rescans_total") == len(ran)
+    for g in ck.c.others:           # they served traffic throughout
+        assert ck.c.sh.last["commit"][g].max() >= ck.c.n - 4
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_flag_is_zero_over_steady_traffic_in_every_group(fused):
+    G, R = 3, 3
+    sh = ShardedCluster(CFG, R, G)
+    sh.profiler = StepPhaseProfiler(metrics=MetricsRegistry())
+    sh.place_leaders()
+    for i in range(3):
+        for g in range(G):
+            for j in range(12):
+                sh.submit(g, sh.leader(g), b"r%d-%03d" % (i, j))
+        ticket = sh.begin_burst() if fused else sh.begin_step()
+        assert (ticket.kind, ticket.K > 1) == (
+            ("burst", True) if fused else ("step", False))
+        for res in (sh.finish(ticket), sh.step()):
+            assert res["cfg_rescanned"].tolist() == [[0] * R] * G
+    assert sh.last["commit"].min() > 12
+    assert sh.profiler.metrics.get("cfg_rescans_total") == 0
 
 
 @pytest.mark.parametrize("fused", [False, True])

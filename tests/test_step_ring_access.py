@@ -4,11 +4,12 @@ In a steady step nothing reads or writes more than O(window_slots)
 rows of a replica's ring: every read goes rows first
 (``consensus.log.rows_at``), and the one full-ring pass, the config
 rescan, sits in a branch of a REAL ``cond`` in every mapping (under
-``vmap`` too: its predicate is reduced over the replica axis, so it is
-unbatched). Checked on the jaxpr of each builder the benchmark's cells
-and the other engines use, so no chip is needed: outside a ``cond``
-branch the ring may only be gathered from, scattered into or reshaped
-whole.
+``vmap`` too: its predicate is reduced over the replica axis, and under
+the group engines' second ``vmap`` over the group batch axis as well,
+so it is unbatched). Checked on the jaxpr of each builder the
+benchmark's cells and the other engines use, so no chip is needed:
+outside a ``cond`` branch the ring may only be gathered from, scattered
+into or reshaped whole.
 """
 
 import math
@@ -19,7 +20,7 @@ import pytest
 
 from rdma_paxos_tpu.config import LogConfig
 from rdma_paxos_tpu.consensus.log import META_W
-from rdma_paxos_tpu.consensus.step import make_step_input
+from rdma_paxos_tpu.consensus.step import GROUP_BATCH_AXIS, make_step_input
 from rdma_paxos_tpu.parallel import mesh as pm
 
 # n_slots appears in no other dimension of any program below
@@ -64,12 +65,15 @@ def _walk(jaxpr, in_cond, seen):
             _walk(s, in_cond or e.primitive.name == "cond", seen)
 
 
-def _conds(jaxpr, found):
+def _eqns(jaxpr, name):
+    """Every equation of primitive ``name``, nested ones included."""
+    found = []
     for e in jaxpr.eqns:
-        if e.primitive.name == "cond":
+        if e.primitive.name == name:
             found.append(e)
         for s in _subjaxprs(e):
-            _conds(s, found)
+            found += _eqns(s, name)
+    return found
 
 
 def _state(R):
@@ -93,8 +97,48 @@ def _burst_args(R):
             sds((R,), i32))
 
 
+G = 3   # groups a device holds in the group mappings
+
+
+def _grouped(args, n_groups):
+    """A single-group builder's abstract arguments with the group axis
+    put before the replica axis of every leaf."""
+    def lead(x, at):
+        return jax.ShapeDtypeStruct(
+            x.shape[:at] + (n_groups,) + x.shape[at:], x.dtype)
+    if len(args) == 2:          # (state, StepInput)
+        return jax.tree.map(lambda x: lead(x, 0), args)
+    # state, then the K-stacked inputs, then the per-replica ones
+    return (jax.tree.map(lambda x: lead(x, 0), args[0]),
+            *(lead(x, 1) for x in args[1:4]),
+            *(lead(x, 0) for x in args[4:]))
+
+
+def _group_program(kind, R):
+    """The six group mappings: ``G`` groups a device, and for the
+    ``spmd_group_*`` ones as many device rows of a ``(group, replica)``
+    CPU mesh as the devices allow."""
+    step = kind.endswith("_step")
+    args = _step_args(R) if step else _burst_args(R)
+    kw = dict(fanout="psum")
+    if kind.endswith("_scan"):
+        kw["replay_slots"] = 32
+    if kind.startswith("sim_"):
+        return getattr(pm, "build_" + kind)(CFG, R, **kw), _grouped(args, G)
+    shards = min(2, len(jax.devices()) // R)
+    if shards < 1:
+        pytest.skip(f"a (group, replica) mesh needs {R} devices")
+    if step:
+        kw["elections"] = False
+    mesh = pm.build_mesh_2d(shards, R)
+    return (getattr(pm, "build_" + kind)(CFG, R, mesh, **kw),
+            _grouped(args, G * shards))
+
+
 def _program(kind, R):
     """``(jitted program, abstract arguments)`` of one builder."""
+    if "_group_" in kind:
+        return _group_program(kind, R)
     if kind == "sim_step":
         return pm.build_sim_step(CFG, R, fanout="psum"), _step_args(R)
     if kind == "sim_stable_step":
@@ -114,7 +158,9 @@ def _program(kind, R):
 
 
 KINDS = ("sim_step", "sim_stable_step", "sim_burst", "sim_scan",
-         "spmd_step", "spmd_burst")
+         "spmd_step", "spmd_burst",
+         "sim_group_step", "sim_group_burst", "sim_group_scan",
+         "spmd_group_step", "spmd_group_burst", "spmd_group_scan")
 
 
 @pytest.mark.parametrize("R", [3, 7])
@@ -137,8 +183,7 @@ def test_steady_program_reads_rows_and_rescans_under_a_cond(kind, R):
             "outside a cond branch: read rows first (log.rows_at)")
 
     # the conditional is real in this mapping, and the rescan is in it
-    conds = []
-    _conds(jaxpr, conds)
+    conds = _eqns(jaxpr, "cond")
     rescans = []
     for e in conds:
         for br in e.params["branches"]:
@@ -152,3 +197,29 @@ def test_steady_program_reads_rows_and_rescans_under_a_cond(kind, R):
         "select_n that runs every step")
     # and nothing else in the program reduces over every slot
     assert not [p for c, p, _i, _o in seen if not c and p in RESCAN_OPS]
+
+
+@pytest.mark.parametrize("R", [3, 7])
+@pytest.mark.parametrize("kind", KINDS)
+def test_only_group_mappings_reduce_over_the_group_batch(kind, R):
+    """The predicate's reduction over groups is the one ``pmax`` of any
+    program: the single-group builders trace none and name the group
+    batch axis nowhere, the group mappings trace exactly one, of one
+    scalar a group, over the ``vmap``'s own axis alone: on a (group,
+    replica) mesh nothing crosses chips for it."""
+    fn, args = _program(kind, R)
+    closed = jax.make_jaxpr(fn)(*args)
+    text = str(closed)
+    # vmap has resolved its own name to a position, so it is in no
+    # program's text: not as a mesh axis, not as a collective's
+    assert GROUP_BATCH_AXIS not in text
+    pmaxes = _eqns(closed.jaxpr, "pmax")
+    if "_group_" not in kind:
+        assert not pmaxes and "pmax" not in text
+        return
+    assert len(pmaxes) == 1, pmaxes
+    (e,) = pmaxes
+    assert all(isinstance(a, int) for a in e.params["axes"]), e.params
+    (v,) = e.invars
+    # a device's own G groups, whatever the mesh's group axis holds
+    assert v.aval.shape == (G,) and v.aval.dtype == jnp.int32
