@@ -32,6 +32,9 @@ Function-level exemptions:
 - functions carrying ``# holds-lock: <lockname>`` on or above the
   ``def`` line (documented caller-holds contract without the suffix).
 
+A ``with`` holds the lock it names, or the lock handed to the call it
+makes (``with held(prof, self._host_lock, phase):``).
+
 Anything else is a finding; intentional lock-free accesses that are
 genuinely safe get a one-line justification in ``baseline.toml``.
 """
@@ -160,13 +163,18 @@ def _holds_locks(mod, func) -> set:
 
 def _with_held(mod, node: ast.AST, lock: str, func) -> bool:
     """Is ``node`` lexically inside a ``with`` whose items include an
-    expression ending in ``.{lock}`` (any receiver), within ``func``?"""
+    expression ending in ``.{lock}`` (any receiver), or a call handed
+    one (``held(prof, self._host_lock, phase)``, the profiled take of
+    ``obs/spans.py``), within ``func``?"""
     for anc in mod.ancestors(node):
         if isinstance(anc, ast.With):
             for item in anc.items:
-                chain = attr_chain(item.context_expr)
-                if chain and chain.split(".")[-1] == lock:
-                    return True
+                expr = item.context_expr
+                for e in (expr.args if isinstance(expr, ast.Call)
+                          else [expr]):
+                    chain = attr_chain(e)
+                    if chain and chain.split(".")[-1] == lock:
+                        return True
         if anc is func:
             break
     return False

@@ -49,16 +49,23 @@ inside the optional fencing path):
     ``idle_wait``      parked on ``_wake`` (idle park, ``period`` wait)
     ``admin_pump``     ``_drain_admin`` + ``_pump_submitq``
     ``host_encode``    batch pack (holds ``input_transfer`` on the
-                       single-step path)
-    ``device_dispatch`` program enqueue (holds ``input_transfer`` on the
-                       burst path)
+                       single-step path, and ``dispatch_lock_wait``:
+                       its take of the host lock, when contended)
+    ``device_dispatch`` program enqueue: ``input_transfer`` (burst
+                       path), ``dispatch_lock_wait`` (its own take of
+                       the host lock, when contended), ``program_call``
+                       (the call into the compiled program, alone,
+                       inside the lock)
     ``device_sync``    ``fence=True`` only
     ``quorum_wait``    block on the step's ONE packed read, then
                        ``readback_rest``: the reads compiled only on
                        request (none in the default programs)
     ``post_readback``  audit ingest, telemetry, stamps, requeue
-    ``apply``          ``replay_fetch`` (fetch bind + both host reads),
-                       ``replay_decode`` (``decode_window``)
+    ``apply``          ``replay_fetch`` [``fetch_lock_wait`` (the take
+                       that binds the fetch, when contended),
+                       ``fetch_enqueue`` (the fetch program's enqueue,
+                       under the lock), ``fetch_read`` (both host
+                       reads)], ``replay_decode`` (``decode_window``)
     ``finish_tail``    flight record, rebase, spans, leases, reads
     ``post_step_rules`` ``_post_step`` but for the two below
     ``apply_replay_ack`` ``store_append``, ``replay_send``,
@@ -68,7 +75,14 @@ inside the optional fencing path):
     ``unattributed``   the rest of the cycle
   ``intake_to_ack``    per operation: ``PendingEvent.t0`` -> ack release
   ``intake_queue_wait`` per operation: ``t0`` -> the pump that dispatches
+  ``replay_answer_wait`` per answer ``ReplayEngine._settle`` blocked for:
+                       the follower app's turn, of ``replay_send``
+                       (credited once a dispatch, no ring entry)
   ==================== ==============================================
+
+  The two lock waits are recorded by :class:`held` only when the take
+  found the lock taken (``count`` = contended takes): the other loop
+  thread, or intake, was inside it.
 
   Counters: ``readback_arrays_total`` (device-to-host reads in
   ``quorum_wait``), ``cfg_rescans_total`` (program steps whose
@@ -619,6 +633,13 @@ PHASE_IDLE_WAIT = "idle_wait"            # parked on _wake
 PHASE_PIPELINE_WAIT = "pipeline_wait"    # dispatch thread waits for the
                                          # readback thread (drain / depth)
 PHASE_INPUT_TRANSFER = "input_transfer"  # jnp.asarray of step inputs
+PHASE_PROGRAM_CALL = "program_call"      # fn(state, *args) alone, in the lock
+# a CONTENDED take of the engine's host lock (``held``): an uncontended
+# one reads no clock and leaves no sample
+PHASE_DISPATCH_LOCK_WAIT = "dispatch_lock_wait"  # begin_step / begin_burst
+PHASE_FETCH_LOCK_WAIT = "fetch_lock_wait"  # the take that binds the fetch
+PHASE_FETCH_ENQUEUE = "fetch_enqueue"    # _fetch_all(...) under the lock
+PHASE_FETCH_READ = "fetch_read"          # both np.asarray of the fetch
 PHASE_READBACK_REST = "readback_rest"    # reads after the packed one
 PHASE_POST_READBACK = "post_readback"    # finish: quorum_wait -> apply
 PHASE_REPLAY_FETCH = "replay_fetch"      # fetch bind + both host reads
@@ -632,6 +653,8 @@ PHASE_OBSERVE = "observe"                # _observe_step + cadences
 # per operation, credited (no start/stop, no ring entry)
 OP_INTAKE_TO_ACK = "intake_to_ack"       # PendingEvent.t0 -> release
 OP_INTAKE_QUEUE_WAIT = "intake_queue_wait"  # PendingEvent.t0 -> pump
+# per answer a ReplayEngine blocked for, credited once a dispatch
+OP_REPLAY_ANSWER_WAIT = "replay_answer_wait"  # _settle's blocking recv
 # a membership change and a replica's recovery: rare, recorded only when
 # they run. The first two nest where they run (admin_pump, or
 # post_step_rules for the auto-recovery); the last two span many cycles
@@ -667,7 +690,12 @@ class StepPhaseProfiler:
     + ``profiler`` + ``unattributed`` = ``cycle``; it never enters the
     event ring (a span over everything would name every device-idle
     gap), nor does ``pipeline_wait``, which spans the other thread's
-    whole cycle. A phase instance
+    whole cycle. Two deep go ``device_dispatch`` > ``program_call`` and
+    ``apply`` > ``replay_fetch`` > ``fetch_enqueue`` / ``fetch_read``;
+    a take of the engine's host lock that had to wait (:meth:`acquire`,
+    through :class:`held`) is ``dispatch_lock_wait`` or
+    ``fetch_lock_wait`` inside the phase that took it, a wait by design
+    like ``pipeline_wait`` but in the ring. A phase instance
     that runs (waits apart) longer than :attr:`STALL_US` leaves one
     ``phase_stall`` trace event (innermost phase only) and adds to ``phase_stalls_total`` /
     ``phase_stall_us_total{phase}``.
@@ -681,11 +709,15 @@ class StepPhaseProfiler:
     DETAIL = (PHASE_CYCLE, PHASE_UNATTRIBUTED, PHASE_PROFILER,
               PHASE_ADMIN_PUMP, PHASE_DISPATCH_GATE, PHASE_IDLE_WAIT,
               PHASE_PIPELINE_WAIT, PHASE_INPUT_TRANSFER,
+              PHASE_PROGRAM_CALL, PHASE_DISPATCH_LOCK_WAIT,
               PHASE_READBACK_REST, PHASE_POST_READBACK,
-              PHASE_REPLAY_FETCH, PHASE_REPLAY_DECODE, PHASE_FINISH_TAIL,
+              PHASE_REPLAY_FETCH, PHASE_FETCH_LOCK_WAIT,
+              PHASE_FETCH_ENQUEUE, PHASE_FETCH_READ,
+              PHASE_REPLAY_DECODE, PHASE_FINISH_TAIL,
               PHASE_STORE_APPEND, PHASE_REPLAY_SEND, PHASE_REPLAY_DRAIN,
               PHASE_POST_STEP_RULES, PHASE_OBSERVE, OP_INTAKE_TO_ACK,
-              OP_INTAKE_QUEUE_WAIT, PHASE_CHECKPOINT, PHASE_RECOVER,
+              OP_INTAKE_QUEUE_WAIT, OP_REPLAY_ANSWER_WAIT,
+              PHASE_CHECKPOINT, PHASE_RECOVER,
               SPAN_CONFIG_CHANGE, SPAN_APP_REBUILD)
     COUNTERS = ("readback_arrays_total", "cfg_rescans_total",
                 "replay_applies_total",
@@ -695,7 +727,8 @@ class StepPhaseProfiler:
                 "replay_reconnects_total")
     # a thread waiting by design: its length counts towards no stall,
     # its own or of the phase it waits in
-    WAITS = (PHASE_IDLE_WAIT, PHASE_PIPELINE_WAIT)
+    WAITS = (PHASE_IDLE_WAIT, PHASE_PIPELINE_WAIT,
+             PHASE_DISPATCH_LOCK_WAIT, PHASE_FETCH_LOCK_WAIT)
     # spans over (nearly) everything another thread does: in the event
     # ring they would name every device-idle gap
     NOT_IN_RING = (PHASE_CYCLE, PHASE_PIPELINE_WAIT)
@@ -798,6 +831,17 @@ class StepPhaseProfiler:
             stack[0][4] += span
             stack[0][5] += span - ns
 
+    def acquire(self, lock, phase: str) -> None:
+        """Take ``lock``; only a take that finds it taken is a sample
+        of ``phase`` (one of :attr:`WAITS`), so an uncontended take
+        reads no clock and ``acc[phase]``'s count is the number of
+        contended ones."""
+        if lock.acquire(False):
+            return
+        self.start(phase)
+        lock.acquire()
+        self.stop(phase)
+
     def credit(self, name: str, total_us: float, n: int = 1) -> None:
         """Add ``n`` samples totalling ``total_us`` to ``acc[name]``
         with no clock read and no ring entry: how a per-operation sum
@@ -851,6 +895,29 @@ class StepPhaseProfiler:
             f"{phase}: n={s['n']} mean={s['total_us'] / s['n']:.1f}us "
             f"max={s['max_us']:.1f}us"
             for phase, s in self.sums().items())
+
+
+class held:
+    """``with held(prof, lock, phase):`` is ``with lock:`` whose take,
+    when it has to wait, is recorded as ``phase``
+    (:meth:`StepPhaseProfiler.acquire`); ``prof`` may be None. The one
+    way the engines take their host lock on the loop threads' paths;
+    the lock-discipline pass reads the lock off the call's arguments."""
+
+    __slots__ = ("_prof", "_lock", "_phase")
+
+    def __init__(self, prof: Optional[StepPhaseProfiler], lock,
+                 phase: str):
+        self._prof, self._lock, self._phase = prof, lock, phase
+
+    def __enter__(self) -> None:
+        if self._prof is None:
+            self._lock.acquire()
+        else:
+            self._prof.acquire(self._lock, self._phase)
+
+    def __exit__(self, *exc) -> None:
+        self._lock.release()
 
 
 # ---------------------------------------------------------------------------

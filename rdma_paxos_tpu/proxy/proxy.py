@@ -374,6 +374,10 @@ class ReplayEngine:
         self._awaiting: Optional[socket.socket] = None
         self._whole = False         # the last write there ended a request
         self._reply_bytes = 0       # app output read since the last drain
+        # answers blocked for since the last take_answer_waits, and the
+        # time blocked: the follower app's turn
+        self._waits = 0
+        self._wait_ns = 0
         self._sink = bytearray(65536)   # where that output is read to
         self._unanswered = 0        # waits in a row that timed out
         self.order_timeouts = 0
@@ -448,6 +452,7 @@ class ReplayEngine:
         if s is None:
             return
         sink = self._sink
+        t0 = time.perf_counter_ns() if wait else 0
         try:
             n = s.recv_into(sink, 0, 0 if wait else socket.MSG_DONTWAIT)
         except BlockingIOError:
@@ -459,6 +464,13 @@ class ReplayEngine:
         except OSError:
             self._awaiting = None
             return
+        finally:
+            if wait:
+                # the one place an answer is waited for, so the count
+                # holds whoever calls (or wraps) ``apply``; an answer
+                # that never comes was ``ORDER_WAIT_S`` blocked all the same
+                self._waits += 1
+                self._wait_ns += time.perf_counter_ns() - t0
         self._awaiting = None
         self._unanswered = 0
         self._reply_bytes += n
@@ -672,6 +684,14 @@ class ReplayEngine:
         self._settle(wait=False)
         n, self._reply_bytes = self._reply_bytes, 0
         return n
+
+    def take_answer_waits(self) -> Tuple[int, int]:
+        """-> (answers :meth:`_settle` blocked for, nanoseconds blocked)
+        since the last call: what of the replay pass was the app's turn
+        and not this process's."""
+        out = (self._waits, self._wait_ns)
+        self._waits = self._wait_ns = 0
+        return out
 
     def close(self) -> None:
         for s in self.conns.values():

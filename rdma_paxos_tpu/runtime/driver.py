@@ -1565,6 +1565,9 @@ class ClusterDriver:
             (cur, t0), rt.rebuild = rt.rebuild, None
             self._feed_store(rt, cur, len(rt.store))
             rt.app_dirty = False
+            # the rebuild's waits for the fresh app are ``app_rebuild``'s,
+            # not the next dispatch's ``replay_answer_wait``
+            rt.replay.take_answer_waits()
             prof = self._phase_prof
             prof.credit("app_rebuild", (time.perf_counter() - t0) * 1e6)
             prof.count("replay_reconnects_total", len(rt.replay.conns))
@@ -1916,6 +1919,13 @@ class ClusterDriver:
         reply_bytes = sum(engine.drain_responses() for engine, _ in replays)
         prof.stop("replay_drain")
         prof.count("replay_reply_bytes_total", reply_bytes)
+        # of ``replay_send``, the followers' apps' turn: what the engines
+        # blocked for in ``_settle``, credited once and not timed an apply
+        waits = [engine.take_answer_waits() for engine, _ in replays]
+        n_waits = sum(n for n, _ in waits)
+        if n_waits:
+            prof.credit("replay_answer_wait",
+                        sum(ns for _, ns in waits) / 1e3, n_waits)
         prof.stop("apply_replay_ack")
 
     def _replay_lost(self, engine: ReplayEngine, exc: OSError) -> None:

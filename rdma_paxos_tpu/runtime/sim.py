@@ -26,6 +26,7 @@ from rdma_paxos_tpu.consensus.log import (
 from rdma_paxos_tpu.consensus.state import Role
 from rdma_paxos_tpu.consensus.step import (
     SCAN_KEYS, StepInput, fetch_window, unpack_scalars)
+from rdma_paxos_tpu.obs.spans import held
 from rdma_paxos_tpu.parallel.mesh import (
     build_sim_burst, build_sim_scan, build_sim_step, build_spmd_burst,
     build_spmd_scan, build_spmd_step, make_replica_mesh, stack_states)
@@ -708,7 +709,7 @@ class SimCluster:
                 "fanout='gather' to model partitions")
         bufs = self._step_bufs()
         count = np.zeros((R,), np.int32)
-        with self._host_lock:
+        with held(prof, self._host_lock, "dispatch_lock_wait"):
             taken = []
             for r in range(R):
                 take = self.pending[r][:B] if take_batch else []
@@ -753,8 +754,12 @@ class SimCluster:
         if prof is not None:
             prof.stop("host_encode")
             prof.start("device_dispatch")
-        with self._host_lock:
+        with held(prof, self._host_lock, "dispatch_lock_wait"):
+            if prof is not None:
+                prof.start("program_call")
             self.state, out = fn(self.state, inp)
+            if prof is not None:
+                prof.stop("program_call")
             ticket = StepTicket("step", out, taken, timeouts, 1, bufs)
             self._tickets.append(ticket)
             self.inflight_dispatches += 1
@@ -790,7 +795,7 @@ class SimCluster:
                 "psum fan-out requires full connectivity; use "
                 "fanout='gather' to model partitions")
         tiers = self._tiers(max_k)
-        with self._host_lock:
+        with held(prof, self._host_lock, "dispatch_lock_wait"):
             # capacity sizing: never enqueue more than the ring can
             # take without drops, so mid-burst drops (which would
             # reorder a connection's fragments against later steps)
@@ -831,8 +836,12 @@ class SimCluster:
                 jnp.asarray(applied), jnp.asarray(qdepth))
         if prof is not None:
             prof.stop("input_transfer")
-        with self._host_lock:
+        with held(prof, self._host_lock, "dispatch_lock_wait"):
+            if prof is not None:
+                prof.start("program_call")
             self.state, outs = fn(self.state, *args)
+            if prof is not None:
+                prof.stop("program_call")
             ticket = StepTicket("scan" if scan else "burst", outs,
                                 taken, (), K, bufs,
                                 applied0=applied if scan else None)
@@ -1363,12 +1372,19 @@ class SimCluster:
             # M_GIDX integrity check still guards slot recycling. Only
             # the BIND holds the lock; the blocking result read below
             # runs outside it so the dispatch path never stalls.
-            with self._host_lock:
+            with held(prof, self._host_lock, "fetch_lock_wait"):
+                if prof is not None:
+                    prof.start("fetch_enqueue")
                 wd_fut, wm_fut = self._fetch_all(self.state.log, starts)
+                if prof is not None:
+                    prof.stop("fetch_enqueue")
+            if prof is not None:
+                prof.start("fetch_read")
             # wm is read last: a wrapper over _fetch_all (the
             # benchmark's span) ends inside its conversion
             wd_all, wm_all = np.asarray(wd_fut), np.asarray(wm_fut)
             if prof is not None:
+                prof.stop("fetch_read")
                 prof.stop("replay_fetch")
                 prof.start("replay_decode")
             for r in todo:

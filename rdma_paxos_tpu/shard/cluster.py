@@ -63,6 +63,7 @@ from rdma_paxos_tpu.consensus.log import (
 from rdma_paxos_tpu.consensus.state import Role
 from rdma_paxos_tpu.consensus.step import (
     StepInput, fetch_window)
+from rdma_paxos_tpu.obs.spans import held
 from rdma_paxos_tpu.parallel.mesh import (
     GROUP_AXIS, REPLICA_AXIS, build_mesh_2d, build_sim_group_burst,
     build_sim_group_scan, build_sim_group_step, build_spmd_group_burst,
@@ -544,7 +545,7 @@ class ShardedCluster:
         bufs = self._step_bufs()
         count = np.zeros((G, R), np.int32)
         qdepth = np.zeros((G, R), np.int32)
-        with self._host_lock:
+        with held(prof, self._host_lock, "dispatch_lock_wait"):
             taken: List[List[list]] = [[[] for _ in range(R)]
                                        for _ in range(G)]
             for g in range(G):
@@ -600,8 +601,12 @@ class ShardedCluster:
         if prof is not None:
             prof.stop("host_encode")
             prof.start("device_dispatch")
-        with self._host_lock:
+        with held(prof, self._host_lock, "dispatch_lock_wait"):
+            if prof is not None:
+                prof.start("program_call")
             self.state, out = fn(self.state, inp)
+            if prof is not None:
+                prof.stop("program_call")
             ticket = StepTicket("step", out, taken, tmo, 1, bufs)
             self._tickets.append(ticket)
             self.inflight_dispatches += 1
@@ -643,7 +648,7 @@ class ShardedCluster:
         qdepth = np.zeros((G, R), np.int32)
         taken: List[List[list]] = [[[] for _ in range(R)]
                                    for _ in range(G)]
-        with self._host_lock:
+        with held(prof, self._host_lock, "dispatch_lock_wait"):
             reserved = self.reserved_appends()
             last = self.last
             for g in range(G):
@@ -682,8 +687,12 @@ class ShardedCluster:
                 jnp.asarray(applied), jnp.asarray(qdepth))
         if prof is not None:
             prof.stop("input_transfer")
-        with self._host_lock:
+        with held(prof, self._host_lock, "dispatch_lock_wait"):
+            if prof is not None:
+                prof.start("program_call")
             self.state, outs = fn(self.state, *args)
+            if prof is not None:
+                prof.stop("program_call")
             ticket = StepTicket("scan" if scan else "burst", outs,
                                 taken, {}, K, bufs,
                                 applied0=applied if scan else None)
@@ -945,13 +954,20 @@ class ShardedCluster:
                 prof.start("replay_fetch")
             # bind under the host lock (donation hazard — see
             # SimCluster._replay_committed); block on results outside it
-            with self._host_lock:
+            with held(prof, self._host_lock, "fetch_lock_wait"):
+                if prof is not None:
+                    prof.start("fetch_enqueue")
                 wd_fut, wm_fut = self._fetch_all(self.state.log, starts)
+                if prof is not None:
+                    prof.stop("fetch_enqueue")
             self.fetch_dispatches += 1
+            if prof is not None:
+                prof.start("fetch_read")
             # wm is read last: a wrapper over _fetch_all (the
             # benchmark's span) ends inside its conversion
             wd_all, wm_all = np.asarray(wd_fut), np.asarray(wm_fut)
             if prof is not None:
+                prof.stop("fetch_read")
                 prof.stop("replay_fetch")
                 prof.start("replay_decode")
             for g, r in todo:

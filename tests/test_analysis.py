@@ -182,6 +182,23 @@ def test_lock_discipline_flags_unlocked_access(tmp_path):
         "pending" in f.message, f
 
 
+def test_lock_discipline_sees_the_lock_handed_to_a_profiled_take(tmp_path):
+    """``with held(prof, self._host_lock, phase):`` (obs/spans.py: the
+    take whose wait is a phase) holds the lock it is handed; a call
+    handed another lock, or none, does not."""
+    mod = _LOCKMOD_BAD.replace(
+        "        with self._host_lock:\n",
+        "        with held(self.prof, self._host_lock, 'fetch_lock_wait'):\n")
+    assert mod != _LOCKMOD_BAD
+    _write(tmp_path, "rdma_paxos_tpu/runtime/sim.py", mod)
+    fs = _run(tmp_path, "lock-discipline")
+    assert [f.line for f in fs] == [14], fs      # bad() alone, as before
+    _write(tmp_path, "rdma_paxos_tpu/runtime/sim.py", mod.replace(
+        "self._host_lock, 'fetch_lock_wait'", "self._other, 'x'"))
+    assert sorted(f.line for f in _run(tmp_path, "lock-discipline")) \
+        == [11, 14]
+
+
 def test_lock_discipline_honors_writes_mode_and_conflict(tmp_path):
     mod = _LOCKMOD_BAD.replace("# guarded-by: _host_lock",
                                "# guarded-by: _host_lock [writes]")

@@ -173,3 +173,93 @@ def test_an_unfinished_request_is_not_waited_for(late_app):
     assert app.served == [b"SET other 1", b"SET big aaaabbbb"]
     assert eng.order_timeouts == 0
     eng.close()
+
+
+class SlowApp(threading.Thread):
+    """One connection at a time in its own thread: ``+OK`` to every
+    line, ``delay`` s after it was read."""
+
+    def __init__(self, delay):
+        super().__init__(daemon=True)
+        self.delay = delay
+        self.srv = socket.socket()
+        self.srv.bind(("127.0.0.1", 0))
+        self.srv.listen(16)
+        self.port = self.srv.getsockname()[1]
+        self.start()
+
+    def run(self):
+        while True:
+            try:
+                c, _ = self.srv.accept()
+            except OSError:
+                return
+            threading.Thread(target=self.serve, args=(c,),
+                             daemon=True).start()
+
+    def serve(self, c):
+        try:
+            while True:
+                data = c.recv(65536)
+                if not data:
+                    return
+                for _ in range(data.count(b"\n")):
+                    time.sleep(self.delay)
+                    c.sendall(b"+OK\n")
+        except OSError:
+            pass
+
+
+@pytest.fixture()
+def slow_app():
+    app = SlowApp(0.005)
+    yield app
+    app.srv.close()
+
+
+@pytest.mark.parametrize("wrapped", [False, True],
+                         ids=["apply", "apply_wrapped"])
+def test_answer_waits_are_counted_where_they_are_waited_for(slow_app,
+                                                            wrapped):
+    """n answers blocked for are n samples of at least the app's 5 ms
+    each; the fault injection of ``perfbench`` replaces ``apply`` on the
+    instance and the counts, kept in ``_settle``, survive it."""
+    eng = ReplayEngine("127.0.0.1", slow_app.port)
+    if wrapped:
+        real, seen = eng.apply, []
+
+        def faulty(etype, conn_id, payload):
+            seen.append(conn_id)
+            return real(etype, conn_id, payload)
+        eng.apply = faulty
+    assert eng.take_answer_waits() == (0, 0)
+    n = 6
+    for i in range(n + 1):      # the first write has nothing to wait for
+        eng.apply(SEND, 300 + i % 2, b"SET k%d v\n" % i)
+    waits, wait_ns = eng.take_answer_waits()
+    assert waits == n and wait_ns >= n * 0.005 * 0.9e9
+    assert eng.order_timeouts == 0
+    assert eng.take_answer_waits() == (0, 0)    # handed back once
+    if wrapped:
+        assert len(seen) == n + 1
+    eng.close()
+
+
+def test_what_is_not_waited_for_is_not_counted(slow_app):
+    eng = ReplayEngine("127.0.0.1", slow_app.port)
+    # an unfinished request (``_whole`` false): bytes for another
+    # connection go out at once, nothing can answer yet
+    eng.apply(SEND, 401, b"SET big aaaa")
+    eng.apply(SEND, 402, b"SET other ")
+    # the run on one connection, and the drain's look without waiting
+    eng.apply(SEND, 402, b"1\n")
+    eng._settle(wait=False)
+    eng.drain_responses()
+    assert eng.take_answer_waits() == (0, 0)
+    deadline = time.time() + 5
+    while eng._awaiting is not None and time.time() < deadline:
+        time.sleep(0.01)
+        eng._settle(wait=False)         # read once there, never waited for
+    assert eng._awaiting is None
+    assert eng.take_answer_waits() == (0, 0)
+    eng.close()

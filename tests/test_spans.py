@@ -1107,3 +1107,194 @@ def test_lowered_program_carries_each_scope(lowered_texts, scope):
     text = lowered_texts["fetch" if scope == "replay_fetch" else "step"]
     # the path a device trace shows: jit(replica_step)/vmap(append)/...
     assert re.search(r"[/(]%s[/)]" % scope, text), scope
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 45: where the two loop threads wait for each other. The host
+# lock's contended takes, the program call and the replay fetch's parts
+# are phases; a follower app's answer time is a credited sum
+# ---------------------------------------------------------------------------
+
+LOCK_WAITS = ("dispatch_lock_wait", "fetch_lock_wait")
+ISSUE45_PHASES = LOCK_WAITS + ("program_call", "fetch_enqueue",
+                               "fetch_read", "replay_answer_wait")
+
+
+def _hold(lock, seconds):
+    """Another thread takes ``lock`` and keeps it ``seconds``; returns
+    once it holds it, with the thread."""
+    got = threading.Event()
+
+    def holder():
+        with lock:
+            got.set()
+            time.sleep(seconds)
+    t = threading.Thread(target=holder, daemon=True)
+    t.start()
+    assert got.wait(5)
+    return t
+
+
+@pytest.mark.parametrize("phase", ISSUE45_PHASES)
+def test_issue45_phase_is_in_acc_from_the_start(phase):
+    prof = StepPhaseProfiler()
+    assert prof.acc[phase] == (0, 0.0, 0.0)
+    assert phase in StepPhaseProfiler.DETAIL
+    # a wait by design is no stall; each of the others is a ring entry
+    assert (phase in StepPhaseProfiler.WAITS) == (phase in LOCK_WAITS)
+    assert phase not in StepPhaseProfiler.NOT_IN_RING
+
+
+def test_cycle_account_closes_with_the_new_phases_nested_two_deep():
+    prof = StepPhaseProfiler()
+    prof.enable_events()
+    lock = threading.RLock()
+    prof.start("cycle")
+    prof.start("device_dispatch")
+    prof.start("input_transfer")
+    prof.stop("input_transfer")
+    holder = _hold(lock, 0.01)
+    with spans_mod.held(prof, lock, "dispatch_lock_wait"):
+        prof.start("program_call")
+        time.sleep(0.002)
+        prof.stop("program_call")
+    prof.stop("device_dispatch")
+    holder.join(5)
+    holder = _hold(lock, 0.01)
+    prof.start("apply")
+    prof.start("replay_fetch")
+    with spans_mod.held(prof, lock, "fetch_lock_wait"):
+        prof.start("fetch_enqueue")
+        prof.stop("fetch_enqueue")
+    prof.start("fetch_read")
+    time.sleep(0.002)
+    prof.stop("fetch_read")
+    prof.stop("replay_fetch")
+    prof.stop("apply")
+    prof.stop("cycle")
+    holder.join(5)
+    acc = prof.acc
+    for phase in ISSUE45_PHASES[:-1]:
+        assert acc[phase][0] == 1, phase
+    # direct children + profiler + unattributed = cycle, exactly
+    parts = sum(acc[p][1] for p in ("device_dispatch", "apply",
+                                    "profiler", "unattributed"))
+    assert abs(parts - acc["cycle"][1]) < 1e-3
+    # inclusive totals: each part inside what holds it
+    assert (acc["input_transfer"][1] + acc["dispatch_lock_wait"][1]
+            + acc["program_call"][1] <= acc["device_dispatch"][1])
+    assert acc["dispatch_lock_wait"][1] >= 5_000
+    fetch_parts = sum(acc[p][1] for p in ("fetch_lock_wait",
+                                          "fetch_enqueue", "fetch_read"))
+    assert fetch_parts <= acc["replay_fetch"][1] <= acc["apply"][1]
+    assert acc["replay_fetch"][1] - fetch_parts < 200   # bookkeeping
+    assert set(ISSUE45_PHASES[:-1]) <= {e[0] for e in prof.events}
+
+
+def test_a_long_contended_take_is_a_wait_and_no_stall():
+    reg, ring = MetricsRegistry(), TraceRing()
+    prof = StepPhaseProfiler(metrics=reg, trace=ring)
+    low = TimeoutConfig().elec_timeout_low
+    lock = threading.RLock()
+    prof.start("cycle")
+    prof.start("device_dispatch")
+    holder = _hold(lock, low + 0.05)            # 150 ms
+    with spans_mod.held(prof, lock, "dispatch_lock_wait"):
+        pass
+    prof.stop("device_dispatch")
+    prof.stop("cycle")
+    holder.join(5)
+    n, total, _mx = prof.acc["dispatch_lock_wait"]
+    assert n == 1 and total >= low * 1e6
+    assert prof.acc["device_dispatch"][1] >= low * 1e6
+    assert ring.events(kind="phase_stall") == []
+    assert reg.snapshot()["counters"]["phase_stalls_total"] == 0
+
+
+def test_acquire_of_a_free_lock_reads_no_clock(monkeypatch):
+    prof = StepPhaseProfiler()
+    prof.enable_events()
+    reads = []
+    real = spans_mod._now_ns
+
+    def counted():
+        reads.append(1)
+        return real()
+    monkeypatch.setattr(spans_mod, "_now_ns", counted)
+    lock = threading.RLock()
+    prof.acquire(lock, "fetch_lock_wait")
+    assert lock._is_owned()
+    lock.release()
+    with spans_mod.held(prof, lock, "dispatch_lock_wait"):
+        assert lock._is_owned()
+    with spans_mod.held(None, lock, "dispatch_lock_wait"):   # no profiler
+        assert lock._is_owned()
+    assert not lock._is_owned()
+    assert reads == [] and not prof.events
+    assert all(prof.acc[p] == (0, 0.0, 0.0) for p in LOCK_WAITS)
+    # and a contended one reads it: a start and a stop
+    holder = _hold(lock, 0.01)
+    prof.acquire(lock, "fetch_lock_wait")
+    lock.release()
+    holder.join(5)
+    assert len(reads) >= 2 and prof.acc["fetch_lock_wait"][0] == 1
+
+
+@pytest.mark.parametrize("engine", ["sim", "sharded"])
+def test_contended_host_lock_takes_are_named_in_both_engines(engine):
+    """Another thread holds ``_host_lock`` 30 ms while ``begin_burst``,
+    then ``_replay_committed``, run: each side's wait is one sample of
+    its own phase, and the fetch's three parts are the fetch."""
+    from rdma_paxos_tpu.shard.cluster import ShardedCluster
+    if engine == "sim":
+        c = SimCluster(ACCT_CFG, 3)
+        c.run_until_elected(0)
+
+        def submit(i):
+            c.submit(0, b"SET k%d v" % i)
+        wedge = [(r,) for r in range(3)]
+    else:
+        c = ShardedCluster(ACCT_CFG, 3, 2)
+        assert c.place_leaders("round_robin") == [0, 1]
+
+        def submit(i):
+            for g in range(2):
+                c.submit(g, g, b"SET k%d v" % i)
+        wedge = [(g, r) for g in range(2) for r in range(3)]
+    for i in range(3):                  # compile the burst and the fetch
+        submit(i)
+        c.step_burst()
+    prof = c.profiler = StepPhaseProfiler()
+    submit(10)
+    holder = _hold(c._host_lock, 0.03)
+    ticket = c.begin_burst()
+    holder.join(5)
+    n, total, _mx = prof.acc["dispatch_lock_wait"]
+    assert n == 1 and total >= 25_000           # its first take met it
+    assert prof.acc["program_call"][0] == 1
+    assert (prof.acc["program_call"][1]
+            <= prof.acc["device_dispatch"][1])
+    # the entry commits with every apply wedged, so that the fetch is
+    # still to run when the lock is held again
+    for w in wedge:
+        c.wedge_apply(*w)
+    c.finish(ticket)
+    for _ in range(3):
+        res = c.step_burst()
+    assert prof.acc["replay_fetch"][0] == 0
+    for w in wedge:
+        c.unwedge_apply(*w)
+    holder = _hold(c._host_lock, 0.03)
+    c._replay_committed(res)
+    holder.join(5)
+    acc = prof.acc
+    assert acc["fetch_lock_wait"][0] == 1
+    assert acc["fetch_lock_wait"][1] >= 25_000
+    assert acc["fetch_enqueue"][0] == acc["fetch_read"][0] \
+        == acc["replay_fetch"][0] >= 1
+    parts = sum(acc[p][1] for p in ("fetch_lock_wait", "fetch_enqueue",
+                                    "fetch_read"))
+    assert parts <= acc["replay_fetch"][1] <= parts / 0.97
+    assert acc["dispatch_lock_wait"][0] == 1    # no other take waited
+    applied = c.applied
+    assert int(applied.min()) == int(res["commit"].min()) > 0
