@@ -28,8 +28,9 @@ from rdma_paxos_tpu.consensus.step import (
     SCAN_KEYS, StepInput, fetch_window, unpack_scalars)
 from rdma_paxos_tpu.obs.spans import held
 from rdma_paxos_tpu.parallel.mesh import (
-    build_sim_burst, build_sim_scan, build_sim_step, build_spmd_burst,
-    build_spmd_scan, build_spmd_step, make_replica_mesh, stack_states)
+    REPLICA_AXIS, build_sim_burst, build_sim_scan, build_sim_step,
+    build_spmd_burst, build_spmd_scan, build_spmd_step, make_replica_mesh,
+    stack_states)
 from rdma_paxos_tpu.runtime import hostpath
 from rdma_paxos_tpu.runtime.hostpath import LazyReplayStream
 
@@ -208,6 +209,35 @@ def decode_window(wm: np.ndarray, wd: np.ndarray, n: int,
     hostpath.extend_stream(replayed, batch)
     if collect_frames:
         frames.append(batch.frames())
+
+
+def make_put(mesh):
+    """The cluster's ONE host-to-device put: ``put(arrays, stacked=0)``
+    takes a tuple of host arrays, the first ``stacked`` of them a
+    burst's ``[K, R, ...]`` stacks and the rest ``[R, ...]`` rows, and
+    returns the device arguments in the same order.
+
+    Without a mesh it is ``jnp.asarray`` an array (every replica a vmap
+    row on the default device). With one, every array goes out in ONE
+    ``jax.device_put`` with the sharding the ``build_spmd_*`` programs'
+    ``in_specs`` name for it (``P("replica")`` for rows, ``P(None,
+    "replica")`` for stacks), replica r's slice straight to chip r: an
+    argument put on one chip is split over the mesh INSIDE the call,
+    one array after the other, on the dispatch thread and under the
+    host lock (0.7-1.0 ms each on four chips: PERF.md, PR 46). The
+    slices are views of the caller's buffer and live by its rule (a
+    ticket keeps its staging buffers until ``finish``)."""
+    if mesh is None:
+        return lambda arrays, stacked=0: tuple(
+            jnp.asarray(a) for a in arrays)
+    P = jax.sharding.PartitionSpec
+    rows = jax.sharding.NamedSharding(mesh, P(REPLICA_AXIS))
+    stacks = jax.sharding.NamedSharding(mesh, P(None, REPLICA_AXIS))
+
+    def put(arrays, stacked=0):
+        return jax.device_put(
+            arrays, (stacks,) * stacked + (rows,) * (len(arrays) - stacked))
+    return put
 
 
 class StepTicket:
@@ -416,7 +446,11 @@ class SimCluster:
                 jax.sharding.NamedSharding(
                     self.mesh, jax.sharding.PartitionSpec("replica")))
         else:
+            self.mesh = None
             self._step = self._build_step(elections=True)
+        # every argument of a dispatch and of the replay fetch goes to
+        # the device through this (shardings built here, once)
+        self._put = make_put(self.mesh)
         # all replicas' windows in ONE dispatch (the per-replica loop of
         # fetch+slice dispatches dominated the host replay path). The
         # REPLAY window is wider than the protocol window: a K-step
@@ -727,23 +761,16 @@ class SimCluster:
             tmo[r] = 1
         if prof is not None:
             prof.start("input_transfer")
-        inp = StepInput(
-            batch_data=jnp.asarray(bufs["data"]),
-            batch_meta=jnp.asarray(bufs["meta"]),
-            batch_count=jnp.asarray(count),
-            timeout_fired=jnp.asarray(tmo),
-            peer_mask=jnp.asarray(mask),
-            apply_done=jnp.asarray(applied),
-            queue_depth=jnp.asarray(qdepth),
-            **(dict(
-                # device watch compares log offsets: shift the armed
-                # ABSOLUTE index by the i32 rollovers applied so far
-                txn_watch=jnp.full(
-                    (R,), (self._txn_watch - self.rebased_total
-                           if self._txn_watch >= 0 else -1), jnp.int32),
-                txn_term=jnp.full((R,), self._txn_wterm, jnp.int32),
-            ) if self._txn else {}),
-        )
+        leaves = (bufs["data"], bufs["meta"], count, tmo, mask, applied,
+                  qdepth)
+        if self._txn:
+            # device watch compares log offsets: shift the armed
+            # ABSOLUTE index by the i32 rollovers applied so far
+            leaves += (
+                np.full((R,), (self._txn_watch - self.rebased_total
+                               if self._txn_watch >= 0 else -1), np.int32),
+                np.full((R,), self._txn_wterm, np.int32))
+        inp = StepInput(*self._put(leaves))     # in field order
         if prof is not None:
             prof.stop("input_transfer")
         # no timer fired ⟹ Phase B is provably a no-op: dispatch the
@@ -831,9 +858,8 @@ class SimCluster:
             prof.stop("host_encode")
             prof.start("device_dispatch")
             prof.start("input_transfer")
-        args = (jnp.asarray(bufs["data"]), jnp.asarray(bufs["meta"]),
-                jnp.asarray(count), jnp.asarray(mask),
-                jnp.asarray(applied), jnp.asarray(qdepth))
+        args = self._put((bufs["data"], bufs["meta"], count, mask,
+                          applied, qdepth), stacked=3)
         if prof is not None:
             prof.stop("input_transfer")
         with held(prof, self._host_lock, "dispatch_lock_wait"):
@@ -1106,33 +1132,34 @@ class SimCluster:
         commit pipeline; paying it before traffic starts keeps the
         serving path pause-free."""
         cfg, R, B = self.cfg, self.R, self.cfg.batch_slots
-        inp = StepInput(
-            batch_data=jnp.zeros((R, B, cfg.slot_words), jnp.int32),
-            batch_meta=jnp.zeros((R, B, META_W), jnp.int32),
-            batch_count=jnp.zeros((R,), jnp.int32),
-            timeout_fired=jnp.zeros((R,), jnp.int32),
-            peer_mask=jnp.asarray(self.peer_mask),
-            apply_done=jnp.zeros((R,), jnp.int32),
-            queue_depth=jnp.zeros((R,), jnp.int32),
-            **(dict(txn_watch=jnp.full((R,), -1, jnp.int32),
-                    txn_term=jnp.zeros((R,), jnp.int32))
-               if self._txn else {}))
+        row = np.zeros((R,), np.int32)
+        # through the dispatches' own put: an argument placed otherwise
+        # is another executable of the same program, and the first
+        # served dispatch would compile it inside the loop
+        inp = StepInput(*self._put(
+            (np.zeros((R, B, cfg.slot_words), np.int32),
+             np.zeros((R, B, META_W), np.int32),
+             row, row, self.peer_mask, row, row)
+            + ((np.full((R,), -1, np.int32), row) if self._txn else ())))
         for elections in (True, False):
             fn = self._build_step(elections=elections)
             st = jax.tree.map(lambda x: x.copy(), self.state)
             fn(st, inp)
-        pm = jnp.asarray(self.peer_mask)
-        ap = jnp.zeros((R,), jnp.int32)
         for K in (tiers if tiers is not None else self.K_TIERS):
             fns = [self._burst_fn(K)]
             if self.scan:
                 fns.append(self._scan_fn(K))
+            args = self._put(
+                (np.zeros((K, R, B, cfg.slot_words), np.int32),
+                 np.zeros((K, R, B, META_W), np.int32),
+                 np.zeros((K, R), np.int32), self.peer_mask, row, row),
+                stacked=3)
             for fn in fns:
                 st = jax.tree.map(lambda x: x.copy(), self.state)
-                fn(st, jnp.zeros((K, R, B, cfg.slot_words), jnp.int32),
-                   jnp.zeros((K, R, B, META_W), jnp.int32),
-                   jnp.zeros((K, R), jnp.int32), pm, ap,
-                   jnp.zeros((R,), jnp.int32))
+                fn(st, *args)
+        # and the replay fetch, so that its first served use compiles
+        # nothing
+        self._fetch_all(self.state.log, *self._put((row,)))
 
     def step(self, timeouts: Sequence[int] = ()) -> Dict[str, np.ndarray]:
         require_drained(self._tickets, "step")
@@ -1357,7 +1384,7 @@ class SimCluster:
                     and self.applied[r] < int(res["commit"][r])]
             if not todo:
                 return
-            starts = jnp.asarray(self.applied.astype(np.int32))
+            starts, = self._put((self.applied.astype(np.int32),))
             prof = self.profiler
             if prof is not None:
                 prof.start("replay_fetch")
