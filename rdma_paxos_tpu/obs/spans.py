@@ -97,7 +97,16 @@ inside the optional fencing path):
   off the replay sockets), ``intake_fragments_total`` and
   ``intake_payload_bytes_total`` (log entries and bytes admitted at
   intake: an operation larger than a slot is several entries),
-  ``phase_stalls_total`` and
+  ``pruned_slots_total`` (the leader's ``head`` advance: slots the
+  pruner gave back), ``append_clamped_total`` (entries a dispatch
+  offered the leader's ring that its capacity clamp did not take; they
+  are queued again), ``ring_wraps_total`` (times the leader's ``end``
+  crossed a multiple of ``n_slots``), all three off the packed row the
+  readback reads anyway, ``replay_requests_total`` (replayed SENDs that
+  ended a request: ``replay_applies_total`` over it is the calls a
+  whole request costs a follower), ``replay_order_timeouts_total``
+  (``ReplayEngine.order_timeouts``: answers waited ``ORDER_WAIT_S``
+  for in vain), ``phase_stalls_total`` and
   ``phase_stall_us_total{phase}`` (a phase instance longer than
   ``TimeoutConfig.elec_timeout_low``; each also leaves one
   ``phase_stall`` event in the trace ring).
@@ -724,7 +733,9 @@ class StepPhaseProfiler:
                 "replay_followers_total", "replay_reply_bytes_total",
                 "intake_fragments_total", "intake_payload_bytes_total",
                 "recover_bytes_total", "recover_entries_total",
-                "replay_reconnects_total")
+                "replay_reconnects_total", "pruned_slots_total",
+                "append_clamped_total", "ring_wraps_total",
+                "replay_requests_total", "replay_order_timeouts_total")
     # a thread waiting by design: its length counts towards no stall,
     # its own or of the phase it waits in
     WAITS = (PHASE_IDLE_WAIT, PHASE_PIPELINE_WAIT,

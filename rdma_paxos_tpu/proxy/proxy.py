@@ -381,6 +381,10 @@ class ReplayEngine:
         self._sink = bytearray(65536)   # where that output is read to
         self._unanswered = 0        # waits in a row that timed out
         self.order_timeouts = 0
+        # since the last take_replayed: writes that ended a request,
+        # and order_timeouts as it stood then
+        self._requests = 0
+        self._timeouts_taken = 0
         # local (ephemeral) ports of our replay sockets: the driver uses
         # these to recognize its own replayed connections arriving back
         # through the app's interposition shim. port -> None while its
@@ -497,6 +501,7 @@ class ReplayEngine:
             s.sendall(payload)
             self._awaiting = s
             self._whole = payload.endswith(b"\n")
+            self._requests += self._whole
         elif etype == int(EntryType.CLOSE):
             s = self.conns.pop(conn_id, None)
             if s is not None:
@@ -691,6 +696,13 @@ class ReplayEngine:
         and not this process's."""
         out = (self._waits, self._wait_ns)
         self._waits = self._wait_ns = 0
+        return out
+
+    def take_replayed(self) -> Tuple[int, int]:
+        """-> (writes that ended a request, answers waited
+        ``ORDER_WAIT_S`` for in vain) since the last call."""
+        out = (self._requests, self.order_timeouts - self._timeouts_taken)
+        self._requests, self._timeouts_taken = 0, self.order_timeouts
         return out
 
     def close(self) -> None:

@@ -1926,6 +1926,9 @@ class ClusterDriver:
         if n_waits:
             prof.credit("replay_answer_wait",
                         sum(ns for _, ns in waits) / 1e3, n_waits)
+        done = [engine.take_replayed() for engine, _ in replays]
+        prof.count("replay_requests_total", sum(n for n, _ in done))
+        prof.count("replay_order_timeouts_total", sum(t for _, t in done))
         prof.stop("apply_replay_ack")
 
     def _replay_lost(self, engine: ReplayEngine, exc: OSError) -> None:

@@ -181,6 +181,32 @@ def clamp_burst_take(pending_len: int, end: int, head: int,
     return min(pending_len, max(avail, 0), max_take)
 
 
+def count_ring(prof, last, res, taken, n_slots: int, g=...) -> None:
+    """What one dispatch did to the leader's ring, off the packed row
+    against the last dispatch's (``last`` is kept in the same rebase
+    frame as the state, and the rollover's delta is a multiple of
+    ``n_slots``): slots the pruner gave back (``head``'s advance),
+    entries offered that the capacity clamp did not take (they are
+    queued again: the same ``accepted`` the requeue rule reads), and
+    turns of the ring ``end`` completed. Of one group (the sharded
+    engine's ``g``); no leader, or two claims, counts nothing."""
+    if last is None:
+        return
+    lead = np.flatnonzero(res["role"][g] == int(Role.LEADER))
+    if lead.size != 1:
+        return
+    r = int(lead[0])
+    head, end = res["head"][g], res["end"][g]
+    for counter, n in (
+            ("pruned_slots_total", int(head[r]) - int(last["head"][g][r])),
+            ("ring_wraps_total", int(end[r]) // n_slots
+             - int(last["end"][g][r]) // n_slots),
+            ("append_clamped_total",
+             len(taken[r]) - int(res["accepted"][g][r]))):
+        if n > 0:
+            prof.count(counter, n)
+
+
 def rebase_delta_of(heads: Sequence[int], n_slots: int) -> int:
     """Rebase frontier rule: the coordinated i32-rollover delta is the
     minimum head rounded DOWN to a multiple of ``n_slots`` (the slot
@@ -919,6 +945,7 @@ class SimCluster:
             prof.count("cfg_rescans_total", int(res["cfg_rescanned"].max()))
             prof.stop("quorum_wait")
             prof.start("post_readback")
+            count_ring(prof, self.last, res, ticket.taken, self.cfg.n_slots)
         if self._audit:
             # ingest BEFORE _maybe_rebase: the emitted indices are raw
             # (pre-rollover), consistent with the current rebased_total

@@ -72,7 +72,7 @@ from rdma_paxos_tpu.parallel.mesh import (
 from rdma_paxos_tpu.runtime.hostpath import LazyReplayStream
 from rdma_paxos_tpu.runtime.sim import (
     STEP_CACHE, SimCluster, StagingPool, StepTicket, cap_tiers,
-    clamp_burst_take, decode_window, pack_rows, read_scalars,
+    clamp_burst_take, count_ring, decode_window, pack_rows, read_scalars,
     rebase_delta_of, requeue_shortfall, require_drained)
 from rdma_paxos_tpu.shard.router import KeyRouter
 
@@ -747,6 +747,9 @@ class ShardedCluster:
             prof.count("cfg_rescans_total", int(res["cfg_rescanned"].max()))
             prof.stop("quorum_wait")
             prof.start("post_readback")
+            for g in range(G):
+                count_ring(prof, self.last, res, ticket.taken[g],
+                           self.cfg.n_slots, g)
         if self._audit:
             if burst or scan:
                 get = (out.__getitem__ if scan

@@ -228,6 +228,10 @@ def replaced(request, tmp_path_factory):
         views = [dict(count=int(ask(p, [b"COUNT"])[0]),
                       answers=ask(p, [b"GET " + k for k in keys]))
                  for p in ports]
+        # the check's own sessions on the leader's app are log entries
+        # too, and a follower's store trails the leader's by a dispatch
+        wait_until(lambda: len({len(rt.store) for rt in d.runtimes}) == 1,
+                   "the stores never agree")
         stores = [len(rt.store) for rt in d.runtimes]
         d.stop()
         assert d.loop_error is None
