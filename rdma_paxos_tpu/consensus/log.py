@@ -228,6 +228,31 @@ def extract_window(
     return rows_at(log, start + jnp.arange(window_slots, dtype=jnp.int32))
 
 
+def window_rows(
+    log: Log, start: jax.Array, window_slots: int
+) -> jax.Array:
+    """:func:`extract_window`'s rows, bit for bit, FUSED as the ring
+    stores them (``[W, slot_words + META_W]``) and read as SLICES: a
+    window is contiguous in the ring but for one wrap, so it is the
+    ``window_slots`` slots from ``start``'s (or, within a window of the
+    ring's end, the ring's last ``window_slots``), then the ring's
+    first ``window_slots`` where it wraps, and of the two laid end to
+    end the ``window_slots`` rows from ``start``'s place among them.
+    For a reader OUTSIDE the step (the replay fetch), which holds the
+    ring as the device rests it: a gather by row makes the v5e convert
+    the whole slot-minor ring first (``copy.4``, 1-3 ms a fetch), a
+    slice of consecutive slots is read where it lies (PERF.md section
+    6, PR 48). The step keeps :func:`extract_window`: its ring is
+    converted for its scatters anyway."""
+    n_slots, W = log.n_slots, window_slots
+    s = slot_of(start, n_slots)
+    at = jnp.minimum(s, n_slots - W)
+    cols = log.buf.shape[-1]
+    tail = jax.lax.dynamic_slice(log.buf, (at, 0), (W, cols))
+    both = jnp.concatenate([tail, log.buf[:W]], axis=0)
+    return jax.lax.dynamic_slice(both, (s - at, 0), (W, cols))
+
+
 def absorb_window(
     log: Log,
     my_end: jax.Array,

@@ -70,7 +70,7 @@ from rdma_paxos_tpu.config import LogConfig
 from rdma_paxos_tpu.consensus.log import (
     EntryType, Log, M_GIDX, M_TERM, M_TYPE, META_W,
     append_batch, absorb_window, extract_window, last_term, rows_at,
-    slot_of,
+    slot_of, window_rows,
 )
 from rdma_paxos_tpu.consensus.state import ConfigState, ReplicaState, Role
 from rdma_paxos_tpu.ops.quorum import R_PAD, commit_scan
@@ -128,7 +128,7 @@ class StepInput:
 @dataclasses.dataclass
 class StepOutput:
     """Per-replica device→host results of one step (small scalars only; bulk
-    committed payload is fetched separately, see ``fetch_window``)."""
+    committed payload is fetched separately, see ``fetch_rows``)."""
 
     term: jax.Array
     role: jax.Array
@@ -1200,10 +1200,22 @@ def unpack_scalars(rows: np.ndarray) -> Dict[str, np.ndarray]:
     return res
 
 
-def fetch_window(log: Log, start: jax.Array, *, window_slots: int):
-    """Host helper: gather ``window_slots`` entries beginning at ``start`` —
-    used by the driver to read newly committed payloads for replay/persist
-    (the analog of apply_committed_entries walking the log,
-    ``dare_server.c:1815-1974``)."""
+def fetch_rows(log: Log, start: jax.Array, *, window_slots: int):
+    """Host helper: the ``window_slots`` entries beginning at ``start``
+    as the ring stores them (``[W, slot_words + META_W]``: payload
+    words, then the framing metadata) — what the driver reads newly
+    committed payloads for replay/persist through (the analog of
+    apply_committed_entries walking the log,
+    ``dare_server.c:1815-1974``). ONE array, because every array a
+    program hands the host costs a read of its own (0.45-0.5 ms on the
+    v5e's host whatever its size: PERF.md section 6, PR 48); the host
+    takes the columns apart for nothing."""
     with jax.named_scope("replay_fetch"):
-        return extract_window(log, start, window_slots)
+        return window_rows(log, start, window_slots)
+
+
+def fetch_window(log: Log, start: jax.Array, *, window_slots: int):
+    """:func:`fetch_rows` as ``(data, meta)``, the columns taken apart
+    on the device (``extract_window``'s results, bit for bit)."""
+    w = fetch_rows(log, start, window_slots=window_slots)
+    return w[..., :log.slot_words], w[..., log.slot_words:]

@@ -106,7 +106,10 @@ inside the optional fencing path):
   ended a request: ``replay_applies_total`` over it is the calls a
   whole request costs a follower), ``replay_order_timeouts_total``
   (``ReplayEngine.order_timeouts``: answers waited ``ORDER_WAIT_S``
-  for in vain), ``phase_stalls_total`` and
+  for in vain), ``fetch_rows_total`` (rows a replica each standalone
+  replay fetch asked of the device: the static width it ran at; over
+  ``replay_fetch``'s count it says which width serves),
+  ``phase_stalls_total`` and
   ``phase_stall_us_total{phase}`` (a phase instance longer than
   ``TimeoutConfig.elec_timeout_low``; each also leaves one
   ``phase_stall`` event in the trace ring).
@@ -735,7 +738,8 @@ class StepPhaseProfiler:
                 "recover_bytes_total", "recover_entries_total",
                 "replay_reconnects_total", "pruned_slots_total",
                 "append_clamped_total", "ring_wraps_total",
-                "replay_requests_total", "replay_order_timeouts_total")
+                "replay_requests_total", "replay_order_timeouts_total",
+                "fetch_rows_total")
     # a thread waiting by design: its length counts towards no stall,
     # its own or of the phase it waits in
     WAITS = (PHASE_IDLE_WAIT, PHASE_PIPELINE_WAIT,

@@ -25,7 +25,7 @@ from rdma_paxos_tpu.consensus.log import (
     EntryType, M_CONN, M_GIDX, M_LEN, M_REQID, M_TYPE, META_W)
 from rdma_paxos_tpu.consensus.state import Role
 from rdma_paxos_tpu.consensus.step import (
-    SCAN_KEYS, StepInput, fetch_window, unpack_scalars)
+    SCAN_KEYS, StepInput, fetch_rows, unpack_scalars)
 from rdma_paxos_tpu.obs.spans import held
 from rdma_paxos_tpu.parallel.mesh import (
     REPLICA_AXIS, build_sim_burst, build_sim_scan, build_sim_step,
@@ -266,6 +266,76 @@ def make_put(mesh):
     return put
 
 
+class FetchedColumns:
+    """A column range of fetched rows that are still on the device:
+    converts like an array (``np.asarray``). The rows come to the host
+    ONCE, at the first conversion of either range (a ``jax.Array``
+    keeps its host copy), and a range is a view of that copy."""
+
+    __slots__ = ("rows", "cols")
+
+    def __init__(self, rows, cols: slice):
+        self.rows, self.cols = rows, cols
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.rows)[..., self.cols]
+
+
+class ReplayFetch:
+    """The standalone replay fetch of BOTH engines: ``fetch_rows`` over
+    every replica's ring row (``batch_axes`` vmaps: ``[R]``, or the
+    sharded engine's ``[G, R]``), compiled at a few static widths of
+    the engine's replay window and called at the smallest that holds
+    ``need``, the rows the furthest-behind replica lacks. The engine
+    sets ``need`` under its host lock and calls the fetch as
+    ``cluster._fetch_all(log, starts)``, looked up at call time: a
+    wrapper put over that attribute (the benchmark's span) sees two
+    arguments and two results that convert with ``np.asarray``, payload
+    words and metadata, as it always did. The metadata's ``shape[-2]``
+    says how wide the fetch was.
+
+    A c50 dispatch commits 50 entries and the widest window is 4,096
+    rows a replica: every row was copied to the host, in two arrays,
+    after a ring-sized layout copy, 3.5-7.4 ms a dispatch (PERF.md
+    section 6, PR 48). A need above the widest is swept in gulps of
+    the widest by the caller's loop, as before."""
+
+    # the replay window over these: 256, 1,024 and 4,096 rows a replica
+    # at the cells' geometry (a c1 dispatch commits 1 entry, c50 50, s5
+    # 200, and twice that after a hiccup of the loop)
+    DIVISORS = (16, 4, 1)
+
+    def __init__(self, widest: int, batch_axes: int):
+        self.widths = tuple(sorted({max(widest // d, 1)
+                                    for d in self.DIVISORS}))
+        self.programs = {}
+        for W in self.widths:
+            def fn(log, start, W=W):
+                return fetch_rows(log, start, window_slots=W)
+            for _ in range(batch_axes):
+                fn = jax.vmap(fn)
+            self.programs[W] = jax.jit(fn)
+        # set by the engine inside its host lock, read by the call
+        # it makes next, inside the same take
+        self.need = widest
+
+    def width_for(self, need: int) -> int:
+        return next((W for W in self.widths if W >= need),
+                    self.widths[-1])
+
+    def __call__(self, log, starts):
+        rows = self.programs[self.width_for(self.need)](log, starts)
+        words = log.slot_words
+        return (FetchedColumns(rows, slice(0, words)),
+                FetchedColumns(rows, slice(words, None)))
+
+    def warm(self, log, starts) -> None:
+        """Every width once (results dropped), so that no served fetch
+        compiles or loads a program."""
+        for fn in self.programs.values():
+            fn(log, starts)
+
+
 class StepTicket:
     """One dispatched-but-not-finished protocol step/burst.
 
@@ -481,12 +551,15 @@ class SimCluster:
         # fetch+slice dispatches dominated the host replay path). The
         # REPLAY window is wider than the protocol window: a K-step
         # burst commits up to K*batch_slots entries at once, and each
-        # fetch dispatch costs host time — sweep in big gulps.
+        # fetch dispatch costs host time — sweep in gulps of at most
+        # this, in the narrowest of ReplayFetch's widths that holds
+        # what was committed.
         self._replay_W = min(cfg.n_slots // 2,
                              max(4 * cfg.window_slots, 256))
-        self._fetch_all = jax.jit(jax.vmap(
-            lambda log, start: fetch_window(
-                log, start, window_slots=self._replay_W)))
+        # _fetch_all is what the fetch is CALLED through (a traced
+        # benchmark run wraps it)
+        self._replay_fetch = ReplayFetch(self._replay_W, 1)
+        self._fetch_all = self._replay_fetch
         # host bookkeeping
         # host apply cursor — single-writer: advanced in-place by the
         # finishing (readback) thread only; whole-array WRITES rebind
@@ -1184,9 +1257,9 @@ class SimCluster:
             for fn in fns:
                 st = jax.tree.map(lambda x: x.copy(), self.state)
                 fn(st, *args)
-        # and the replay fetch, so that its first served use compiles
-        # nothing
-        self._fetch_all(self.state.log, *self._put((row,)))
+        # and the replay fetch at every width, so that no served use
+        # compiles anything
+        self._replay_fetch.warm(self.state.log, *self._put((row,)))
 
     def step(self, timeouts: Sequence[int] = ()) -> Dict[str, np.ndarray]:
         require_drained(self._tickets, "step")
@@ -1370,7 +1443,6 @@ class SimCluster:
         ZERO standalone fetch dispatches; any remainder falls through
         to the fetch loop below (identical decode → identical
         streams)."""
-        W = self._replay_W
         if scan_rows is not None:
             wd_fut, wm_fut, applied0 = scan_rows
             staged = int(wm_fut.shape[-2])     # K-sized, <= replay_W
@@ -1412,6 +1484,8 @@ class SimCluster:
             if not todo:
                 return
             starts, = self._put((self.applied.astype(np.int32),))
+            need = max(int(res["commit"][r] - self.applied[r])
+                       for r in todo)
             prof = self.profiler
             if prof is not None:
                 prof.start("replay_fetch")
@@ -1429,6 +1503,7 @@ class SimCluster:
             with held(prof, self._host_lock, "fetch_lock_wait"):
                 if prof is not None:
                     prof.start("fetch_enqueue")
+                self._replay_fetch.need = need
                 wd_fut, wm_fut = self._fetch_all(self.state.log, starts)
                 if prof is not None:
                     prof.stop("fetch_enqueue")
@@ -1437,9 +1512,11 @@ class SimCluster:
             # wm is read last: a wrapper over _fetch_all (the
             # benchmark's span) ends inside its conversion
             wd_all, wm_all = np.asarray(wd_fut), np.asarray(wm_fut)
+            W = wm_all.shape[-2]        # the width the fetch chose
             if prof is not None:
                 prof.stop("fetch_read")
                 prof.stop("replay_fetch")
+                prof.count("fetch_rows_total", W)
                 prof.start("replay_decode")
             for r in todo:
                 commit = int(res["commit"][r])

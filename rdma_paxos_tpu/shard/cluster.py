@@ -61,8 +61,7 @@ from rdma_paxos_tpu.config import LogConfig, REBASE_STALL_STEPS
 from rdma_paxos_tpu.consensus.log import (
     EntryType, Log, M_CONN, M_GIDX, M_LEN, M_REQID, M_TYPE, META_W)
 from rdma_paxos_tpu.consensus.state import Role
-from rdma_paxos_tpu.consensus.step import (
-    StepInput, fetch_window)
+from rdma_paxos_tpu.consensus.step import StepInput
 from rdma_paxos_tpu.obs.spans import held
 from rdma_paxos_tpu.parallel.mesh import (
     GROUP_AXIS, REPLICA_AXIS, build_mesh_2d, build_sim_group_burst,
@@ -71,7 +70,7 @@ from rdma_paxos_tpu.parallel.mesh import (
     stack_group_states)
 from rdma_paxos_tpu.runtime.hostpath import LazyReplayStream
 from rdma_paxos_tpu.runtime.sim import (
-    STEP_CACHE, SimCluster, StagingPool, StepTicket, cap_tiers,
+    STEP_CACHE, ReplayFetch, SimCluster, StagingPool, StepTicket, cap_tiers,
     clamp_burst_take, count_ring, decode_window, pack_rows, read_scalars,
     rebase_delta_of, requeue_shortfall, require_drained)
 from rdma_paxos_tpu.shard.router import KeyRouter
@@ -213,9 +212,10 @@ class ShardedCluster:
         self.fetch_dispatches = 0
         self._replay_W = min(cfg.n_slots // 2,
                              max(4 * cfg.window_slots, 256))
-        self._fetch_all = jax.jit(jax.vmap(jax.vmap(
-            lambda log, start: fetch_window(
-                log, start, window_slots=self._replay_W))))
+        # SimCluster's fetch over [G, R]: the same few widths, the
+        # same hook a traced benchmark run wraps
+        self._replay_fetch = ReplayFetch(self._replay_W, 2)
+        self._fetch_all = self._replay_fetch
         # ---- per-group host bookkeeping (mirrors SimCluster) ----
         G, R = self.G, self.R
         self.applied = np.zeros((G, R), np.int64)
@@ -523,6 +523,8 @@ class ShardedCluster:
                    jnp.zeros((K, G, R, B, META_W), jnp.int32),
                    jnp.zeros((K, G, R), jnp.int32), pm, ap,
                    jnp.zeros((G, R), jnp.int32))
+        # and the replay fetch at every width (SimCluster.prewarm)
+        self._replay_fetch.warm(self.state.log, ap)
 
     def begin_step(self, timeouts: TimeoutsLike = (),
                    take_batch: bool = True) -> StepTicket:
@@ -898,8 +900,8 @@ class ShardedCluster:
 
     def _replay_committed(self, res, scan_rows=None) -> None:
         """Per-group host apply loop — ALL groups' and replicas'
-        windows ride ONE fetch dispatch per sweep (the [G, R]-vmapped
-        ``fetch_window``). Same integrity rule as ``SimCluster``: a
+        windows ride ONE fetch dispatch per sweep (``ReplayFetch`` over
+        ``[G, R]``). Same integrity rule as ``SimCluster``: a
         fetched entry whose stamped gidx disagrees with the expected
         apply index means the slot was recycled past this member —
         flag ``(g, r)`` for snapshot recovery and stop replaying.
@@ -910,7 +912,6 @@ class ShardedCluster:
         commit delta fits the staged window pays zero fetch
         dispatches."""
         import time as _time
-        W = self._replay_W
         t_group: Dict[int, int] = {}
         if scan_rows is not None:
             wd_fut, wm_fut, applied0 = scan_rows
@@ -952,6 +953,8 @@ class ShardedCluster:
             if not todo:
                 break
             starts = jnp.asarray(self.applied.astype(np.int32))
+            need = max(int(res["commit"][g, r] - self.applied[g, r])
+                       for g, r in todo)
             prof = self.profiler
             if prof is not None:
                 prof.start("replay_fetch")
@@ -960,6 +963,7 @@ class ShardedCluster:
             with held(prof, self._host_lock, "fetch_lock_wait"):
                 if prof is not None:
                     prof.start("fetch_enqueue")
+                self._replay_fetch.need = need
                 wd_fut, wm_fut = self._fetch_all(self.state.log, starts)
                 if prof is not None:
                     prof.stop("fetch_enqueue")
@@ -969,9 +973,11 @@ class ShardedCluster:
             # wm is read last: a wrapper over _fetch_all (the
             # benchmark's span) ends inside its conversion
             wd_all, wm_all = np.asarray(wd_fut), np.asarray(wm_fut)
+            W = wm_all.shape[-2]        # the width the fetch chose
             if prof is not None:
                 prof.stop("fetch_read")
                 prof.stop("replay_fetch")
+                prof.count("fetch_rows_total", W)
                 prof.start("replay_decode")
             for g, r in todo:
                 t0 = _time.perf_counter_ns()

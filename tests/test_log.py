@@ -9,7 +9,8 @@ import pytest
 from rdma_paxos_tpu.config import LogConfig
 from rdma_paxos_tpu.consensus.log import (
     EntryType, M_LEN, M_TERM, M_TYPE, META_W,
-    absorb_window, append_batch, extract_window, last_term, make_log,
+    Log, absorb_window, append_batch, extract_window, last_term, make_log,
+    window_rows,
 )
 
 CFG = LogConfig(n_slots=16, slot_bytes=16, window_slots=8, batch_slots=4)
@@ -72,6 +73,29 @@ def test_wraparound_extract():
     assert int(end) == 28
     wd, _ = extract_window(log, i32(24), 4)  # crosses slot 15 -> 0
     np.testing.assert_array_equal(np.asarray(wd[:4, 0]), [24, 25, 26, 27])
+
+
+@pytest.mark.parametrize("W", [1, 4, 8, 16])
+def test_window_rows_are_the_gathered_window(W):
+    """The replay fetch's slices (``window_rows``) give the rows of the
+    modular gather (``extract_window``), fused, from every start: the
+    ring's first turn, each slot of the wrap, later turns, the top of
+    the i32 range; alone and under ``vmap`` with a start a ring row."""
+    import jax
+    rng = np.random.default_rng(W)
+    n, cols = CFG.n_slots, CFG.slot_words + META_W
+    logs = Log(buf=jnp.asarray(
+        rng.integers(0, 1 << 30, (3, n, cols), dtype=np.int32)))
+    sliced = jax.jit(jax.vmap(lambda lg, s: window_rows(lg, s, W)))
+    gathered = jax.jit(jax.vmap(lambda lg, s: extract_window(lg, s, W)))
+    starts = list(range(3 * n)) + [2 ** 31 - 2 * n + k for k in range(n)]
+    for s0 in starts:
+        at = i32([s0, (s0 + 5) % (3 * n), (s0 * 7 + 3) % (3 * n)])
+        rows = np.asarray(sliced(logs, at))
+        wd, wm = gathered(logs, at)
+        assert rows.shape == (3, W, cols)
+        np.testing.assert_array_equal(rows[..., :CFG.slot_words], wd)
+        np.testing.assert_array_equal(rows[..., CFG.slot_words:], wm)
 
 
 def test_absorb_extends():

@@ -855,6 +855,59 @@ def test_replay_fetch_phase_counts_fetch_dispatches(tmp_path,
         srv.close()
 
 
+def test_benchmark_span_installed_still_serves_every_fetch_width(tmp_path):
+    """With the benchmark's ``(log, starts)`` wrapper over
+    ``cluster._fetch_all`` (``enable_tracing``) the fetch still runs at
+    the smallest width that holds the need: the width rides on the
+    engine's ``ReplayFetch``, which the wrapper does not see, and
+    ``fetch_rows_total`` counts it once a fetch, ``replay_fetch`` and
+    the benchmark's span once each."""
+    d, srv, fetches, dep = _account_driver(tmp_path, pipeline=0,
+                                           bench_wrapper=True)
+    try:
+        rf = d.cluster._replay_fetch
+        assert rf.widths == (4, 16, 64)
+        assert d.cluster._fetch_all is not rf       # the wrapper is on
+        handler = d._make_handler(0)
+        conn = (0 << 24) | 9
+        ev = handler(int(EntryType.CONNECT), conn, b"")
+        assert _step_until(d, ev.done.is_set)
+        span = dep.bench_spans["replay_fetch"]
+        sent = 0
+        # follower 2's app stands still for `need` SETs, then applies
+        for need, widths in ((1, [4]), (4, [4]), (5, [16]), (16, [16]),
+                             (17, [64]), (64, [64]), (70, [64, 16])):
+            d.cluster.wedge_apply(2)
+            evs = [handler(int(EntryType.SEND), conn,
+                           b"SET k%d v\n" % (sent + i))
+                   for i in range(need)]
+            sent += need
+            assert _step_until(d, lambda: all(e.done.is_set()
+                                              for e in evs))
+            d.step()
+            d.step()
+            last = d.cluster.last
+            assert int(last["commit"][2]) - d.cluster.applied[2] == need
+            rows0 = d.obs.metrics.get("fetch_rows_total")
+            n0, span0 = len(fetches), span.count
+            acc0 = d._phase_prof.acc["replay_fetch"][0]
+            d.cluster.unwedge_apply(2)
+            d.step()
+            assert d.cluster.applied[2] == int(d.cluster.last["commit"][2])
+            n = len(fetches) - n0
+            assert n == len(widths), (need, n)
+            assert span.count - span0 == n
+            assert d._phase_prof.acc["replay_fetch"][0] - acc0 == n
+            assert (d.obs.metrics.get("fetch_rows_total") - rows0
+                    == sum(widths)), need
+        sets = [p for (_, _, _, p) in d.cluster.replayed[2]
+                if p.startswith(b"SET")]
+        assert sets == [b"SET k%d v\n" % i for i in range(sent)]
+    finally:
+        d.stop()
+        srv.close()
+
+
 # a membership change and a replica's recovery (ISSUE 43): recorded
 # only when they run
 REPLACEMENT_PHASES = ("config_change", "checkpoint", "recover",
@@ -1096,17 +1149,23 @@ def lowered_texts():
     c = SimCluster(ACCT_CFG, 3, audit=True, telemetry=True)
     inp = jax.vmap(lambda _: make_step_input(ACCT_CFG, 3))(jnp.arange(3))
     step = c._build_step(elections=True).lower(c.state, inp)
-    fetch = c._fetch_all.lower(c.state.log, jnp.zeros((3,), jnp.int32))
-    return dict(step=step.as_text(debug_info=True),
-                fetch=fetch.as_text(debug_info=True))
+    fetches = {W: fn.lower(c.state.log, jnp.zeros((3,), jnp.int32))
+               for W, fn in c._fetch_all.programs.items()}
+    assert len(fetches) == 3
+    return dict(step=[step.as_text(debug_info=True)],
+                fetch=[f.as_text(debug_info=True)
+                       for f in fetches.values()])
 
 
 @pytest.mark.parametrize("scope", STEP_SCOPES + ("replay_fetch",))
 def test_lowered_program_carries_each_scope(lowered_texts, scope):
     import re
-    text = lowered_texts["fetch" if scope == "replay_fetch" else "step"]
-    # the path a device trace shows: jit(replica_step)/vmap(append)/...
-    assert re.search(r"[/(]%s[/)]" % scope, text), scope
+    # the replay fetch is one program a static width: each carries it
+    for text in lowered_texts["fetch" if scope == "replay_fetch"
+                              else "step"]:
+        # the path a device trace shows:
+        # jit(replica_step)/vmap(append)/...
+        assert re.search(r"[/(]%s[/)]" % scope, text), scope
 
 
 # ---------------------------------------------------------------------------

@@ -241,7 +241,9 @@ def test_spmd_prewarm_leaves_no_compile_for_the_served_path():
     fns = dict(step=c._build_step(elections=True),
                stable=c._build_step(elections=False),
                burst=c._burst_fn(2), scan=c._scan_fn(2),
-               fetch=c._fetch_all)
+               **{"fetch_%d" % W: fn
+                  for W, fn in c._fetch_all.programs.items()})
+    assert len(fns) == 4 + 3        # the fetch at each of its widths
     assert {k: f._cache_size() for k, f in fns.items()} == dict.fromkeys(
         fns, 1)
     c.run_until_elected(0)

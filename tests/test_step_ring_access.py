@@ -223,3 +223,35 @@ def test_only_group_mappings_reduce_over_the_group_batch(kind, R):
     (v,) = e.invars
     # a device's own G groups, whatever the mesh's group axis holds
     assert v.aval.shape == (G,) and v.aval.dtype == jnp.int32
+
+
+@pytest.mark.parametrize("lead", [(3,), (7,), (G, 3)],
+                         ids=["R3", "R7", "G3R3"])
+def test_replay_fetch_slices_its_rows_out_of_the_ring(lead):
+    """The standalone replay fetch (``runtime.sim.ReplayFetch``, both
+    engines) reads a window as SLICES of consecutive slots, at every
+    width: each operation that touches the ring takes ``W`` slots a
+    ring row from ONE start index a row, and hands on ``W`` rows. A
+    gather of ``W`` single rows (``rows_at``) makes the v5e convert the
+    whole slot-minor ring before it (``copy.4``: PERF.md section 6, PR
+    48); what the chip's compiler makes of this program is checked
+    there, this is what can be seen without it."""
+    from rdma_paxos_tpu.consensus.log import Log
+    from rdma_paxos_tpu.runtime.sim import ReplayFetch
+    cols = CFG.slot_words + META_W
+    log = Log(buf=jax.ShapeDtypeStruct(lead + (N, cols), jnp.int32))
+    starts = jax.ShapeDtypeStruct(lead, jnp.int32)
+    fetch = ReplayFetch(64, len(lead))
+    assert fetch.widths == (4, 16, 64)
+    for W, fn in fetch.programs.items():
+        jaxpr = jax.make_jaxpr(fn)(log, starts).jaxpr
+        seen = []
+        _walk(jaxpr, False, seen)
+        assert {p for _c, p, _i, _o in seen} == {"gather", "slice"}, seen
+        for _c, prim, ins, outs in seen:
+            assert ins == [lead + (N, cols)]
+            assert outs == [lead + (W, cols)], (W, prim, outs)
+        (g,) = [e for e in _eqns(jaxpr, "gather") if _is_ring(e.invars[0])]
+        # one (slot, column) start a ring row: W slots from there
+        assert g.params["slice_sizes"] == (1,) * len(lead) + (W, cols)
+        assert g.invars[1].aval.shape == lead + (2,)
