@@ -241,13 +241,16 @@ def rebase_offsets(state_b: ReplicaState, delta) -> ReplicaState:
     u64 byte offsets outlive any deployment (dare_log.h:77-103).
 
     Works on the vmap-batched state and (transparently, no collectives)
-    on a shard_map-sharded state: every operation is elementwise."""
+    on a shard_map-sharded state: every operation is elementwise.
+    ``delta`` is one number for all rows, or an array of the state's
+    leading shape with each row's own (the sharded engine's ``[G, R]``:
+    groups roll over independently)."""
     i32 = jnp.int32
     d = jnp.asarray(delta, i32)
     sw = state_b.log.slot_words
     gcol = sw + M_GIDX
     buf = state_b.log.buf
-    buf = buf.at[..., gcol].add(-d)
+    buf = buf.at[..., gcol].add(-d[..., None])
     return dataclasses.replace(
         state_b,
         log=Log(buf=buf),

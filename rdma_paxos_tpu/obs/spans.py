@@ -109,6 +109,11 @@ inside the optional fencing path):
   for in vain), ``fetch_rows_total`` (rows a replica each standalone
   replay fetch asked of the device: the static width it ran at; over
   ``replay_fetch``'s count it says which width serves),
+  ``input_put_calls_total`` and ``input_put_bytes_total`` (transfers
+  the engine's one put, ``runtime/sim.py`` ``make_put``, started for a
+  dispatch's arguments and its replay fetch's, and the bytes it handed
+  over: a ``jnp.asarray`` is one, a ``jax.device_put`` of a whole
+  tuple onto a mesh is one),
   ``phase_stalls_total`` and
   ``phase_stall_us_total{phase}`` (a phase instance longer than
   ``TimeoutConfig.elec_timeout_low``; each also leaves one
@@ -739,7 +744,8 @@ class StepPhaseProfiler:
                 "replay_reconnects_total", "pruned_slots_total",
                 "append_clamped_total", "ring_wraps_total",
                 "replay_requests_total", "replay_order_timeouts_total",
-                "fetch_rows_total")
+                "fetch_rows_total", "input_put_calls_total",
+                "input_put_bytes_total")
     # a thread waiting by design: its length counts towards no stall,
     # its own or of the phase it waits in
     WAITS = (PHASE_IDLE_WAIT, PHASE_PIPELINE_WAIT,

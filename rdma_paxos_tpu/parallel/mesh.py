@@ -73,11 +73,24 @@ def build_mesh_2d(group_shards: int, replicas: int,
                 (GROUP_AXIS, REPLICA_AXIS))
 
 
+def axes_spec(mesh: Mesh, lead: int = 0) -> P:
+    """``P`` of an array whose axes, after ``lead`` unsharded ones (a
+    burst's K), are the mesh's: ``P("replica")`` on a replica mesh,
+    ``P("group", "replica")`` on :func:`build_mesh_2d`'s. A mesh axis
+    of ONE device shards nothing, and jit leaves it out of the specs of
+    a program's outputs (``None``): it is left out here the same way,
+    so that an argument put with this spec and the state a program
+    handed back are ONE sharding, and one executable serves prewarm's
+    placed state and the served one."""
+    return P(*(None,) * lead,
+             *(n if mesh.shape[n] > 1 else None for n in mesh.axis_names))
+
+
 def group_sharding(mesh: Mesh):
     """The ``NamedSharding`` placing ``[group, replica, ...]`` state
     pytrees on a :func:`build_mesh_2d` mesh."""
     from jax.sharding import NamedSharding
-    return NamedSharding(mesh, P(GROUP_AXIS, REPLICA_AXIS))
+    return NamedSharding(mesh, axes_spec(mesh))
 
 
 def stack_states(cfg: LogConfig, n_replicas: int, group_size: int

@@ -28,7 +28,7 @@ from rdma_paxos_tpu.consensus.step import (
     SCAN_KEYS, StepInput, fetch_rows, unpack_scalars)
 from rdma_paxos_tpu.obs.spans import held
 from rdma_paxos_tpu.parallel.mesh import (
-    REPLICA_AXIS, build_sim_burst, build_sim_scan, build_sim_step,
+    axes_spec, build_sim_burst, build_sim_scan, build_sim_step,
     build_spmd_burst, build_spmd_scan, build_spmd_step, make_replica_mesh,
     stack_states)
 from rdma_paxos_tpu.runtime import hostpath
@@ -237,30 +237,51 @@ def decode_window(wm: np.ndarray, wd: np.ndarray, n: int,
         frames.append(batch.frames())
 
 
-def make_put(mesh):
-    """The cluster's ONE host-to-device put: ``put(arrays, stacked=0)``
+def make_put(cluster):
+    """The engine's ONE host-to-device put: ``put(arrays, stacked=0)``
     takes a tuple of host arrays, the first ``stacked`` of them a
-    burst's ``[K, R, ...]`` stacks and the rest ``[R, ...]`` rows, and
-    returns the device arguments in the same order.
+    burst's ``[K, ...]`` stacks and the rest rows led by the mesh's
+    axes (``[R, ...]``, or the sharded engine's ``[G, R, ...]``), and
+    returns the device arguments in the same order. Bound once, to
+    ``cluster.mesh`` as it stands; both engines' dispatches, prewarm
+    and replay fetch go through it.
 
-    Without a mesh it is ``jnp.asarray`` an array (every replica a vmap
+    Without a mesh it is ``jnp.asarray`` an array (every row a vmap
     row on the default device). With one, every array goes out in ONE
     ``jax.device_put`` with the sharding the ``build_spmd_*`` programs'
-    ``in_specs`` name for it (``P("replica")`` for rows, ``P(None,
-    "replica")`` for stacks), replica r's slice straight to chip r: an
-    argument put on one chip is split over the mesh INSIDE the call,
-    one array after the other, on the dispatch thread and under the
-    host lock (0.7-1.0 ms each on four chips: PERF.md, PR 46). The
-    slices are views of the caller's buffer and live by its rule (a
-    ticket keeps its staging buffers until ``finish``)."""
+    ``in_specs`` name for it (``parallel/mesh.py`` ``axes_spec``: the
+    mesh's axes lead a row, ``P("replica")`` or ``P("group",
+    "replica")``; a stack has ``None`` before them),
+    each slice straight to its chip: an argument put on one chip is
+    split over the mesh INSIDE the call, one array after the other, on
+    the dispatch thread and under the host lock (0.7-1.0 ms each on
+    four chips: PERF.md, PR 46). The slices are views of the caller's
+    buffer and live by its rule (a ticket keeps its staging buffers
+    until ``finish``).
+
+    Each call counts, where the engine has a profiler, the transfers
+    it started (``input_put_calls_total``: one a ``jnp.asarray``, one
+    a ``device_put`` whatever it carries) and the bytes it handed over
+    (``input_put_bytes_total``)."""
+    mesh = cluster.mesh
+
+    def count(arrays, calls):
+        prof = cluster.profiler
+        if prof is not None:
+            prof.count("input_put_calls_total", calls)
+            prof.count("input_put_bytes_total",
+                       sum(a.nbytes for a in arrays))
+
     if mesh is None:
-        return lambda arrays, stacked=0: tuple(
-            jnp.asarray(a) for a in arrays)
-    P = jax.sharding.PartitionSpec
-    rows = jax.sharding.NamedSharding(mesh, P(REPLICA_AXIS))
-    stacks = jax.sharding.NamedSharding(mesh, P(None, REPLICA_AXIS))
+        def put(arrays, stacked=0):
+            count(arrays, len(arrays))
+            return tuple(jnp.asarray(a) for a in arrays)
+        return put
+    rows = jax.sharding.NamedSharding(mesh, axes_spec(mesh))
+    stacks = jax.sharding.NamedSharding(mesh, axes_spec(mesh, 1))
 
     def put(arrays, stacked=0):
+        count(arrays, 1)
         return jax.device_put(
             arrays, (stacks,) * stacked + (rows,) * (len(arrays) - stacked))
     return put
@@ -546,7 +567,7 @@ class SimCluster:
             self._step = self._build_step(elections=True)
         # every argument of a dispatch and of the replay fetch goes to
         # the device through this (shardings built here, once)
-        self._put = make_put(self.mesh)
+        self._put = make_put(self)
         # all replicas' windows in ONE dispatch (the per-replica loop of
         # fetch+slice dispatches dominated the host replay path). The
         # REPLAY window is wider than the protocol window: a K-step

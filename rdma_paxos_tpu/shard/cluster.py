@@ -49,17 +49,15 @@ election timeouts.
 from __future__ import annotations
 
 import collections
-import dataclasses
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from rdma_paxos_tpu.config import LogConfig, REBASE_STALL_STEPS
 from rdma_paxos_tpu.consensus.log import (
-    EntryType, Log, M_CONN, M_GIDX, M_LEN, M_REQID, M_TYPE, META_W)
+    EntryType, M_CONN, M_GIDX, M_LEN, M_REQID, M_TYPE, META_W)
 from rdma_paxos_tpu.consensus.state import Role
 from rdma_paxos_tpu.consensus.step import StepInput
 from rdma_paxos_tpu.obs.spans import held
@@ -71,8 +69,8 @@ from rdma_paxos_tpu.parallel.mesh import (
 from rdma_paxos_tpu.runtime.hostpath import LazyReplayStream
 from rdma_paxos_tpu.runtime.sim import (
     STEP_CACHE, ReplayFetch, SimCluster, StagingPool, StepTicket, cap_tiers,
-    clamp_burst_take, count_ring, decode_window, pack_rows, read_scalars,
-    rebase_delta_of, requeue_shortfall, require_drained)
+    clamp_burst_take, count_ring, decode_window, make_put, pack_rows,
+    read_scalars, rebase_delta_of, requeue_shortfall, require_drained)
 from rdma_paxos_tpu.shard.router import KeyRouter
 
 TimeoutsLike = Union[None, Dict[int, Sequence[int]],
@@ -153,6 +151,11 @@ class ShardedCluster:
                     f"{shape[0]} group shards")
         self.mesh = mesh
         self._mode = "sim" if mesh is None else "spmd-group"
+        # SimCluster's put: every argument of a dispatch, of prewarm,
+        # of the replay fetch and of a rebase goes to the device through
+        # it, ``[G, R, ...]`` rows (and a burst's ``[K, G, R, ...]``
+        # stacks) split ``P(group, replica)`` where there is a mesh
+        self._put = make_put(self)
         # cache-key stand-in for the mesh: static device layout only —
         # deliberately independent of G, so clusters of ANY group
         # count on one mesh share compiled programs
@@ -495,36 +498,34 @@ class ShardedCluster:
         tiers are shared across groups by construction, and across
         clusters through the shared runtime cache."""
         cfg, G, R, B = self.cfg, self.G, self.R, self.cfg.batch_slots
-        inp = StepInput(
-            batch_data=jnp.zeros((G, R, B, cfg.slot_words), jnp.int32),
-            batch_meta=jnp.zeros((G, R, B, META_W), jnp.int32),
-            batch_count=jnp.zeros((G, R), jnp.int32),
-            timeout_fired=jnp.zeros((G, R), jnp.int32),
-            peer_mask=jnp.asarray(self.peer_mask),
-            apply_done=jnp.zeros((G, R), jnp.int32),
-            queue_depth=jnp.zeros((G, R), jnp.int32),
-            **(dict(txn_watch=jnp.full((G, R), -1, jnp.int32),
-                    txn_term=jnp.zeros((G, R), jnp.int32))
-               if self._txn else {}))
+        # through the dispatches' own put: a committed, sharded argument
+        # and an uncommitted one-chip argument are two executables of
+        # one ``jax.jit`` (see SimCluster.prewarm)
+        row = np.zeros((G, R), np.int32)
+        inp = StepInput(*self._put(
+            (np.zeros((G, R, B, cfg.slot_words), np.int32),
+             np.zeros((G, R, B, META_W), np.int32),
+             row, row, self.peer_mask, row, row)
+            + ((np.full((G, R), -1, np.int32), row)
+               if self._txn else ())))
         for elections in (True, False):
             fn, _ = self._build_step(elections=elections)
             st = jax.tree.map(lambda x: x.copy(), self.state)
             fn(st, inp)
-        pm = jnp.asarray(self.peer_mask)
-        ap = jnp.zeros((G, R), jnp.int32)
         for K in (tiers if tiers is not None else self.K_TIERS):
             fns = [self._burst_fn(K)]
             if self.scan:
                 fns.append(self._scan_fn(K))
+            args = self._put(
+                (np.zeros((K, G, R, B, cfg.slot_words), np.int32),
+                 np.zeros((K, G, R, B, META_W), np.int32),
+                 np.zeros((K, G, R), np.int32), self.peer_mask, row,
+                 row), stacked=3)
             for fn, _ in fns:
                 st = jax.tree.map(lambda x: x.copy(), self.state)
-                fn(st,
-                   jnp.zeros((K, G, R, B, cfg.slot_words), jnp.int32),
-                   jnp.zeros((K, G, R, B, META_W), jnp.int32),
-                   jnp.zeros((K, G, R), jnp.int32), pm, ap,
-                   jnp.zeros((G, R), jnp.int32))
+                fn(st, *args)
         # and the replay fetch at every width (SimCluster.prewarm)
-        self._replay_fetch.warm(self.state.log, ap)
+        self._replay_fetch.warm(self.state.log, *self._put((row,)))
 
     def begin_step(self, timeouts: TimeoutsLike = (),
                    take_batch: bool = True) -> StepTicket:
@@ -571,27 +572,20 @@ class ShardedCluster:
                 tmo_arr[g, r] = 1
         if prof is not None:
             prof.start("input_transfer")
-        inp = StepInput(
-            batch_data=jnp.asarray(bufs["data"]),
-            batch_meta=jnp.asarray(bufs["meta"]),
-            batch_count=jnp.asarray(count),
-            timeout_fired=jnp.asarray(tmo_arr),
-            peer_mask=jnp.asarray(mask),
-            apply_done=jnp.asarray(applied),
-            queue_depth=jnp.asarray(qdepth),
-            **(dict(
-                # device watches compare log offsets: shift each armed
-                # ABSOLUTE index by that group's i32 rollovers, then
-                # broadcast across the replica axis
-                txn_watch=jnp.asarray(np.broadcast_to(
+        leaves = (bufs["data"], bufs["meta"], count, tmo_arr, mask,
+                  applied, qdepth)
+        if self._txn:
+            # device watches compare log offsets: shift each armed
+            # ABSOLUTE index by that group's i32 rollovers, then
+            # broadcast across the replica axis
+            leaves += (
+                np.broadcast_to(
                     np.where(self._txn_watch >= 0,
                              self._txn_watch - self.rebased_total,
-                             -1)[:, None], (G, R)).astype(np.int32)),
-                txn_term=jnp.asarray(np.broadcast_to(
-                    self._txn_wterm[:, None],
-                    (G, R)).astype(np.int32)),
-            ) if self._txn else {}),
-        )
+                             -1)[:, None], (G, R)).astype(np.int32),
+                np.broadcast_to(self._txn_wterm[:, None],
+                                (G, R)).astype(np.int32))
+        inp = StepInput(*self._put(leaves))     # in field order
         if prof is not None:
             prof.stop("input_transfer")
         # no timer fired in ANY group ⟹ Phase B is provably a no-op
@@ -684,9 +678,8 @@ class ShardedCluster:
             prof.stop("host_encode")
             prof.start("device_dispatch")
             prof.start("input_transfer")
-        args = (jnp.asarray(bufs["data"]), jnp.asarray(bufs["meta"]),
-                jnp.asarray(count), jnp.asarray(mask),
-                jnp.asarray(applied), jnp.asarray(qdepth))
+        args = self._put((bufs["data"], bufs["meta"], count, mask,
+                          applied, qdepth), stacked=3)
         if prof is not None:
             prof.stop("input_transfer")
         with held(prof, self._host_lock, "dispatch_lock_wait"):
@@ -952,7 +945,7 @@ class ShardedCluster:
                     and self.applied[g, r] < int(res["commit"][g, r])]
             if not todo:
                 break
-            starts = jnp.asarray(self.applied.astype(np.int32))
+            starts, = self._put((self.applied.astype(np.int32),))
             need = max(int(res["commit"][g, r] - self.applied[g, r])
                        for g, r in todo)
             prof = self.profiler
@@ -1045,15 +1038,15 @@ class ShardedCluster:
         if not deltas.any():
             return
         self._apply_rebase(deltas)
+        # rebound, not written in place: the packed row's views are
+        # read-only (as SimCluster does). audit_start is an index too
+        # (the ledger already ingested pre-rollover)
+        for k in ("head", "apply", "commit", "end", "audit_start"):
+            if k in res:
+                res[k] = res[k] - deltas[:, None].astype(res[k].dtype)
         for g in np.nonzero(deltas)[0]:
             d = int(deltas[g])
             self.applied[g] -= d
-            for k in ("head", "apply", "commit", "end"):
-                res[k][g] = res[k][g] - d
-            # keep the returned dict self-consistent: audit_start is
-            # an index too (the ledger already ingested pre-rollover)
-            if "audit_start" in res:
-                res["audit_start"][g] = res["audit_start"][g] - d
             self.rebases[g] += 1
             self.rebased_total[g] += d
             self.rebase_stall_steps[g] = 0
@@ -1071,26 +1064,18 @@ class ShardedCluster:
         """Elementwise per-group offset subtraction — the grouped form
         of ``consensus.snapshot.rebase_offsets`` (same invariants:
         delta <= that group's min head, multiple of n_slots). Called
-        from ``_maybe_rebase`` under the host lock."""
-        state = self.state
-        d_gr = jnp.asarray(deltas.astype(np.int32))[:, None]   # [G, 1]
-        d_buf = d_gr[:, :, None]                               # [G, 1, 1]
-        sw = state.log.slot_words
-        gcol = sw + M_GIDX
-        buf = state.log.buf.at[..., gcol].add(-d_buf)
-        self.state = dataclasses.replace(
-            state,
-            log=Log(buf=buf),
-            head=state.head - d_gr,
-            apply=state.apply - d_gr,
-            commit=state.commit - d_gr,
-            end=state.end - d_gr,
-            cfg_src=jnp.where(state.cfg_src >= 0,
-                              state.cfg_src - d_gr, state.cfg_src),
-        )
+        from ``_maybe_rebase`` under the host lock. That one program
+        over the state where it lies: the deltas go out through the
+        put, a group's in each of its rows, so that nothing moves
+        between chips (eager operations would put their constants on
+        one chip and spread them over the mesh)."""
+        from rdma_paxos_tpu.consensus.snapshot import rebase_offsets
+        d_gr, = self._put((np.broadcast_to(
+            deltas.astype(np.int32)[:, None], (self.G, self.R)),))
+        self.state = rebase_offsets(self.state, d_gr)
         if self.mesh is not None:
-            # the eager elementwise pass may leave drifted shardings;
-            # re-place so the next donated dispatch pays no reshard
+            # the program's outputs follow its inputs; re-place all the
+            # same so the next donated dispatch can pay no reshard
             # (rebases are rare — deferred until the pipeline drains)
             self.state = jax.device_put(self.state,
                                         group_sharding(self.mesh))

@@ -25,7 +25,8 @@ from rdma_paxos_tpu.runtime.driver import ClusterDriver
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 METRIC_FILES = sorted(glob.glob(
     os.path.join(ROOT, "perfbench", "metrics", "*.json")))
-CLUSTER_KIND = "interposed_app_cluster"
+# the kinds served by a ``ShardedClusterDriver``
+CLUSTER_KINDS = ("interposed_app_cluster", "interposed_app_cluster_mesh")
 
 
 def files_by_kind():
@@ -39,7 +40,7 @@ def files_by_kind():
         with open(os.path.join(ROOT, cfg["file"])) as f:
             kind_of[cfg["name"]] = json.load(f)["deployment"]
     clustered = {w["name"] for w in bench["workloads"]
-                 if kind_of[w["config"]] == CLUSTER_KIND}
+                 if kind_of[w["config"]] in CLUSTER_KINDS}
     lists = {m["name"]: set(m.get("workloads", ()))
              for m in bench["end_to_end"] + bench["per_layer"]}
     cluster, single = [], []

@@ -10,6 +10,7 @@ field, to the plain reference
 The client contract the driver's module text states is kept: every
 writer of a key reaches it through its group's leader's app."""
 
+import contextlib
 import subprocess
 import threading
 import time
@@ -31,15 +32,18 @@ CFG = LogConfig(n_slots=1024, slot_bytes=512, window_slots=64,
 RECORDS = 30
 
 
-@pytest.fixture()
-def cluster(tmp_path):
+@contextlib.contextmanager
+def served_cluster(workdir, **kw):
+    """-> (driver, ports): three apps under the shim behind a running
+    ``ShardedClusterDriver(CFG, 3, 3, fanout="psum", **kw)``, group g
+    led by replica g."""
     subprocess.run(["make", "-C", NATIVE], check=True, capture_output=True)
     ports = free_ports(R)
     apps, driver = [], None
     try:
-        driver = ShardedClusterDriver(CFG, R, G, workdir=str(tmp_path),
-                                      app_ports=ports, fanout="psum")
-        spawn_apps(apps, ports, tmp_path)
+        driver = ShardedClusterDriver(CFG, R, G, workdir=str(workdir),
+                                      app_ports=ports, fanout="psum", **kw)
+        spawn_apps(apps, ports, workdir)
         assert driver.cluster.place_leaders("round_robin") == [0, 1, 2]
         driver.run(period=0.002)
         deadline = time.time() + 120
@@ -55,6 +59,12 @@ def cluster(tmp_path):
             a.wait()
 
 
+@pytest.fixture()
+def cluster(tmp_path):
+    with served_cluster(tmp_path) as up:
+        yield up
+
+
 def group_of(driver, key: bytes) -> int:
     return driver.router.group_of(key_prefix_of(b"HGETALL " + key))
 
@@ -64,9 +74,11 @@ def key_in_group(driver, stem: bytes, g: int) -> bytes:
                 if group_of(driver, k) == g)
 
 
-@pytest.mark.parametrize("case", ["hot_group", "thread_per_group"])
-def test_three_apps_hold_what_the_reference_admits(cluster, case):
-    driver, ports = cluster
+def serve_mix(driver, ports, case: str) -> dict:
+    """The load and then the mix, every operation through the app of
+    the replica that leads its key's group; -> what was acknowledged
+    (``writes``, ``reads``), the routing ``table``, ``keys`` and the
+    completions by group."""
     leaders = driver.leaders()
     table = {key_of(rec): group_of(driver, key_of(rec))
              for rec in range(RECORDS)}
@@ -140,13 +152,18 @@ def test_three_apps_hold_what_the_reference_admits(cluster, case):
     assert driver.leaders() == leaders and driver.loop_error is None
     n_ops = RECORDS + per_thread * mix["connections"]
     assert sum(done_by_group) == n_ops and all(done_by_group)
-    if case == "hot_group":
-        hot = table[key_of(keys.by_rank[0])]
-        assert done_by_group[hot] > n_ops / 2, (hot, done_by_group)
+    return dict(table=table, keys=keys, writes=writes, reads=reads,
+                done_by_group=done_by_group, n_ops=n_ops)
 
-    # a marker through EACH group's leader's app: an app that shows
-    # group g's has replayed everything g's log holds before it. The
-    # connection's first key pins it to the group it is to ride
+
+def apps_against_reference(driver, ports, served: dict) -> list:
+    """A marker through EACH group's leader's app, seen on every app
+    (an app that shows group g's has replayed everything g's log holds
+    before it; the connection's first key pins it to the group it is
+    to ride); then every app's ``COUNT`` and records, held to the plain
+    reference of what was acknowledged: -> an app a row,
+    ``dict(count, faults (by group), records)``."""
+    leaders, table = driver.leaders(), served["table"]
     conns = [Client(p) for p in ports]
     markers = [key_in_group(driver, b"marker", g) for g in range(G)]
     for g in range(G):
@@ -159,20 +176,37 @@ def test_three_apps_hold_what_the_reference_admits(cluster, case):
         time.sleep(0.05)
     assert not behind
 
-    regs = ClusterRegisters(writes, table, G)
+    regs = ClusterRegisters(served["writes"], table, G)
     assert sum(regs.records_per_group()) == RECORDS and not regs.strays
-    assert reads and writes
-    for r in reads:
+    assert served["reads"] and served["writes"]
+    for r in served["reads"]:
         assert regs.read_faults(r) == []
-    answers = []
+    apps = []
     for conn in conns:
-        assert int(conn.ask(b"COUNT\n")) == RECORDS + G     # the markers
         recs = {key: conn.ask(b"HGETALL %s\n" % key) for key in table}
-        faults = regs.app_faults({k: ref.parse_record(v)
-                                  for k, v in recs.items()})
-        assert faults == [[] for _ in range(G)]
-        answers.append(recs)
-    assert all(a == answers[0] for a in answers)        # all three alike
+        apps.append(dict(
+            count=int(conn.ask(b"COUNT\n")) - G,       # the markers
+            faults=regs.app_faults({k: ref.parse_record(v)
+                                    for k, v in recs.items()}),
+            records=recs))
+    return apps
+
+
+@pytest.mark.parametrize("case", ["hot_group", "thread_per_group"])
+def test_three_apps_hold_what_the_reference_admits(cluster, case):
+    driver, ports = cluster
+    served = serve_mix(driver, ports, case)
+    done_by_group = served["done_by_group"]
+    if case == "hot_group":
+        hot = served["table"][key_of(served["keys"].by_rank[0])]
+        assert done_by_group[hot] > served["n_ops"] / 2, (
+            hot, done_by_group)
+
+    apps = apps_against_reference(driver, ports, served)
+    for app in apps:
+        assert app["count"] == RECORDS
+        assert app["faults"] == [[] for _ in range(G)]
+    assert all(a["records"] == apps[0]["records"] for a in apps)
 
     counters = driver.obs.metrics.snapshot()["counters"]
     # every replica's app followed two groups, none out of order
