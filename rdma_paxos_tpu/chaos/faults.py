@@ -235,15 +235,14 @@ def corrupt_slot(cluster, r: int, g_idx: int, *,
     be on the drained serial path."""
     import dataclasses as _dc
 
-    from rdma_paxos_tpu.consensus.log import Log as _Log
-
     slot = int(g_idx) & (cluster.cfg.n_slots - 1)
     buf = cluster.state.log.buf
     if group is None:
         buf = buf.at[int(r), slot, int(word)].add(1)
     else:
         buf = buf.at[int(group), int(r), slot, int(word)].add(1)
-    cluster.state = _dc.replace(cluster.state, log=_Log(buf=buf))
+    cluster.state = _dc.replace(
+        cluster.state, log=_dc.replace(cluster.state.log, buf=buf))
 
 
 def crash_replica(cluster, r: int, link: LinkModel) -> None:

@@ -8,12 +8,22 @@ rules (``dare_log.h:466-558``).
 
 TPU-native redesign (NOT a translation):
 
-* **Slot-based ring, SoA layout.** Fixed-size slots; payload lives in an
-  ``[n_slots, slot_words] int32`` array, per-entry metadata in an
-  ``[n_slots, META_W] int32`` array (struct-of-arrays — XLA/VPU-friendly,
-  where the reference packs variable-size structs into a byte buffer).
-  Oversize payloads are fragmented by the proxy into consecutive SEND
-  entries, which is semantically lossless for stream replay.
+* **Slot-based ring, one fused row a slot.** Fixed-size slots; a slot is
+  ONE ``int32`` row of ONE ``[n_slots, row_words]`` array: ``slot_words``
+  of payload, then the ``META_W`` columns of framing metadata, then zero
+  padding up to the next multiple of 128 words (:func:`row_words`; the
+  reference packs variable-size structs into a byte buffer). One array,
+  so that every ring gather / scatter of the hot path touches one; a
+  multiple of the TPU's 128 lanes, so that row-major is the layout the
+  runtime RESTS the ring in and the step, which reads and writes rows,
+  compiles no layout copy of it (two of them, each over the whole ring,
+  were 60% of the step at 136 columns: PERF.md section 6, PR 50). The
+  pad is on the device only: whatever leaves it (the replay fetch, a
+  snapshot's or an exported row's ``log_buf``, a digest) carries the
+  LIVE columns, ``slot_words + META_W`` (:func:`live_rows`), and
+  :func:`pad_rows` puts the pad back at install. Oversize payloads are
+  fragmented by the proxy into consecutive SEND entries, which is
+  semantically lossless for stream replay.
 * **Global monotone indices.** ``head/apply/commit/end`` are monotonically
   increasing int32 *entry* indices; the slot of global index ``g`` is
   ``g % n_slots``. The reference's wrap-around entry-splitting machinery
@@ -38,6 +48,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from rdma_paxos_tpu.config import LogConfig
 
@@ -89,58 +100,95 @@ M_TYPE, M_TERM, M_CONN, M_REQID, M_LEN, M_GIDX = 0, 1, 2, 3, 4, 5
 # counter can overflow into misclassification.
 M_GEN = 6
 META_W = 8  # padded for alignment
+# a ring row is padded to a multiple of the TPU's lane count, in words
+ROW_ALIGN = 128
+
+
+def row_words(slot_words: int) -> int:
+    """Width of a ring row: ``slot_words + META_W`` rounded UP to a
+    multiple of :data:`ROW_ALIGN` (nothing is added where it already
+    is one)."""
+    return -(-(slot_words + META_W) // ROW_ALIGN) * ROW_ALIGN
+
+
+def live_slot_words(rows) -> int:
+    """``slot_words`` of rows in the LIVE format (``[..., slot_words +
+    META_W]``: fetched windows, digest inputs, a ``log_buf`` on the
+    host). A ring row's own width says nothing of it: ask the
+    :class:`Log`."""
+    return rows.shape[-1] - META_W
+
+
+def live_rows(rows, slot_words: int):
+    """The live columns (payload, then metadata) of rows read out of
+    the ring: taken from what was gathered, never from the ring."""
+    return rows[..., :slot_words + META_W]
+
+
+def pad_rows(live: np.ndarray) -> np.ndarray:
+    """Host rows in the live format, zero-padded to the ring's width:
+    what installs a ``log_buf`` that travelled without its pad."""
+    pad = -live.shape[-1] % ROW_ALIGN
+    if not pad:
+        return live
+    return np.pad(live, [(0, 0)] * (live.ndim - 1) + [(0, pad)])
 
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class Log:
-    """Per-replica log. Payload words and framing metadata live FUSED in
-    one ``[n_slots, slot_words + META_W]`` array so every ring gather /
-    scatter in the replication hot path touches a single array (the
-    dominant step cost scales with the number of these ops, measured ~2x
-    win over separate data/meta arrays).
+    """Per-replica log: one ``[n_slots, row_words(slot_words)]`` array,
+    a row ``[payload | metadata | zero pad]`` (module docstring).
 
-    Device code reads ROWS FIRST (:func:`rows_at`, :func:`extract_window`:
-    index ``buf`` by slot, then take the columns of what was gathered).
-    ``data`` / ``meta`` are column views of the WHOLE ring and on a device
-    they are not free: the v5e does not fuse such a slice into the gather
-    that follows it, it materialises ``[n_slots, 8 | slot_words]`` for
-    every replica, 0.6 ms a view and step at 3 x 131072 slots even where
-    ONE row of it is read (four of them were half of a 9.6 ms dispatch;
-    PERF.md section 6, PR 30). They stay for host-side callers, tests, and
-    the step's config rescan, which must see every slot and runs only on
-    a step where a cached config source was invalidated."""
+    Device code reads ROWS FIRST (:func:`rows_at`, :func:`extract_window`,
+    :func:`window_rows`: index ``buf`` by slot, then take the columns of
+    what was gathered) and there is no column view of the ring to reach
+    for: on the v5e such a view is not fused into the gather that
+    follows it, it materialises ``[n_slots, columns]`` for every replica
+    (four of them were half of a 9.6 ms dispatch; PERF.md section 6, PR
+    30). The one reader of every slot, the step's config rescan, which
+    runs only where a cached config source was invalidated, has
+    :func:`ring_meta`.
 
-    buf: jax.Array    # [..., n_slots, slot_words + META_W] int32
+    ``slot_words`` is static (part of the tree's structure, not a
+    leaf): the padded width does not say where the metadata starts."""
 
-    # Shape/view properties are axis-agnostic: they work both on a single
-    # replica's [n_slots, cols] buf and on batched [R, n_slots, cols] state
-    # (vmap/stacked), so callers never hand-compute fused-layout offsets.
+    buf: jax.Array    # [..., n_slots, row_words(slot_words)] int32
+    slot_words: int = dataclasses.field(metadata=dict(static=True))
 
+    # axis-agnostic: a single replica's [n_slots, cols] buf or batched
+    # [R, n_slots, cols] state (vmap/stacked)
     @property
     def n_slots(self) -> int:
         return self.buf.shape[-2]
 
-    @property
-    def slot_words(self) -> int:
-        return self.buf.shape[-1] - META_W
-
-    @property
-    def data(self) -> jax.Array:   # [..., n_slots, slot_words]
-        return self.buf[..., :self.slot_words]
-
-    @property
-    def meta(self) -> jax.Array:   # [..., n_slots, META_W]
-        return self.buf[..., self.slot_words:]
-
 
 def make_log(cfg: LogConfig) -> Log:
-    return Log(buf=jnp.zeros((cfg.n_slots, cfg.slot_words + META_W),
-                             jnp.int32))
+    return Log(buf=jnp.zeros((cfg.n_slots, row_words(cfg.slot_words)),
+                             jnp.int32),
+               slot_words=cfg.slot_words)
 
 
 def _fuse(data: jax.Array, meta: jax.Array) -> jax.Array:
-    return jnp.concatenate([data, meta], axis=-1)
+    """``[N, slot_words]`` and ``[N, META_W]`` as ring rows, the pad
+    written as zeros with them (so it stays zero)."""
+    pad = row_words(data.shape[-1]) - data.shape[-1] - META_W
+    parts = [data, meta]
+    if pad:
+        parts.append(jnp.zeros(data.shape[:-1] + (pad,), data.dtype))
+    return jnp.concatenate(parts, axis=-1)
+
+
+def _split(rows: jax.Array, slot_words: int):
+    """``(data, meta)`` of rows gathered from the ring."""
+    return (rows[..., :slot_words],
+            rows[..., slot_words:slot_words + META_W])
+
+
+def ring_meta(log: Log) -> jax.Array:
+    """The metadata columns of EVERY slot, ``[n_slots, META_W]``: a
+    ring-sized read, for the config rescan's taken branch alone."""
+    return _split(log.buf, log.slot_words)[1]
 
 
 def slot_of(g: jax.Array, n_slots: int) -> jax.Array:
@@ -153,8 +201,7 @@ def rows_at(log: Log, g: jax.Array) -> Tuple[jax.Array, jax.Array]:
     or ``[N]``): ONE gather of ``buf`` by row, the columns taken from
     what was gathered — O(rows read) of the ring, never a view of it.
     The read every device-side caller goes through."""
-    w = log.buf[slot_of(g, log.n_slots)]
-    return w[..., :log.slot_words], w[..., log.slot_words:]
+    return _split(log.buf[slot_of(g, log.n_slots)], log.slot_words)
 
 
 def last_term(log: Log, end: jax.Array) -> jax.Array:
@@ -207,7 +254,7 @@ def append_batch(
     meta = batch_meta.at[:, M_TERM].set(term)
     meta = meta.at[:, M_GIDX].set(end + offs)
     new_buf = log.buf.at[idx].set(_fuse(batch_data, meta), mode="drop")
-    return Log(new_buf), end + n
+    return dataclasses.replace(log, buf=new_buf), end + n
 
 
 # ---------------------------------------------------------------------------
@@ -231,26 +278,27 @@ def extract_window(
 def window_rows(
     log: Log, start: jax.Array, window_slots: int
 ) -> jax.Array:
-    """:func:`extract_window`'s rows, bit for bit, FUSED as the ring
-    stores them (``[W, slot_words + META_W]``) and read as SLICES: a
-    window is contiguous in the ring but for one wrap, so it is the
-    ``window_slots`` slots from ``start``'s (or, within a window of the
-    ring's end, the ring's last ``window_slots``), then the ring's
-    first ``window_slots`` where it wraps, and of the two laid end to
-    end the ``window_slots`` rows from ``start``'s place among them.
-    For a reader OUTSIDE the step (the replay fetch), which holds the
-    ring as the device rests it: a gather by row makes the v5e convert
-    the whole slot-minor ring first (``copy.4``, 1-3 ms a fetch), a
-    slice of consecutive slots is read where it lies (PERF.md section
-    6, PR 48). The step keeps :func:`extract_window`: its ring is
-    converted for its scatters anyway."""
+    """:func:`extract_window`'s rows, bit for bit, FUSED and in the
+    LIVE format (``[W, slot_words + META_W]``: the pad stays on the
+    device) and read as SLICES: a window is contiguous in the ring but
+    for one wrap, so it is the ``window_slots`` slots from ``start``'s
+    (or, within a window of the ring's end, the ring's last
+    ``window_slots``), then the ring's first ``window_slots`` where it
+    wraps, and of the two laid end to end the ``window_slots`` rows
+    from ``start``'s place among them. For a reader OUTSIDE the step
+    (the replay fetch): whole rows are sliced out of the ring where it
+    lies, whatever layout the device rests it in (a gather by row made
+    the v5e convert the whole ring first while it rested slot-minor:
+    PERF.md section 6, PR 48), and the live columns are taken from the
+    rows that were sliced."""
     n_slots, W = log.n_slots, window_slots
     s = slot_of(start, n_slots)
     at = jnp.minimum(s, n_slots - W)
     cols = log.buf.shape[-1]
     tail = jax.lax.dynamic_slice(log.buf, (at, 0), (W, cols))
     both = jnp.concatenate([tail, log.buf[:W]], axis=0)
-    return jax.lax.dynamic_slice(both, (s - at, 0), (W, cols))
+    return live_rows(jax.lax.dynamic_slice(both, (s - at, 0), (W, cols)),
+                     log.slot_words)
 
 
 def absorb_window(
@@ -311,4 +359,4 @@ def absorb_window(
         jnp.where(any_conflict, wend, jnp.maximum(my_end, wend)),
         my_end,
     ).astype(jnp.int32)
-    return Log(new_buf), new_end
+    return dataclasses.replace(log, buf=new_buf), new_end

@@ -26,7 +26,7 @@ import numpy as np
 
 from rdma_paxos_tpu.config import DIGEST_EPOCH
 from rdma_paxos_tpu.consensus.log import (
-    Log, M_GIDX, M_TERM, META_W, slot_of)
+    M_GIDX, M_TERM, live_rows, live_slot_words, slot_of)
 from rdma_paxos_tpu.consensus.state import ReplicaState
 from rdma_paxos_tpu.consensus.step import digest_fold
 from rdma_paxos_tpu.obs import trace as obs_trace
@@ -96,9 +96,10 @@ def take_snapshot(state_b: ReplicaState, donor: int,
                   rebased_total: int = 0) -> Snapshot:
     """Capture a snapshot from replica ``donor`` of a batched state.
 
-    Batched state carries the fused log as ``buf[R, n_slots, slot_words +
-    META_W]`` (``[G, R, ...]`` with ``group``); the determinant term of
-    entry ``apply-1`` lives at ``buf[..., slot, slot_words + M_TERM]``.
+    Batched state carries the fused log as ``buf[R, n_slots,
+    row_words]`` (``[G, R, ...]`` with ``group``; ``consensus/log.py``);
+    the determinant term of entry ``apply-1`` lives at ``buf[..., slot,
+    slot_words + M_TERM]``.
 
     ``index`` overrides the determinant index: pass the donor's HOST
     apply counter when the accompanying ``store_blob`` was produced by
@@ -127,10 +128,11 @@ def take_snapshot(state_b: ReplicaState, donor: int,
         term = int(log.buf[idx + (slot, log.slot_words + M_TERM)])
     digest_epoch, a_start, a_dig = 0, -1, None
     if digests:
-        # one device->host pull of the donor's fused row; the digest
-        # chain is host-computed with the SHARED fold (xp=numpy)
-        buf_np = np.asarray(log.buf[idx])
-        sw = buf_np.shape[-1] - META_W
+        # one device->host pull of the donor's fused row, its live
+        # columns; the digest chain is host-computed with the SHARED
+        # fold (xp=numpy)
+        sw = log.slot_words
+        buf_np = np.asarray(live_rows(log.buf[idx], sw))
         n_slots = buf_np.shape[0]
         lo = max(int(np.asarray(state_b.head[idx])), 0)
         slots = (np.arange(lo, apply_) & (n_slots - 1)
@@ -183,7 +185,7 @@ def _install_body(state_b: ReplicaState, idx, index, term, cur_term,
     anchor = slot_of(jnp.maximum(index - 1, 0), n_slots)
     buf = buf.at[idx + (anchor, slot_words + M_TERM)].set(
         jnp.where(index > 0, term, 0).astype(i32))
-    log = Log(buf=buf)
+    log = dataclasses.replace(state_b.log, buf=buf)
     bm_old_u = bm_old.astype(jnp.uint32)
     bm_new_u = bm_new.astype(jnp.uint32)
     sets = dict(head=index, apply=index, commit=index, end=index,
@@ -253,7 +255,7 @@ def rebase_offsets(state_b: ReplicaState, delta) -> ReplicaState:
     buf = buf.at[..., gcol].add(-d[..., None])
     return dataclasses.replace(
         state_b,
-        log=Log(buf=buf),
+        log=dataclasses.replace(state_b.log, buf=buf),
         head=state_b.head - d,
         apply=state_b.apply - d,
         commit=state_b.commit - d,
@@ -268,8 +270,11 @@ def export_row(state_b: ReplicaState, r: int) -> dict:
     unit of cross-generation recovery (the analog of the joiner
     RDMA-reading the donor's snapshot buffer AND log tail in one shot,
     ``rc_recover_sm`` + ``rc_recover_log``, ``dare_ibv_rc.c:603-856``).
-    Keys are ReplicaState field names; the log travels as ``log_buf``."""
-    out = {"log_buf": np.asarray(state_b.log.buf[r])}
+    Keys are ReplicaState field names; the log travels as ``log_buf``,
+    the ring's LIVE columns (``[n_slots, slot_words + META_W]``: the pad
+    is the device's, ``log.pad_rows`` puts it back at install)."""
+    out = {"log_buf": np.asarray(
+        live_rows(state_b.log.buf[r], state_b.log.slot_words))}
     for f in dataclasses.fields(ReplicaState):
         if f.name == "log":
             continue
@@ -307,8 +312,7 @@ def genesis_row(donor_row: dict, *, group_mask: int, epoch: int,
 
     row = {k: np.array(v, copy=True) for k, v in donor_row.items()}
     buf = row["log_buf"]
-    slot_words = buf.shape[-1] - META_W
-    types = buf[:, slot_words + M_TYPE]
+    types = buf[:, live_slot_words(buf) + M_TYPE]
     types[types == int(EntryType.CONFIG)] = int(EntryType.NOOP)
     new_term = (int(row["term"]) if term is None else int(term)) + 1
     i32, u32 = np.int32, np.uint32

@@ -69,8 +69,8 @@ from jax import lax
 from rdma_paxos_tpu.config import LogConfig
 from rdma_paxos_tpu.consensus.log import (
     EntryType, Log, M_GIDX, M_TERM, M_TYPE, META_W,
-    append_batch, absorb_window, extract_window, last_term, rows_at,
-    slot_of, window_rows,
+    append_batch, absorb_window, extract_window, last_term, live_rows,
+    live_slot_words, ring_meta, rows_at, slot_of, window_rows,
 )
 from rdma_paxos_tpu.consensus.state import ConfigState, ReplicaState, Role
 from rdma_paxos_tpu.ops.quorum import R_PAD, commit_scan
@@ -228,12 +228,14 @@ def digest_fold(rows, *, xp=jnp):
     digests can never drift. The layout version is
     ``config.DIGEST_EPOCH``; bump it whenever this fold changes.
 
-    ``rows``: ``[N, slot_words + META_W]`` u32 (jnp or numpy — both
-    wrap u32 arithmetic identically)."""
+    ``rows``: ``[N, slot_words + META_W]`` u32, the LIVE columns of
+    ring rows (``log.live_rows``: the ring's zero pad is no part of an
+    entry and of no digest) (jnp or numpy — both wrap u32 arithmetic
+    identically)."""
     u32 = xp.uint32
     prime = u32(0x01000193)                     # FNV-1a prime
     acc = xp.full((rows.shape[0],), 0x811C9DC5, u32)   # FNV offset basis
-    gidx_col = rows.shape[1] - META_W + M_GIDX
+    gidx_col = live_slot_words(rows) + M_GIDX
     for c in range(rows.shape[1]):
         if c == gidx_col:
             continue
@@ -272,7 +274,7 @@ def build_redigest(cfg: LogConfig, *, window_slots: int):
 
     def fn(buf_row, start):
         g = start + jnp.arange(W, dtype=i32)
-        rows = buf_row[slot_of(g, cfg.n_slots)]
+        rows = live_rows(buf_row[slot_of(g, cfg.n_slots)], sw)
         dig = digest_fold(rows.astype(u32))
         return dig, rows[:, sw + M_TERM].astype(i32), rows[:, sw + M_GIDX]
     return jax.jit(fn)
@@ -726,9 +728,9 @@ def replica_step(
 
         def _cfg_rescan(_):
             # the one place that must see every slot: a column view of the
-            # whole ring (Log.meta) is what it costs; the row found is
-            # then read as a row
-            all_meta = log3.meta
+            # whole ring (log.ring_meta) is what it costs; the row found
+            # is then read as a row
+            all_meta = ring_meta(log3)
             all_gidx = all_meta[:, M_GIDX]
             live = ((all_meta[:, M_TYPE] == int(EntryType.CONFIG))
                     & (all_gidx >= head1) & (all_gidx < end3))
@@ -916,7 +918,8 @@ def replica_step(
             a_g = (commit2 - W) + jnp.arange(W, dtype=i32)
             audit_start = jnp.maximum(jnp.maximum(commit2 - W, head2), 0)
             a_valid = a_g >= audit_start
-            a_rows = log3.buf[slot_of(a_g, cfg.n_slots)].astype(u32)
+            a_rows = live_rows(log3.buf[slot_of(a_g, cfg.n_slots)],
+                               cfg.slot_words).astype(u32)
             # the fold lives in digest_fold — shared with the range
             # re-digest program and the host-side snapshot verification,
             # so no digest producer can drift from another
@@ -1201,9 +1204,9 @@ def unpack_scalars(rows: np.ndarray) -> Dict[str, np.ndarray]:
 
 
 def fetch_rows(log: Log, start: jax.Array, *, window_slots: int):
-    """Host helper: the ``window_slots`` entries beginning at ``start``
-    as the ring stores them (``[W, slot_words + META_W]``: payload
-    words, then the framing metadata) — what the driver reads newly
+    """Host helper: the ``window_slots`` entries beginning at ``start``,
+    fused (``[W, slot_words + META_W]``: payload words, then the
+    framing metadata; the ring's pad stays on the device) — what the driver reads newly
     committed payloads for replay/persist through (the analog of
     apply_committed_entries walking the log,
     ``dare_server.c:1815-1974``). ONE array, because every array a

@@ -352,7 +352,7 @@ def build_spmd_group_scan(cfg: LogConfig, n_replicas: int, mesh: Mesh,
     host bookkeeping expects — same host code as the vmap engine."""
     import jax.numpy as jnp
     from jax import lax
-    from rdma_paxos_tpu.consensus.log import Log, extract_window
+    from rdma_paxos_tpu.consensus.log import extract_window
 
     core = functools.partial(
         replica_step, cfg=cfg, n_replicas=n_replicas,
@@ -383,9 +383,8 @@ def build_spmd_group_scan(cfg: LogConfig, n_replicas: int, mesh: Mesh,
 
         (st, _acc), ys = lax.scan(body, (st, zeros_g),
                                   (datas_b, metas_b, counts_b))
-        wd, wm = jax.vmap(lambda buf, s: extract_window(
-            Log(buf=buf), s, replay_slots))(st.log.buf,
-                                            applied_b[:, 0])
+        wd, wm = jax.vmap(lambda log, s: extract_window(
+            log, s, replay_slots))(st.log, applied_b[:, 0])
         out = {k: jax.tree.map(lambda x: x[:, :, None], v)
                for k, v in ys.items()}           # [K, Gl, 1, ...]
         out["replay_data"] = wd[:, None]

@@ -93,7 +93,8 @@ class HostReplicaDriver:
         from rdma_paxos_tpu.consensus.log import Log as _Log
         self._local_fetch = jax.jit(
             lambda buf, start: fetch_window(
-                _Log(buf=buf), start, window_slots=cfg.window_slots))
+                _Log(buf=buf, slot_words=cfg.slot_words), start,
+                window_slots=cfg.window_slots))
 
         self.state = jax.device_put(stack_states(cfg, self.R, group_size
                                                  or self.R),
@@ -121,7 +122,7 @@ class HostReplicaDriver:
         this at the same point with the SAME row (all fetched it from the
         generation's donor)."""
         import dataclasses as _dc
-        from rdma_paxos_tpu.consensus.log import Log
+        from rdma_paxos_tpu.consensus.log import pad_rows
         from rdma_paxos_tpu.consensus.state import ReplicaState
 
         def put(leaf: np.ndarray) -> jax.Array:
@@ -137,8 +138,9 @@ class HostReplicaDriver:
                 continue
             cur = getattr(self.state, f.name)
             fields[f.name] = put(np.asarray(row[f.name]).astype(cur.dtype))
-        fields["log"] = Log(buf=put(np.asarray(row["log_buf"],
-                                               np.int32)))
+        # ``log_buf`` travels as live columns: the pad goes back here
+        fields["log"] = _dc.replace(self.state.log, buf=put(
+            pad_rows(np.asarray(row["log_buf"], np.int32))))
         self.state = ReplicaState(**fields)
 
     def restore_hardstate(self, term: int, voted_term: int,
@@ -428,10 +430,14 @@ class HostReplicaDriver:
         import dataclasses as _dc
         from rdma_paxos_tpu.consensus.state import ReplicaState
 
+        from rdma_paxos_tpu.consensus.log import live_rows
+
         def local(arr):
             return np.asarray(self._local_shard(arr, 0)[0])
 
-        out = {"log_buf": local(self.state.log.buf)}
+        log = self.state.log
+        out = {"log_buf": np.asarray(live_rows(
+            self._local_shard(log.buf, 0)[0], log.slot_words))}
         for f in _dc.fields(ReplicaState):
             if f.name != "log":
                 out[f.name] = local(getattr(self.state, f.name))

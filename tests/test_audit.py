@@ -31,7 +31,6 @@ import numpy as np
 import pytest
 
 from rdma_paxos_tpu.config import LogConfig, TimeoutConfig
-from rdma_paxos_tpu.consensus.log import Log
 from rdma_paxos_tpu.obs import Observability
 from rdma_paxos_tpu.obs import audit as audit_mod
 from rdma_paxos_tpu.obs.alerts import AlertEngine, default_rules
@@ -56,7 +55,8 @@ def _corrupt(cluster, replica, g_idx, *, group=None, word=0):
         buf = buf.at[replica, slot, word].add(1)
     else:
         buf = buf.at[group, replica, slot, word].add(1)
-    cluster.state = dataclasses.replace(cluster.state, log=Log(buf=buf))
+    cluster.state = dataclasses.replace(
+        cluster.state, log=dataclasses.replace(cluster.state.log, buf=buf))
 
 
 # ---------------------------------------------------------------------------

@@ -90,7 +90,7 @@ def test_genesis_boot_in_sim():
     # install the genesis row on every replica of the new world
     leaves = {}
     import dataclasses
-    from rdma_paxos_tpu.consensus.log import Log
+    from rdma_paxos_tpu.consensus.log import pad_rows
     from rdma_paxos_tpu.consensus.state import ReplicaState
     for f in dataclasses.fields(ReplicaState):
         if f.name == "log":
@@ -99,8 +99,8 @@ def test_genesis_boot_in_sim():
         leaves[f.name] = jnp.broadcast_to(
             jnp.asarray(np.asarray(g[f.name]).astype(cur.dtype)),
             cur.shape)
-    leaves["log"] = Log(buf=jnp.broadcast_to(
-        jnp.asarray(g["log_buf"]), c2.state.log.buf.shape))
+    leaves["log"] = dataclasses.replace(c2.state.log, buf=jnp.broadcast_to(
+        jnp.asarray(pad_rows(g["log_buf"])), c2.state.log.buf.shape))
     c2.state = ReplicaState(**leaves)
     c2.run_until_elected(1)
     c2.submit(1, b"new-world")

@@ -10,7 +10,7 @@ from rdma_paxos_tpu.config import LogConfig
 from rdma_paxos_tpu.consensus.log import (
     EntryType, M_LEN, M_TERM, M_TYPE, META_W,
     Log, absorb_window, append_batch, extract_window, last_term, make_log,
-    window_rows,
+    row_words, window_rows,
 )
 
 CFG = LogConfig(n_slots=16, slot_bytes=16, window_slots=8, batch_slots=4)
@@ -84,8 +84,10 @@ def test_window_rows_are_the_gathered_window(W):
     import jax
     rng = np.random.default_rng(W)
     n, cols = CFG.n_slots, CFG.slot_words + META_W
-    logs = Log(buf=jnp.asarray(
-        rng.integers(0, 1 << 30, (3, n, cols), dtype=np.int32)))
+    # the pad columns hold noise here: none of it may come back
+    logs = Log(buf=jnp.asarray(rng.integers(
+        0, 1 << 30, (3, n, row_words(CFG.slot_words)), dtype=np.int32)),
+        slot_words=CFG.slot_words)
     sliced = jax.jit(jax.vmap(lambda lg, s: window_rows(lg, s, W)))
     gathered = jax.jit(jax.vmap(lambda lg, s: extract_window(lg, s, W)))
     starts = list(range(3 * n)) + [2 ** 31 - 2 * n + k for k in range(n)]

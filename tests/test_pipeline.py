@@ -122,7 +122,6 @@ def test_engine_pipelined_audit_localizes_corruption():
     single-bit flip of a follower's committed slot is localized to the
     exact first (term, index) while the pipeline is in flight."""
     import dataclasses
-    from rdma_paxos_tpu.consensus.log import Log
 
     c = SimCluster(CFG, 3, audit=True)
     c.run_until_elected(0)
@@ -132,7 +131,8 @@ def test_engine_pipelined_audit_localizes_corruption():
     target = int(c.last["commit"].min()) - 1
     slot = target & (CFG.n_slots - 1)
     buf = c.state.log.buf.at[2, slot, 0].add(1)
-    c.state = dataclasses.replace(c.state, log=Log(buf=buf))
+    c.state = dataclasses.replace(
+        c.state, log=dataclasses.replace(c.state.log, buf=buf))
     t1 = c.begin_step()
     t2 = c.begin_step()
     c.finish(t1)

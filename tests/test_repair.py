@@ -32,7 +32,7 @@ import pytest
 
 from rdma_paxos_tpu.chaos.faults import corrupt_slot
 from rdma_paxos_tpu.config import DIGEST_EPOCH, LogConfig, TimeoutConfig
-from rdma_paxos_tpu.consensus.log import M_GIDX, META_W
+from rdma_paxos_tpu.consensus.log import M_GIDX, META_W, live_rows
 from rdma_paxos_tpu.consensus.snapshot import (
     SnapshotEpochError, SnapshotVerifyError, install_snapshot,
     take_snapshot, verify_snapshot)
@@ -87,7 +87,7 @@ def test_host_fold_bit_identical_to_device_fold():
     start = int(res["audit_start"][0])
     commit = int(res["commit"][0])
     assert commit > start
-    buf = np.asarray(c.state.log.buf[0])
+    buf = live_rows(np.asarray(c.state.log.buf[0]), CFG.slot_words)
     slots = np.arange(start, commit) & (CFG.n_slots - 1)
     host = digest_fold(buf[slots].astype(np.uint32), xp=np)
     W = CFG.window_slots
