@@ -45,9 +45,8 @@ def measure(R: int, iters: int) -> dict:
     import numpy as np
 
     from rdma_paxos_tpu.config import LogConfig
-    from rdma_paxos_tpu.consensus.log import (
-        EntryType, M_LEN, M_TYPE, META_W)
-    from rdma_paxos_tpu.consensus.step import StepInput
+    from rdma_paxos_tpu.consensus.log import EntryType, M_LEN, M_TYPE
+    from rdma_paxos_tpu.consensus.step import arg_layout
     from rdma_paxos_tpu.parallel.mesh import (
         build_spmd_burst, build_spmd_step, make_replica_mesh,
         stack_states)
@@ -57,46 +56,34 @@ def measure(R: int, iters: int) -> dict:
     mesh = make_replica_mesh(R)
     shard = jax.sharding.NamedSharding(
         mesh, jax.sharding.PartitionSpec("replica"))
-    kshard = jax.sharding.NamedSharding(
-        mesh, jax.sharding.PartitionSpec(None, "replica"))
     B, K = cfg.batch_slots, 8
-    data = jax.device_put(
-        np.zeros((K, R, B, cfg.slot_words), np.int32), kshard)
-    meta_np = np.zeros((K, R, B, META_W), np.int32)
-    meta_np[:, :, :, M_TYPE] = int(EntryType.SEND)
-    meta_np[:, :, :, M_LEN] = 16
-    meta = jax.device_put(meta_np, kshard)
-    count = jax.device_put(np.full((K, R), B, np.int32), kshard)
-    peer = jax.device_put(np.ones((R, R), np.int32), shard)
+    # a dispatch's host inputs are ONE packed array, a row a replica
+    lay = arg_layout(cfg, R, K)
+    packed_np = lay.idle((R,))
+    parts = lay.views(packed_np)
+    parts["meta"][..., M_TYPE] = int(EntryType.SEND)
+    parts["meta"][..., M_LEN] = 16
+    parts["count"][:] = B
+    packed = jax.device_put(packed_np, shard)
+    applied_at = divmod({n: o for n, o, _ in lay.fields}["applied"], 128)
 
     step = build_spmd_step(cfg, R, mesh, fanout="psum", donate=False)
     burst = build_spmd_burst(cfg, R, mesh, fanout="psum")
     state = jax.device_put(stack_states(cfg, R, R), shard)
-    inp = StepInput(
-        batch_data=jax.device_put(
-            np.zeros((R, B, cfg.slot_words), np.int32), shard),
-        batch_meta=jax.device_put(
-            np.zeros((R, B, META_W), np.int32), shard),
-        batch_count=jax.device_put(np.zeros((R,), np.int32), shard),
-        timeout_fired=jax.device_put(
-            np.zeros((R,), np.int32).copy(), shard).at[0].set(1),
-        peer_mask=peer,
-        apply_done=jax.device_put(np.zeros((R,), np.int32), shard),
-        queue_depth=jax.device_put(np.zeros((R,), np.int32), shard))
-    state, _ = step(state, inp)            # election
+    lay1 = arg_layout(cfg, R)
+    inp_np = lay1.idle((R,))
+    lay1.split(inp_np)["timeout"][0] = 1
+    state, _ = step(state, jax.device_put(inp_np, shard))   # election
 
-    applied = jax.device_put(np.zeros((R,), np.int32), shard)
-    qd = jax.device_put(np.zeros((R,), np.int32), shard)
-    state, outs = burst(state, data, meta, count, peer,
-                        applied, qd)       # warmup compile + run
+    state, outs = burst(state, packed)     # warmup compile + run
     jax.block_until_ready(outs.commit)
     pre = int(np.asarray(state.commit)[0])
     t0 = time.perf_counter()
     for _ in range(iters):
-        applied = state.commit.copy()      # echo applies => pruning (copy:
-        # burst donates the state; the same buffer cannot also be an arg)
-        state, outs = burst(state, data, meta, count, peer,
-                            applied, qd)
+        # echo applies => pruning (a copy of the cursors: burst donates
+        # the state; the same buffer cannot also be an arg)
+        packed = packed.at[(slice(None),) + applied_at].set(state.commit)
+        state, outs = burst(state, packed)
     final = int(np.asarray(state.commit)[0])   # forces drain (uniform
     dt = time.perf_counter() - t0              # protocol w/ bench.py)
     steps = iters * K

@@ -59,6 +59,7 @@ dominant leader's row.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -122,6 +123,139 @@ class StepInput:
     # (the host subtracts its rebase total); -1 = no watch armed.
     txn_watch: Optional[jax.Array] = None   # i32 — prepare log offset
     txn_term: Optional[jax.Array] = None    # i32 — term it was appended in
+
+
+# the packed argument's minor axis, the chip's 128 lanes, and the rows
+# of one (8, 128) tile of its resting layout: a replica's rows are
+# whole tiles
+ARG_LANES = 128
+ARG_TILE_ROWS = 8
+
+
+@dataclasses.dataclass(frozen=True)
+class ArgLayout:
+    """Where a dispatch's host inputs lie in its ONE packed argument:
+    an i32 array ``[*lead, rows, 128]`` whose leading axes are the
+    mesh's (``[R, rows, 128]``; the sharded engine's ``[G, R, rows,
+    128]``), so that one sharding covers it and replica r's words go
+    to chip r whole. A replica's ``rows x 128`` words hold, row-major,
+    K steps' batches and the small words: each field of
+    :attr:`fields` is ``(name, offset, shape)`` in words, the tail a
+    zero pad to whole (8, 128) tiles. The minor axis is the lanes', so the array rests on the
+    chip in the host's own word order (a transfer is a copy, and at
+    ``slot_words`` 128 the batch is a reshape of whole rows). The host
+    side (the staging buffers ARE views of the packed array:
+    :meth:`views`) and the programs (:meth:`split` /
+    :meth:`step_input`, in the trace) both read this; nothing else
+    knows an offset. Made by :func:`arg_layout`."""
+
+    K: int
+    fields: Tuple[Tuple[str, int, Tuple[int, ...]], ...]
+    rows: int
+
+    def shape(self, lead: Tuple[int, ...] = ()) -> Tuple[int, ...]:
+        return tuple(lead) + (self.rows, ARG_LANES)
+
+    def split(self, packed) -> Dict[str, jax.Array]:
+        """``{name: the field's words as [*lead, *shape]}``: static
+        slices and reshapes, of a numpy array (views of it) as of a
+        traced one. A field takes the rows that hold it; one that
+        starts and ends on a row's edge is reshaped as it lies."""
+        lead = packed.shape[:-2]
+        parts = {}
+        for name, off, shape in self.fields:
+            n = int(np.prod(shape))
+            first, last = off // ARG_LANES, -(-(off + n) // ARG_LANES)
+            words = packed[..., first:last, :]
+            if n != (last - first) * ARG_LANES:
+                lo = off - first * ARG_LANES
+                words = words.reshape(lead + (-1,))[..., lo:lo + n]
+            parts[name] = words.reshape(lead + shape)
+        return parts
+
+    def views(self, packed: np.ndarray) -> Dict[str, np.ndarray]:
+        """The host's way in: :meth:`split` with K moved to the front
+        of the batch fields (``data [K, *lead, B, slot_words]``,
+        ``meta``, ``count [K, *lead]``), the shapes the engines pack
+        by. Views, all of them: a write lands in ``packed``."""
+        lead = packed.ndim - 2
+        parts = self.split(packed)
+        for name in ("data", "meta", "count"):
+            parts[name] = np.moveaxis(parts[name], lead, 0)
+        assert all(np.may_share_memory(v, packed) for v in parts.values())
+        return parts
+
+    def idle(self, lead: Tuple[int, ...] = (), peer_mask=1) -> np.ndarray:
+        """A packed argument that asks nothing: no entry, no timer,
+        ``peer_mask`` heard (everyone: an all-zero mask is a deaf
+        replica, not an idle one), no watch armed."""
+        packed = np.zeros(self.shape(lead), np.int32)
+        parts = self.split(packed)
+        parts["peer_mask"][:] = peer_mask
+        if "txn_watch" in parts:
+            parts["txn_watch"][:] = -1
+        return packed
+
+    def step_input(self, parts: Dict[str, jax.Array], k,
+                   timeout_fired=None) -> StepInput:
+        """The :class:`StepInput` of step ``k`` (a traced index in a
+        fused program's loop, 0 in a single step) out of
+        :meth:`split`'s parts: the batch read in place, no transposed
+        copy of the K stack. ``timeout_fired`` replaces the row's own
+        word (a fused program fires no timer)."""
+        lead = parts["applied"].ndim
+
+        def at(x):
+            return lax.dynamic_index_in_dim(x, k, axis=lead,
+                                            keepdims=False)
+        return StepInput(
+            batch_data=at(parts["data"]), batch_meta=at(parts["meta"]),
+            batch_count=at(parts["count"]),
+            timeout_fired=(parts["timeout"] if timeout_fired is None
+                           else timeout_fired),
+            peer_mask=parts["peer_mask"], apply_done=parts["applied"],
+            queue_depth=parts["qdepth"],
+            txn_watch=parts.get("txn_watch"),
+            txn_term=parts.get("txn_term"))
+
+
+@functools.lru_cache(maxsize=None)
+def arg_layout(cfg: LogConfig, n_replicas: int, K: int = 1,
+               txn: bool = False) -> ArgLayout:
+    """The :class:`ArgLayout` of a K-step dispatch (a single step is
+    K = 1) of ``n_replicas``-wide groups; ``txn`` adds the two watch
+    words of the transaction lane's step."""
+    B = cfg.batch_slots
+    shapes = [("data", (K, B, cfg.slot_words)), ("meta", (K, B, META_W)),
+              ("count", (K,)), ("peer_mask", (n_replicas,)),
+              ("applied", ()), ("qdepth", ()), ("timeout", ())]
+    if txn:
+        shapes += [("txn_watch", ()), ("txn_term", ())]
+    fields, off = [], 0
+    for name, shape in shapes:
+        fields.append((name, off, shape))
+        off += int(np.prod(shape))
+    tile = ARG_LANES * ARG_TILE_ROWS
+    rows = -(-off // tile) * ARG_TILE_ROWS
+    if K > 1:
+        # a tile more than K - 1 steps' at the least (a toy geometry's
+        # step can hide in the pad): :func:`arg_layout_of` reads K
+        # back off the rows
+        rows = max(rows, arg_layout(cfg, n_replicas, K - 1).rows
+                   + ARG_TILE_ROWS)
+    return ArgLayout(K=K, fields=tuple(fields), rows=rows)
+
+
+def arg_layout_of(cfg: LogConfig, n_replicas: int, rows: int) -> ArgLayout:
+    """The fused dispatch's :func:`arg_layout` of ``rows`` rows: how a
+    burst or scan program, polymorphic in K as ever, reads K off the
+    one argument it is handed."""
+    K = 1
+    while arg_layout(cfg, n_replicas, K).rows < rows:
+        K += 1
+    lay = arg_layout(cfg, n_replicas, K)
+    assert lay.rows == rows, (rows, K, lay.rows)
+    return lay
 
 
 @jax.tree_util.register_dataclass

@@ -395,33 +395,25 @@ def merge_timeline(span_dumps, *, phase_events: Optional[Sequence] = None,
 # per-variant compiled-program cost reports
 # ---------------------------------------------------------------------------
 
-def _example_step_args(cluster):
-    """An idle (state, StepInput) pair shaped for ``cluster`` — the
-    prewarm shapes, which are exactly what the serving path
-    dispatches. The state is converted to ``ShapeDtypeStruct``s so
-    lowering never touches live device buffers (safe to run while the
-    driver loop keeps dispatching — donation cannot invalidate an
-    abstract aval)."""
+def _example_args(cluster, K: int = 1):
+    """An abstract (state, packed argument) pair shaped for a K-step
+    dispatch of ``cluster`` (a single step is K = 1) — the prewarm
+    shapes, which are exactly what the serving path dispatches. Both
+    are ``ShapeDtypeStruct``s, so lowering never touches live device
+    buffers (safe to run while the driver loop keeps dispatching —
+    donation cannot invalidate an abstract aval)."""
     import jax
     import jax.numpy as jnp
 
-    from rdma_paxos_tpu.consensus.log import META_W
-    from rdma_paxos_tpu.consensus.step import StepInput
+    from rdma_paxos_tpu.consensus.step import arg_layout
 
-    cfg, R, B = cluster.cfg, cluster.R, cluster.cfg.batch_slots
     G = getattr(cluster, "G", None)
-    lead = (G, R) if G is not None else (R,)
+    lead = (G, cluster.R) if G is not None else (cluster.R,)
     state = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), cluster.state)
-    inp = StepInput(
-        batch_data=jnp.zeros(lead + (B, cfg.slot_words), jnp.int32),
-        batch_meta=jnp.zeros(lead + (B, META_W), jnp.int32),
-        batch_count=jnp.zeros(lead, jnp.int32),
-        timeout_fired=jnp.zeros(lead, jnp.int32),
-        peer_mask=jnp.ones(lead + (R,), jnp.int32),
-        apply_done=jnp.zeros(lead, jnp.int32),
-        queue_depth=jnp.zeros(lead, jnp.int32))
-    return state, inp, lead
+    lay = arg_layout(cluster.cfg, cluster.R, K,
+                     bool(getattr(cluster, "_txn", False)) and K == 1)
+    return state, jax.ShapeDtypeStruct(lay.shape(lead), jnp.int32)
 
 
 def _analyze(lowered) -> dict:
@@ -476,28 +468,17 @@ def program_report(cluster, *, tiers: Sequence[int] = ()) -> dict:
     shapes; nothing is executed or donated."""
     import jax
 
-    from rdma_paxos_tpu.consensus.log import META_W
-
-    state, inp, lead = _example_step_args(cluster)
-    cfg, B = cluster.cfg, cluster.cfg.batch_slots
+    cfg = cluster.cfg
     variants = []
     for elections in (True, False):
         fn = _unpack_build(cluster._build_step(elections=elections))
         row = dict(variant=("step/full" if elections else "step/stable"))
-        row.update(_analyze(fn.lower(state, inp)))
+        row.update(_analyze(fn.lower(*_example_args(cluster))))
         variants.append(row)
-    import jax.numpy as jnp
     for K in tiers:
         fn = _unpack_build(cluster._burst_fn(K))
         row = dict(variant="burst/K=%d" % K)
-        row.update(_analyze(fn.lower(
-            state,
-            jnp.zeros((K,) + lead + (B, cfg.slot_words), jnp.int32),
-            jnp.zeros((K,) + lead + (B, META_W), jnp.int32),
-            jnp.zeros((K,) + lead, jnp.int32),
-            jnp.ones(lead + (cluster.R,), jnp.int32),
-            jnp.zeros(lead, jnp.int32),
-            jnp.zeros(lead, jnp.int32))))
+        row.update(_analyze(fn.lower(*_example_args(cluster, K))))
         variants.append(row)
     return dict(
         schema=1, kind="program_report", anchor=clock_anchor(),

@@ -109,11 +109,18 @@ inside the optional fencing path):
   for in vain), ``fetch_rows_total`` (rows a replica each standalone
   replay fetch asked of the device: the static width it ran at; over
   ``replay_fetch``'s count it says which width serves),
-  ``input_put_calls_total`` and ``input_put_bytes_total`` (transfers
-  the engine's one put, ``runtime/sim.py`` ``make_put``, started for a
-  dispatch's arguments and its replay fetch's, and the bytes it handed
-  over: a ``jnp.asarray`` is one, a ``jax.device_put`` of a whole
-  tuple onto a mesh is one),
+  ``input_put_calls_total``, ``input_put_buffers_total`` and
+  ``input_put_bytes_total`` (what the engine's one put, ``runtime/
+  sim.py`` ``make_put``, started: a CALL is one host array handed
+  over, by ``jnp.asarray`` or by one ``jax.device_put`` onto a mesh;
+  a dispatch's arguments are ONE packed array since PR 51, and its
+  replay fetch's ``starts`` one more, so 2 a dispatch on either path.
+  BUFFERS are the device arrays a call made: one without a mesh, one
+  a chip of the mesh with, so ``input_put_buffers_total`` over
+  ``phase.device_dispatch.count`` reads 2 on one chip and 6 on a
+  three-chip mesh, where six arrays a burst read 7 and 21: a put
+  costs by the array and by the buffer, not by the byte. BYTES are
+  the host array's ``nbytes``, the staging buffer whole),
   ``phase_stalls_total`` and
   ``phase_stall_us_total{phase}`` (a phase instance longer than
   ``TimeoutConfig.elec_timeout_low``; each also leaves one
@@ -745,7 +752,7 @@ class StepPhaseProfiler:
                 "append_clamped_total", "ring_wraps_total",
                 "replay_requests_total", "replay_order_timeouts_total",
                 "fetch_rows_total", "input_put_calls_total",
-                "input_put_bytes_total")
+                "input_put_bytes_total", "input_put_buffers_total")
     # a thread waiting by design: its length counts towards no stall,
     # its own or of the phase it waits in
     WAITS = (PHASE_IDLE_WAIT, PHASE_PIPELINE_WAIT,

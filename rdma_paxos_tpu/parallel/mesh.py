@@ -21,13 +21,15 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from rdma_paxos_tpu.config import LogConfig
+from rdma_paxos_tpu.consensus.log import extract_window
 from rdma_paxos_tpu.consensus.state import ReplicaState, make_replica_state
 from rdma_paxos_tpu.consensus.step import (
-    GROUP_BATCH_AXIS, StepInput, replica_step, scan_readback, vmap_groups,
-    with_scalars)
+    GROUP_BATCH_AXIS, arg_layout, arg_layout_of, group_step, replica_step,
+    scan_readback, vmap_groups, with_scalars)
 
 REPLICA_AXIS = "replica"
 GROUP_AXIS = "group"
@@ -112,23 +114,58 @@ def stack_group_states(cfg: LogConfig, n_groups: int, n_replicas: int,
         lambda x: jnp.broadcast_to(x, (n_groups,) + x.shape), one)
 
 
-def _with_step_scalars(step):
-    """A batched single step whose output carries its packed readback
-    row. ``wraps`` keeps the step's name, which names the compiled
-    program (``jit_replica_step``) in device traces."""
+def _with_step_scalars(step, lay):
+    """A batched single step over its ONE packed argument
+    (``consensus/step.py`` ``arg_layout``, K = 1), whose output carries
+    its packed readback row. ``wraps`` keeps the step's name, which
+    names the compiled program (``jit_replica_step``) in device
+    traces."""
     @functools.wraps(step)
-    def stepped(state_b, inp_b):
-        st, out = step(state_b, inp_b)
+    def stepped(state_b, packed):
+        st, out = step(state_b, lay.step_input(lay.split(packed), 0))
         return st, with_scalars(out, out.accepted, st)
     return stepped
 
 
-def _squeeze(tree):
-    return jax.tree.map(lambda x: x[0], tree)
+def _fused_steps(step, cfg, n_replicas, state, packed, readback):
+    """The K steps of a fused program (burst or scan tier), K read off
+    the packed argument's rows: a ``lax.scan`` of ``step`` over the
+    step index, each step's batch read in place out of the packed rows
+    (``ArgLayout.step_input``). The host's apply cursors and remaining
+    backlog are the same in every step, no timer fires, ``accepted``
+    is carried cumulative; ``readback(out, acc, st)`` is what a step
+    hands back. Returns ``(state, stacked readbacks, parts)``."""
+    lay = arg_layout_of(cfg, n_replicas, packed.shape[-2])
+    parts = lay.split(packed)
+    # created in-trace, NOT closure-captured: a captured jnp array is
+    # embedded in the lowered module as a literal
+    zeros = jnp.zeros_like(parts["applied"])
+
+    def body(carry, k):
+        st, acc = carry
+        st, out = step(st, lay.step_input(parts, k, timeout_fired=zeros))
+        acc = acc + out.accepted
+        return (st, acc), readback(out, acc, st)
+    (st, _acc), ys = lax.scan(body, (state, zeros),
+                              jnp.arange(lay.K, dtype=jnp.int32))
+    return st, ys, parts
 
 
-def _unsqueeze(tree):
-    return jax.tree.map(lambda x: x[None], tree)
+def _squeeze(tree, axis=0):
+    return jax.tree.map(lambda x: jnp.squeeze(x, axis), tree)
+
+
+def _unsqueeze(tree, axis=0):
+    return jax.tree.map(lambda x: jnp.expand_dims(x, axis), tree)
+
+
+def _replica_core(cfg, n_replicas, **kw):
+    return functools.partial(replica_step, cfg=cfg, n_replicas=n_replicas,
+                             axis_name=REPLICA_AXIS, **kw)
+
+
+def _jit(fn, donate):
+    return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
 
 def build_spmd_step(cfg: LogConfig, n_replicas: int, mesh: Mesh, *,
@@ -138,20 +175,22 @@ def build_spmd_step(cfg: LogConfig, n_replicas: int, mesh: Mesh, *,
                     telemetry: bool = False, txn: bool = False):
     """Compile the protocol step over a real device mesh.
 
-    Takes/returns *batched* pytrees (leading ``replica`` axis, sharded one
-    row per device). State buffers are donated so the log arrays update
-    in-place on device across steps — the analog of the reference's log
-    living pinned in registered MRs (``rc_memory_reg``,
-    ``dare_ibv_rc.c:240-276``).
+    Takes the *batched* state (leading ``replica`` axis, sharded one
+    row per device) and the step's ONE packed argument ``[R, rows, 128]``
+    (``arg_layout(cfg, R, 1, txn)``), sharded the same way. State
+    buffers are donated so the log arrays update in-place on device
+    across steps — the analog of the reference's log living pinned in
+    registered MRs (``rc_memory_reg``, ``dare_ibv_rc.c:240-276``).
     """
-    core = functools.partial(
-        replica_step, cfg=cfg, n_replicas=n_replicas,
-        axis_name=REPLICA_AXIS, use_pallas=use_pallas, interpret=interpret,
+    core = _replica_core(
+        cfg, n_replicas, use_pallas=use_pallas, interpret=interpret,
         fanout=fanout, elections=elections, audit=audit,
         telemetry=telemetry, txn=txn)
+    lay = arg_layout(cfg, n_replicas, 1, txn)
 
-    def per_device(state_b, inp_b):
-        st, out = core(_squeeze(state_b), _squeeze(inp_b))
+    def per_device(state_b, packed_b):
+        st, out = core(_squeeze(state_b),
+                       lay.step_input(lay.split(packed_b[0]), 0))
         return (_unsqueeze(st),
                 _unsqueeze(with_scalars(out, out.accepted, st)))
 
@@ -159,7 +198,7 @@ def build_spmd_step(cfg: LogConfig, n_replicas: int, mesh: Mesh, *,
         per_device, mesh=mesh,
         in_specs=(P(REPLICA_AXIS), P(REPLICA_AXIS)),
         out_specs=(P(REPLICA_AXIS), P(REPLICA_AXIS)))
-    return jax.jit(mapped, donate_argnums=(0,) if donate else ())
+    return _jit(mapped, donate)
 
 
 def build_sim_burst(cfg: LogConfig, n_replicas: int, *,
@@ -180,47 +219,45 @@ def build_sim_burst(cfg: LogConfig, n_replicas: int, *,
     cursors are frozen across the burst (the host cannot replay
     mid-burst), so pruning advances at most to the pre-burst applied
     offsets; the caller's capacity sizing must fit the whole burst in
-    the pre-burst free space. K is the leading axis of the stacked
-    inputs; returns the final state plus the per-step stacked outputs,
-    whose ``scal`` (``[K, R, len(SCAN_KEYS) + R]``, ``accepted``
-    cumulative in-program) is the ONE array the host reads: row
-    ``[-1]`` is the burst's result."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    core = functools.partial(
-        replica_step, cfg=cfg, n_replicas=n_replicas,
-        axis_name=REPLICA_AXIS, use_pallas=use_pallas, interpret=interpret,
-        fanout=fanout, elections=False, audit=audit,
-        telemetry=telemetry)
+    the pre-burst free space. The burst's host inputs are ONE packed
+    array ``[R, rows, 128]`` (``arg_layout(cfg, R, K)``: K steps' ``data
+    [K, B, sw]``, ``meta [K, B, MW]`` and ``count [K]`` a replica, its
+    ``peer_mask`` row, ``applied`` = the HOST's true apply cursor —
+    echoing ``st.commit`` would let pressure-gated (and forced) pruning
+    recycle slots the host has not replayed yet — and ``qdepth`` = the
+    host backlog REMAINING beyond this burst, so the final step's
+    gathered burst_hint keeps bursts back-to-back under sustained
+    load); K is read off its rows. Returns the final state plus the
+    per-step stacked outputs, whose ``scal`` (``[K, R, len(SCAN_KEYS) +
+    R]``, ``accepted`` cumulative in-program) is the ONE array the
+    host reads: row ``[-1]`` is the burst's result."""
+    core = _replica_core(
+        cfg, n_replicas, use_pallas=use_pallas, interpret=interpret,
+        fanout=fanout, elections=False, audit=audit, telemetry=telemetry)
     vstep = jax.vmap(core, in_axes=(0, 0), axis_name=REPLICA_AXIS)
 
-    def burst(state_b, datas, metas, counts, peer_mask, applied, qdepth):
-        # created in-trace, NOT closure-captured: a captured jnp array
-        # is embedded in the lowered module as a literal (one more
-        # constant per compiled tier), where an in-trace zeros is free
-        zeros_r = jnp.zeros((n_replicas,), jnp.int32)
-        # datas [K, R, B, sw]; metas [K, R, B, MW]; counts [K, R];
-        # applied [R] = the HOST's true apply cursors, frozen across the
-        # burst — echoing st.commit here would let pressure-gated (and
-        # forced) pruning recycle slots the host has not replayed yet.
-        # qdepth [R] = the host backlog REMAINING beyond this burst, so
-        # the final step's gathered burst_hint keeps bursts back-to-back
-        # under sustained load instead of resetting to zero
-        def body(carry, xs):
-            st, acc = carry
-            d, m, c = xs
-            inp = StepInput(
-                batch_data=d, batch_meta=m, batch_count=c,
-                timeout_fired=zeros_r, peer_mask=peer_mask,
-                apply_done=applied, queue_depth=qdepth)
-            st, out = vstep(st, inp)
-            acc = acc + out.accepted
-            return (st, acc), with_scalars(out, acc, st)
-        (st, _acc), outs = lax.scan(body, (state_b, zeros_r),
-                                    (datas, metas, counts))
+    def burst(state_b, packed):
+        st, outs, _parts = _fused_steps(vstep, cfg, n_replicas, state_b,
+                                        packed, with_scalars)
         return st, outs
-    return jax.jit(burst, donate_argnums=(0,) if donate else ())
+    return _jit(burst, donate)
+
+
+def _scan_tier(step, fetch, cfg, n_replicas, audit, telemetry):
+    """``(state, packed) -> (state, readback dict)`` of the K-window
+    scan tier: :func:`_fused_steps` handing back the consolidated
+    minimal readback, and ``fetch(log, applied)``'s replay rows out of
+    the post-scan log."""
+    readback = functools.partial(scan_readback, audit=audit,
+                                 telemetry=telemetry)
+
+    def scan(state, packed):
+        st, ys, parts = _fused_steps(step, cfg, n_replicas, state,
+                                     packed, readback)
+        ys["replay_data"], ys["replay_meta"] = fetch(st.log,
+                                                     parts["applied"])
+        return st, ys
+    return scan
 
 
 def build_sim_scan(cfg: LogConfig, n_replicas: int, *,
@@ -229,9 +266,9 @@ def build_sim_scan(cfg: LogConfig, n_replicas: int, *,
                    donate: bool = True, fanout: str = "gather",
                    audit: bool = False, telemetry: bool = False):
     """The device-resident K-window scan tier: K fused protocol steps
-    (the :func:`build_sim_burst` ``lax.scan``) returning ONE
-    consolidated minimal readback instead of the full per-step output
-    stacks — only what the host rules consume:
+    (the :func:`build_sim_burst` ``lax.scan``, over the same ONE packed
+    argument) returning ONE consolidated minimal readback instead of
+    the full per-step output stacks — only what the host rules consume:
 
     * ``scal`` ``[K, R, len(SCAN_KEYS) + R]`` i32 — the per-step
       packed rows (``accepted`` cumulative; config view and the
@@ -249,43 +286,14 @@ def build_sim_scan(cfg: LogConfig, n_replicas: int, *,
     K serial steps — pinned by ``tests/test_scan.py``. Engines cache
     the compiled fn under distinct ``"scan"``-marked STEP_CACHE keys:
     scan-off clusters' key sets and programs are untouched."""
-    import jax.numpy as jnp
-    from jax import lax
-    from rdma_paxos_tpu.consensus.log import extract_window
-
-    core = functools.partial(
-        replica_step, cfg=cfg, n_replicas=n_replicas,
-        axis_name=REPLICA_AXIS, use_pallas=use_pallas,
-        interpret=interpret, fanout=fanout, elections=False,
-        audit=audit, telemetry=telemetry)
+    core = _replica_core(
+        cfg, n_replicas, use_pallas=use_pallas, interpret=interpret,
+        fanout=fanout, elections=False, audit=audit, telemetry=telemetry)
     vstep = jax.vmap(core, in_axes=(0, 0), axis_name=REPLICA_AXIS)
     vfetch = jax.vmap(lambda log, s: extract_window(
         log, s, replay_slots))
-
-    def scan(state_b, datas, metas, counts, peer_mask, applied,
-             qdepth):
-        zeros_r = jnp.zeros((n_replicas,), jnp.int32)
-
-        def body(carry, xs):
-            st, acc = carry
-            d, m, c = xs
-            inp = StepInput(
-                batch_data=d, batch_meta=m, batch_count=c,
-                timeout_fired=zeros_r, peer_mask=peer_mask,
-                apply_done=applied, queue_depth=qdepth)
-            st, out = vstep(st, inp)
-            acc = acc + out.accepted
-            ys = scan_readback(out, acc, st, audit=audit,
-                               telemetry=telemetry)
-            return (st, acc), ys
-
-        (st, _acc), ys = lax.scan(body, (state_b, zeros_r),
-                                  (datas, metas, counts))
-        wd, wm = vfetch(st.log, applied)
-        ys["replay_data"] = wd
-        ys["replay_meta"] = wm
-        return st, ys
-    return jax.jit(scan, donate_argnums=(0,) if donate else ())
+    return _jit(_scan_tier(vstep, vfetch, cfg, n_replicas, audit,
+                           telemetry), donate)
 
 
 def build_sim_group_scan(cfg: LogConfig, n_replicas: int, *,
@@ -296,13 +304,9 @@ def build_sim_group_scan(cfg: LogConfig, n_replicas: int, *,
                          audit: bool = False,
                          telemetry: bool = False):
     """:func:`build_sim_scan` with a leading ``group`` batch axis —
-    the sharded engine's K-window scan tier (inputs shaped like
-    :func:`build_sim_group_burst`; readback dict axes gain ``G``)."""
-    import jax.numpy as jnp
-    from jax import lax
-    from rdma_paxos_tpu.consensus.log import extract_window
-    from rdma_paxos_tpu.consensus.step import group_step
-
+    the sharded engine's K-window scan tier (the packed argument
+    shaped like :func:`build_sim_group_burst`'s; readback dict axes
+    gain ``G``)."""
     gstep = group_step(cfg=cfg, n_replicas=n_replicas,
                        axis_name=REPLICA_AXIS, use_pallas=use_pallas,
                        interpret=interpret, fanout=fanout,
@@ -310,31 +314,19 @@ def build_sim_group_scan(cfg: LogConfig, n_replicas: int, *,
                        telemetry=telemetry)
     vfetch = jax.vmap(jax.vmap(lambda log, s: extract_window(
         log, s, replay_slots)))
+    return _jit(_scan_tier(gstep, vfetch, cfg, n_replicas, audit,
+                           telemetry), donate)
 
-    def scan(state_gb, datas, metas, counts, peer_mask, applied,
-             qdepth):
-        zeros_gr = jnp.zeros_like(counts[0])
 
-        def body(carry, xs):
-            st, acc = carry
-            d, m, c = xs
-            inp = StepInput(
-                batch_data=d, batch_meta=m, batch_count=c,
-                timeout_fired=zeros_gr, peer_mask=peer_mask,
-                apply_done=applied, queue_depth=qdepth)
-            st, out = gstep(st, inp)
-            acc = acc + out.accepted
-            ys = scan_readback(out, acc, st, audit=audit,
-                               telemetry=telemetry)
-            return (st, acc), ys
-
-        (st, _acc), ys = lax.scan(body, (state_gb, zeros_gr),
-                                  (datas, metas, counts))
-        wd, wm = vfetch(st.log, applied)
-        ys["replay_data"] = wd
-        ys["replay_meta"] = wm
-        return st, ys
-    return jax.jit(scan, donate_argnums=(0,) if donate else ())
+def _scan_out_spec(spec_k, spec_rows, audit, telemetry):
+    out_spec = dict(scal=spec_k, replay_data=spec_rows,
+                    replay_meta=spec_rows)
+    if audit:
+        out_spec.update(audit_start=spec_k, audit_digest=spec_k,
+                        audit_term=spec_k, audit_commit=spec_k)
+    if telemetry:
+        out_spec["telemetry"] = spec_k
+    return out_spec
 
 
 def build_spmd_group_scan(cfg: LogConfig, n_replicas: int, mesh: Mesh,
@@ -350,67 +342,28 @@ def build_spmd_group_scan(cfg: LogConfig, n_replicas: int, mesh: Mesh,
     Each device extracts its own replicas' replay rows locally; the
     out_specs gather assembles the global ``[G, R, ...]`` arrays the
     host bookkeeping expects — same host code as the vmap engine."""
-    import jax.numpy as jnp
-    from jax import lax
-    from rdma_paxos_tpu.consensus.log import extract_window
-
-    core = functools.partial(
-        replica_step, cfg=cfg, n_replicas=n_replicas,
-        axis_name=REPLICA_AXIS, use_pallas=use_pallas,
-        interpret=interpret, fanout=fanout, elections=False,
-        audit=audit, telemetry=telemetry,
+    core = _replica_core(
+        cfg, n_replicas, use_pallas=use_pallas, interpret=interpret,
+        fanout=fanout, elections=False, audit=audit, telemetry=telemetry,
         group_batch_axis=GROUP_BATCH_AXIS)
-    vcore = vmap_groups(core)                   # the device's own groups
+    scan = _scan_tier(
+        vmap_groups(core),                      # the device's own groups
+        jax.vmap(lambda log, s: extract_window(log, s, replay_slots)),
+        cfg, n_replicas, audit, telemetry)
 
-    def per_device(state_b, datas_b, metas_b, counts_b, peer_b,
-                   applied_b, qdepth_b):
-        st = jax.tree.map(lambda x: x[:, 0], state_b)   # [Gl, ...]
-        zeros_g = jnp.zeros_like(counts_b[0, :, 0])     # [Gl]
+    def per_device(state_b, packed_b):          # [Gl, 1, ...]
+        st, ys = scan(_squeeze(state_b, 1), packed_b[:, 0])
+        rows = ("replay_data", "replay_meta")
+        return (_unsqueeze(st, 1),              # ys: [K, Gl, 1, ...]
+                {k: _unsqueeze(v, 1 if k in rows else 2)
+                 for k, v in ys.items()})
 
-        def body(carry, xs):
-            s, acc = carry
-            d, m, c = xs                # d: [Gl, 1, B, sw] etc.
-            inp = StepInput(
-                batch_data=d[:, 0], batch_meta=m[:, 0],
-                batch_count=c[:, 0], timeout_fired=zeros_g,
-                peer_mask=peer_b[:, 0], apply_done=applied_b[:, 0],
-                queue_depth=qdepth_b[:, 0])
-            s, out = vcore(s, inp)
-            acc = acc + out.accepted
-            ys = scan_readback(out, acc, s, audit=audit,
-                               telemetry=telemetry)
-            return (s, acc), ys
-
-        (st, _acc), ys = lax.scan(body, (st, zeros_g),
-                                  (datas_b, metas_b, counts_b))
-        wd, wm = jax.vmap(lambda log, s: extract_window(
-            log, s, replay_slots))(st.log, applied_b[:, 0])
-        out = {k: jax.tree.map(lambda x: x[:, :, None], v)
-               for k, v in ys.items()}           # [K, Gl, 1, ...]
-        out["replay_data"] = wd[:, None]
-        out["replay_meta"] = wm[:, None]
-        return (jax.tree.map(lambda x: x[:, None], st), out)
-
-    spec_k = P(None, GROUP_AXIS, REPLICA_AXIS)
-    out_spec = dict(scal=spec_k,
-                    replay_data=P(GROUP_AXIS, REPLICA_AXIS),
-                    replay_meta=P(GROUP_AXIS, REPLICA_AXIS))
-    if audit:
-        out_spec.update(audit_start=spec_k, audit_digest=spec_k,
-                        audit_term=spec_k, audit_commit=spec_k)
-    if telemetry:
-        out_spec["telemetry"] = spec_k
+    spec = P(GROUP_AXIS, REPLICA_AXIS)
     mapped = _shard_map(
-        per_device, mesh=mesh,
-        in_specs=(P(GROUP_AXIS, REPLICA_AXIS),
-                  P(None, GROUP_AXIS, REPLICA_AXIS),
-                  P(None, GROUP_AXIS, REPLICA_AXIS),
-                  P(None, GROUP_AXIS, REPLICA_AXIS),
-                  P(GROUP_AXIS, REPLICA_AXIS),
-                  P(GROUP_AXIS, REPLICA_AXIS),
-                  P(GROUP_AXIS, REPLICA_AXIS)),
-        out_specs=(P(GROUP_AXIS, REPLICA_AXIS), out_spec))
-    return jax.jit(mapped, donate_argnums=(0,) if donate else ())
+        per_device, mesh=mesh, in_specs=(spec, spec),
+        out_specs=(spec, _scan_out_spec(
+            P(None, GROUP_AXIS, REPLICA_AXIS), spec, audit, telemetry)))
+    return _jit(mapped, donate)
 
 
 def build_spmd_scan(cfg: LogConfig, n_replicas: int, mesh: Mesh, *,
@@ -424,60 +377,26 @@ def build_spmd_scan(cfg: LogConfig, n_replicas: int, mesh: Mesh, *,
     extracted from its local log shard inside the one collective
     dispatch (the per-iteration ``fetch_local_window`` dispatches of
     the lock-step loop disappear)."""
-    import jax.numpy as jnp
-    from jax import lax
-    from rdma_paxos_tpu.consensus.log import extract_window
+    core = _replica_core(
+        cfg, n_replicas, use_pallas=use_pallas, interpret=interpret,
+        fanout=fanout, elections=False, audit=audit, telemetry=telemetry)
+    scan = _scan_tier(
+        core, lambda log, s: extract_window(log, s, replay_slots),
+        cfg, n_replicas, audit, telemetry)
 
-    core = functools.partial(
-        replica_step, cfg=cfg, n_replicas=n_replicas,
-        axis_name=REPLICA_AXIS, use_pallas=use_pallas,
-        interpret=interpret, fanout=fanout, elections=False,
-        audit=audit, telemetry=telemetry)
+    def per_device(state_b, packed_b):          # [1, ...]
+        st, ys = scan(_squeeze(state_b), packed_b[0])
+        rows = ("replay_data", "replay_meta")
+        return (_unsqueeze(st),                 # ys: [K, 1, ...]
+                {k: _unsqueeze(v, 0 if k in rows else 1)
+                 for k, v in ys.items()})
 
-    def per_device(state_b, datas_b, metas_b, counts_b, peer_b,
-                   applied_b, qdepth_b):
-        st = _squeeze(state_b)
-
-        def body(carry, xs):
-            s, acc = carry
-            d, m, c = xs
-            inp = StepInput(
-                batch_data=d[0], batch_meta=m[0], batch_count=c[0],
-                timeout_fired=jnp.zeros((), jnp.int32),
-                peer_mask=peer_b[0], apply_done=applied_b[0],
-                queue_depth=qdepth_b[0])
-            s, out = core(s, inp)
-            acc = acc + out.accepted
-            ys = scan_readback(out, acc, s, audit=audit,
-                               telemetry=telemetry)
-            return (s, acc), ys
-
-        (st, _acc), ys = lax.scan(
-            body, (st, jnp.zeros((), jnp.int32)),
-            (datas_b, metas_b, counts_b))
-        wd, wm = extract_window(st.log, applied_b[0], replay_slots)
-        out = {k: jax.tree.map(lambda x: x[:, None], v)
-               for k, v in ys.items()}           # [K, 1, ...]
-        out["replay_data"] = wd[None]
-        out["replay_meta"] = wm[None]
-        return _unsqueeze(st), out
-
-    spec_k = P(None, REPLICA_AXIS)
-    out_spec = dict(scal=spec_k,
-                    replay_data=P(REPLICA_AXIS),
-                    replay_meta=P(REPLICA_AXIS))
-    if audit:
-        out_spec.update(audit_start=spec_k, audit_digest=spec_k,
-                        audit_term=spec_k, audit_commit=spec_k)
-    if telemetry:
-        out_spec["telemetry"] = spec_k
+    spec = P(REPLICA_AXIS)
     mapped = _shard_map(
-        per_device, mesh=mesh,
-        in_specs=(P(REPLICA_AXIS), P(None, REPLICA_AXIS),
-                  P(None, REPLICA_AXIS), P(None, REPLICA_AXIS),
-                  P(REPLICA_AXIS), P(REPLICA_AXIS), P(REPLICA_AXIS)),
-        out_specs=(P(REPLICA_AXIS), out_spec))
-    return jax.jit(mapped, donate_argnums=(0,) if donate else ())
+        per_device, mesh=mesh, in_specs=(spec, spec),
+        out_specs=(spec, _scan_out_spec(P(None, REPLICA_AXIS), spec,
+                                        audit, telemetry)))
+    return _jit(mapped, donate)
 
 
 def build_spmd_burst(cfg: LogConfig, n_replicas: int, mesh: Mesh, *,
@@ -486,47 +405,24 @@ def build_spmd_burst(cfg: LogConfig, n_replicas: int, mesh: Mesh, *,
                      audit: bool = False,
                      telemetry: bool = False):
     """:func:`build_sim_burst` over a real device mesh (``shard_map`` with
-    the K-step scan inside the per-device program); the same stacked
+    the K-step scan inside the per-device program, each device handed
+    its replica's row of the ONE packed argument); the same stacked
     outputs, ``scal`` the one array the host reads."""
-    import jax.numpy as jnp
-    from jax import lax
+    core = _replica_core(
+        cfg, n_replicas, use_pallas=use_pallas, interpret=interpret,
+        fanout=fanout, elections=False, audit=audit, telemetry=telemetry)
 
-    core = functools.partial(
-        replica_step, cfg=cfg, n_replicas=n_replicas,
-        axis_name=REPLICA_AXIS, use_pallas=use_pallas, interpret=interpret,
-        fanout=fanout, elections=False, audit=audit,
-        telemetry=telemetry)
-
-    def per_device(state_b, datas_b, metas_b, counts_b, peer_b,
-                   applied_b, qdepth_b):
-        st = _squeeze(state_b)
-
-        def body(carry, xs):
-            s, acc = carry
-            d, m, c = xs
-            inp = StepInput(
-                batch_data=d[0], batch_meta=m[0], batch_count=c[0],
-                timeout_fired=jnp.zeros((), jnp.int32),
-                peer_mask=peer_b[0], apply_done=applied_b[0],
-                # remaining backlog rides every burst step's gather so
-                # the final burst_hint sustains back-to-back bursts
-                queue_depth=qdepth_b[0])
-            s, out = core(s, inp)
-            acc = acc + out.accepted
-            return (s, acc), with_scalars(out, acc, s)
-        (st, _acc), outs = lax.scan(
-            body, (st, jnp.zeros((), jnp.int32)),
-            (datas_b, metas_b, counts_b))
-        return (_unsqueeze(st),
-                jax.tree.map(lambda x: x[:, None], outs))   # [K, 1, ...]
+    def per_device(state_b, packed_b):          # [1, ...]
+        st, outs, _parts = _fused_steps(
+            core, cfg, n_replicas, _squeeze(state_b), packed_b[0],
+            with_scalars)
+        return _unsqueeze(st), _unsqueeze(outs, 1)      # [K, 1, ...]
 
     mapped = _shard_map(
         per_device, mesh=mesh,
-        in_specs=(P(REPLICA_AXIS), P(None, REPLICA_AXIS),
-                  P(None, REPLICA_AXIS), P(None, REPLICA_AXIS),
-                  P(REPLICA_AXIS), P(REPLICA_AXIS), P(REPLICA_AXIS)),
+        in_specs=(P(REPLICA_AXIS), P(REPLICA_AXIS)),
         out_specs=(P(REPLICA_AXIS), P(None, REPLICA_AXIS)))
-    return jax.jit(mapped, donate_argnums=(0,) if donate else ())
+    return _jit(mapped, donate)
 
 
 def build_sim_group_step(cfg: LogConfig, n_replicas: int, *,
@@ -536,18 +432,18 @@ def build_sim_group_step(cfg: LogConfig, n_replicas: int, *,
                          telemetry: bool = False, txn: bool = False):
     """Compile the G-group × R-replica protocol step as ONE program on
     one device (:func:`rdma_paxos_tpu.consensus.step.group_step` under
-    ``jit``). The group axis is a batch axis — groups are independent;
+    ``jit``) over the ONE packed argument ``[G, R, rows, 128]``. The group
+    axis is a batch axis — groups are independent;
     only the replica axis carries protocol collectives (the group axis
     is named for one scalar, the rescan's gate: ``group_step``) — so
     one dispatch steps every group (the sharded-cluster hot path)."""
-    from rdma_paxos_tpu.consensus.step import group_step
     gstep = group_step(cfg=cfg, n_replicas=n_replicas,
                        axis_name=REPLICA_AXIS, use_pallas=use_pallas,
                        interpret=interpret, fanout=fanout,
                        elections=elections, audit=audit,
                        telemetry=telemetry, txn=txn)
-    return jax.jit(_with_step_scalars(gstep),
-                   donate_argnums=(0,) if donate else ())
+    return _jit(_with_step_scalars(
+        gstep, arg_layout(cfg, n_replicas, 1, txn)), donate)
 
 
 def build_sim_group_burst(cfg: LogConfig, n_replicas: int, *,
@@ -561,36 +457,19 @@ def build_sim_group_burst(cfg: LogConfig, n_replicas: int, *,
     (``lax.scan`` of the group-batched stable step). Same contract as
     the single-group burst — no elections inside the burst, host apply
     cursors frozen across it, capacity sized by the caller — applied
-    per group. Inputs: datas ``[K, G, R, B, sw]``, metas
-    ``[K, G, R, B, MW]``, counts ``[K, G, R]``, peer_mask
-    ``[G, R, R]``, applied/qdepth ``[G, R]``."""
-    import jax.numpy as jnp
-    from jax import lax
-    from rdma_paxos_tpu.consensus.step import group_step
-
+    per group. The packed argument is ``[G, R, rows, 128]``, a (group,
+    replica) pair's row laid out as :func:`build_sim_burst`'s."""
     gstep = group_step(cfg=cfg, n_replicas=n_replicas,
                        axis_name=REPLICA_AXIS, use_pallas=use_pallas,
                        interpret=interpret, fanout=fanout,
                        elections=False, audit=audit,
                        telemetry=telemetry)
 
-    def burst(state_gb, datas, metas, counts, peer_mask, applied, qdepth):
-        zeros_gr = jnp.zeros_like(counts[0])
-
-        def body(carry, xs):
-            st, acc = carry
-            d, m, c = xs
-            inp = StepInput(
-                batch_data=d, batch_meta=m, batch_count=c,
-                timeout_fired=zeros_gr, peer_mask=peer_mask,
-                apply_done=applied, queue_depth=qdepth)
-            st, out = gstep(st, inp)
-            acc = acc + out.accepted
-            return (st, acc), with_scalars(out, acc, st)
-        (st, _acc), outs = lax.scan(body, (state_gb, zeros_gr),
-                                    (datas, metas, counts))
+    def burst(state_gb, packed):
+        st, outs, _parts = _fused_steps(gstep, cfg, n_replicas, state_gb,
+                                        packed, with_scalars)
         return st, outs
-    return jax.jit(burst, donate_argnums=(0,) if donate else ())
+    return _jit(burst, donate)
 
 
 def build_spmd_group_step(cfg: LogConfig, n_replicas: int, mesh: Mesh,
@@ -604,7 +483,8 @@ def build_spmd_group_step(cfg: LogConfig, n_replicas: int, mesh: Mesh,
     replicas advanced by ONE ``shard_map``-compiled dispatch spanning
     ``group_shards * R`` chips.
 
-    Axis layout: the global ``[G, R, ...]`` pytrees are sharded
+    Axis layout: the global ``[G, R, ...]`` state and the packed
+    argument ``[G, R, rows, 128]`` are sharded
     ``P(group, replica)`` — each device holds ``G / group_shards``
     whole group rows of exactly one replica column. Inside the
     per-device program the replica axis (local size 1) is squeezed and
@@ -616,28 +496,23 @@ def build_spmd_group_step(cfg: LogConfig, n_replicas: int, mesh: Mesh,
     ``group`` mesh axis. The compiled program is polymorphic in the
     local group count, so the cache key carries the mesh — not G
     (``tests/test_mesh.py`` pins the single-compile property)."""
-    core = functools.partial(
-        replica_step, cfg=cfg, n_replicas=n_replicas,
-        axis_name=REPLICA_AXIS, use_pallas=use_pallas,
-        interpret=interpret, fanout=fanout, elections=elections,
-        audit=audit, telemetry=telemetry, txn=txn,
-        group_batch_axis=GROUP_BATCH_AXIS)
+    core = _replica_core(
+        cfg, n_replicas, use_pallas=use_pallas, interpret=interpret,
+        fanout=fanout, elections=elections, audit=audit,
+        telemetry=telemetry, txn=txn, group_batch_axis=GROUP_BATCH_AXIS)
     vcore = vmap_groups(core)                   # the device's own groups
+    lay = arg_layout(cfg, n_replicas, 1, txn)
 
-    def per_device(state_b, inp_b):
-        st, out = vcore(jax.tree.map(lambda x: x[:, 0], state_b),
-                        jax.tree.map(lambda x: x[:, 0], inp_b))
-        out = with_scalars(out, out.accepted, st)
-        return (jax.tree.map(lambda x: x[:, None], st),
-                jax.tree.map(lambda x: x[:, None], out))
+    def per_device(state_b, packed_b):          # [Gl, 1, ...]
+        st, out = vcore(_squeeze(state_b, 1),
+                        lay.step_input(lay.split(packed_b[:, 0]), 0))
+        return (_unsqueeze(st, 1),
+                _unsqueeze(with_scalars(out, out.accepted, st), 1))
 
-    mapped = _shard_map(
-        per_device, mesh=mesh,
-        in_specs=(P(GROUP_AXIS, REPLICA_AXIS),
-                  P(GROUP_AXIS, REPLICA_AXIS)),
-        out_specs=(P(GROUP_AXIS, REPLICA_AXIS),
-                   P(GROUP_AXIS, REPLICA_AXIS)))
-    return jax.jit(mapped, donate_argnums=(0,) if donate else ())
+    spec = P(GROUP_AXIS, REPLICA_AXIS)
+    mapped = _shard_map(per_device, mesh=mesh, in_specs=(spec, spec),
+                        out_specs=(spec, spec))
+    return _jit(mapped, donate)
 
 
 def build_spmd_group_burst(cfg: LogConfig, n_replicas: int, mesh: Mesh,
@@ -651,53 +526,26 @@ def build_spmd_group_burst(cfg: LogConfig, n_replicas: int, mesh: Mesh,
     dispatch (``lax.scan`` of the group-vmapped stable step inside the
     per-device program). Same contract as the single-device group
     burst — no elections inside, host apply cursors frozen, capacity
-    sized by the caller — applied per group. Input shapes match
-    :func:`build_sim_group_burst`; K is unsharded, ``[G, R]`` axes are
-    sharded ``P(group, replica)``."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    core = functools.partial(
-        replica_step, cfg=cfg, n_replicas=n_replicas,
-        axis_name=REPLICA_AXIS, use_pallas=use_pallas,
-        interpret=interpret, fanout=fanout, elections=False,
-        audit=audit, telemetry=telemetry,
+    sized by the caller — applied per group. The packed argument is
+    :func:`build_sim_group_burst`'s, sharded ``P(group, replica)``:
+    a device is handed its replica's rows whole."""
+    core = _replica_core(
+        cfg, n_replicas, use_pallas=use_pallas, interpret=interpret,
+        fanout=fanout, elections=False, audit=audit, telemetry=telemetry,
         group_batch_axis=GROUP_BATCH_AXIS)
     vcore = vmap_groups(core)                   # the device's own groups
 
-    def per_device(state_b, datas_b, metas_b, counts_b, peer_b,
-                   applied_b, qdepth_b):
-        st = jax.tree.map(lambda x: x[:, 0], state_b)   # [Gl, ...]
-        zeros_g = jnp.zeros_like(counts_b[0, :, 0])     # [Gl]
+    def per_device(state_b, packed_b):          # [Gl, 1, ...]
+        st, outs, _parts = _fused_steps(
+            vcore, cfg, n_replicas, _squeeze(state_b, 1),
+            packed_b[:, 0], with_scalars)
+        return _unsqueeze(st, 1), _unsqueeze(outs, 2)   # [K, Gl, 1, ...]
 
-        def body(carry, xs):
-            s, acc = carry
-            d, m, c = xs                # d: [Gl, 1, B, sw] etc.
-            inp = StepInput(
-                batch_data=d[:, 0], batch_meta=m[:, 0],
-                batch_count=c[:, 0], timeout_fired=zeros_g,
-                peer_mask=peer_b[:, 0], apply_done=applied_b[:, 0],
-                queue_depth=qdepth_b[:, 0])
-            s, out = vcore(s, inp)
-            acc = acc + out.accepted
-            return (s, acc), with_scalars(out, acc, s)
-        (st, _acc), outs = lax.scan(body, (st, zeros_g),
-                                    (datas_b, metas_b, counts_b))
-        return (jax.tree.map(lambda x: x[:, None], st),
-                jax.tree.map(lambda x: x[:, :, None], outs))
-
+    spec = P(GROUP_AXIS, REPLICA_AXIS)
     mapped = _shard_map(
-        per_device, mesh=mesh,
-        in_specs=(P(GROUP_AXIS, REPLICA_AXIS),
-                  P(None, GROUP_AXIS, REPLICA_AXIS),
-                  P(None, GROUP_AXIS, REPLICA_AXIS),
-                  P(None, GROUP_AXIS, REPLICA_AXIS),
-                  P(GROUP_AXIS, REPLICA_AXIS),
-                  P(GROUP_AXIS, REPLICA_AXIS),
-                  P(GROUP_AXIS, REPLICA_AXIS)),
-        out_specs=(P(GROUP_AXIS, REPLICA_AXIS),
-                   P(None, GROUP_AXIS, REPLICA_AXIS)))
-    return jax.jit(mapped, donate_argnums=(0,) if donate else ())
+        per_device, mesh=mesh, in_specs=(spec, spec),
+        out_specs=(spec, P(None, GROUP_AXIS, REPLICA_AXIS)))
+    return _jit(mapped, donate)
 
 
 def build_sim_step(cfg: LogConfig, n_replicas: int, *,
@@ -706,12 +554,12 @@ def build_sim_step(cfg: LogConfig, n_replicas: int, *,
                    elections: bool = True, audit: bool = False,
                    telemetry: bool = False, txn: bool = False):
     """Compile the protocol step as an N-replica simulation on one device
-    (``vmap`` with a named axis — identical collective semantics)."""
-    core = functools.partial(
-        replica_step, cfg=cfg, n_replicas=n_replicas,
-        axis_name=REPLICA_AXIS, use_pallas=use_pallas, interpret=interpret,
+    (``vmap`` with a named axis — identical collective semantics) over
+    its ONE packed argument ``[R, rows, 128]``."""
+    core = _replica_core(
+        cfg, n_replicas, use_pallas=use_pallas, interpret=interpret,
         fanout=fanout, elections=elections, audit=audit,
         telemetry=telemetry, txn=txn)
     vstep = jax.vmap(core, in_axes=(0, 0), axis_name=REPLICA_AXIS)
-    return jax.jit(_with_step_scalars(vstep),
-                   donate_argnums=(0,) if donate else ())
+    return _jit(_with_step_scalars(
+        vstep, arg_layout(cfg, n_replicas, 1, txn)), donate)

@@ -36,9 +36,9 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from rdma_paxos_tpu.config import LogConfig
-from rdma_paxos_tpu.consensus.log import EntryType, META_W
+from rdma_paxos_tpu.consensus.log import EntryType
 from rdma_paxos_tpu.consensus.step import (
-    SCAN_KEYS, StepInput, fetch_window, unpack_scalars)
+    SCAN_KEYS, arg_layout, fetch_window, unpack_scalars)
 from rdma_paxos_tpu.parallel.mesh import (
     REPLICA_AXIS, build_spmd_step, stack_states)
 
@@ -78,12 +78,11 @@ class HostReplicaDriver:
             # same kernel as the benches: Pallas quorum scan on TPU
             use_pallas=jax.default_backend() == "tpu")
         # one jitted burst builder (lazily built): the scan length
-        # follows the [K, ...] input shape, so jit specializes per K
+        # follows the packed argument's rows, so jit specializes per K
         self._burst = None
         # the K-window scan tier (lazily built; RP_SCAN=1 daemons):
         # fused steps + consolidated readback + local replay window
         self._scan = None
-        self._ksharding = NamedSharding(self.mesh, P(None, REPLICA_AXIS))
 
         # HOST-LOCAL window fetch: reads THIS replica's log shard only —
         # a single-device program outside the SPMD step, so hosts may
@@ -100,18 +99,17 @@ class HostReplicaDriver:
                                                  or self.R),
                                     self._sharding)
         self._local_dev = self.mesh.devices.flat[self.me]
-        # persistent zero-copy staging buffers for window encode:
-        # allocated once, repacked in place each iteration with only
-        # the previously-dirty rows zeroed (per-step [B,...] allocation
-        # + full memset was a measurable share of host_encode). Safe to
+        # persistent zero-copy staging rows for window encode, one a K
+        # (a single step is K = 1): THIS replica's row of the
+        # dispatch's ONE packed argument (``consensus/step.py``
+        # ``arg_layout``) with views of it by field, allocated once and
+        # repacked in place each iteration with only the
+        # previously-dirty rows zeroed (per-step [B,...] allocation +
+        # full memset was a measurable share of host_encode). Safe to
         # reuse because step()/step_burst() extract their outputs
         # before returning — the lock-step daemon never has a dispatch
         # in flight when the next iteration repacks.
-        B = cfg.batch_slots
-        self._stage = dict(
-            data=np.zeros((B, cfg.slot_words), np.int32),
-            meta=np.zeros((B, META_W), np.int32), dirty=0)
-        self._kstage: Dict[int, dict] = {}   # K -> burst staging set
+        self._kstage: Dict[int, dict] = {}   # K -> staging row
 
     # ------------------------------------------------------------------
 
@@ -164,36 +162,63 @@ class HostReplicaDriver:
             )
         self.state = upd(self.state, g)
 
-    def _global_from_local(self, local: np.ndarray,
-                           fill=0) -> jax.Array:
+    def _global_from_local(self, local: np.ndarray, fill=0,
+                           other: Optional[np.ndarray] = None
+                           ) -> jax.Array:
         """Build a [R, ...] global array where this host provides row
         ``me`` (other rows come from the other hosts). When several mesh
         devices are addressable by THIS process (single-process testing),
-        the extra rows are filled with the field's NEUTRAL value ``fill``
-        (0 = no input for batches/timeouts; peer_mask passes 1 — an
-        all-zero mask would make those replicas deaf, not idle)."""
+        the extra rows are ``other``, or filled with the NEUTRAL value
+        ``fill`` (0 = no input)."""
+        if other is None:
+            other = np.full_like(local, fill)
         shards = []
         for d in self.mesh.devices.flat:
             if d.process_index != jax.process_index():
                 continue
-            row = (local if d == self._local_dev
-                   else np.full_like(local, fill))
+            row = local if d == self._local_dev else other
             shards.append(jax.device_put(row[None], d))
         return jax.make_array_from_single_device_arrays(
             (self.R,) + local.shape, self._sharding, shards)
+
+    def _pack(self, K: int, batches, *, apply_done: int, gen: int,
+              queue_depth: int, timeout_fired: bool = False,
+              peer_mask: Optional[np.ndarray] = None) -> jax.Array:
+        """The ``[R, rows, 128]`` packed argument of a K-step dispatch,
+        this host providing its replica's row: up to K client batches
+        (empty on followers) and the small words, written into the
+        staging row's views. An extra row (single-process testing) is
+        the idle one: no input, everyone heard (an all-zero mask would
+        make those replicas deaf, not idle)."""
+        st = self._kstage.get(K)
+        if st is None:
+            lay = arg_layout(self.cfg, self.R, K)
+            packed = np.zeros(lay.shape(), np.int32)
+            st = self._kstage[K] = dict(
+                lay.views(packed), packed=packed, idle=lay.idle(),
+                dirty=[0] * K)
+        data, meta, dirty = st["data"], st["meta"], st["dirty"]
+        for k, n in enumerate(dirty):
+            if n:
+                data[k, :n] = 0
+                meta[k, :n] = 0
+                dirty[k] = 0
+        st["count"][:] = 0
+        for k, batch in enumerate(list(batches)[:K]):
+            dirty[k] = self._pack_batch(batch, data[k], meta[k], gen)
+            st["count"][k] = dirty[k]
+        st["peer_mask"][:] = 1 if peer_mask is None else peer_mask
+        st["applied"][...] = apply_done
+        st["qdepth"][...] = queue_depth
+        st["timeout"][...] = int(timeout_fired)
+        return self._global_from_local(st["packed"], other=st["idle"])
 
     def make_input(self, batch: Sequence[Tuple[int, int, int, bytes]] = (),
                    timeout_fired: bool = False,
                    apply_done: int = 0,
                    peer_mask: Optional[np.ndarray] = None,
-                   gen: int = 0, queue_depth: int = 0) -> StepInput:
-        cfg, B = self.cfg, self.cfg.batch_slots
-        st = self._stage
-        if st["dirty"]:
-            st["data"][:st["dirty"]] = 0
-            st["meta"][:st["dirty"]] = 0
-        data, meta = st["data"], st["meta"]
-        st["dirty"] = self._pack_batch(batch, data, meta, gen)
+                   gen: int = 0, queue_depth: int = 0) -> jax.Array:
+        """A single step's packed argument."""
         if peer_mask is not None and self._fanout == "psum":
             # the psum fan-out is sound only under full connectivity: a
             # partition mask could leave two self-claimed leaders whose
@@ -205,21 +230,10 @@ class HostReplicaDriver:
                     "psum fan-out requires an all-ones peer_mask; "
                     "build the driver with fanout='gather' to model "
                     "partitions")
-        pm = (np.ones(self.R, np.int32) if peer_mask is None
-              else peer_mask.astype(np.int32))
-        return StepInput(
-            batch_data=self._global_from_local(data),
-            batch_meta=self._global_from_local(meta),
-            batch_count=self._global_from_local(
-                np.asarray(min(len(batch), B), np.int32)),
-            timeout_fired=self._global_from_local(
-                np.asarray(int(timeout_fired), np.int32)),
-            peer_mask=self._global_from_local(pm, fill=1),
-            apply_done=self._global_from_local(
-                np.asarray(apply_done, np.int32)),
-            queue_depth=self._global_from_local(
-                np.asarray(queue_depth, np.int32)),
-        )
+        return self._pack(1, [batch], apply_done=apply_done, gen=gen,
+                          queue_depth=queue_depth,
+                          timeout_fired=timeout_fired,
+                          peer_mask=peer_mask)
 
     def _pack_batch(self, batch, data: np.ndarray, meta: np.ndarray,
                     gen: int) -> int:
@@ -267,20 +281,6 @@ class HostReplicaDriver:
         rows = np.asarray(local)
         return unpack_scalars(rows.reshape(-1, rows.shape[-1])[-1])
 
-    def _kglobal(self, local_k: np.ndarray, fill=0) -> jax.Array:
-        """[K, R, ...] global array sharded on axis 1; this host provides
-        column ``me`` (other columns come from the other hosts)."""
-        shards = []
-        for d in self.mesh.devices.flat:
-            if d.process_index != jax.process_index():
-                continue
-            col = (local_k if d == self._local_dev
-                   else np.full_like(local_k, fill))
-            shards.append(jax.device_put(col[:, None], d))
-        return jax.make_array_from_single_device_arrays(
-            (local_k.shape[0], self.R) + local_k.shape[1:],
-            self._ksharding, shards)
-
     def _burst_fn(self):
         if self._burst is None:
             from rdma_paxos_tpu.parallel.mesh import build_spmd_burst
@@ -307,30 +307,9 @@ class HostReplicaDriver:
         carries the heartbeat). Returns this replica's final-step
         outputs plus ``accepted`` summed over the burst."""
         assert K > 0, K
-        cfg, B = self.cfg, self.cfg.batch_slots
-        st = self._kstage.get(K)
-        if st is None:
-            st = self._kstage[K] = dict(
-                data=np.zeros((K, B, cfg.slot_words), np.int32),
-                meta=np.zeros((K, B, META_W), np.int32),
-                dirty=[0] * K)
-        data, meta, dirty = st["data"], st["meta"], st["dirty"]
-        for k, n in enumerate(dirty):
-            if n:
-                data[k, :n] = 0
-                meta[k, :n] = 0
-                dirty[k] = 0
-        count = np.zeros((K,), np.int32)
-        for k, batch in enumerate(list(batches)[:K]):
-            dirty[k] = self._pack_batch(batch, data[k], meta[k], gen)
-            count[k] = min(len(batch), B)
-        fn = self._burst_fn()
-        pm = self._global_from_local(np.ones(self.R, np.int32), fill=1)
-        ap = self._global_from_local(np.asarray(apply_done, np.int32))
-        qd = self._global_from_local(np.asarray(queue_depth, np.int32))
-        self.state, outs = fn(self.state, self._kglobal(data),
-                              self._kglobal(meta), self._kglobal(count),
-                              pm, ap, qd)
+        self.state, outs = self._burst_fn()(self.state, self._pack(
+            K, batches, apply_done=apply_done, gen=gen,
+            queue_depth=queue_depth))
         res = self._local_scalars(outs.scal, 1)
         if self._audit:
             # audit windows for EVERY fused step (not just the last) —
@@ -374,30 +353,9 @@ class HostReplicaDriver:
         ``res`` matches :meth:`step_burst`'s (``accepted`` summed,
         audit windows per fused step when compiled)."""
         assert K > 0, K
-        cfg, B = self.cfg, self.cfg.batch_slots
-        st = self._kstage.get(K)
-        if st is None:
-            st = self._kstage[K] = dict(
-                data=np.zeros((K, B, cfg.slot_words), np.int32),
-                meta=np.zeros((K, B, META_W), np.int32),
-                dirty=[0] * K)
-        data, meta, dirty = st["data"], st["meta"], st["dirty"]
-        for k, n in enumerate(dirty):
-            if n:
-                data[k, :n] = 0
-                meta[k, :n] = 0
-                dirty[k] = 0
-        count = np.zeros((K,), np.int32)
-        for k, batch in enumerate(list(batches)[:K]):
-            dirty[k] = self._pack_batch(batch, data[k], meta[k], gen)
-            count[k] = min(len(batch), B)
-        fn = self._scan_fn()
-        pm = self._global_from_local(np.ones(self.R, np.int32), fill=1)
-        ap = self._global_from_local(np.asarray(apply_done, np.int32))
-        qd = self._global_from_local(np.asarray(queue_depth, np.int32))
-        self.state, outs = fn(self.state, self._kglobal(data),
-                              self._kglobal(meta),
-                              self._kglobal(count), pm, ap, qd)
+        self.state, outs = self._scan_fn()(self.state, self._pack(
+            K, batches, apply_done=apply_done, gen=gen,
+            queue_depth=queue_depth))
 
         res = self._local_scalars(outs["scal"], 1)
         if self._audit and res["term"] is not None:

@@ -22,10 +22,10 @@ import numpy as np
 
 from rdma_paxos_tpu.config import LogConfig, REBASE_STALL_STEPS
 from rdma_paxos_tpu.consensus.log import (
-    EntryType, M_CONN, M_GIDX, M_LEN, M_REQID, M_TYPE, META_W)
+    EntryType, M_CONN, M_GIDX, M_LEN, M_REQID, M_TYPE)
 from rdma_paxos_tpu.consensus.state import Role
 from rdma_paxos_tpu.consensus.step import (
-    SCAN_KEYS, StepInput, fetch_rows, unpack_scalars)
+    SCAN_KEYS, arg_layout, fetch_rows, unpack_scalars)
 from rdma_paxos_tpu.obs.spans import held
 from rdma_paxos_tpu.parallel.mesh import (
     axes_spec, build_sim_burst, build_sim_scan, build_sim_step,
@@ -238,52 +238,48 @@ def decode_window(wm: np.ndarray, wd: np.ndarray, n: int,
 
 
 def make_put(cluster):
-    """The engine's ONE host-to-device put: ``put(arrays, stacked=0)``
-    takes a tuple of host arrays, the first ``stacked`` of them a
-    burst's ``[K, ...]`` stacks and the rest rows led by the mesh's
-    axes (``[R, ...]``, or the sharded engine's ``[G, R, ...]``), and
-    returns the device arguments in the same order. Bound once, to
-    ``cluster.mesh`` as it stands; both engines' dispatches, prewarm
-    and replay fetch go through it.
+    """The engine's ONE host-to-device put: ``put(array)`` takes ONE
+    host array whose leading axes are the mesh's (``[R, ...]``, or the
+    sharded engine's ``[G, R, ...]``) and returns it on the device.
+    Bound once, to ``cluster.mesh`` as it stands; both engines'
+    dispatches, prewarm, replay fetch and rebase go through it.
 
-    Without a mesh it is ``jnp.asarray`` an array (every row a vmap
-    row on the default device). With one, every array goes out in ONE
-    ``jax.device_put`` with the sharding the ``build_spmd_*`` programs'
-    ``in_specs`` name for it (``parallel/mesh.py`` ``axes_spec``: the
-    mesh's axes lead a row, ``P("replica")`` or ``P("group",
-    "replica")``; a stack has ``None`` before them),
-    each slice straight to its chip: an argument put on one chip is
-    split over the mesh INSIDE the call, one array after the other, on
-    the dispatch thread and under the host lock (0.7-1.0 ms each on
+    A dispatch hands it its ONE packed argument (``consensus/step.py``
+    ``arg_layout``: the burst's batches and small words, a row a
+    replica), where it handed over six arrays until PR 51: a put costs
+    by the ARRAY, not by the byte (about 0.28 ms each on one chip, 84
+    us a device buffer on a mesh, 0.03 ms a MB: PERF.md, PRs 46, 51).
+
+    Without a mesh it is ``jnp.asarray`` (every row a vmap row on the
+    default device). With one, ONE ``jax.device_put`` with the sharding
+    the ``build_spmd_*`` programs' ``in_specs`` name (``parallel/
+    mesh.py`` ``axes_spec``: ``P("replica")`` or ``P("group",
+    "replica")``), replica r's rows straight to chip r: an argument
+    put on one chip is split over the mesh INSIDE the call, on the
+    dispatch thread and under the host lock (0.7-1.0 ms an array on
     four chips: PERF.md, PR 46). The slices are views of the caller's
-    buffer and live by its rule (a ticket keeps its staging buffers
+    buffer and live by its rule (a ticket keeps its staging buffer
     until ``finish``).
 
-    Each call counts, where the engine has a profiler, the transfers
-    it started (``input_put_calls_total``: one a ``jnp.asarray``, one
-    a ``device_put`` whatever it carries) and the bytes it handed over
+    Each call counts, where the engine has a profiler, the transfer it
+    started (``input_put_calls_total``), the device buffers it made
+    (``input_put_buffers_total``: one without a mesh, one a chip of the
+    mesh with) and the bytes it handed over
     (``input_put_bytes_total``)."""
     mesh = cluster.mesh
+    buffers = 1 if mesh is None else mesh.size
+    rows = (None if mesh is None
+            else jax.sharding.NamedSharding(mesh, axes_spec(mesh)))
 
-    def count(arrays, calls):
+    def put(array):
         prof = cluster.profiler
         if prof is not None:
-            prof.count("input_put_calls_total", calls)
-            prof.count("input_put_bytes_total",
-                       sum(a.nbytes for a in arrays))
-
-    if mesh is None:
-        def put(arrays, stacked=0):
-            count(arrays, len(arrays))
-            return tuple(jnp.asarray(a) for a in arrays)
-        return put
-    rows = jax.sharding.NamedSharding(mesh, axes_spec(mesh))
-    stacks = jax.sharding.NamedSharding(mesh, axes_spec(mesh, 1))
-
-    def put(arrays, stacked=0):
-        count(arrays, 1)
-        return jax.device_put(
-            arrays, (stacks,) * stacked + (rows,) * (len(arrays) - stacked))
+            prof.count("input_put_calls_total", 1)
+            prof.count("input_put_buffers_total", buffers)
+            prof.count("input_put_bytes_total", array.nbytes)
+        if rows is None:
+            return jnp.asarray(array)
+        return jax.device_put(array, rows)
     return put
 
 
@@ -405,10 +401,19 @@ def read_scalars(ticket: StepTicket) -> Dict[str, np.ndarray]:
 class StagingPool:
     """Persistent, reusable host staging buffers for window encode.
 
+    A set is ONE contiguous i32 buffer, the dispatch's packed argument
+    (``packed``: ``[*lead, rows, 128]``, ``consensus/step.py``
+    ``arg_layout``), and VIEWS of it by field: ``data`` / ``meta``
+    (and ``data_u8``) where ``pack_rows`` writes the batch, the small
+    words (``count``, ``peer_mask``, ``applied``, ``qdepth``, ...)
+    where ``begin_*`` writes them, so that what was packed is what is
+    put, and no byte is copied twice.
+
     Allocating + zeroing the [R, B, slot_words] batch arrays every
     step was a measurable share of ``host_encode``; the pool hands out
     preallocated sets and zeroes ONLY the rows the previous user
-    actually wrote (recorded at release). A set stays checked out for
+    actually wrote (recorded at release; the small words are written
+    anew every dispatch). A set stays checked out for
     the lifetime of its ticket, so a pipelined driver can never
     overwrite a buffer an in-flight dispatch is still reading —
     double-buffering falls out of the pool discipline (depth D keeps
@@ -418,15 +423,24 @@ class StagingPool:
         self._pools: Dict[tuple, List[dict]] = {}
         self._lock = threading.Lock()
 
-    def acquire(self, key: tuple, make) -> dict:
+    def acquire(self, lay, lead: tuple, fused: bool = True) -> dict:
+        """A set for ``lay`` under the leading axes ``lead``; a single
+        step's (``fused=False``: K = 1) has the batch fields' K axis
+        dropped."""
+        key = (lay, lead, fused)
         with self._lock:
             pool = self._pools.setdefault(key, [])
             if pool:
                 return pool.pop()
-        bufs = make()
+        packed = np.zeros(lay.shape(lead), np.int32)
+        bufs = lay.views(packed)
+        if not fused:
+            for name in ("data", "meta", "count"):
+                bufs[name] = bufs[name][0]
         # u8 view of the payload words: zero-copy packing target (one
         # bytes->row copy per entry instead of pad+frombuffer+copy)
         bufs["data_u8"] = bufs["data"].view(np.uint8)
+        bufs["packed"] = packed
         bufs["key"] = key
         return bufs
 
@@ -817,18 +831,13 @@ class SimCluster:
     K_TIERS = (2, 4, 8, 16)
 
     def _step_bufs(self) -> dict:
-        cfg, R, B = self.cfg, self.R, self.cfg.batch_slots
         return self._staging.acquire(
-            ("step", R, B), lambda: dict(
-                data=np.zeros((R, B, cfg.slot_words), np.int32),
-                meta=np.zeros((R, B, META_W), np.int32)))
+            arg_layout(self.cfg, self.R, 1, self._txn), (self.R,),
+            fused=False)
 
     def _burst_bufs(self, K: int) -> dict:
-        cfg, R, B = self.cfg, self.R, self.cfg.batch_slots
         return self._staging.acquire(
-            ("burst", K, R, B), lambda: dict(
-                data=np.zeros((K, R, B, cfg.slot_words), np.int32),
-                meta=np.zeros((K, R, B, META_W), np.int32)))
+            arg_layout(self.cfg, self.R, K), (self.R,))
 
     # holds-lock: _host_lock
     def reserved_appends(self) -> np.ndarray:
@@ -862,7 +871,8 @@ class SimCluster:
                 "psum fan-out requires full connectivity; use "
                 "fanout='gather' to model partitions")
         bufs = self._step_bufs()
-        count = np.zeros((R,), np.int32)
+        count = bufs["count"]
+        count[:] = 0
         with held(prof, self._host_lock, "dispatch_lock_wait"):
             taken = []
             for r in range(R):
@@ -870,27 +880,26 @@ class SimCluster:
                 if take:
                     self.pending[r] = self.pending[r][B:]
                 taken.append(take)
-            qdepth = np.array([len(q) for q in self.pending], np.int32)
-            applied = self.applied.astype(np.int32)
+            bufs["qdepth"][:] = [len(q) for q in self.pending]
+            bufs["applied"][:] = self.applied
         for r, take in enumerate(taken):
             if take:
                 pack_rows(bufs, (r,), take, cfg.slot_bytes)
                 count[r] = len(take)
-        tmo = np.zeros((R,), np.int32)
+        tmo = bufs["timeout"]
+        tmo[:] = 0
         for r in timeouts:
             tmo[r] = 1
-        if prof is not None:
-            prof.start("input_transfer")
-        leaves = (bufs["data"], bufs["meta"], count, tmo, mask, applied,
-                  qdepth)
+        bufs["peer_mask"][:] = mask
         if self._txn:
             # device watch compares log offsets: shift the armed
             # ABSOLUTE index by the i32 rollovers applied so far
-            leaves += (
-                np.full((R,), (self._txn_watch - self.rebased_total
-                               if self._txn_watch >= 0 else -1), np.int32),
-                np.full((R,), self._txn_wterm, np.int32))
-        inp = StepInput(*self._put(leaves))     # in field order
+            bufs["txn_watch"][:] = (self._txn_watch - self.rebased_total
+                                    if self._txn_watch >= 0 else -1)
+            bufs["txn_term"][:] = self._txn_wterm
+        if prof is not None:
+            prof.start("input_transfer")
+        packed = self._put(bufs["packed"])
         if prof is not None:
             prof.stop("input_transfer")
         # no timer fired ⟹ Phase B is provably a no-op: dispatch the
@@ -904,7 +913,7 @@ class SimCluster:
         with held(prof, self._host_lock, "dispatch_lock_wait"):
             if prof is not None:
                 prof.start("program_call")
-            self.state, out = fn(self.state, inp)
+            self.state, out = fn(self.state, packed)
             if prof is not None:
                 prof.stop("program_call")
             ticket = StepTicket("step", out, taken, timeouts, 1, bufs)
@@ -959,12 +968,12 @@ class SimCluster:
                 take_n.append(n)
                 taken.append(self.pending[r][:n])
                 self.pending[r] = self.pending[r][n:]
-            qdepth = np.array([len(q) for q in self.pending], np.int32)
+            qdepth = [len(q) for q in self.pending]
             applied = self.applied.astype(np.int32)
         k_needed = max(1, max(-(-n // B) for n in take_n))
         K = next(k for k in tiers if k >= k_needed)
         bufs = self._burst_bufs(K)
-        count = np.zeros((K, R), np.int32)
+        count = bufs["count"]
         for r in range(R):
             n = take_n[r]
             for k in range(-(-n // B) if n else 0):
@@ -972,20 +981,22 @@ class SimCluster:
                           cfg.slot_bytes)
             for k in range(K):
                 count[k, r] = max(0, min(n - k * B, B))
+        bufs["peer_mask"][:] = mask
+        bufs["applied"][:] = applied
+        bufs["qdepth"][:] = qdepth
         scan = self.scan
         fn = self._scan_fn(K) if scan else self._burst_fn(K)
         if prof is not None:
             prof.stop("host_encode")
             prof.start("device_dispatch")
             prof.start("input_transfer")
-        args = self._put((bufs["data"], bufs["meta"], count, mask,
-                          applied, qdepth), stacked=3)
+        packed = self._put(bufs["packed"])
         if prof is not None:
             prof.stop("input_transfer")
         with held(prof, self._host_lock, "dispatch_lock_wait"):
             if prof is not None:
                 prof.start("program_call")
-            self.state, outs = fn(self.state, *args)
+            self.state, outs = fn(self.state, packed)
             if prof is not None:
                 prof.stop("program_call")
             ticket = StepTicket("scan" if scan else "burst", outs,
@@ -1252,35 +1263,30 @@ class SimCluster:
         first-use JIT pause of seconds mid-serving stalls the whole
         commit pipeline; paying it before traffic starts keeps the
         serving path pause-free."""
-        cfg, R, B = self.cfg, self.R, self.cfg.batch_slots
-        row = np.zeros((R,), np.int32)
-        # through the dispatches' own put: an argument placed otherwise
-        # is another executable of the same program, and the first
-        # served dispatch would compile it inside the loop
-        inp = StepInput(*self._put(
-            (np.zeros((R, B, cfg.slot_words), np.int32),
-             np.zeros((R, B, META_W), np.int32),
-             row, row, self.peer_mask, row, row)
-            + ((np.full((R,), -1, np.int32), row) if self._txn else ())))
+        cfg, R = self.cfg, self.R
+        # through the dispatches' own put, at the dispatches' own
+        # shapes: an argument placed otherwise is another executable
+        # of the same program, and the first served dispatch would
+        # compile it inside the loop
+        def idle(lay):
+            return self._put(lay.idle((R,), self.peer_mask))
+        packed = idle(arg_layout(cfg, R, 1, self._txn))
         for elections in (True, False):
             fn = self._build_step(elections=elections)
             st = jax.tree.map(lambda x: x.copy(), self.state)
-            fn(st, inp)
+            fn(st, packed)
         for K in (tiers if tiers is not None else self.K_TIERS):
             fns = [self._burst_fn(K)]
             if self.scan:
                 fns.append(self._scan_fn(K))
-            args = self._put(
-                (np.zeros((K, R, B, cfg.slot_words), np.int32),
-                 np.zeros((K, R, B, META_W), np.int32),
-                 np.zeros((K, R), np.int32), self.peer_mask, row, row),
-                stacked=3)
+            packed = idle(arg_layout(cfg, R, K))
             for fn in fns:
                 st = jax.tree.map(lambda x: x.copy(), self.state)
-                fn(st, *args)
+                fn(st, packed)
         # and the replay fetch at every width, so that no served use
         # compiles anything
-        self._replay_fetch.warm(self.state.log, *self._put((row,)))
+        self._replay_fetch.warm(self.state.log,
+                                self._put(np.zeros((R,), np.int32)))
 
     def step(self, timeouts: Sequence[int] = ()) -> Dict[str, np.ndarray]:
         require_drained(self._tickets, "step")
@@ -1504,7 +1510,7 @@ class SimCluster:
                     and self.applied[r] < int(res["commit"][r])]
             if not todo:
                 return
-            starts, = self._put((self.applied.astype(np.int32),))
+            starts = self._put(self.applied.astype(np.int32))
             need = max(int(res["commit"][r] - self.applied[r])
                        for r in todo)
             prof = self.profiler

@@ -57,9 +57,9 @@ import numpy as np
 
 from rdma_paxos_tpu.config import LogConfig, REBASE_STALL_STEPS
 from rdma_paxos_tpu.consensus.log import (
-    EntryType, M_CONN, M_GIDX, M_LEN, M_REQID, M_TYPE, META_W)
+    EntryType, M_CONN, M_GIDX, M_LEN, M_REQID, M_TYPE)
 from rdma_paxos_tpu.consensus.state import Role
-from rdma_paxos_tpu.consensus.step import StepInput
+from rdma_paxos_tpu.consensus.step import arg_layout
 from rdma_paxos_tpu.obs.spans import held
 from rdma_paxos_tpu.parallel.mesh import (
     GROUP_AXIS, REPLICA_AXIS, build_mesh_2d, build_sim_group_burst,
@@ -392,18 +392,13 @@ class ShardedCluster:
         return out
 
     def _step_bufs(self) -> dict:
-        cfg, G, R, B = self.cfg, self.G, self.R, self.cfg.batch_slots
         return self._staging.acquire(
-            ("gstep", G, R, B), lambda: dict(
-                data=np.zeros((G, R, B, cfg.slot_words), np.int32),
-                meta=np.zeros((G, R, B, META_W), np.int32)))
+            arg_layout(self.cfg, self.R, 1, self._txn), (self.G, self.R),
+            fused=False)
 
     def _burst_bufs(self, K: int) -> dict:
-        cfg, G, R, B = self.cfg, self.G, self.R, self.cfg.batch_slots
         return self._staging.acquire(
-            ("gburst", K, G, R, B), lambda: dict(
-                data=np.zeros((K, G, R, B, cfg.slot_words), np.int32),
-                meta=np.zeros((K, G, R, B, META_W), np.int32)))
+            arg_layout(self.cfg, self.R, K), (self.G, self.R))
 
     # holds-lock: _host_lock
     def reserved_appends(self) -> np.ndarray:
@@ -497,35 +492,29 @@ class ShardedCluster:
         copies of the live state. One compile covers ALL groups — the
         tiers are shared across groups by construction, and across
         clusters through the shared runtime cache."""
-        cfg, G, R, B = self.cfg, self.G, self.R, self.cfg.batch_slots
-        # through the dispatches' own put: a committed, sharded argument
-        # and an uncommitted one-chip argument are two executables of
-        # one ``jax.jit`` (see SimCluster.prewarm)
-        row = np.zeros((G, R), np.int32)
-        inp = StepInput(*self._put(
-            (np.zeros((G, R, B, cfg.slot_words), np.int32),
-             np.zeros((G, R, B, META_W), np.int32),
-             row, row, self.peer_mask, row, row)
-            + ((np.full((G, R), -1, np.int32), row)
-               if self._txn else ())))
+        cfg, G, R = self.cfg, self.G, self.R
+        # through the dispatches' own put, at the dispatches' own
+        # shapes: a committed, sharded argument and an uncommitted
+        # one-chip argument are two executables of one ``jax.jit``
+        # (see SimCluster.prewarm)
+        def idle(lay):
+            return self._put(lay.idle((G, R), self.peer_mask))
+        packed = idle(arg_layout(cfg, R, 1, self._txn))
         for elections in (True, False):
             fn, _ = self._build_step(elections=elections)
             st = jax.tree.map(lambda x: x.copy(), self.state)
-            fn(st, inp)
+            fn(st, packed)
         for K in (tiers if tiers is not None else self.K_TIERS):
             fns = [self._burst_fn(K)]
             if self.scan:
                 fns.append(self._scan_fn(K))
-            args = self._put(
-                (np.zeros((K, G, R, B, cfg.slot_words), np.int32),
-                 np.zeros((K, G, R, B, META_W), np.int32),
-                 np.zeros((K, G, R), np.int32), self.peer_mask, row,
-                 row), stacked=3)
+            packed = idle(arg_layout(cfg, R, K))
             for fn, _ in fns:
                 st = jax.tree.map(lambda x: x.copy(), self.state)
-                fn(st, *args)
+                fn(st, packed)
         # and the replay fetch at every width (SimCluster.prewarm)
-        self._replay_fetch.warm(self.state.log, *self._put((row,)))
+        self._replay_fetch.warm(self.state.log,
+                                self._put(np.zeros((G, R), np.int32)))
 
     def begin_step(self, timeouts: TimeoutsLike = (),
                    take_batch: bool = True) -> StepTicket:
@@ -546,8 +535,8 @@ class ShardedCluster:
                 "psum fan-out requires full connectivity; use "
                 "fanout='gather' to model partitions")
         bufs = self._step_bufs()
-        count = np.zeros((G, R), np.int32)
-        qdepth = np.zeros((G, R), np.int32)
+        count, qdepth = bufs["count"], bufs["qdepth"]
+        count[:] = 0
         with held(prof, self._host_lock, "dispatch_lock_wait"):
             taken: List[List[list]] = [[[] for _ in range(R)]
                                        for _ in range(G)]
@@ -559,33 +548,30 @@ class ShardedCluster:
                         self.pending[g][r] = self.pending[g][r][B:]
                     taken[g][r] = take
                     qdepth[g, r] = len(self.pending[g][r])
-            applied = self.applied.astype(np.int32)
+            bufs["applied"][:] = self.applied
         for g in range(G):
             for r in range(R):
                 take = taken[g][r]
                 if take:
                     pack_rows(bufs, (g, r), take, cfg.slot_bytes)
                     count[g, r] = len(take)
-        tmo_arr = np.zeros((G, R), np.int32)
+        tmo_arr = bufs["timeout"]
+        tmo_arr[:] = 0
         for g, rs in tmo.items():
             for r in rs:
                 tmo_arr[g, r] = 1
-        if prof is not None:
-            prof.start("input_transfer")
-        leaves = (bufs["data"], bufs["meta"], count, tmo_arr, mask,
-                  applied, qdepth)
+        bufs["peer_mask"][:] = mask
         if self._txn:
             # device watches compare log offsets: shift each armed
             # ABSOLUTE index by that group's i32 rollovers, then
             # broadcast across the replica axis
-            leaves += (
-                np.broadcast_to(
-                    np.where(self._txn_watch >= 0,
-                             self._txn_watch - self.rebased_total,
-                             -1)[:, None], (G, R)).astype(np.int32),
-                np.broadcast_to(self._txn_wterm[:, None],
-                                (G, R)).astype(np.int32))
-        inp = StepInput(*self._put(leaves))     # in field order
+            bufs["txn_watch"][:] = np.where(
+                self._txn_watch >= 0,
+                self._txn_watch - self.rebased_total, -1)[:, None]
+            bufs["txn_term"][:] = self._txn_wterm[:, None]
+        if prof is not None:
+            prof.start("input_transfer")
+        packed = self._put(bufs["packed"])
         if prof is not None:
             prof.stop("input_transfer")
         # no timer fired in ANY group ⟹ Phase B is provably a no-op
@@ -600,7 +586,7 @@ class ShardedCluster:
         with held(prof, self._host_lock, "dispatch_lock_wait"):
             if prof is not None:
                 prof.start("program_call")
-            self.state, out = fn(self.state, inp)
+            self.state, out = fn(self.state, packed)
             if prof is not None:
                 prof.stop("program_call")
             ticket = StepTicket("step", out, taken, tmo, 1, bufs)
@@ -662,7 +648,7 @@ class ShardedCluster:
         k_needed = max(1, int(-(-take_n.max() // B)))
         K = next(k for k in tiers if k >= k_needed)
         bufs = self._burst_bufs(K)
-        count = np.zeros((K, G, R), np.int32)
+        count = bufs["count"]
         for g in range(G):
             for r in range(R):
                 n = int(take_n[g, r])
@@ -672,20 +658,22 @@ class ShardedCluster:
                               cfg.slot_bytes)
                 for k in range(K):
                     count[k, g, r] = max(0, min(n - k * B, B))
+        bufs["peer_mask"][:] = mask
+        bufs["applied"][:] = applied
+        bufs["qdepth"][:] = qdepth
         scan = self.scan
         fn, key = self._scan_fn(K) if scan else self._burst_fn(K)
         if prof is not None:
             prof.stop("host_encode")
             prof.start("device_dispatch")
             prof.start("input_transfer")
-        args = self._put((bufs["data"], bufs["meta"], count, mask,
-                          applied, qdepth), stacked=3)
+        packed = self._put(bufs["packed"])
         if prof is not None:
             prof.stop("input_transfer")
         with held(prof, self._host_lock, "dispatch_lock_wait"):
             if prof is not None:
                 prof.start("program_call")
-            self.state, outs = fn(self.state, *args)
+            self.state, outs = fn(self.state, packed)
             if prof is not None:
                 prof.stop("program_call")
             ticket = StepTicket("scan" if scan else "burst", outs,
@@ -945,7 +933,7 @@ class ShardedCluster:
                     and self.applied[g, r] < int(res["commit"][g, r])]
             if not todo:
                 break
-            starts, = self._put((self.applied.astype(np.int32),))
+            starts = self._put(self.applied.astype(np.int32))
             need = max(int(res["commit"][g, r] - self.applied[g, r])
                        for g, r in todo)
             prof = self.profiler
@@ -1070,8 +1058,8 @@ class ShardedCluster:
         between chips (eager operations would put their constants on
         one chip and spread them over the mesh)."""
         from rdma_paxos_tpu.consensus.snapshot import rebase_offsets
-        d_gr, = self._put((np.broadcast_to(
-            deltas.astype(np.int32)[:, None], (self.G, self.R)),))
+        d_gr = self._put(np.broadcast_to(
+            deltas.astype(np.int32)[:, None], (self.G, self.R)))
         self.state = rebase_offsets(self.state, d_gr)
         if self.mesh is not None:
             # the program's outputs follow its inputs; re-place all the

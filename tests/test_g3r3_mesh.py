@@ -32,6 +32,7 @@ import pytest
 from rdma_paxos_tpu.config import LogConfig
 from rdma_paxos_tpu.consensus.log import META_W, EntryType
 from rdma_paxos_tpu.consensus.state import Role
+from rdma_paxos_tpu.consensus.step import arg_layout
 from rdma_paxos_tpu.obs.metrics import MetricsRegistry
 from rdma_paxos_tpu.obs.spans import StepPhaseProfiler
 from rdma_paxos_tpu.shard.cluster import ShardedCluster
@@ -176,12 +177,9 @@ def test_without_a_mesh_arguments_stay_one_device_arrays(monkeypatch):
     while c.rebases.min() < 1:      # a rebase's deltas too
         submit_each(c, 8)
         c.step()
-    put = c._put((np.zeros((2, G, R), np.int32),
-                  np.zeros((G, R), np.int32)), stacked=1)
-    assert isinstance(put, tuple) and len(put) == 2
-    for a in put:
-        assert isinstance(a.sharding, jax.sharding.SingleDeviceSharding)
-        assert not a.committed
+    a = c._put(np.zeros((G, R), np.int32))
+    assert isinstance(a.sharding, jax.sharding.SingleDeviceSharding)
+    assert not a.committed
 
 
 def test_mesh_prewarm_leaves_no_compile_for_the_served_path():
@@ -359,19 +357,26 @@ def test_mesh_path_records_the_phases_where_the_one_chip_path_does():
     assert one["fetch_enqueue"] == one["fetch_read"] == one["replay_fetch"]
     fetches = one["replay_fetch"]
     assert fetches >= 2
-    # one device_put a dispatch and one a fetch on the mesh; an
-    # asarray an argument without one (7 a step, 6 a burst, 1 a fetch)
+    # ONE array a step, one a burst, one a fetch, on either path: a
+    # device_put on the mesh, an asarray without one (six arrays a
+    # burst and seven a step until PR 51)
     assert mesh_counters["input_put_calls_total"] == 3 + fetches
-    assert one_counters["input_put_calls_total"] == 7 + 6 + 7 + fetches
-    # the bytes handed over are the staging buffers', mesh or none
+    assert one_counters["input_put_calls_total"] == 3 + fetches
+    # a device buffer a call without a mesh, one a chip with
+    assert one_counters["input_put_buffers_total"] == 3 + fetches
+    assert mesh_counters["input_put_buffers_total"] == 3 * (3 + fetches)
+    # the bytes handed over are the staging buffers', mesh or none: by
+    # the layout's formula, a row a (group, replica) pair
     assert (mesh_counters["input_put_bytes_total"]
             == one_counters["input_put_bytes_total"])
-    B, sw = GCFG.batch_slots, GCFG.slot_words
     row = 4 * G * R
-    step = row * B * (sw + META_W) + 4 * row + row * R
-    burst = 2 * row * B * (sw + META_W) + 2 * row + 2 * row + row * R
-    assert one_counters["input_put_bytes_total"] == (
-        2 * step + burst + fetches * row)
+    step, burst = (128 * arg_layout(GCFG, R, k).rows for k in (1, 2))
+    B, sw = GCFG.batch_slots, GCFG.slot_words
+    for k, width in ((1, step), (2, burst)):
+        words = k * B * (sw + META_W) + k + R + 3
+        assert 0 <= width - words < 1024 * k and width % 1024 == 0
+    assert one_counters["input_put_bytes_total"] == row * (
+        2 * step + burst + fetches)
     assert c.health()["mesh"] == dict(layout="1x3", group_shards=1,
                                       devices=[0, 1, 2])
 
@@ -382,6 +387,34 @@ def test_both_engines_probes_carry_the_put_counters_from_construction():
     counters = metrics.snapshot()["counters"]
     assert counters["input_put_calls_total"] == 0
     assert counters["input_put_bytes_total"] == 0
+    assert counters["input_put_buffers_total"] == 0
+
+
+@pytest.mark.parametrize("mesh", [None, MESH], ids=["one_chip", "mesh"])
+def test_put_buffers_a_dispatch_read_as_off_a_timeline(mesh):
+    """``counter.input_put_buffers_total`` over
+    ``phase.device_dispatch.count``, the way a run's ``timeline.json``
+    gives it (two probes of the profiler's account): a burst's ONE
+    array and its fetch's one are 2.0 a dispatch on one chip and 6.0
+    on the three-chip mesh; six arrays a burst read 7.0 and 21.0."""
+    c = placed(mesh=mesh)
+    metrics = MetricsRegistry()
+    c.profiler = StepPhaseProfiler(metrics)
+
+    def probe():
+        return dict(
+            buffers=metrics.snapshot()["counters"][
+                "input_put_buffers_total"],
+            dispatches=c.profiler.acc["device_dispatch"][0],
+            fetches=c.profiler.acc["replay_fetch"][0])
+    c.finish(c.begin_step())
+    opened = probe()
+    for _ in range(4):              # every burst commits and is fetched
+        submit_each(c, 12)
+        c.finish(c.begin_burst())
+    d = {k: v - opened[k] for k, v in probe().items()}
+    assert d["dispatches"] == d["fetches"] == 4
+    assert d["buffers"] / d["dispatches"] == (2.0 if mesh is None else 6.0)
 
 
 # ---------------------------------------------------------------------------

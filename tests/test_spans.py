@@ -1145,9 +1145,9 @@ def lowered_texts():
     import jax
     import jax.numpy as jnp
 
-    from rdma_paxos_tpu.consensus.step import make_step_input
+    from rdma_paxos_tpu.consensus.step import arg_layout
     c = SimCluster(ACCT_CFG, 3, audit=True, telemetry=True)
-    inp = jax.vmap(lambda _: make_step_input(ACCT_CFG, 3))(jnp.arange(3))
+    inp = jnp.zeros(arg_layout(ACCT_CFG, 3).shape((3,)), jnp.int32)
     step = c._build_step(elections=True).lower(c.state, inp)
     fetches = {W: fn.lower(c.state.log, jnp.zeros((3,), jnp.int32))
                for W, fn in c._fetch_all.programs.items()}

@@ -218,12 +218,9 @@ def test_default_mode_arguments_stay_one_device_arrays(monkeypatch):
     c.finish(c.begin_burst())
     c.finish(c.begin_step())        # the followers' commit
     assert len(c.replayed[2]) == 13
-    put = c._put((np.zeros((2, 3), np.int32), np.zeros(3, np.int32)),
-                 stacked=1)
-    assert isinstance(put, tuple) and len(put) == 2
-    for a in put:
-        assert isinstance(a.sharding, jax.sharding.SingleDeviceSharding)
-        assert not a.committed
+    a = c._put(np.zeros(3, np.int32))
+    assert isinstance(a.sharding, jax.sharding.SingleDeviceSharding)
+    assert not a.committed
 
 
 def test_spmd_prewarm_leaves_no_compile_for_the_served_path():

@@ -28,7 +28,7 @@ import pytest
 
 from rdma_paxos_tpu.config import LogConfig
 from rdma_paxos_tpu.consensus.log import META_W, ROW_ALIGN, Log, row_words
-from rdma_paxos_tpu.consensus.step import GROUP_BATCH_AXIS, make_step_input
+from rdma_paxos_tpu.consensus.step import GROUP_BATCH_AXIS, arg_layout
 from rdma_paxos_tpu.parallel import mesh as pm
 
 # n_slots appears in no other dimension of any program below
@@ -95,21 +95,17 @@ def _state(R):
     return jax.eval_shape(lambda: pm.stack_states(CFG, R, R))
 
 
+def _packed(R, k):
+    """The ONE host-fed argument of a ``k``-step dispatch, abstract."""
+    return jax.ShapeDtypeStruct(arg_layout(CFG, R, k).shape((R,)), jnp.int32)
+
+
 def _step_args(R):
-    inp = jax.eval_shape(lambda: jax.tree.map(
-        lambda x: jnp.broadcast_to(x, (R,) + x.shape),
-        make_step_input(CFG, R)))
-    return _state(R), inp
+    return _state(R), _packed(R, 1)
 
 
 def _burst_args(R):
-    i32 = jnp.int32
-    sds = jax.ShapeDtypeStruct
-    return (_state(R),
-            sds((K, R, CFG.batch_slots, CFG.slot_words), i32),
-            sds((K, R, CFG.batch_slots, META_W), i32),
-            sds((K, R), i32), sds((R, R), i32), sds((R,), i32),
-            sds((R,), i32))
+    return _state(R), _packed(R, K)
 
 
 G = 3   # groups a device holds in the group mappings
@@ -118,15 +114,9 @@ G = 3   # groups a device holds in the group mappings
 def _grouped(args, n_groups):
     """A single-group builder's abstract arguments with the group axis
     put before the replica axis of every leaf."""
-    def lead(x, at):
-        return jax.ShapeDtypeStruct(
-            x.shape[:at] + (n_groups,) + x.shape[at:], x.dtype)
-    if len(args) == 2:          # (state, StepInput)
-        return jax.tree.map(lambda x: lead(x, 0), args)
-    # state, then the K-stacked inputs, then the per-replica ones
-    return (jax.tree.map(lambda x: lead(x, 0), args[0]),
-            *(lead(x, 1) for x in args[1:4]),
-            *(lead(x, 0) for x in args[4:]))
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((n_groups,) + x.shape, x.dtype),
+        args)
 
 
 def _group_program(kind, R):
@@ -405,22 +395,28 @@ def test_compiled_for_a_v5e_the_step_copies_no_ring(v5e):
     ``conditional`` no ``copy`` or ``transpose`` is ring-sized. At 136
     columns the runtime rested the ring slot-minor (``{1,2,0}``) and
     the step converted all of it on entry and back on exit, every
-    dispatch: 1.87 of c50's 3.1 ms step (PERF.md section 6, PR 50)."""
+    dispatch: 1.87 of c50's 3.1 ms step (PERF.md section 6, PR 50).
+    The program is ``jit_burst`` and takes ONE host-fed parameter
+    beside the state (PR 51: six arrays a put cost 1.65 ms a
+    dispatch, whatever they held)."""
     def on_chip(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e)
-    R, i32, B = 3, jnp.int32, CELLS.batch_slots
+    R = 3
     state = jax.tree.map(on_chip, jax.eval_shape(
         lambda: pm.stack_states(CELLS, R, R)))
-    args = (state,
-            on_chip(jax.ShapeDtypeStruct((K, R, B, CELLS.slot_words), i32)),
-            on_chip(jax.ShapeDtypeStruct((K, R, B, META_W), i32)),
-            on_chip(jax.ShapeDtypeStruct((K, R), i32)),
-            on_chip(jax.ShapeDtypeStruct((R, R), i32)),
-            on_chip(jax.ShapeDtypeStruct((R,), i32)),
-            on_chip(jax.ShapeDtypeStruct((R,), i32)))
+    shape = arg_layout(CELLS, R, K).shape((R,))
+    args = (state, on_chip(jax.ShapeDtypeStruct(shape, jnp.int32)))
     fn = pm.build_sim_burst(CELLS, R, fanout="psum", use_pallas=True)
     hlo = fn.lower(*args).compile().as_text()
+    # the name the trace readers select the step's program by
+    assert hlo.startswith("HloModule jit_burst,"), hlo[:80]
     comps, entry = _computations(hlo)
+    # ONE host-fed parameter beside the state: the packed argument
+    n_state = len(jax.tree.leaves(state))
+    params = [line for line in comps[entry] if " parameter(" in line]
+    assert len(params) == n_state + 1, len(params)
+    (packed,) = [line for line in params if "s32[%d,%d,%d]" % shape in line]
+    assert "packed" in packed, packed
     ring = "s32[%d,%d,%d]" % (R, CELLS.n_slots, row_words(CELLS.slot_words))
     (param,) = [line for line in comps[entry]
                 if " parameter(" in line and ring in line]
