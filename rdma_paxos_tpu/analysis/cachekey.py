@@ -37,7 +37,7 @@ PASS_ID = "cache-key"
 # are read in a builder (new flags get added HERE, once)
 STATIC_FLAGS: Set[str] = {
     "cfg", "R", "_mode", "_use_pallas", "_interpret", "_fanout",
-    "_audit", "_telemetry", "_mesh_key", "_txn",
+    "_audit", "_telemetry", "_key_mesh", "_txn",
 }
 
 # reads that are legitimately NOT in the key because another key
@@ -45,7 +45,7 @@ STATIC_FLAGS: Set[str] = {
 COVERED_BY: Dict[str, Tuple[str, ...]] = {
     # the replica/device mesh is constructed from (cfg, R) + the
     # engine mode / static device layout, both key components
-    "mesh": ("_mode", "_mesh_key"),
+    "mesh": ("_mode", "_key_mesh"),
 }
 
 # never program-shaping: cache plumbing and builder machinery

@@ -30,6 +30,8 @@ SCOPE = (
     "rdma_paxos_tpu/ops/",
     "rdma_paxos_tpu/parallel/",
     "rdma_paxos_tpu/shard/",
+    # the engines' one body (ClusterEngine) and its single-group front
+    # end; the sharded front end is under shard/ above
     "rdma_paxos_tpu/runtime/sim.py",
     "rdma_paxos_tpu/runtime/timers.py",
     "rdma_paxos_tpu/runtime/hostpath.py",

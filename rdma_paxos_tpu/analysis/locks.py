@@ -55,12 +55,15 @@ PASS_ID = "lock-discipline"
 # where accesses are checked (attr-name matching also catches e.g.
 # ``self.cluster.pending`` reads from the drivers)
 LOCK_MODULES = (
+    # ClusterEngine: BOTH engines' guarded fields are declared here, once
     "rdma_paxos_tpu/runtime/sim.py",
     "rdma_paxos_tpu/runtime/driver.py",
     "rdma_paxos_tpu/runtime/sharded_driver.py",
     "rdma_paxos_tpu/runtime/repair.py",
     "rdma_paxos_tpu/runtime/reads.py",
     "rdma_paxos_tpu/runtime/governor.py",
+    # declares nothing since the engines share one body; its accesses
+    # (the front end's addressing) are still checked
     "rdma_paxos_tpu/shard/cluster.py",
     "rdma_paxos_tpu/streams/__init__.py",
     "rdma_paxos_tpu/streams/scan.py",

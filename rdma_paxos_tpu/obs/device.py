@@ -452,14 +452,6 @@ def _analyze(lowered) -> dict:
     return out
 
 
-def _unpack_build(built):
-    """Engines disagree on the builder return shape: SimCluster gives
-    the callable, ShardedCluster a ``(callable, cache_key)`` pair."""
-    if isinstance(built, tuple):
-        return built[0]
-    return built
-
-
 def program_report(cluster, *, tiers: Sequence[int] = ()) -> dict:
     """Cost/memory report for every step variant this cluster serves
     (full + stable step, plus the requested fused-burst tiers) —
@@ -471,12 +463,12 @@ def program_report(cluster, *, tiers: Sequence[int] = ()) -> dict:
     cfg = cluster.cfg
     variants = []
     for elections in (True, False):
-        fn = _unpack_build(cluster._build_step(elections=elections))
+        fn, _ = cluster._program("step", elections=elections)
         row = dict(variant=("step/full" if elections else "step/stable"))
         row.update(_analyze(fn.lower(*_example_args(cluster))))
         variants.append(row)
     for K in tiers:
-        fn = _unpack_build(cluster._burst_fn(K))
+        fn, _ = cluster._program("burst", K)
         row = dict(variant="burst/K=%d" % K)
         row.update(_analyze(fn.lower(*_example_args(cluster, K))))
         variants.append(row)

@@ -8,12 +8,19 @@ per device of a real mesh (``mode="spmd"``, shard_map).
 
 Partitions/crashes are expressed through per-replica ``peer_mask`` rows —
 the analog of ``reconf_bench.sh`` killing processes, but reproducible.
+
+The host bookkeeping is written once, in ``ClusterEngine``, over a grid
+of cells; ``SimCluster`` (one group, cells ``(r,)``) and
+``shard.cluster.ShardedCluster`` (G groups, cells ``(g, r)``) are its two
+front ends. The module-level rules above the class are what the body is
+made of and what ``runtime/host.py`` and the drivers share with it.
 """
 
 from __future__ import annotations
 
 import collections
 import threading
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -21,8 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from rdma_paxos_tpu.config import LogConfig, REBASE_STALL_STEPS
-from rdma_paxos_tpu.consensus.log import (
-    EntryType, M_CONN, M_GIDX, M_LEN, M_REQID, M_TYPE)
+from rdma_paxos_tpu.consensus.log import EntryType, M_GIDX
 from rdma_paxos_tpu.consensus.state import Role
 from rdma_paxos_tpu.consensus.step import (
     SCAN_KEYS, arg_layout, fetch_rows, unpack_scalars)
@@ -46,12 +52,9 @@ STEP_CACHE: Dict[tuple, object] = {}
 
 
 # ---------------------------------------------------------------------------
-# Shared host-bookkeeping rules — ONE implementation for BOTH engines
-# (SimCluster and shard.cluster.ShardedCluster). These four rules used
-# to be duplicated with a group index bolted on; any drift between the
-# copies silently broke the G=1 ≡ SimCluster bit-equivalence contract,
-# so the rules now live here and both engines call them (the ROADMAP
-# carried-over refactor unlocking the mesh/e2e/resharding work).
+# Host-bookkeeping rules as module functions: what ``ClusterEngine``'s
+# methods call once a cell (or once an entry: these stay free of the
+# grid), and what is shared with callers outside the engines.
 # ---------------------------------------------------------------------------
 
 def redigest_fn(cfg: LogConfig, window_slots: int):
@@ -181,15 +184,16 @@ def clamp_burst_take(pending_len: int, end: int, head: int,
     return min(pending_len, max(avail, 0), max_take)
 
 
-def count_ring(prof, last, res, taken, n_slots: int, g=...) -> None:
+def count_ring(prof, last, res, taken, n_slots: int, g=()) -> None:
     """What one dispatch did to the leader's ring, off the packed row
     against the last dispatch's (``last`` is kept in the same rebase
     frame as the state, and the rollover's delta is a multiple of
     ``n_slots``): slots the pruner gave back (``head``'s advance),
     entries offered that the capacity clamp did not take (they are
     queued again: the same ``accepted`` the requeue rule reads), and
-    turns of the ring ``end`` completed. Of one group (the sharded
-    engine's ``g``); no leader, or two claims, counts nothing."""
+    turns of the ring ``end`` completed. Of one group (its scope
+    ``g``, ``taken`` its replicas' takes); no leader, or two claims,
+    counts nothing."""
     if last is None:
         return
     lead = np.flatnonzero(res["role"][g] == int(Role.LEADER))
@@ -487,21 +491,139 @@ def assemble_frames(types, conns, lens, raw, idxs) -> bytes:
                                      blob, offs)
 
 
-class SimCluster:
-    """N-replica protocol simulation with host-side bookkeeping."""
+def cell_of(nest, idx: tuple):
+    """The entry of a per-cell nest of lists (``pending``, ``replayed``,
+    ``frames``, a ticket's ``taken``) at ``idx``: ``nest[r]`` in a
+    single group, ``nest[g][r]`` in the sharded engine. The group
+    prefix ``idx[:-1]`` gives that group's list of replicas (the whole
+    nest in a single group)."""
+    for i in idx:
+        nest = nest[i]
+    return nest
+
+
+def set_cell(nest, idx: tuple, value) -> None:
+    """Rebind the entry of a per-cell nest at ``idx``."""
+    cell_of(nest, idx[:-1])[idx[-1]] = value
+
+
+def nest_cells(flat: List, lead: tuple) -> List:
+    """A list with one entry a cell, in cell order, as the nest the
+    callers index: itself under ``(R,)``, ``[g][r]`` under ``(G, R)``."""
+    for n in reversed(lead[1:]):
+        flat = [flat[i:i + n] for i in range(0, len(flat), n)]
+    return flat
+
+
+class ScopeCounter:
+    """A host counter kept one a rebase scope (a consensus group): an
+    int64 array of the scope shape, under ``_<name>`` on the instance,
+    which the shared body indexes by a cell's group prefix. From
+    outside it is that array (``rebased_total[g]``) where the engine
+    has groups and a plain int where it is ONE group (scope shape
+    ``()``): the drivers put it into health documents as it comes."""
+
+    def __set_name__(self, owner, name):
+        self.slot = "_" + name
+
+    def __get__(self, obj, owner=None):
+        if obj is None:
+            return self
+        arr = getattr(obj, self.slot)
+        return arr if arr.ndim else int(arr)
+
+    def __set__(self, obj, value):
+        getattr(obj, self.slot)[...] = value
+
+
+class ClusterEngine:
+    """The host bookkeeping of BOTH engines, written once over the grid
+    of cells: the dispatch cycle (``begin_step`` / ``begin_burst`` /
+    ``finish``), requeue, replay, audit, rebase, spans and the choice
+    of the compiled program.
+
+    A cell is one replica's row of one consensus group and is named by
+    an index tuple: ``(r,)`` in a single group (``SimCluster``, lead
+    shape ``(R,)``), ``(g, r)`` in the sharded engine
+    (``ShardedCluster``, lead shape ``(G, R)``). ``res[k][idx]``,
+    ``self.applied[idx]`` and ``pack_rows(bufs, (k,) + idx, ...)`` mean
+    the same thing under both; the lists a caller indexes
+    (``pending[r]`` / ``pending[g][r]``) are reached with ``cell_of``.
+    A cell's group prefix ``idx[:-1]`` is its SCOPE: what rolls over
+    together, has one leader, one txn watch and one link model (``()``
+    is the single group: one scope over all replicas).
+
+    The two classes below are front ends: a constructor, the table of
+    ``parallel/mesh.py`` builders with the engine's part of the
+    ``STEP_CACHE`` key, a few hooks where the engines truly differ
+    (``_norm_timeouts``, ``_link_models``, ``_span_rep``,
+    ``_count_appends``, ``_observe``), and the public addressing (the
+    sharded methods take a ``group`` first). What is seen from outside
+    keeps its shape: ``res[k]`` is ``[R]`` or ``[G, R]``, and
+    ``need_recovery`` / ``_wedged`` / ``read_blocked`` hold ``r`` or
+    ``(g, r)``."""
 
     # legacy alias (tests and callers key off the class attribute);
     # the SAME dict object as the module-level shared cache
     _STEP_CACHE: Dict[tuple, object] = STEP_CACHE
 
-    def __init__(self, cfg: LogConfig, n_replicas: int,
-                 group_size: Optional[int] = None, *, mode: str = "sim",
-                 use_pallas: Optional[bool] = None,
-                 interpret: bool = False,
-                 fanout: str = "gather", stable_fast_path: bool = True,
-                 audit: bool = False, flight_capacity: int = 64,
-                 telemetry: bool = False, scan: bool = False,
-                 txn: bool = False):
+    # burst size tiers: the smallest tier >= the steps needed is compiled
+    # (bounded recompiles) and padded with zero-count steps
+    K_TIERS = (2, 4, 8, 16)
+
+    # consecutive post-threshold zero-delta steps before the stall is
+    # declared — shared with NodeDaemon (config.REBASE_STALL_STEPS)
+    REBASE_STALL_STEPS = REBASE_STALL_STEPS
+
+    # coordinated i32-offset rollovers performed (see _maybe_rebase)
+    rebases = ScopeCounter()
+    rebased_total = ScopeCounter()
+    # rebase-stall surfacing (ADVICE.md #3): a heard-but-lagging
+    # row's low head pins the agreed delta at 0, so end marches
+    # toward the i32 ceiling with no rollover possible. Consecutive
+    # post-threshold steps with delta 0 are counted; past
+    # REBASE_STALL_STEPS each further step increments
+    # ``rebase_stalled`` (and the attached registry's counter), and
+    # the transition emits one ``rebase_stalled`` trace event
+    # (re-armed by the next successful rollover).
+    rebase_stall_steps = ScopeCounter()
+    rebase_stalled = ScopeCounter()
+
+    # ---------------- what a front end supplies ----------------
+
+    # kind ("step" | "burst" | "scan") -> (the kind's mark in a
+    # STEP_CACHE key, its builder without a mesh, its builder over one)
+    _PROGRAMS: Dict[str, tuple] = {}
+
+    def _norm_timeouts(self, timeouts) -> tuple:
+        """``(kept, cells)``: the fired election timers as the ticket
+        and the flight record keep them, and as cell indices."""
+        raise NotImplementedError
+
+    def _link_models(self) -> dict:
+        """scope -> attached chaos link model (an int names a group)."""
+        raise NotImplementedError
+
+    def _span_rep(self, *idx) -> int:
+        """A cell's replica id in the span recorder's heaps."""
+        raise NotImplementedError
+
+    def _count_appends(self, prof, appended: int) -> None:
+        """``appended`` scopes' leaders appended in this dispatch."""
+        raise NotImplementedError
+
+    def _observe(self, res) -> None:
+        """Per-scope metric series at the tail of ``finish``."""
+        raise NotImplementedError
+
+    # ---------------- construction ----------------
+
+    def __init__(self, cfg: LogConfig, lead: tuple, state, *, mode: str,
+                 mesh, key_mesh: tuple, state_sharding,
+                 group_size: Optional[int], use_pallas: Optional[bool],
+                 interpret: bool, fanout: str, stable_fast_path: bool,
+                 audit: bool, flight_capacity: int, telemetry: bool,
+                 scan: bool, txn: bool):
         self.cfg = cfg
         # device-resident K-window scan tier (hostpath PR): with
         # scan=True, begin_burst dispatches the fused-scan program —
@@ -513,19 +635,30 @@ class SimCluster:
         # their STEP_CACHE keys are untouched (tests pin it).
         self.scan = bool(scan)
         self.scan_dispatches = 0
-        self.R = n_replicas
-        self.group_size = group_size or n_replicas
+        self._lead = lead = tuple(int(n) for n in lead)
+        self.R = lead[-1]
+        self.group_size = group_size or self.R
+        # the grid: every cell's index, beside the key it has in
+        # need_recovery / _wedged (r, or (g, r)); and every scope's
+        self._cells = list(np.ndindex(*lead))
+        self._keyed = [(idx, idx if len(idx) > 1 else idx[0])
+                       for idx in self._cells]
+        self._scopes = list(np.ndindex(*lead[:-1]))
         self._mode = mode
+        # the mesh's part of a STEP_CACHE key (nothing where the mode
+        # says it all; the static device layout otherwise)
+        self._key_mesh = key_mesh
         # correctness observability (obs/audit.py): audit=True compiles
         # the digest-chain step variants (distinct cache keys — the
         # default programs are untouched), feeds every step's digest
-        # windows to a cluster AuditLedger, and records a bounded
-        # flight ring of step inputs/outputs for post-mortem dumps
+        # windows to a cluster AuditLedger keyed (group, term, index),
+        # and records a bounded flight ring of step inputs/outputs for
+        # post-mortem dumps
         self._audit = audit
         if audit:
             from rdma_paxos_tpu.obs.audit import (
                 AuditLedger, FlightRecorder)
-            self.auditor = AuditLedger(n_replicas)
+            self.auditor = AuditLedger(self.R, *lead[:-1])
             self.flight = FlightRecorder(flight_capacity)
         else:
             self.auditor = None
@@ -535,23 +668,29 @@ class SimCluster:
         # programs untouched, exactly the audit= discipline), reduces
         # each dispatch's vectors host-side at finish() (the readback
         # thread under the pipelined driver), accumulates them into
-        # ``device_counters`` [R, T_N], and exports device_* registry
-        # series when an obs facade is attached
+        # ``device_counters`` [*lead, T_N], and exports device_*
+        # registry series when an obs facade is attached. On a mesh the
+        # out_specs gather brings every chip's vector back into the
+        # global array, so per-shard counters survive the shard_map
+        # (tests pin mesh ≡ vmap telemetry parity).
         self._telemetry = telemetry
         if telemetry:
             from rdma_paxos_tpu.obs import device as _device
-            self.device_counters = _device.zeros(n_replicas)
+            self.device_counters = _device.zeros(*lead)
         else:
             self.device_counters = None
         # cross-group transaction lane (txn/lane.py): txn=True compiles
-        # the prepare-vote step variants (distinct cache keys — default
-        # programs untouched, exactly the audit=/telemetry= discipline;
-        # tests/test_txn.py pins txn=False bit-identity). The armed
-        # watch is host state in the ABSOLUTE index domain; begin_step
-        # converts to the log-offset domain the device compares in.
+        # the prepare-vote SERIAL step variants (distinct cache keys —
+        # default programs untouched, exactly the audit=/telemetry=
+        # discipline; tests/test_txn.py pins txn=False bit-identity;
+        # burst/scan programs never carry the lane). The armed watch,
+        # one a scope, is host state in the ABSOLUTE index domain;
+        # begin_step converts to the log-offset domain the device
+        # compares in, and the votes come back as the ``[*lead]``
+        # matrix from the SAME dispatch that replicated the prepares.
         self._txn = txn
-        self._txn_watch = -1      # absolute prepare index (-1 = clear)
-        self._txn_wterm = 0       # term the prepare was appended under
+        self._txn_watch = np.full(lead[:-1], -1, np.int64)  # -1 = clear
+        self._txn_wterm = np.zeros(lead[:-1], np.int64)
         # production default: the Pallas quorum kernel on TPU (same code
         # path as the benches), jnp reference scan elsewhere
         if use_pallas is None:
@@ -563,26 +702,27 @@ class SimCluster:
         # election timer fired (the latency hot path — Phase B statically
         # removed, one fewer collective); compiled lazily on first use
         self._stable_fast_path = stable_fast_path
+        self.mesh = mesh
+        # placed across the mesh up front, so the donated step never
+        # pays a layout change mid-serving
+        self._state_sharding = state_sharding
         # the donated device-state handle: REBINDING it races the next
         # dispatch  # guarded-by: _host_lock [writes]
-        self.state = stack_states(cfg, n_replicas, self.group_size)
-        if mode == "spmd":
-            mkey = (cfg, n_replicas, "mesh")
-            if mkey not in self._STEP_CACHE:
-                self._STEP_CACHE[mkey] = make_replica_mesh(n_replicas)
-            self.mesh = self._STEP_CACHE[mkey]
-            self._step = self._build_step(elections=True)
-            self.state = jax.device_put(
-                self.state,
-                jax.sharding.NamedSharding(
-                    self.mesh, jax.sharding.PartitionSpec("replica")))
-        else:
-            self.mesh = None
-            self._step = self._build_step(elections=True)
-        # every argument of a dispatch and of the replay fetch goes to
-        # the device through this (shardings built here, once)
+        self.state = (state if state_sharding is None
+                      else jax.device_put(state, state_sharding))
+        self._program("step", elections=True)
+        # compile-count accounting: every shared-cache key this cluster
+        # dispatches through (the single-compile guard's witness)
+        self.programs_used: set = set()
+        # device dispatch counters: protocol steps (the one-dispatch-
+        # per-step claim shard_bench proves) and replay fetch sweeps
+        self.dispatches = 0
+        self.fetch_dispatches = 0
+        # every argument of a dispatch, of prewarm, of the replay fetch
+        # and of a rebase goes to the device through this (shardings
+        # built here, once)
         self._put = make_put(self)
-        # all replicas' windows in ONE dispatch (the per-replica loop of
+        # all cells' windows in ONE dispatch (the per-replica loop of
         # fetch+slice dispatches dominated the host replay path). The
         # REPLAY window is wider than the protocol window: a K-step
         # burst commits up to K*batch_slots entries at once, and each
@@ -593,19 +733,19 @@ class SimCluster:
                              max(4 * cfg.window_slots, 256))
         # _fetch_all is what the fetch is CALLED through (a traced
         # benchmark run wraps it)
-        self._replay_fetch = ReplayFetch(self._replay_W, 1)
+        self._replay_fetch = ReplayFetch(self._replay_W, len(lead))
         self._fetch_all = self._replay_fetch
         # host bookkeeping
         # host apply cursor — single-writer: advanced in-place by the
         # finishing (readback) thread only; whole-array WRITES rebind
         # under the lock  # guarded-by: _host_lock [writes]
-        self.applied = np.zeros(n_replicas, np.int64)
-        self.peer_mask = np.ones((n_replicas, n_replicas), np.int32)
+        self.applied = np.zeros(lead, np.int64)
+        # every scope its own hear-matrix: its own fault domain
+        self.peer_mask = np.ones(lead + (self.R,), np.int32)
         # a split that partition() found sound under the psum fan-out
         self._psum_split = False
         # guarded-by: _host_lock
-        self.pending: List[List[Tuple[int, int, int, bytes]]] = [
-            [] for _ in range(n_replicas)]
+        self.pending = nest_cells([[] for _ in self._cells], lead)
         # pipelined dispatch (begin_*/finish): FIFO of in-flight
         # tickets, the staging-buffer pool, and the dispatch
         # concurrency counters (max_inflight_dispatches is the
@@ -623,36 +763,27 @@ class SimCluster:
         # a complete (stale at worst) result dict by design
         # guarded-by: _host_lock [writes]
         self.last: Optional[Dict[str, np.ndarray]] = None
-        # (type, conn_id, req_id, payload) per replica, in apply order
+        # (type, conn_id, req_id, payload) per cell, in apply order
         # — columnar LazyReplayStream batches on the hot path, legacy
         # tuple view on demand (tests/models/recovery)
-        self.replayed: List[LazyReplayStream] = [
-            LazyReplayStream() for _ in range(n_replicas)]
+        self.replayed = nest_cells(
+            [LazyReplayStream() for _ in self._cells], lead)
         # store-ready framed blobs (([u32 len][etype][conn][payload])*)
         # built VECTORIZED during the window decode — the driver hands
         # them to StableStore.append_framed untouched. Only produced
         # when a consumer opts in (collect_frames), so pure-sim tests
         # don't accumulate them.
         self.collect_frames = False
-        self.frames: List[List[bytes]] = [[] for _ in range(n_replicas)]
-        # replicas whose log was force-pruned past their apply cursor
+        self.frames = nest_cells([[] for _ in self._cells], lead)
+        # cells whose log was force-pruned past their apply cursor
         # (force_log_pruning left them behind): replay stops — recycled
         # slots must never reach the app — until snapshot recovery
         self.need_recovery: set = set()
         self._wedged: set = set()     # test hook: frozen apply (wedged app)
-        # coordinated i32-offset rollovers performed (see _maybe_rebase)
-        self.rebases = 0
-        self.rebased_total = 0
-        # rebase-stall surfacing (ADVICE.md #3): a heard-but-lagging
-        # row's low head pins the agreed delta at 0, so end marches
-        # toward the i32 ceiling with no rollover possible. Consecutive
-        # post-threshold steps with delta 0 are counted; past
-        # REBASE_STALL_STEPS each further step increments
-        # ``rebase_stalled`` (and the attached registry's counter), and
-        # the transition emits one ``rebase_stalled`` trace event
-        # (re-armed by the next successful rollover).
-        self.rebase_stall_steps = 0
-        self.rebase_stalled = 0
+        self._rebases = np.zeros(lead[:-1], np.int64)
+        self._rebased_total = np.zeros(lead[:-1], np.int64)
+        self._rebase_stall_steps = np.zeros(lead[:-1], np.int64)
+        self._rebase_stalled = np.zeros(lead[:-1], np.int64)
         # host-side observability facade (rdma_paxos_tpu.obs); attached
         # by ClusterDriver (or tests). NEVER read inside jitted code —
         # instrumentation must not change compiled-step cache keys.
@@ -662,14 +793,6 @@ class SimCluster:
         # fenced device sync / quorum-wait readback / apply). Host-side
         # only; with fence off it never blocks and never imports jax.
         self.profiler = None
-        # pluggable per-link fault model (rdma_paxos_tpu.chaos.faults
-        # .LinkModel): when attached, each step's peer_mask INPUT is
-        # rewritten host-side into the effective hear-matrix
-        # (asymmetric breaks, seeded drop/delay/dup, crashed
-        # replicas). Purely a data rewrite — compiled-step cache keys
-        # are unchanged (tests/test_chaos.py guards it). step_index is
-        # the logical clock the model's per-step randomness keys on.
-        self.link_model = None
         # read-path subsystem (runtime/reads.py, attached via
         # reads.attach): step-domain leader leases observed — and the
         # queued read hub drained — at the tail of every finish(),
@@ -692,7 +815,8 @@ class SimCluster:
         # exactly like leases/reads. Pure host bookkeeping: the tier
         # it picks is always one of the prewarmed K_TIERS programs,
         # so it adds no STEP_CACHE keys (tests/test_governor.py pins
-        # the ladder-only contract).
+        # the ladder-only contract). ONE program spans all scopes, so
+        # the dispatch uses the max over the per-group rungs.
         self.governor = None
         # cross-group 2PC coordinator (txn/coordinator.py, attached via
         # txn.attach_coordinator): observed at the very tail of every
@@ -700,11 +824,17 @@ class SimCluster:
         # is next-step demand. Pure host bookkeeping; the device lane
         # it reads rides the txn= step variant's cache keys only.
         self.txn = None
-        # replicas barred from SERVING reads by the repair pipeline
+        # elastic topology controller (topology/transition.py,
+        # attached via topology.attach_topology to an engine with
+        # groups): fed record placements from the stamp loop (same
+        # outside-the-host-lock contract as txn) and observed at the
+        # finish() tail, after txn. Host bookkeeping only.
+        self.topology = None
+        # cells barred from SERVING reads by the repair pipeline
         # (digest quarantine AND the storm policy, whose holds leave
         # replay running and so never enter need_recovery) — consulted
         # by the KVS serving gate and the read hub; keys match
-        # need_recovery's shape (r here, (g, r) on the sharded engine)
+        # need_recovery's shape
         self.read_blocked: set = set()
         self.step_index = 0
         # dispatch-side logical clock: advances at begin_* (step_index
@@ -719,19 +849,945 @@ class SimCluster:
         from rdma_paxos_tpu.analysis import runtime_guard
         runtime_guard.maybe_guard(self, "_host_lock", __file__)
 
+    # ---------------- addressing shared by the front ends ----------------
+
+    @staticmethod
+    def _labels(scope: tuple) -> dict:
+        """The labels of a scope's metric series, trace events, ledger
+        windows and span keys: its group, none for the single group."""
+        return {"group": scope[0]} if scope else {}
+
+    def _submit(self, idx: tuple, entries) -> None:
+        """Locked: a concurrent ``begin_*`` batch take swaps the pending
+        list object, and an unlocked append to the old object would be
+        silently lost."""
+        with self._host_lock:
+            cell_of(self.pending, idx).extend(entries)
+
+    def _arm_txn_watch(self, scope: tuple, index: int, term: int) -> None:
+        if not self._txn:
+            raise RuntimeError("set_txn_watch requires txn=True")
+        self._txn_watch[scope] = int(index)
+        self._txn_wterm[scope] = int(term)
+
+    def _disarm_txn_watch(self, scope: tuple) -> None:
+        self._txn_watch[scope] = -1
+        self._txn_wterm[scope] = 0
+
+    def _partition(self, scope: tuple, groups) -> None:
+        """Split one scope's replicas: they hear only same-group peers.
+        One rebind of the matrix: a dispatch on another thread reads
+        the old one or the new one, never a half-written one."""
+        mask = np.zeros((self.R, self.R), np.int32)
+        for g in groups:
+            for i in g:
+                for j in g:
+                    mask[i, j] = 1
+        np.fill_diagonal(mask, 1)
+        full = self.peer_mask.copy()
+        full[scope] = mask
+        self.peer_mask = full
+
+    def _heal(self, scope: tuple) -> None:
+        full = self.peer_mask.copy()
+        full[scope] = 1
+        self.peer_mask = full
+        self._psum_split = False
+
+    def _leader(self, scope: tuple) -> int:
+        """The scope's leader iff exactly one replica claims it."""
+        assert self.last is not None
+        ids = [r for r in range(self.R)
+               if self.last["role"][scope + (r,)] == int(Role.LEADER)]
+        return ids[0] if len(ids) == 1 else -1
+
+    def _elect(self, idx: tuple, timeouts, max_steps: int) -> int:
+        for _ in range(max_steps):
+            res = self.step(timeouts=timeouts)
+            if res["role"][idx] == int(Role.LEADER):
+                return idx[-1]
+        raise AssertionError(
+            "election did not converge"
+            + (" in group %d" % idx[0] if len(idx) > 1 else ""))
+
+    # ---------------- the compiled programs ----------------
+
+    def _scan_slots(self, K: int) -> int:
+        """The scan tier's staged replay width: a K-step scan advances
+        commit by at most ``K * batch_slots``, so a small-K dispatch
+        never pays the full replay window's extract/transfer (the
+        fallback fetch covers a host that fell further behind)."""
+        return min(self._replay_W,
+                   max(K * self.cfg.batch_slots,
+                       self.cfg.window_slots))
+
+    def _program(self, kind: str, K: Optional[int] = None,
+                 elections: Optional[bool] = None) -> tuple:
+        """``(program, key)``: fetch (or compile once into the SHARED
+        runtime cache) the protocol step (``kind="step"``, with or
+        without ``elections``), the K-step burst or the K-window scan
+        of this engine's static config — the single source of every
+        variant, so they can never drift apart in build flags, and the
+        ONE place a ``STEP_CACHE`` key is formed. The key carries
+        everything static that shapes the program — the engine mode
+        and (``_key_mesh``) the static device layout — and deliberately
+        NOT a group count: the jitted callable is batch-size-
+        polymorphic, so clusters of ANY group count share one entry
+        per variant. The "audit" / "telemetry" / "txn" marks are
+        appended ONLY when asked for, and "scan" keys exist only on
+        scan=True clusters: default clusters' keys (and programs) are
+        bit-identical to the ones from before each option
+        (tests/test_audit.py and its siblings guard exactly this)."""
+        mark, build, build_mesh = self._PROGRAMS[kind]
+        if self.mesh is not None:
+            build = build_mesh
+        step = kind == "step"
+        slots = self._scan_slots(K) if kind == "scan" else None
+        key = ((self.cfg, self.R, self._mode) + self._key_mesh
+               + (self._use_pallas, self._interpret, self._fanout) + mark
+               + ((elections,) if step else (K,) if slots is None
+                  else (K, slots))
+               + (("audit",) if self._audit else ())
+               + (("telemetry",) if self._telemetry else ())
+               + (("txn",) if self._txn and step else ()))
+        fn = STEP_CACHE.get(key)
+        if fn is None:
+            kw = dict(use_pallas=self._use_pallas,
+                      interpret=self._interpret, fanout=self._fanout,
+                      audit=self._audit, telemetry=self._telemetry)
+            if step:
+                kw.update(elections=elections, txn=self._txn)
+            if slots is not None:
+                kw.update(replay_slots=slots)
+            over = () if self.mesh is None else (self.mesh,)
+            fn = build(self.cfg, self.R, *over, **kw)
+            STEP_CACHE[key] = fn
+        return fn, key
+
+    def prewarm(self, tiers: Optional[Sequence[int]] = None) -> None:
+        """Compile every step variant and burst tier up front (on copies
+        of the live state — donation would otherwise consume it). A
+        first-use JIT pause of seconds mid-serving stalls the whole
+        commit pipeline; paying it before traffic starts keeps the
+        serving path pause-free. One compile covers ALL groups, and all
+        clusters through the shared runtime cache."""
+        cfg, R, lead = self.cfg, self.R, self._lead
+        # through the dispatches' own put, at the dispatches' own
+        # shapes: an argument placed otherwise (a committed, sharded
+        # argument and an uncommitted one-chip argument) is another
+        # executable of the same ``jax.jit``, and the first served
+        # dispatch would compile it inside the loop
+        def idle(lay):
+            return self._put(lay.idle(lead, self.peer_mask))
+
+        def run(fn, packed):
+            fn(jax.tree.map(lambda x: x.copy(), self.state), packed)
+        packed = idle(arg_layout(cfg, R, 1, self._txn))
+        for elections in (True, False):
+            run(self._program("step", elections=elections)[0], packed)
+        for K in (tiers if tiers is not None else self.K_TIERS):
+            packed = idle(arg_layout(cfg, R, K))
+            for kind in ("burst", "scan") if self.scan else ("burst",):
+                run(self._program(kind, K)[0], packed)
+        # and the replay fetch at every width, so that no served use
+        # compiles anything
+        self._replay_fetch.warm(self.state.log,
+                                self._put(np.zeros(lead, np.int32)))
+
+    # ---------------- stepping ----------------
+
+    def _effective_mask(self) -> np.ndarray:
+        """The step's hear-matrix: the base peer_mask, each scope's
+        refined by its attached link model (host-side data only; psum
+        fan-out still requires the EFFECTIVE mask to be full)."""
+        models = self._link_models()
+        if not models:
+            return self.peer_mask
+        mask = self.peer_mask.copy()
+        for scope, lm in models.items():
+            mask[scope] = lm.effective_mask(mask[scope],
+                                            self._dispatch_clock)
+        return mask
+
+    def _dispatch_mask(self) -> np.ndarray:
+        mask = self._effective_mask()
+        if (self._fanout == "psum" and not self._psum_split
+                and not mask.all()):
+            raise ValueError(
+                "psum fan-out requires full connectivity; use "
+                "fanout='gather' to model partitions")
+        return mask
+
+    def _step_bufs(self) -> dict:
+        return self._staging.acquire(
+            arg_layout(self.cfg, self.R, 1, self._txn), self._lead,
+            fused=False)
+
+    def _burst_bufs(self, K: int) -> dict:
+        return self._staging.acquire(
+            arg_layout(self.cfg, self.R, K), self._lead)
+
+    # holds-lock: _host_lock
+    def reserved_appends(self) -> np.ndarray:
+        """Per-cell appends dispatched but not yet finished — the
+        pipelined capacity reservation (``end`` has not caught up).
+        Callers hold ``_host_lock`` (begin_burst's capacity sizing and
+        the chaos runner's drained-serial room check)."""
+        out = np.zeros(self._lead, np.int64)
+        for t in self._tickets:
+            for idx in self._cells:
+                out[idx] += len(cell_of(t.taken, idx))
+        return out
+
+    def _dispatch(self, prof, fn, key, packed, kind: str, taken: List,
+                  timeouts, K: int, bufs: dict,
+                  applied0=None) -> StepTicket:
+        """Call the program on the packed argument and queue its
+        ticket (``taken`` as the nest callers index), under the host
+        lock: the state handle is rebound."""
+        with held(prof, self._host_lock, "dispatch_lock_wait"):
+            if prof is not None:
+                prof.start("program_call")
+            self.state, out = fn(self.state, packed)
+            if prof is not None:
+                prof.stop("program_call")
+            ticket = StepTicket(kind, out, taken, timeouts, K, bufs,
+                                applied0=applied0)
+            if kind == "scan":
+                self.scan_dispatches += 1
+            self._tickets.append(ticket)
+            self.inflight_dispatches += 1
+            self.max_inflight_dispatches = max(
+                self.max_inflight_dispatches, self.inflight_dispatches)
+        if prof is not None:
+            prof.stop("device_dispatch")
+        self.dispatches += 1
+        self.programs_used.add(key)
+        self._dispatch_clock += K
+        return ticket
+
+    def begin_step(self, timeouts=(),
+                   take_batch: bool = True) -> StepTicket:
+        """Encode + DISPATCH one protocol step for every cell in one
+        device dispatch; returns immediately with the in-flight ticket
+        (pass to :meth:`finish`, FIFO). ``timeouts`` fires election
+        timers, in the front end's addressing. With
+        ``take_batch=False`` no client entries are packed (heartbeat /
+        election dispatches of the pipelined driver, which routes all
+        appends through capacity-clamped bursts so a shortfall requeue
+        can never reorder against in-flight dispatches)."""
+        cfg, B = self.cfg, self.cfg.batch_slots
+        prof = self.profiler
+        if prof is not None:
+            prof.start("host_encode")
+        timeouts, fired = self._norm_timeouts(timeouts)
+        mask = self._dispatch_mask()
+        bufs = self._step_bufs()
+        count, qdepth = bufs["count"], bufs["qdepth"]
+        count[:] = 0
+        with held(prof, self._host_lock, "dispatch_lock_wait"):
+            taken = []
+            for idx in self._cells:
+                queue = cell_of(self.pending, idx)
+                take = queue[:B] if take_batch else []
+                if take:
+                    set_cell(self.pending, idx, queue[B:])
+                taken.append(take)
+                qdepth[idx] = len(queue) - len(take)
+            bufs["applied"][:] = self.applied
+        for idx, take in zip(self._cells, taken):
+            if take:
+                pack_rows(bufs, idx, take, cfg.slot_bytes)
+                count[idx] = len(take)
+        tmo = bufs["timeout"]
+        tmo[:] = 0
+        for idx in fired:
+            tmo[idx] = 1
+        bufs["peer_mask"][:] = mask
+        if self._txn:
+            # device watches compare log offsets: shift each armed
+            # ABSOLUTE index by its scope's i32 rollovers so far, then
+            # broadcast across the replica axis
+            bufs["txn_watch"][:] = np.where(
+                self._txn_watch >= 0,
+                self._txn_watch - self._rebased_total, -1)[..., None]
+            bufs["txn_term"][:] = self._txn_wterm[..., None]
+        if prof is not None:
+            prof.start("input_transfer")
+        packed = self._put(bufs["packed"])
+        if prof is not None:
+            prof.stop("input_transfer")
+        # no timer fired in ANY scope ⟹ Phase B is provably a no-op:
+        # dispatch the stable step (bit-identical outputs, one fewer
+        # collective)
+        fn, key = self._program(
+            "step", elections=bool(fired) or not self._stable_fast_path)
+        if prof is not None:
+            prof.stop("host_encode")
+            prof.start("device_dispatch")
+        return self._dispatch(prof, fn, key, packed, "step",
+                              nest_cells(taken, self._lead), timeouts, 1,
+                              bufs)
+
+    def _tiers(self, max_k: Optional[int]) -> Tuple[int, ...]:
+        """Fused tiers bounded at ``max_k`` (the shared ``cap_tiers``
+        rule — a subset of ``K_TIERS``, never a new compile)."""
+        return cap_tiers(self.K_TIERS, max_k)
+
+    def begin_burst(self, max_k: Optional[int] = None) -> StepTicket:
+        """Encode + DISPATCH up to ``max(K_TIERS)`` fused protocol
+        steps for every cell; returns immediately with the in-flight
+        ticket. Capacity sizing subtracts appends reserved by OTHER
+        in-flight tickets, so pipelined bursts can never overrun the
+        ring (a mid-burst drop would reorder a connection's
+        fragments). ``max_k`` caps the tier choice (and the take) at a
+        lower rung of the same ladder — the governor's dial."""
+        cfg, B = self.cfg, self.cfg.batch_slots
+        assert self.last is not None, "burst requires a stepped cluster"
+        prof = self.profiler
+        if prof is not None:
+            prof.start("host_encode")
+        mask = self._dispatch_mask()
+        tiers = self._tiers(max_k)
+        with held(prof, self._host_lock, "dispatch_lock_wait"):
+            # capacity sizing: never enqueue more than the ring can
+            # take without drops, so mid-burst drops (which would
+            # reorder a connection's fragments against later steps)
+            # cannot occur
+            reserved = self.reserved_appends()
+            last = self.last
+            taken, qdepth = [], []
+            for idx in self._cells:
+                queue = cell_of(self.pending, idx)
+                n = clamp_burst_take(
+                    len(queue), int(last["end"][idx]),
+                    int(last["head"][idx]), cfg.n_slots,
+                    tiers[-1] * B, int(reserved[idx]))
+                taken.append(queue[:n])
+                set_cell(self.pending, idx, queue[n:])
+                qdepth.append(len(queue) - n)
+            applied = self.applied.astype(np.int32)
+        k_needed = max(1, max(-(-len(take) // B) for take in taken))
+        K = next(k for k in tiers if k >= k_needed)
+        bufs = self._burst_bufs(K)
+        count = bufs["count"]
+        for idx, take in zip(self._cells, taken):
+            n = len(take)
+            for k in range(-(-n // B)):
+                pack_rows(bufs, (k,) + idx, take[k * B:(k + 1) * B],
+                          cfg.slot_bytes)
+            for k in range(K):
+                count[(k,) + idx] = max(0, min(n - k * B, B))
+        bufs["peer_mask"][:] = mask
+        bufs["applied"][:] = applied
+        bufs["qdepth"][:] = np.reshape(qdepth, self._lead)
+        kind = "scan" if self.scan else "burst"
+        fn, key = self._program(kind, K)
+        if prof is not None:
+            prof.stop("host_encode")
+            prof.start("device_dispatch")
+            prof.start("input_transfer")
+        packed = self._put(bufs["packed"])
+        if prof is not None:
+            prof.stop("input_transfer")
+        return self._dispatch(
+            prof, fn, key, packed, kind, nest_cells(taken, self._lead),
+            self._norm_timeouts(())[0], K, bufs,
+            applied0=applied if kind == "scan" else None)
+
+    def finish(self, ticket: StepTicket) -> Dict[str, np.ndarray]:
+        """Block on ``ticket``'s outputs and run every post-step host
+        rule (requeue, replay, audit, flight, rebase, spans) — tickets
+        MUST finish in dispatch order. ``step()``/``step_burst()`` are
+        exactly ``finish(begin_*())``; the pipelined driver finishes
+        from its readback thread while the next dispatch encodes."""
+        assert self._tickets and self._tickets[0] is ticket, \
+            "tickets must finish in dispatch (FIFO) order"
+        # NOT popped here: until ``last`` below reflects this ticket's
+        # appends, a concurrent ``begin_*`` must keep counting them via
+        # reserved_appends() — an early pop would let its capacity
+        # clamp over-admit (and a lockless pop would mutate the deque
+        # under the dispatch thread's locked iteration)
+        prof = self.profiler
+        out = ticket.out
+        scan = ticket.kind == "scan"
+        fused = ticket.kind != "step"
+        if prof is not None:
+            prof.sync(out)              # fenced device_sync (opt-in)
+            prof.start("quorum_wait")
+        res = read_scalars(ticket)      # [*lead] per key
+        # what is compiled only on request keeps a read of its own
+        # (``readback_rest``): none in the default programs
+        reads = 1
+        if prof is not None:
+            prof.start("readback_rest")
+        if not fused and self._txn and out.txn_vote is not None:
+            # serial dispatches only: the txn lane never rides
+            # burst/scan programs (their keys stay untouched)
+            res["txn_vote"] = np.asarray(out.txn_vote)
+            reads += 1
+        if prof is not None:
+            prof.stop("readback_rest")
+            prof.count("readback_arrays_total", reads)
+            # program steps whose full-ring rescan branch ran: its
+            # predicate is reduced over the groups of one program, so
+            # the column reads alike in all of them
+            prof.count("cfg_rescans_total", int(res["cfg_rescanned"].max()))
+            prof.stop("quorum_wait")
+            prof.start("post_readback")
+            for scope in self._scopes:
+                count_ring(prof, self.last, res,
+                           cell_of(ticket.taken, scope),
+                           self.cfg.n_slots, scope)
+        if self._audit:
+            # ingest BEFORE _maybe_rebase: the emitted indices are raw
+            # (pre-rollover), consistent with the current rebased_total
+            if fused:
+                # each fused step emitted its own digest window: ingest
+                # them in order so the tiling property (no gaps) holds
+                get = (out.__getitem__ if scan
+                       else lambda k: getattr(out, "commit"
+                                              if k == "audit_commit"
+                                              else k))
+                a_s = np.asarray(get("audit_start"))   # [K, *lead]
+                a_d = np.asarray(get("audit_digest"))  # [K, *lead, W]
+                a_t = np.asarray(get("audit_term"))    # [K, *lead, W]
+                a_c = np.asarray(get("audit_commit"))  # [K, *lead]
+                for k in range(a_s.shape[0]):
+                    self._ingest_audit(a_s[k], a_d[k], a_t[k], a_c[k])
+                res["audit_start"] = a_s[-1]
+                res["audit_digest"] = a_d[-1]
+                res["audit_term"] = a_t[-1]
+            else:
+                for k in ("audit_start", "audit_digest", "audit_term"):
+                    res[k] = np.asarray(getattr(out, k))
+                self._ingest_audit(res["audit_start"],
+                                   res["audit_digest"],
+                                   res["audit_term"], res["commit"])
+        if self._telemetry:
+            # device-truth counters: reduce the dispatch's per-step
+            # vectors (sum counters / min headroom over a fused burst),
+            # fold into the host accumulator, and export device_*
+            # registry series — all on THIS thread, which under the
+            # pipelined driver is the readback thread (finish runs
+            # there), so telemetry never rides the dispatch path
+            from rdma_paxos_tpu.obs import device as _device
+            tv = np.asarray(out["telemetry"] if scan
+                            else out.telemetry, dtype=np.int64)
+            res["telemetry"] = _device.reduce_steps(tv) if fused else tv
+            _device.accumulate(self.device_counters, res["telemetry"])
+            _device.ingest(self.obs, res["telemetry"])
+        # ring-full backpressure / deposition: the appended set is a
+        # PREFIX of ``taken`` — requeue the remainder in order
+        # (submissions to non-leaders are dropped by design)
+        takes = [(idx, cell_of(ticket.taken, idx)) for idx in self._cells]
+        noted = self.txn is not None or self.topology is not None
+        notes = []
+        appended = 0        # scopes whose leader appended in this dispatch
+        with self._host_lock:
+            for idx, take in takes:
+                if take and res["role"][idx] == int(Role.LEADER):
+                    acc = int(res["accepted"][idx])
+                    appended += acc > 0
+                    self._stamp_appends(idx, take, acc, res)
+                    if noted and acc > 0:
+                        # (group, replica, ...): the single group is 0
+                        notes.append(((0,) + idx)[-2:] + (
+                            take[:acc], int(res["term"][idx]),
+                            int(res["end"][idx])
+                            + int(self._rebased_total[idx[:-1]])))
+                    requeue_shortfall(cell_of(self.pending, idx), take,
+                                      acc)
+        # OUTSIDE _host_lock: note_appends takes the coordinator (or
+        # controller) lock, which client threads hold while submitting
+        # (coordinator -> cluster order) — calling it from the stamp
+        # loop would be the reverse order, an ABBA deadlock against
+        # kvs.transact()
+        for note in notes:
+            if self.txn is not None:
+                self.txn.note_appends(*note)
+            if self.topology is not None:
+                self.topology.note_appends(*note)
+        if prof is not None:
+            self._count_appends(prof, appended)
+            prof.stop("post_readback")
+            prof.start("apply")
+        self._replay_committed(
+            res, scan_rows=((out["replay_data"], out["replay_meta"],
+                             ticket.applied0) if scan else None))
+        if prof is not None:
+            prof.stop("apply")
+            prof.start("finish_tail")
+        if self._audit:
+            self._record_flight(res, ticket.taken, ticket.timeouts,
+                                burst_k=ticket.K)
+        # the i32 rollover rewrites offsets host-side: it must never
+        # run under dispatches still in flight (their outputs carry
+        # pre-rollover offsets) — defer until the pipeline drains; the
+        # threshold stays crossed, so the draining finish applies it
+        with self._host_lock:
+            self._tickets.popleft()     # retire: last now covers it
+            self.inflight_dispatches -= 1
+            if not self._tickets:
+                self._maybe_rebase(res)
+            self.last = res
+        self.step_index += ticket.K
+        self._observe_spans(res)
+        self._observe(res)
+        # read path: renew/revoke leases from this FINISHED step's
+        # verified-quorum outputs, then serve due queued reads —
+        # between pipelined tickets, never inside one
+        if self.leases is not None:
+            self.leases.observe(self, res)
+        if self.reads is not None:
+            self.reads.drain(self)
+        if self.streams is not None:
+            self.streams.observe(self, res)
+        if self.governor is not None:
+            self.governor.observe(self, res)
+        if self.txn is not None:
+            self.txn.observe(self, res)
+        if self.topology is not None:
+            self.topology.observe(self, res)
+        if fused:
+            B = self.cfg.batch_slots
+            self._staging.release(ticket.bufs, [
+                ((k,) + idx, min(B, len(take) - k * B))
+                for idx, take in takes
+                for k in range(-(-len(take) // B))])
+        else:
+            self._staging.release(ticket.bufs, [
+                (idx, len(take)) for idx, take in takes])
+        if prof is not None:
+            prof.stop("finish_tail")
+        return res
+
+    def drain(self) -> Optional[Dict[str, np.ndarray]]:
+        """Finish every in-flight ticket in order; returns the final
+        result (or None when nothing was in flight)."""
+        res = None
+        while self._tickets:
+            res = self.finish(self._tickets[0])
+        return res
+
+    def step(self, timeouts=()) -> Dict[str, np.ndarray]:
+        """One protocol step for EVERY cell in one device dispatch
+        (``timeouts`` as :meth:`begin_step` takes them). Returns
+        ``[*lead]`` result arrays."""
+        require_drained(self._tickets, "step")
+        return self.finish(self.begin_step(timeouts))
+
+    def step_burst(self, max_k: Optional[int] = None
+                   ) -> Dict[str, np.ndarray]:
+        """Drain the pending queues through up to ``max(K_TIERS)`` fused
+        protocol steps in ONE device dispatch (multi-step driver mode —
+        the host-side analog of the reference's busy commit loop). No
+        election timeouts fire inside the burst; the caller must only
+        burst while every trafficked group has a known leader. Returns
+        the final step's outputs (``accepted`` aggregated over the
+        burst). With ``scan=True`` the dispatch rides the K-window scan
+        tier (same step outputs, consolidated readback + in-dispatch
+        replay rows). ``max_k`` caps the tier at a lower ladder rung
+        (the governor's dial)."""
+        require_drained(self._tickets, "step_burst")
+        return self.finish(self.begin_burst(max_k=max_k))
+
+    # ---------------- host apply / rebase ----------------
+
+    def _apply_window(self, idx: tuple, key, wm, wd, n: int) -> None:
+        """``n`` fetched rows into cell ``idx``'s replay stream.
+
+        Force-pruned laggards: when the ring no longer PHYSICALLY holds
+        entry `applied` (a newer entry recycled its slot — possible
+        once forced pruning let appends run ahead of a wedged member's
+        apply), replaying would feed garbage to the app. The stamped
+        global index (M_GIDX) proves integrity: fetched-entry gidx ==
+        expected index, else flag for snapshot recovery and stop.
+        Being merely below `head` is NOT sufficient to flag — the
+        benign one-step lazy-push lag puts followers there routinely
+        while their slots are still intact."""
+        if n > 0 and int(wm[0, M_GIDX]) != self.applied[idx]:
+            self.need_recovery.add(key)         # slot recycled
+            return
+        decode_window(wm, wd, n, cell_of(self.replayed, idx),
+                      cell_of(self.frames, idx), self.collect_frames,
+                      rebase=int(self._rebased_total[idx[:-1]]))
+        self.applied[idx] += n
+
+    def _replay_committed(self, res, scan_rows=None) -> None:
+        """Host apply loop: fetch newly committed entries from the device
+        log and 'replay' them (tests record them; the real driver hands
+        them to the proxy) — apply_committed_entries analog
+        (dare_server.c:1815-1974). All cells' windows ride ONE fetch
+        dispatch per sweep (``ReplayFetch`` over the lead shape). Frame
+        assembly rides the same decode pass, and where there are
+        groups so does each group's share of the apply phase
+        (``step_phase_us{phase=apply, group=g}``).
+
+        ``scan_rows`` (the K-window scan tier): ``(wd_fut, wm_fut,
+        applied0)`` replay rows that rode the scan dispatch itself,
+        starting at the pre-dispatch apply cursors — consumed FIRST, so
+        a scan step whose commit delta fits the staged window pays
+        ZERO standalone fetch dispatches; any remainder falls through
+        to the fetch loop below (identical decode → identical
+        streams)."""
+        t_scope: Dict[tuple, int] = {}
+        if scan_rows is not None:
+            wd_fut, wm_fut, applied0 = scan_rows
+            staged = int(wm_fut.shape[-2])     # K-sized, <= replay_W
+            wd_all = wm_all = None
+            for idx, key in self._keyed:
+                if key in self._wedged or key in self.need_recovery:
+                    continue
+                commit = int(res["commit"][idx])
+                off = int(self.applied[idx]) - int(applied0[idx])
+                n = int(min(commit - self.applied[idx], staged - off))
+                if n <= 0 or off < 0:
+                    continue
+                if wd_all is None:      # lazy: transfer only if used
+                    wd_all = np.asarray(wd_fut)
+                    wm_all = np.asarray(wm_fut)
+                t0 = time.perf_counter_ns()
+                self._apply_window(idx, key, wm_all[idx][off:off + n],
+                                   wd_all[idx][off:off + n], n)
+                t_scope[idx[:-1]] = (t_scope.get(idx[:-1], 0)
+                                     + time.perf_counter_ns() - t0)
+        while True:
+            todo = [(idx, key) for idx, key in self._keyed
+                    if key not in self._wedged
+                    and key not in self.need_recovery
+                    and self.applied[idx] < int(res["commit"][idx])]
+            if not todo:
+                break
+            starts = self._put(self.applied.astype(np.int32))
+            need = max(int(res["commit"][idx] - self.applied[idx])
+                       for idx, _ in todo)
+            prof = self.profiler
+            if prof is not None:
+                prof.start("replay_fetch")
+            # bind the fetch's log argument UNDER the host lock: the
+            # pipelined dispatch thread donates the current state
+            # buffers into the next step's dispatch, and a fetch bound
+            # after that donation reads deleted buffers. Binding first
+            # is sufficient — the runtime keeps an argument buffer
+            # alive for an already-enqueued program — and the newer log
+            # is safe to read: committed entries are immutable, the
+            # rollover is deferred while tickets are in flight, and the
+            # M_GIDX integrity check still guards slot recycling. Only
+            # the BIND holds the lock; the blocking result read below
+            # runs outside it so the dispatch path never stalls.
+            with held(prof, self._host_lock, "fetch_lock_wait"):
+                if prof is not None:
+                    prof.start("fetch_enqueue")
+                self._replay_fetch.need = need
+                wd_fut, wm_fut = self._fetch_all(self.state.log, starts)
+                if prof is not None:
+                    prof.stop("fetch_enqueue")
+            self.fetch_dispatches += 1
+            if prof is not None:
+                prof.start("fetch_read")
+            # wm is read last: a wrapper over _fetch_all (the
+            # benchmark's span) ends inside its conversion
+            wd_all, wm_all = np.asarray(wd_fut), np.asarray(wm_fut)
+            W = wm_all.shape[-2]        # the width the fetch chose
+            if prof is not None:
+                prof.stop("fetch_read")
+                prof.stop("replay_fetch")
+                prof.count("fetch_rows_total", W)
+                prof.start("replay_decode")
+            for idx, key in todo:
+                t0 = time.perf_counter_ns()
+                n = int(min(int(res["commit"][idx]) - self.applied[idx],
+                            W))
+                self._apply_window(idx, key, wm_all[idx], wd_all[idx], n)
+                t_scope[idx[:-1]] = (t_scope.get(idx[:-1], 0)
+                                     + time.perf_counter_ns() - t0)
+            if prof is not None:
+                prof.stop("replay_decode")
+        if self.obs is not None and self.profiler is not None:
+            from rdma_paxos_tpu.obs.metrics import LATENCY_BUCKETS_US
+            for scope, ns in sorted(t_scope.items()):
+                if scope:   # the single group's is the profiler's phase
+                    self.obs.metrics.observe(
+                        "step_phase_us", ns / 1e3,
+                        buckets=LATENCY_BUCKETS_US, phase="apply",
+                        **self._labels(scope))
+
+    def _rebase_stalled_step(self, scope: tuple, res) -> None:
+        """One post-threshold step passed with the scope's rollover
+        delta pinned at 0 — count it, and surface the stall once it
+        persists (the i32 ceiling is approaching and nothing will
+        fire)."""
+        self._rebase_stall_steps[scope] += 1
+        steps = int(self._rebase_stall_steps[scope])
+        if steps < self.REBASE_STALL_STEPS:
+            return
+        self._rebase_stalled[scope] += 1
+        if self.obs is not None:
+            from rdma_paxos_tpu.obs import trace as _trace
+            labels = self._labels(scope)
+            self.obs.metrics.inc("rebase_stalled", **labels)
+            if steps == self.REBASE_STALL_STEPS:
+                heads = [int(h) for h in res["head"][scope]]
+                self.obs.trace.record(
+                    _trace.REBASE_STALLED, **labels,
+                    end_max=int(res["end"][scope].max()),
+                    threshold=self.cfg.rebase_threshold,
+                    min_head=min(heads), heads=heads, steps=steps)
+
+    # holds-lock: _host_lock
+    def _maybe_rebase(self, res) -> None:
+        """Coordinated i32-offset rollover (LogConfig.rebase_threshold),
+        a scope at a time: when a scope's max end crosses the
+        threshold, subtract its minimum head from EVERY offset of ITS
+        replicas and from their host apply cursors — invisible to the
+        protocol (offsets are relative), it restores ~threshold entries
+        of headroom, and other scopes' offsets are untouched. All
+        crossing scopes shift in one elementwise pass. The in-process
+        driver is omniscient, so the min is over ALL replicas (not just
+        heard ones) — partition-safe: a partitioned laggard's low head
+        simply defers the rollover until it recovers or is evicted.
+        ``res`` is adjusted in place so callers observe post-rollover
+        offsets."""
+        threshold = self.cfg.rebase_threshold
+        if int(res["end"].max()) < threshold:
+            return
+        ends = res["end"].max(axis=-1)      # a scope's
+        # the slot of global index g is g % n_slots and entries do NOT
+        # move: the subtraction must preserve the mapping, so the delta
+        # is the min head rounded DOWN to a multiple of n_slots. A
+        # replica already flagged need_recovery is EXCLUDED from the
+        # min: it stopped replaying (snapshot install renumbers it from
+        # the donor), and letting its frozen head pin the rollover
+        # would wedge the whole cluster at the i32 ceiling. Its offsets
+        # may go transiently negative — benign: the gap gate keeps it
+        # from absorbing windows until recovery overwrites them.
+        deltas = np.zeros(self._lead[:-1], np.int64)
+        for scope in self._scopes:
+            if ends[scope] < threshold:
+                continue
+            heads = [int(res["head"][idx]) for idx, key in self._keyed
+                     if idx[:-1] == scope
+                     and key not in self.need_recovery]
+            delta = rebase_delta_of(heads, self.cfg.n_slots)
+            if delta <= 0:
+                self._rebase_stalled_step(scope, res)
+                continue
+            deltas[scope] = delta
+        if not deltas.any():
+            return
+        self._apply_rebase(deltas)
+        # rebound, not written in place: the packed row's views are
+        # read-only. Keep the returned dict self-consistent:
+        # audit_start is an index too (the ledger already ingested
+        # pre-rollover)
+        for k in ("head", "apply", "commit", "end", "audit_start"):
+            if k in res:
+                res[k] = res[k] - deltas[..., None].astype(res[k].dtype)
+        for scope in self._scopes:
+            d = int(deltas[scope])
+            if not d:
+                continue
+            self.applied[scope] -= d
+            self._rebases[scope] += 1
+            self._rebased_total[scope] += d
+            self._rebase_stall_steps[scope] = 0     # re-arm stall detection
+            if self.obs is not None:
+                from rdma_paxos_tpu.obs import trace as _trace
+                labels = self._labels(scope)
+                self.obs.metrics.inc("rebases_total", **labels)
+                self.obs.metrics.inc("rebased_entries_total", d, **labels)
+                self.obs.trace.record(_trace.REBASE_APPLIED, **labels,
+                                      delta=d,
+                                      rebases=int(self._rebases[scope]))
+
+    # holds-lock: _host_lock
+    def _apply_rebase(self, deltas: np.ndarray) -> None:
+        """Elementwise per-scope offset subtraction
+        (``consensus.snapshot.rebase_offsets``; invariants: delta <=
+        that scope's min head, multiple of n_slots). Called from
+        ``_maybe_rebase`` under the host lock. That one program over
+        the state where it lies: the deltas go out through the put, a
+        scope's in each of its rows, so that nothing moves between
+        chips (eager operations would put their constants on one chip
+        and spread them over the mesh)."""
+        from rdma_paxos_tpu.consensus.snapshot import rebase_offsets
+        rows = self._put(np.broadcast_to(
+            deltas.astype(np.int32)[..., None], self._lead))
+        self.state = rebase_offsets(self.state, rows)
+        if self._state_sharding is not None:
+            # the program's outputs follow its inputs; re-place all the
+            # same so the next donated dispatch can pay no reshard
+            # (rebases are rare — deferred until the pipeline drains)
+            self.state = jax.device_put(self.state, self._state_sharding)
+
+    # ------------------------------------------------------------------
+    # silent-divergence auditing (obs/audit.py; audit=True clusters)
+    # ------------------------------------------------------------------
+
+    def _ingest_audit(self, starts, digests, terms, commits) -> None:
+        """Feed one step's per-cell digest windows to the ledger,
+        converted to ABSOLUTE indices (raw + the scope's own
+        rebased_total: groups rebase independently — callers run this
+        before _maybe_rebase so the two stay consistent)."""
+        led = self.auditor
+        led.obs = self.obs              # pick up a late-attached facade
+        W = self.cfg.window_slots
+        for scope in self._scopes:
+            reb = int(self._rebased_total[scope])
+            labels = self._labels(scope)
+            s_l, c_l = starts[scope].tolist(), commits[scope].tolist()
+            for r in range(self.R):
+                start, commit = s_l[r], c_l[r]
+                n = commit - start
+                if n <= 0:
+                    continue
+                off = start - (commit - W)
+                led.record_window(r, start + reb,
+                                  digests[scope + (r,)][off:off + n],
+                                  terms[scope + (r,)][off:off + n],
+                                  commit + reb, step=self.step_index,
+                                  **labels)
+
+    def _record_flight(self, res, taken, timeouts,
+                       burst_k: int = 1) -> None:
+        """One flight-recorder entry per dispatch: the step's inputs
+        (per-cell submitted batches), scalar outputs, host apply
+        cursors, and per-cell digest heads — raw offsets plus the
+        rebased_total in force, so the dump is self-describing.
+        Values stay numpy arrays / payload bytes (fresh per step,
+        copied where a later in-place mutation could reach them); the
+        recorder converts to plain JSON data at dump time only."""
+        entry = dict(
+            step=self.step_index, burst_k=burst_k, timeouts=timeouts,
+            rebased_total=self._rebased_total.copy(),
+            inputs=taken,
+            outputs={k: res[k].copy()
+                     for k in ("term", "role", "leader_id", "head",
+                               "apply", "commit", "end", "accepted")},
+            applied=self.applied.copy(),
+            digests=dict(start=res["audit_start"].copy(),
+                         commit=res["commit"].copy(),
+                         window=res["audit_digest"]))
+        self.flight.record(entry)
+
+    # ------------------------------------------------------------------
+    # span hooks (host-side causal tracing — obs.spans; all no-ops
+    # when no recorder is attached or nothing is sampled)
+    # ------------------------------------------------------------------
+
+    def _span_recorder(self):
+        from rdma_paxos_tpu.obs.spans import active_recorder
+        return active_recorder(self.obs)
+
+    def _stamp_appends(self, idx: tuple, take, acc: int, res) -> None:
+        """The accepted PREFIX of ``take`` landed at absolute indices
+        ``[end-acc, end)`` on the leader at cell ``idx`` — stamp each
+        sampled span with its ``(group, term, index)`` correlation
+        key."""
+        spans = self._span_recorder()
+        if spans is None or not spans.open_count or acc <= 0:
+            return
+        scope = idx[:-1]
+        end_abs = int(res["end"][idx]) + int(self._rebased_total[scope])
+        term = int(res["term"][idx])
+        # the loop below is the only per-OPERATION work of the
+        # post-readback rules: everything about the cell is worked out
+        # before it, and the call is positional (``**labels`` costs
+        # half a microsecond an entry, a keyword 50 ns)
+        rep = self._span_rep(*idx)
+        group = scope[0] if scope else -1       # -1: the unsharded key
+        replicas = [self._span_rep(*scope, r) for r in range(self.R)]
+        for i, (_t, conn, req, _p) in enumerate(take[:acc]):
+            spans.stamp_append(conn, req, term, end_abs - acc + i, rep,
+                               replicas, group)
+
+    def _observe_spans(self, res) -> None:
+        """Advance every cell's commit/apply span frontiers (absolute,
+        rebase-corrected — runs after ``_maybe_rebase`` so the offsets
+        and ``rebased_total`` are mutually consistent)."""
+        spans = self._span_recorder()
+        if spans is None or not spans.open_count:
+            return
+        for idx in self._cells:
+            rebased = int(self._rebased_total[idx[:-1]])
+            rep = self._span_rep(*idx)
+            spans.commit_advance(rep, int(res["commit"][idx]) + rebased)
+            spans.apply_advance(rep, int(self.applied[idx]) + rebased)
+
+
+class SimCluster(ClusterEngine):
+    """N-replica protocol simulation of ONE consensus group: the
+    engine's front end over the lead shape ``(R,)``, addressed by
+    replica."""
+
+    _PROGRAMS = {
+        "step": ((), build_sim_step, build_spmd_step),
+        "burst": (("burst",), build_sim_burst, build_spmd_burst),
+        "scan": (("scan",), build_sim_scan, build_spmd_scan),
+    }
+
+    def __init__(self, cfg: LogConfig, n_replicas: int,
+                 group_size: Optional[int] = None, *, mode: str = "sim",
+                 use_pallas: Optional[bool] = None,
+                 interpret: bool = False,
+                 fanout: str = "gather", stable_fast_path: bool = True,
+                 audit: bool = False, flight_capacity: int = 64,
+                 telemetry: bool = False, scan: bool = False,
+                 txn: bool = False):
+        mesh = sharding = None
+        if mode == "spmd":
+            mkey = (cfg, n_replicas, "mesh")
+            if mkey not in STEP_CACHE:
+                STEP_CACHE[mkey] = make_replica_mesh(n_replicas)
+            mesh = STEP_CACHE[mkey]
+            sharding = jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec("replica"))
+        # pluggable per-link fault model (rdma_paxos_tpu.chaos.faults
+        # .LinkModel): when attached, each step's peer_mask INPUT is
+        # rewritten host-side into the effective hear-matrix
+        # (asymmetric breaks, seeded drop/delay/dup, crashed
+        # replicas). Purely a data rewrite — compiled-step cache keys
+        # are unchanged (tests/test_chaos.py guards it). The
+        # dispatch-side step clock is what the model's per-step
+        # randomness keys on.
+        self.link_model = None
+        super().__init__(
+            cfg, (n_replicas,),
+            stack_states(cfg, n_replicas, group_size or n_replicas),
+            mode=mode, mesh=mesh, key_mesh=(), state_sharding=sharding,
+            group_size=group_size, use_pallas=use_pallas,
+            interpret=interpret, fanout=fanout,
+            stable_fast_path=stable_fast_path, audit=audit,
+            flight_capacity=flight_capacity, telemetry=telemetry,
+            scan=scan, txn=txn)
+
+    # ---------------- the engine's hooks ----------------
+
+    def _norm_timeouts(self, timeouts) -> tuple:
+        kept = [int(r) for r in timeouts]   # may be a one-shot iterable
+        return kept, kept
+
+    def _link_models(self) -> dict:
+        return {} if self.link_model is None else {(): self.link_model}
+
+    def _span_rep(self, r: int) -> int:
+        return r
+
+    def _count_appends(self, prof, appended: int) -> None:
+        pass        # one group: nothing to tell apart
+
+    def _observe(self, res) -> None:
+        pass        # no per-group series
+
     # ---------------- client-side API ----------------
 
     def submit(self, replica: int, payload: bytes,
                etype: EntryType = EntryType.SEND, conn: int = 1,
                req_id: int = 0) -> None:
         """Queue a client entry for the next step on `replica` (it only
-        enters the log if that replica is leader — proxy semantics).
-        Locked: a concurrent ``begin_*`` batch take swaps the pending
-        list object, and an unlocked append to the old object would be
-        silently lost."""
-        with self._host_lock:
-            self.pending[replica].append(
-                (int(etype), conn, req_id, payload))
+        enters the log if that replica is leader — proxy semantics)."""
+        self._submit((replica,), [(int(etype), conn, req_id, payload)])
 
     def submit_many(self, replica: int,
                     entries: Sequence[Tuple[int, int, int, bytes]]
@@ -740,8 +1796,7 @@ class SimCluster:
         payload)`` rows in one locked extend — the drivers' batched
         intake (a per-entry ``submit`` loop was a measurable share of
         the pump under full windows)."""
-        with self._host_lock:
-            self.pending[replica].extend(entries)
+        self._submit((replica,), entries)
 
     def set_txn_watch(self, index: int, term: int) -> None:
         """Arm the prepare watch: every subsequent serial step reports a
@@ -749,28 +1804,16 @@ class SimCluster:
         committed under ``term`` (txn=True clusters only). The watch is
         sticky until :meth:`clear_txn_watch` — the coordinator re-reads
         the vote matrix each step while a prepare is outstanding."""
-        if not self._txn:
-            raise RuntimeError("set_txn_watch requires txn=True")
-        self._txn_watch = int(index)
-        self._txn_wterm = int(term)
+        self._arm_txn_watch((), index, term)
 
     def clear_txn_watch(self) -> None:
-        self._txn_watch = -1
-        self._txn_wterm = 0
+        self._disarm_txn_watch(())
 
     def partition(self, groups: Sequence[Sequence[int]]) -> None:
-        """Split the cluster: replicas hear only same-group peers. One
-        rebind of the matrix: a dispatch on another thread reads the
-        old one or the new one, never a half-written one."""
+        """Split the cluster: replicas hear only same-group peers."""
         if self._fanout == "psum":
             self._check_psum_split(groups)
-        mask = np.zeros_like(self.peer_mask)
-        for g in groups:
-            for i in g:
-                for j in g:
-                    mask[i, j] = 1
-        np.fill_diagonal(mask, 1)
-        self.peer_mask = mask
+        self._partition((), groups)
         self._psum_split = self._fanout == "psum"
 
     def _check_psum_split(self, groups) -> None:
@@ -803,8 +1846,7 @@ class SimCluster:
                 "fanout='gather'")
 
     def heal(self) -> None:
-        self.peer_mask = np.ones_like(self.peer_mask)
-        self._psum_split = False
+        self._heal(())
 
     def wedge_apply(self, r: int) -> None:
         """Freeze replica ``r``'s apply progress (models a wedged app:
@@ -815,487 +1857,6 @@ class SimCluster:
     def unwedge_apply(self, r: int) -> None:
         self._wedged.discard(r)
 
-    # ---------------- stepping ----------------
-
-    def _effective_mask(self):
-        """The step's hear-matrix: the base peer_mask, refined by the
-        attached link model (host-side only; psum fan-out still
-        requires the EFFECTIVE mask to be full)."""
-        if self.link_model is None:
-            return self.peer_mask
-        return self.link_model.effective_mask(self.peer_mask,
-                                              self._dispatch_clock)
-
-    # burst size tiers: the smallest tier >= the steps needed is compiled
-    # (bounded recompiles) and padded with zero-count steps
-    K_TIERS = (2, 4, 8, 16)
-
-    def _step_bufs(self) -> dict:
-        return self._staging.acquire(
-            arg_layout(self.cfg, self.R, 1, self._txn), (self.R,),
-            fused=False)
-
-    def _burst_bufs(self, K: int) -> dict:
-        return self._staging.acquire(
-            arg_layout(self.cfg, self.R, K), (self.R,))
-
-    # holds-lock: _host_lock
-    def reserved_appends(self) -> np.ndarray:
-        """Per-replica appends dispatched but not yet finished — the
-        pipelined capacity reservation (``end`` has not caught up).
-        Callers hold ``_host_lock`` (begin_burst's capacity sizing and
-        the chaos runner's drained-serial room check)."""
-        out = np.zeros(self.R, np.int64)
-        for t in self._tickets:
-            for r in range(self.R):
-                out[r] += len(t.taken[r])
-        return out
-
-    def begin_step(self, timeouts: Sequence[int] = (),
-                   take_batch: bool = True) -> StepTicket:
-        """Encode + DISPATCH one protocol step; returns immediately
-        with the in-flight ticket (pass to :meth:`finish`, FIFO). With
-        ``take_batch=False`` no client entries are packed (heartbeat /
-        election dispatches of the pipelined driver, which routes all
-        appends through capacity-clamped bursts so a shortfall requeue
-        can never reorder against in-flight dispatches)."""
-        timeouts = list(timeouts)       # may be a one-shot iterable
-        prof = self.profiler
-        if prof is not None:
-            prof.start("host_encode")
-        cfg, R, B = self.cfg, self.R, self.cfg.batch_slots
-        mask = self._effective_mask()
-        if (self._fanout == "psum" and not self._psum_split
-                and not mask.all()):
-            raise ValueError(
-                "psum fan-out requires full connectivity; use "
-                "fanout='gather' to model partitions")
-        bufs = self._step_bufs()
-        count = bufs["count"]
-        count[:] = 0
-        with held(prof, self._host_lock, "dispatch_lock_wait"):
-            taken = []
-            for r in range(R):
-                take = self.pending[r][:B] if take_batch else []
-                if take:
-                    self.pending[r] = self.pending[r][B:]
-                taken.append(take)
-            bufs["qdepth"][:] = [len(q) for q in self.pending]
-            bufs["applied"][:] = self.applied
-        for r, take in enumerate(taken):
-            if take:
-                pack_rows(bufs, (r,), take, cfg.slot_bytes)
-                count[r] = len(take)
-        tmo = bufs["timeout"]
-        tmo[:] = 0
-        for r in timeouts:
-            tmo[r] = 1
-        bufs["peer_mask"][:] = mask
-        if self._txn:
-            # device watch compares log offsets: shift the armed
-            # ABSOLUTE index by the i32 rollovers applied so far
-            bufs["txn_watch"][:] = (self._txn_watch - self.rebased_total
-                                    if self._txn_watch >= 0 else -1)
-            bufs["txn_term"][:] = self._txn_wterm
-        if prof is not None:
-            prof.start("input_transfer")
-        packed = self._put(bufs["packed"])
-        if prof is not None:
-            prof.stop("input_transfer")
-        # no timer fired ⟹ Phase B is provably a no-op: dispatch the
-        # stable step (bit-identical outputs, one fewer collective)
-        fn = (self._build_step(elections=False)
-              if self._stable_fast_path and not timeouts
-              else self._step)
-        if prof is not None:
-            prof.stop("host_encode")
-            prof.start("device_dispatch")
-        with held(prof, self._host_lock, "dispatch_lock_wait"):
-            if prof is not None:
-                prof.start("program_call")
-            self.state, out = fn(self.state, packed)
-            if prof is not None:
-                prof.stop("program_call")
-            ticket = StepTicket("step", out, taken, timeouts, 1, bufs)
-            self._tickets.append(ticket)
-            self.inflight_dispatches += 1
-            self.max_inflight_dispatches = max(
-                self.max_inflight_dispatches, self.inflight_dispatches)
-        if prof is not None:
-            prof.stop("device_dispatch")
-        self._dispatch_clock += 1
-        return ticket
-
-    def _tiers(self, max_k: Optional[int]) -> Tuple[int, ...]:
-        """Fused tiers bounded at ``max_k`` (the shared ``cap_tiers``
-        rule — a subset of ``K_TIERS``, never a new compile)."""
-        return cap_tiers(self.K_TIERS, max_k)
-
-    def begin_burst(self, max_k: Optional[int] = None) -> StepTicket:
-        """Encode + DISPATCH up to ``max(K_TIERS)`` fused protocol
-        steps; returns immediately with the in-flight ticket. Capacity
-        sizing subtracts appends reserved by OTHER in-flight tickets,
-        so pipelined bursts can never overrun the ring (a mid-burst
-        drop would reorder a connection's fragments). ``max_k`` caps
-        the tier choice (and the take) at a lower rung of the same
-        ladder — the governor's dial."""
-        cfg, R, B = self.cfg, self.R, self.cfg.batch_slots
-        assert self.last is not None, "burst requires a stepped cluster"
-        prof = self.profiler
-        if prof is not None:
-            prof.start("host_encode")
-        mask = self._effective_mask()
-        if (self._fanout == "psum" and not self._psum_split
-                and not mask.all()):
-            raise ValueError(
-                "psum fan-out requires full connectivity; use "
-                "fanout='gather' to model partitions")
-        tiers = self._tiers(max_k)
-        with held(prof, self._host_lock, "dispatch_lock_wait"):
-            # capacity sizing: never enqueue more than the ring can
-            # take without drops, so mid-burst drops (which would
-            # reorder a connection's fragments against later steps)
-            # cannot occur
-            reserved = self.reserved_appends()
-            last = self.last
-            taken: List[List[Tuple[int, int, int, bytes]]] = []
-            take_n = []
-            for r in range(R):
-                n = clamp_burst_take(
-                    len(self.pending[r]), int(last["end"][r]),
-                    int(last["head"][r]), cfg.n_slots,
-                    tiers[-1] * B, int(reserved[r]))
-                take_n.append(n)
-                taken.append(self.pending[r][:n])
-                self.pending[r] = self.pending[r][n:]
-            qdepth = [len(q) for q in self.pending]
-            applied = self.applied.astype(np.int32)
-        k_needed = max(1, max(-(-n // B) for n in take_n))
-        K = next(k for k in tiers if k >= k_needed)
-        bufs = self._burst_bufs(K)
-        count = bufs["count"]
-        for r in range(R):
-            n = take_n[r]
-            for k in range(-(-n // B) if n else 0):
-                pack_rows(bufs, (k, r), taken[r][k * B:(k + 1) * B],
-                          cfg.slot_bytes)
-            for k in range(K):
-                count[k, r] = max(0, min(n - k * B, B))
-        bufs["peer_mask"][:] = mask
-        bufs["applied"][:] = applied
-        bufs["qdepth"][:] = qdepth
-        scan = self.scan
-        fn = self._scan_fn(K) if scan else self._burst_fn(K)
-        if prof is not None:
-            prof.stop("host_encode")
-            prof.start("device_dispatch")
-            prof.start("input_transfer")
-        packed = self._put(bufs["packed"])
-        if prof is not None:
-            prof.stop("input_transfer")
-        with held(prof, self._host_lock, "dispatch_lock_wait"):
-            if prof is not None:
-                prof.start("program_call")
-            self.state, outs = fn(self.state, packed)
-            if prof is not None:
-                prof.stop("program_call")
-            ticket = StepTicket("scan" if scan else "burst", outs,
-                                taken, (), K, bufs,
-                                applied0=applied if scan else None)
-            if scan:
-                self.scan_dispatches += 1
-            self._tickets.append(ticket)
-            self.inflight_dispatches += 1
-            self.max_inflight_dispatches = max(
-                self.max_inflight_dispatches, self.inflight_dispatches)
-        if prof is not None:
-            prof.stop("device_dispatch")
-        self._dispatch_clock += K
-        return ticket
-
-    def finish(self, ticket: StepTicket) -> Dict[str, np.ndarray]:
-        """Block on ``ticket``'s outputs and run every post-step host
-        rule (requeue, replay, audit, flight, rebase, spans) — tickets
-        MUST finish in dispatch order. ``step()``/``step_burst()`` are
-        exactly ``finish(begin_*())``; the pipelined driver finishes
-        from its readback thread while the next dispatch encodes."""
-        assert self._tickets and self._tickets[0] is ticket, \
-            "tickets must finish in dispatch (FIFO) order"
-        # NOT popped here: until ``last`` below reflects this ticket's
-        # appends, a concurrent ``begin_*`` must keep counting them via
-        # reserved_appends() — an early pop would let its capacity
-        # clamp over-admit (and a lockless pop would mutate the deque
-        # under the dispatch thread's locked iteration)
-        prof = self.profiler
-        out = ticket.out
-        burst = ticket.kind == "burst"
-        scan = ticket.kind == "scan"
-        if prof is not None:
-            prof.sync(out)              # fenced device_sync (opt-in)
-            prof.start("quorum_wait")
-        res = read_scalars(ticket)
-        # what is compiled only on request keeps a read of its own
-        # (``readback_rest``): none in the default programs
-        reads = 1
-        if prof is not None:
-            prof.start("readback_rest")
-        if not (burst or scan) and self._txn and out.txn_vote is not None:
-            # serial dispatches only: the txn lane never rides
-            # burst/scan programs (their keys stay untouched)
-            res["txn_vote"] = np.asarray(out.txn_vote)
-            reads += 1
-        if prof is not None:
-            prof.stop("readback_rest")
-            prof.count("readback_arrays_total", reads)
-            prof.count("cfg_rescans_total", int(res["cfg_rescanned"].max()))
-            prof.stop("quorum_wait")
-            prof.start("post_readback")
-            count_ring(prof, self.last, res, ticket.taken, self.cfg.n_slots)
-        if self._audit:
-            # ingest BEFORE _maybe_rebase: the emitted indices are raw
-            # (pre-rollover), consistent with the current rebased_total
-            if burst or scan:
-                # each fused step emitted its own digest window: ingest
-                # them in order so the tiling property (no gaps) holds
-                get = (out.__getitem__ if scan
-                       else lambda k: getattr(out, "commit"
-                                              if k == "audit_commit"
-                                              else k))
-                a_s = np.asarray(get("audit_start"))   # [K, R]
-                a_d = np.asarray(get("audit_digest"))  # [K, R, W]
-                a_t = np.asarray(get("audit_term"))    # [K, R, W]
-                a_c = np.asarray(get("audit_commit"))  # [K, R]
-                for k in range(a_s.shape[0]):
-                    self._ingest_audit(a_s[k], a_d[k], a_t[k], a_c[k])
-                res["audit_start"] = a_s[-1]
-                res["audit_digest"] = a_d[-1]
-                res["audit_term"] = a_t[-1]
-            else:
-                for k in ("audit_start", "audit_digest", "audit_term"):
-                    res[k] = np.asarray(getattr(out, k))
-                self._ingest_audit(res["audit_start"],
-                                   res["audit_digest"],
-                                   res["audit_term"], res["commit"])
-        if self._telemetry:
-            # device-truth counters: reduce the dispatch's per-step
-            # vectors (sum counters / min headroom over a fused burst),
-            # fold into the host accumulator, and export device_*
-            # registry series — all on THIS thread, which under the
-            # pipelined driver is the readback thread (finish runs
-            # there), so telemetry never rides the dispatch path
-            from rdma_paxos_tpu.obs import device as _device
-            tv = np.asarray(out["telemetry"] if scan
-                            else out.telemetry, dtype=np.int64)
-            res["telemetry"] = (_device.reduce_steps(tv)
-                                if burst or scan else tv)
-            _device.accumulate(self.device_counters, res["telemetry"])
-            _device.ingest(self.obs, res["telemetry"])
-        # ring-full backpressure / deposition: the appended set is a
-        # PREFIX of ``taken`` — requeue the remainder in order
-        # (submissions to non-leaders are dropped by design)
-        txn_notes = []
-        with self._host_lock:
-            for r in range(self.R):
-                take = ticket.taken[r]
-                if take and res["role"][r] == int(Role.LEADER):
-                    acc_r = int(res["accepted"][r])
-                    self._stamp_appends(r, take, acc_r, res)
-                    if self.txn is not None and acc_r > 0:
-                        txn_notes.append(
-                            (0, r, take[:acc_r], int(res["term"][r]),
-                             int(res["end"][r]) + self.rebased_total))
-                    requeue_shortfall(self.pending[r], take, acc_r)
-        # OUTSIDE _host_lock: note_appends takes the coordinator lock,
-        # which client threads hold while submitting (coordinator ->
-        # cluster order) — calling it from the stamp loop would be the
-        # reverse order, an ABBA deadlock against kvs.transact()
-        for note in txn_notes:
-            self.txn.note_appends(*note)
-        if prof is not None:
-            prof.stop("post_readback")
-            prof.start("apply")
-        self._replay_committed(
-            res, scan_rows=((out["replay_data"], out["replay_meta"],
-                             ticket.applied0) if scan else None))
-        if prof is not None:
-            prof.stop("apply")
-            prof.start("finish_tail")
-        if self._audit:
-            self._record_flight(res, ticket.taken, ticket.timeouts,
-                                burst_k=ticket.K)
-        # the i32 rollover rewrites offsets host-side: it must never
-        # run under dispatches still in flight (their outputs carry
-        # pre-rollover offsets) — defer until the pipeline drains; the
-        # threshold stays crossed, so the draining finish applies it
-        with self._host_lock:
-            self._tickets.popleft()     # retire: last now covers it
-            self.inflight_dispatches -= 1
-            if not self._tickets:
-                self._maybe_rebase(res)
-            self.last = res
-        self.step_index += ticket.K
-        self._observe_spans(res)
-        # read path: renew/revoke leases from this FINISHED step's
-        # verified-quorum outputs, then serve due queued reads —
-        # between pipelined tickets, never inside one
-        if self.leases is not None:
-            self.leases.observe(self, res)
-        if self.reads is not None:
-            self.reads.drain(self)
-        if self.streams is not None:
-            self.streams.observe(self, res)
-        if self.governor is not None:
-            self.governor.observe(self, res)
-        if self.txn is not None:
-            self.txn.observe(self, res)
-        if burst or scan:
-            B = self.cfg.batch_slots
-            self._staging.release(ticket.bufs, [
-                ((k, r), min(B, len(t) - k * B))
-                for r, t in enumerate(ticket.taken)
-                for k in range(-(-len(t) // B) if t else 0)])
-        else:
-            self._staging.release(ticket.bufs, [
-                ((r,), len(t)) for r, t in enumerate(ticket.taken)])
-        if prof is not None:
-            prof.stop("finish_tail")
-        return res
-
-    def drain(self) -> Optional[Dict[str, np.ndarray]]:
-        """Finish every in-flight ticket in order; returns the final
-        result (or None when nothing was in flight)."""
-        res = None
-        while self._tickets:
-            res = self.finish(self._tickets[0])
-        return res
-
-    def _burst_fn(self, K: int):
-        # the "audit" marker is appended ONLY when auditing: default
-        # clusters' cache keys are bit-identical to the pre-audit ones
-        # (tests/test_audit.py guards exactly this)
-        key = (self.cfg, self.R, self._mode, self._use_pallas,
-               self._interpret, self._fanout, "burst", K) \
-            + (("audit",) if self._audit else ()) \
-            + (("telemetry",) if self._telemetry else ())
-        fn = self._STEP_CACHE.get(key)
-        if fn is None:
-            kw = dict(use_pallas=self._use_pallas,
-                      interpret=self._interpret, fanout=self._fanout,
-                      audit=self._audit, telemetry=self._telemetry)
-            if self._mode == "spmd":
-                fn = build_spmd_burst(self.cfg, self.R, self.mesh, **kw)
-            else:
-                fn = build_sim_burst(self.cfg, self.R, **kw)
-            self._STEP_CACHE[key] = fn
-        return fn
-
-    def _scan_slots(self, K: int) -> int:
-        """The scan tier's staged replay width: a K-step scan advances
-        commit by at most ``K * batch_slots``, so a small-K dispatch
-        never pays the full replay window's extract/transfer (the
-        fallback fetch covers a host that fell further behind)."""
-        return min(self._replay_W,
-                   max(K * self.cfg.batch_slots,
-                       self.cfg.window_slots))
-
-    def _scan_fn(self, K: int):
-        # the K-window scan tier compiles under its own distinct
-        # "scan"-marked cache keys — scan-off clusters' key sets (and
-        # programs) are bit-identical to the pre-scan ones, exactly
-        # the audit=/telemetry= guard discipline (tests pin it)
-        key = (self.cfg, self.R, self._mode, self._use_pallas,
-               self._interpret, self._fanout, "scan", K,
-               self._scan_slots(K)) \
-            + (("audit",) if self._audit else ()) \
-            + (("telemetry",) if self._telemetry else ())
-        fn = self._STEP_CACHE.get(key)
-        if fn is None:
-            kw = dict(replay_slots=self._scan_slots(K),
-                      use_pallas=self._use_pallas,
-                      interpret=self._interpret, fanout=self._fanout,
-                      audit=self._audit, telemetry=self._telemetry)
-            if self._mode == "spmd":
-                fn = build_spmd_scan(self.cfg, self.R, self.mesh, **kw)
-            else:
-                fn = build_sim_scan(self.cfg, self.R, **kw)
-            self._STEP_CACHE[key] = fn
-        return fn
-
-    def step_burst(self, max_k: Optional[int] = None
-                   ) -> Dict[str, np.ndarray]:
-        """Drain the pending queues through up to ``max(K_TIERS)`` fused
-        protocol steps in ONE device dispatch (multi-step driver mode —
-        the host-side analog of the reference's busy commit loop). No
-        election timeouts fire inside the burst; the caller must only
-        burst while a leader is known. Returns the final step's outputs
-        (``accepted`` aggregated over the burst). With ``scan=True``
-        the dispatch rides the K-window scan tier (same step outputs,
-        consolidated readback + in-dispatch replay rows). ``max_k``
-        caps the tier at a lower ladder rung (the governor's dial)."""
-        require_drained(self._tickets, "step_burst")
-        return self.finish(self.begin_burst(max_k=max_k))
-
-    def _build_step(self, *, elections: bool):
-        """Compile (or fetch cached) the protocol step for this cluster's
-        static config — the single source for both the full and stable
-        variants, so they can never drift apart in build flags."""
-        key = (self.cfg, self.R, self._mode, self._use_pallas,
-               self._interpret, self._fanout, elections) \
-            + (("audit",) if self._audit else ()) \
-            + (("telemetry",) if self._telemetry else ()) \
-            + (("txn",) if self._txn else ())
-        cached = self._STEP_CACHE.get(key)
-        if cached is None:
-            kw = dict(use_pallas=self._use_pallas,
-                      interpret=self._interpret, fanout=self._fanout,
-                      elections=elections, audit=self._audit,
-                      telemetry=self._telemetry, txn=self._txn)
-            if self._mode == "spmd":
-                cached = build_spmd_step(self.cfg, self.R, self.mesh, **kw)
-            else:
-                cached = build_sim_step(self.cfg, self.R, **kw)
-            self._STEP_CACHE[key] = cached
-        return cached
-
-    def prewarm(self, tiers: Optional[Sequence[int]] = None) -> None:
-        """Compile every step variant and burst tier up front (on copies
-        of the live state — donation would otherwise consume it). A
-        first-use JIT pause of seconds mid-serving stalls the whole
-        commit pipeline; paying it before traffic starts keeps the
-        serving path pause-free."""
-        cfg, R = self.cfg, self.R
-        # through the dispatches' own put, at the dispatches' own
-        # shapes: an argument placed otherwise is another executable
-        # of the same program, and the first served dispatch would
-        # compile it inside the loop
-        def idle(lay):
-            return self._put(lay.idle((R,), self.peer_mask))
-        packed = idle(arg_layout(cfg, R, 1, self._txn))
-        for elections in (True, False):
-            fn = self._build_step(elections=elections)
-            st = jax.tree.map(lambda x: x.copy(), self.state)
-            fn(st, packed)
-        for K in (tiers if tiers is not None else self.K_TIERS):
-            fns = [self._burst_fn(K)]
-            if self.scan:
-                fns.append(self._scan_fn(K))
-            packed = idle(arg_layout(cfg, R, K))
-            for fn in fns:
-                st = jax.tree.map(lambda x: x.copy(), self.state)
-                fn(st, packed)
-        # and the replay fetch at every width, so that no served use
-        # compiles anything
-        self._replay_fetch.warm(self.state.log,
-                                self._put(np.zeros((R,), np.int32)))
-
-    def step(self, timeouts: Sequence[int] = ()) -> Dict[str, np.ndarray]:
-        require_drained(self._tickets, "step")
-        return self.finish(self.begin_step(timeouts))
-
-    # ------------------------------------------------------------------
-    # silent-divergence auditing (obs/audit.py; audit=True clusters)
-    # ------------------------------------------------------------------
-
     def redigest(self, replica: int, lo: int, hi: int) -> int:
         """Range re-digest backfill: recompute the digest chain of
         replica ``replica``'s committed entries ``[lo, hi)`` (raw
@@ -1305,259 +1866,6 @@ class SimCluster:
         return run_redigest(self, self.state.log.buf[replica], lo, hi,
                             group=0, rebased_total=self.rebased_total,
                             replica=replica)
-
-    def _ingest_audit(self, starts, digests, terms, commits) -> None:
-        """Feed one step's per-replica digest windows to the ledger,
-        converted to ABSOLUTE indices (raw + rebased_total — callers
-        run this before _maybe_rebase so the two stay consistent)."""
-        led = self.auditor
-        led.obs = self.obs              # pick up a late-attached facade
-        W = self.cfg.window_slots
-        reb = self.rebased_total
-        s_l, c_l = starts.tolist(), commits.tolist()
-        for r in range(self.R):
-            start, commit = s_l[r], c_l[r]
-            n = commit - start
-            if n <= 0:
-                continue
-            off = start - (commit - W)
-            led.record_window(r, start + reb,
-                              digests[r, off:off + n],
-                              terms[r, off:off + n], commit + reb,
-                              step=self.step_index)
-
-    def _record_flight(self, res, taken, timeouts,
-                       burst_k: int = 1) -> None:
-        """One flight-recorder entry per dispatch: the step's inputs
-        (per-replica submitted batches), scalar outputs, host apply
-        cursors, and per-replica digest heads — raw offsets plus the
-        rebased_total in force, so the dump is self-describing.
-        Values stay numpy arrays / payload bytes (fresh per step,
-        copied where a later in-place mutation could reach them); the
-        recorder converts to plain JSON data at dump time only."""
-        entry = dict(
-            step=self.step_index, burst_k=burst_k,
-            timeouts=[int(t) for t in timeouts],
-            rebased_total=int(self.rebased_total),
-            inputs=taken,
-            outputs={k: res[k].copy()
-                     for k in ("term", "role", "leader_id", "head",
-                               "apply", "commit", "end", "accepted")},
-            applied=self.applied.copy(),
-            digests=dict(start=res["audit_start"].copy(),
-                         commit=res["commit"].copy(),
-                         window=res["audit_digest"]))
-        self.flight.record(entry)
-
-    # ------------------------------------------------------------------
-    # span hooks (host-side causal tracing — obs.spans; all no-ops
-    # when no recorder is attached or nothing is sampled)
-    # ------------------------------------------------------------------
-
-    def _span_recorder(self):
-        from rdma_paxos_tpu.obs.spans import active_recorder
-        return active_recorder(self.obs)
-
-    def _stamp_appends(self, r: int, take, acc: int, res) -> None:
-        """The accepted PREFIX of ``take`` landed at absolute indices
-        ``[end-acc, end)`` on leader ``r`` — stamp each sampled span
-        with its ``(term, index)`` correlation key."""
-        spans = self._span_recorder()
-        if spans is None or not spans.open_count or acc <= 0:
-            return
-        end_abs = int(res["end"][r]) + self.rebased_total
-        term = int(res["term"][r])
-        replicas = range(self.R)
-        for i, (_t, conn, req, _p) in enumerate(take[:acc]):
-            spans.stamp_append(conn, req, term, end_abs - acc + i, r,
-                               replicas=replicas)
-
-    def _observe_spans(self, res) -> None:
-        """Advance every replica's commit/apply span frontiers (absolute,
-        rebase-corrected — runs after ``_maybe_rebase`` so the offsets
-        and ``rebased_total`` are mutually consistent)."""
-        spans = self._span_recorder()
-        if spans is None or not spans.open_count:
-            return
-        rebased = self.rebased_total
-        for r in range(self.R):
-            spans.commit_advance(r, int(res["commit"][r]) + rebased)
-            spans.apply_advance(r, int(self.applied[r]) + rebased)
-
-    # consecutive post-threshold zero-delta steps before the stall is
-    # declared — shared with NodeDaemon (config.REBASE_STALL_STEPS)
-    REBASE_STALL_STEPS = REBASE_STALL_STEPS
-
-    def _rebase_stalled_step(self, res) -> None:
-        """One post-threshold step passed with the rollover delta
-        pinned at 0 — count it, and surface the stall once it persists
-        (the i32 ceiling is approaching and nothing will fire)."""
-        self.rebase_stall_steps += 1
-        if self.rebase_stall_steps < self.REBASE_STALL_STEPS:
-            return
-        self.rebase_stalled += 1
-        if self.obs is not None:
-            from rdma_paxos_tpu.obs import trace as _trace
-            self.obs.metrics.inc("rebase_stalled")
-            if self.rebase_stall_steps == self.REBASE_STALL_STEPS:
-                heads = [int(res["head"][r]) for r in range(self.R)]
-                self.obs.trace.record(
-                    _trace.REBASE_STALLED,
-                    end_max=int(res["end"].max()),
-                    threshold=self.cfg.rebase_threshold,
-                    min_head=min(heads), heads=heads,
-                    steps=self.rebase_stall_steps)
-
-    # holds-lock: _host_lock
-    def _maybe_rebase(self, res) -> None:
-        """Coordinated i32-offset rollover (LogConfig.rebase_threshold):
-        when any end offset crosses the threshold, subtract the minimum
-        head from EVERY offset on every replica and from the host apply
-        cursors — invisible to the protocol (offsets are relative), and
-        it restores ~threshold entries of headroom. The in-process
-        driver is omniscient, so the min is over ALL replicas (not just
-        heard ones) — partition-safe: a partitioned laggard's low head
-        simply defers the rollover until it recovers or is evicted.
-        ``res`` is adjusted in place so callers observe post-rollover
-        offsets."""
-        if int(res["end"].max()) < self.cfg.rebase_threshold:
-            return
-        # the slot of global index g is g % n_slots and entries do NOT
-        # move: the subtraction must preserve the mapping, so the delta
-        # is the min head rounded DOWN to a multiple of n_slots. A
-        # replica already flagged need_recovery is EXCLUDED from the
-        # min: it stopped replaying (snapshot install renumbers it from
-        # the donor), and letting its frozen head pin the rollover
-        # would wedge the whole cluster at the i32 ceiling. Its offsets
-        # may go transiently negative — benign: the gap gate keeps it
-        # from absorbing windows until recovery overwrites them.
-        heads = [int(res["head"][r]) for r in range(self.R)
-                 if r not in self.need_recovery]
-        delta = rebase_delta_of(heads, self.cfg.n_slots)
-        if delta <= 0:
-            self._rebase_stalled_step(res)
-            return
-        from rdma_paxos_tpu.consensus.snapshot import rebase_offsets
-        self.state = rebase_offsets(self.state, delta)
-        self.applied -= delta
-        for k in ("head", "apply", "commit", "end"):
-            res[k] = res[k] - delta
-        # keep the returned dict self-consistent: audit_start is an
-        # index too (the ledger already ingested pre-rollover)
-        if "audit_start" in res:
-            res["audit_start"] = res["audit_start"] - delta
-        self.rebases += 1
-        self.rebased_total += delta
-        self.rebase_stall_steps = 0          # re-arm stall detection
-        if self.obs is not None:
-            from rdma_paxos_tpu.obs import trace as _trace
-            self.obs.metrics.inc("rebases_total")
-            self.obs.metrics.inc("rebased_entries_total", delta)
-            self.obs.trace.record(_trace.REBASE_APPLIED, delta=delta,
-                                  rebases=self.rebases)
-
-    def _replay_committed(self, res, scan_rows=None) -> None:
-        """Host apply loop: fetch newly committed entries from the device
-        log and 'replay' them (tests record them; the real driver hands
-        them to the proxy) — apply_committed_entries analog
-        (dare_server.c:1815-1974). All replicas' windows ride ONE device
-        dispatch per sweep.
-
-        ``scan_rows`` (the K-window scan tier): ``(wd_fut, wm_fut,
-        applied0)`` replay rows that rode the scan dispatch itself,
-        starting at the pre-dispatch apply cursors — consumed FIRST, so
-        a scan step whose commit delta fits the staged window pays
-        ZERO standalone fetch dispatches; any remainder falls through
-        to the fetch loop below (identical decode → identical
-        streams)."""
-        if scan_rows is not None:
-            wd_fut, wm_fut, applied0 = scan_rows
-            staged = int(wm_fut.shape[-2])     # K-sized, <= replay_W
-            wd_all = wm_all = None
-            for r in range(self.R):
-                if (r in self._wedged or r in self.need_recovery):
-                    continue
-                commit = int(res["commit"][r])
-                off = int(self.applied[r]) - int(applied0[r])
-                n = int(min(commit - self.applied[r], staged - off))
-                if n <= 0 or off < 0:
-                    continue
-                if wd_all is None:      # lazy: transfer only if used
-                    wd_all = np.asarray(wd_fut)
-                    wm_all = np.asarray(wm_fut)
-                wd = wd_all[r, off:off + n]
-                wm = wm_all[r, off:off + n]
-                if int(wm[0, M_GIDX]) != self.applied[r]:
-                    self.need_recovery.add(r)       # slot recycled
-                    continue
-                decode_window(wm, wd, n, self.replayed[r],
-                              self.frames[r], self.collect_frames,
-                              rebase=self.rebased_total)
-                self.applied[r] += n
-        # Force-pruned laggards: when the ring no longer PHYSICALLY holds
-        # entry `applied` (a newer entry recycled its slot — possible
-        # once forced pruning let appends run ahead of a wedged member's
-        # apply), replaying would feed garbage to the app. The stamped
-        # global index (M_GIDX) proves integrity: fetched-entry gidx ==
-        # expected index, else flag for snapshot recovery and stop.
-        # Being merely below `head` is NOT sufficient to flag — the
-        # benign one-step lazy-push lag puts followers there routinely
-        # while their slots are still intact.
-        while True:
-            todo = [r for r in range(self.R)
-                    if r not in self._wedged
-                    and r not in self.need_recovery
-                    and self.applied[r] < int(res["commit"][r])]
-            if not todo:
-                return
-            starts = self._put(self.applied.astype(np.int32))
-            need = max(int(res["commit"][r] - self.applied[r])
-                       for r in todo)
-            prof = self.profiler
-            if prof is not None:
-                prof.start("replay_fetch")
-            # bind the fetch's log argument UNDER the host lock: the
-            # pipelined dispatch thread donates the current state
-            # buffers into the next step's dispatch, and a fetch bound
-            # after that donation reads deleted buffers. Binding first
-            # is sufficient — the runtime keeps an argument buffer
-            # alive for an already-enqueued program — and the newer log
-            # is safe to read: committed entries are immutable, the
-            # rollover is deferred while tickets are in flight, and the
-            # M_GIDX integrity check still guards slot recycling. Only
-            # the BIND holds the lock; the blocking result read below
-            # runs outside it so the dispatch path never stalls.
-            with held(prof, self._host_lock, "fetch_lock_wait"):
-                if prof is not None:
-                    prof.start("fetch_enqueue")
-                self._replay_fetch.need = need
-                wd_fut, wm_fut = self._fetch_all(self.state.log, starts)
-                if prof is not None:
-                    prof.stop("fetch_enqueue")
-            if prof is not None:
-                prof.start("fetch_read")
-            # wm is read last: a wrapper over _fetch_all (the
-            # benchmark's span) ends inside its conversion
-            wd_all, wm_all = np.asarray(wd_fut), np.asarray(wm_fut)
-            W = wm_all.shape[-2]        # the width the fetch chose
-            if prof is not None:
-                prof.stop("fetch_read")
-                prof.stop("replay_fetch")
-                prof.count("fetch_rows_total", W)
-                prof.start("replay_decode")
-            for r in todo:
-                commit = int(res["commit"][r])
-                n = int(min(commit - self.applied[r], W))
-                wd, wm = wd_all[r], wm_all[r]
-                if n > 0 and int(wm[0, M_GIDX]) != self.applied[r]:
-                    self.need_recovery.add(r)       # slot recycled
-                    continue
-                decode_window(wm, wd, n, self.replayed[r],
-                              self.frames[r], self.collect_frames,
-                              rebase=self.rebased_total)
-                self.applied[r] += n
-            if prof is not None:
-                prof.stop("replay_decode")
 
     # ---------------- inspection ----------------
 
@@ -1571,14 +1879,7 @@ class SimCluster:
         return self.mesh.devices[r]
 
     def leader(self) -> int:
-        assert self.last is not None
-        ids = [r for r in range(self.R)
-               if self.last["role"][r] == int(Role.LEADER)]
-        return ids[0] if len(ids) == 1 else -1
+        return self._leader(())
 
     def run_until_elected(self, candidate: int, max_steps: int = 5) -> int:
-        for _ in range(max_steps):
-            res = self.step(timeouts=[candidate])
-            if res["role"][candidate] == int(Role.LEADER):
-                return candidate
-        raise AssertionError("election did not converge")
+        return self._elect((candidate,), [candidate], max_steps)

@@ -137,11 +137,21 @@ def test_cache_key_silent_when_flag_in_key(tmp_path):
 
 
 def test_cache_key_clean_on_main_builders():
-    """Every real STEP_CACHE builder (runtime/sim.py, shard/cluster.py
-    — 9+ store sites) folds every static flag it reads into its key,
-    with zero baseline entries needed."""
+    """Every real STEP_CACHE builder folds every static flag it reads
+    into its key, with zero baseline entries needed — and the engines'
+    programs have ONE store site between them (``ClusterEngine.
+    _program`` in runtime/sim.py, next to the redigest pass's and the
+    replica mesh's; shard/cluster.py stores nothing)."""
     report = run_analysis(passes=("cache-key",), baseline=None)
     assert report.findings == [], [str(f) for f in report.findings]
+    from rdma_paxos_tpu.analysis import cachekey
+    from rdma_paxos_tpu.analysis.engine import SourceTree
+    tree = SourceTree()
+    sites = {rel: len(cachekey._store_sites(tree.module(rel)))
+             for rel in ("rdma_paxos_tpu/runtime/sim.py",
+                         "rdma_paxos_tpu/shard/cluster.py")}
+    assert sites == {"rdma_paxos_tpu/runtime/sim.py": 3,
+                     "rdma_paxos_tpu/shard/cluster.py": 0}, sites
 
 
 # ---------------------------------------------------------------------------

@@ -119,13 +119,7 @@ def stubbed(c, sharded):
     def program(state, packed):
         got.append(packed)
         return state, None
-    built = (program, ("stub",)) if sharded else program
-    c._build_step = lambda elections: built
-    c._burst_fn = c._scan_fn = lambda K: built
-    if sharded:
-        c._step_full = built
-    else:
-        c._step = program
+    c._program = lambda kind, K=None, elections=None: (program, ("stub",))
     zeros = np.zeros((c.G, c.R) if sharded else (c.R,), np.int64)
     with c._host_lock:
         c.last = dict(end=zeros, head=zeros)

@@ -191,9 +191,10 @@ def test_mesh_prewarm_leaves_no_compile_for_the_served_path():
                     batch_slots=8)
     c = ShardedCluster(cfg, R, G, mesh=MESH, fanout="psum", scan=True)
     c.prewarm(tiers=(2,))
-    fns = dict(step=c._build_step(elections=True)[0],
-               stable=c._build_step(elections=False)[0],
-               burst=c._burst_fn(2)[0], scan=c._scan_fn(2)[0],
+    fns = dict(step=c._program("step", elections=True)[0],
+               stable=c._program("step", elections=False)[0],
+               burst=c._program("burst", 2)[0],
+               scan=c._program("scan", 2)[0],
                **{"fetch_%d" % W: fn
                   for W, fn in c._fetch_all.programs.items()})
     assert len(fns) == 4 + 3        # the fetch at each of its widths

@@ -17,7 +17,9 @@ file), and a ``log_buf`` of live columns installs into the padded ring
 (``snapshot.genesis_row`` -> ``HostReplicaDriver.install_genesis``).
 
 ``python -m tests.test_ring_row_golden`` prints the chains of the tree
-it runs on (how the golden file was made, on the parent).
+it runs on (how the golden file was made, on the parent); with ``keys``
+the STEP_CACHE keys a short schedule leaves behind (``PARENT_KEYS``,
+recorded on PR 52's parent).
 """
 
 import hashlib
@@ -340,5 +342,108 @@ def test_live_columns_digests_and_outputs_are_the_parents(case):
     assert len(got["links"]) == len(want["links"])
 
 
+# ---------------------------------------------------------------------------
+# the STEP_CACHE keys are the parent's
+# ---------------------------------------------------------------------------
+
+# the txn lane's records want 128-byte slots
+KEY_CFG = LogConfig(n_slots=64, slot_bytes=128, window_slots=16,
+                    batch_slots=8)
+_SIM = (3, "sim", False, False, "gather")
+_SPMD = (3, "spmd", False, False, "gather")
+_G = (3, "sim", None, False, False, "gather")
+_MESH = (3, "spmd-group", ((1, 3), (0, 1, 2)), False, False, "gather")
+
+
+def _keys(head, *tails):
+    return {(KEY_CFG,) + head + tail for tail in tails}
+
+
+# what the parent commit (2b82374) left in STEP_CACHE after
+# ``served_keys``, by ``python -m tests.test_ring_row_golden keys``
+# there: (engine, mapping, option) -> keys
+PARENT_KEYS = {
+    ("single", "sim", None): _keys(
+        _SIM, (True,), (False,), ("burst", 2), ("burst", 4)),
+    ("single", "sim", "audit"): _keys(
+        _SIM, (True, "audit"), (False, "audit"), ("burst", 2, "audit"),
+        ("burst", 4, "audit")),
+    ("single", "sim", "scan"): _keys(
+        _SIM, (True,), (False,), ("burst", 2), ("scan", 2, 16),
+        ("scan", 4, 32)),
+    ("single", "sim", "txn"): _keys(
+        _SIM, (True, "txn"), (False, "txn"), ("burst", 2), ("burst", 4)),
+    ("single", "spmd", None): _keys(
+        _SPMD, (True,), (False,), ("burst", 2), ("burst", 4)) | {
+            (KEY_CFG, 3, "mesh")},
+    ("groups", "sim", None): _keys(
+        _G, ("group", True), ("group", False), ("group-burst", 2),
+        ("group-burst", 4)),
+    ("groups", "sim", "audit"): _keys(
+        _G, ("group", True, "audit"), ("group", False, "audit"),
+        ("group-burst", 2, "audit"), ("group-burst", 4, "audit")),
+    ("groups", "sim", "scan"): _keys(
+        _G, ("group", True), ("group", False), ("group-burst", 2),
+        ("group-scan", 2, 16), ("group-scan", 4, 32)),
+    ("groups", "sim", "txn"): _keys(
+        _G, ("group", True, "txn"), ("group", False, "txn"),
+        ("group-burst", 2), ("group-burst", 4)),
+    ("groups", "spmd", None): _keys(
+        _MESH, ("group", True), ("group", False), ("group-burst", 2),
+        ("group-burst", 4)),
+}
+
+
+def served_keys(engine, mapping, option, seed=53):
+    """STEP_CACHE's keys after a prewarm of one tier and a seeded
+    schedule of steps (with and without an election) and bursts, one
+    of them past the prewarmed tier."""
+    from rdma_paxos_tpu.runtime.sim import STEP_CACHE
+    rng = random.Random(seed)
+    opts = {option: True} if option else {}
+    STEP_CACHE.clear()
+    if engine == "single":
+        c = SimCluster(KEY_CFG, 3, mode=mapping, **opts)
+        c.prewarm(tiers=(2,))
+        c.run_until_elected(0)
+        submit = lambda n: c.submit_many(0, [
+            (SEND, 1, 0, _payload(rng, b"k")) for _ in range(n)])
+    else:
+        c = ShardedCluster(KEY_CFG, 3, 3, fanout="gather",
+                           mesh=(1, 3) if mapping == "spmd" else None,
+                           **opts)
+        c.prewarm(tiers=(2,))
+        c.place_leaders("round_robin")
+        submit = lambda n: [c.submit_many(g, g, [
+            (SEND, 1, 0, _payload(rng, b"k")) for _ in range(n)])
+            for g in range(3)]
+    for i in range(6):
+        submit(rng.randrange(1, 2 * KEY_CFG.batch_slots))
+        c.step_burst() if i % 2 else c.step()
+    submit(3 * KEY_CFG.batch_slots)         # a burst of the next tier
+    c.step_burst()
+    c.step()
+    return set(STEP_CACHE)
+
+
+@pytest.mark.parametrize("engine,mapping,option", sorted(
+    PARENT_KEYS, key=lambda case: tuple(map(str, case))))
+def test_step_cache_keys_are_the_parents(engine, mapping, option):
+    """The ONE place that forms a STEP_CACHE key forms the parent's,
+    literally (``cfg`` by value), under every option that marks it."""
+    if mapping == "spmd" and len(jax.devices()) < 3:
+        pytest.skip("needs 3 (virtual) devices")
+    got = served_keys(engine, mapping, option)
+    assert got == PARENT_KEYS[engine, mapping, option], (
+        got ^ PARENT_KEYS[engine, mapping, option])
+
+
 if __name__ == "__main__":
-    print(json.dumps({k: run() for k, run in CASES.items()}, indent=1))
+    import sys
+    if sys.argv[1:] == ["keys"]:
+        for case in PARENT_KEYS:
+            print(case, sorted(
+                (k[1:] for k in served_keys(*case)), key=str))
+    else:
+        print(json.dumps({k: run() for k, run in CASES.items()},
+                         indent=1))

@@ -191,6 +191,65 @@ def test_g1_bit_identical_to_simcluster():
 # compile-cache dedup: one program for a homogeneous cluster
 # ---------------------------------------------------------------------------
 
+# the host bookkeeping the engines share (ISSUE 52): each name is ONE
+# function, ClusterEngine's, inherited by both front ends
+ONE_BODY = (
+    "begin_step", "begin_burst", "finish", "drain", "step", "step_burst",
+    "prewarm", "reserved_appends", "_tiers", "_step_bufs", "_burst_bufs",
+    "_scan_slots", "_effective_mask", "_replay_committed", "_maybe_rebase",
+    "_apply_rebase", "_rebase_stalled_step", "_ingest_audit",
+    "_record_flight", "_stamp_appends", "_span_recorder", "_observe_spans",
+    "_program", "_dispatch", "_apply_window")
+# where the engines truly differ: hooks that BOTH front ends define
+HOOKS = ("_norm_timeouts", "_link_models", "_span_rep", "_count_appends",
+         "_observe")
+
+
+@pytest.mark.parametrize("name", ONE_BODY)
+def test_the_engines_share_one_body(name):
+    """A perf PR that forks a method in one engine fails here, not on
+    the ledger two PRs later."""
+    from rdma_paxos_tpu.runtime.sim import ClusterEngine
+    fn = getattr(ClusterEngine, name)
+    assert getattr(SimCluster, name) is fn, name
+    assert getattr(ShardedCluster, name) is fn, name
+
+
+def test_the_front_ends_define_the_hooks_and_nothing_tells_them_apart():
+    import inspect
+
+    from rdma_paxos_tpu.runtime.sim import ClusterEngine
+    for cls in (SimCluster, ShardedCluster):
+        assert cls.__mro__[1] is ClusterEngine
+        for name in HOOKS + ("_PROGRAMS", "__init__"):
+            assert name in vars(cls), (cls.__name__, name)
+    assert not hasattr(SimCluster(CFG, 3), "G")
+    body = inspect.getsource(ClusterEngine)
+    for probe in ("isinstance(self", "hasattr(self", "SimCluster)",
+                  "ShardedCluster)", "self.G"):
+        assert probe not in body, probe
+
+
+def test_the_stamp_loop_does_nothing_but_stamp():
+    """The one per-OPERATION loop of the post-readback rules calls
+    ``stamp_append``, positionally, and nothing else: PR 52's first
+    build worked a cell's labels out once an entry there, and the
+    chip's host read 0.56 us an entry more (PERF.md sec. 6)."""
+    import ast
+    import inspect
+    import textwrap
+
+    from rdma_paxos_tpu.runtime.sim import ClusterEngine
+    tree = ast.parse(textwrap.dedent(
+        inspect.getsource(ClusterEngine._stamp_appends)))
+    loops = [n for n in ast.walk(tree) if isinstance(n, ast.For)]
+    assert len(loops) == 1
+    calls = [n for stmt in loops[0].body for n in ast.walk(stmt)
+             if isinstance(n, ast.Call)]
+    assert [ast.unparse(c.func) for c in calls] == ["spans.stamp_append"]
+    assert not calls[0].keywords
+
+
 def test_single_compile_for_homogeneous_g4():
     """G groups sharing one LogConfig share ONE compiled step: the
     whole G=4 workload — elections in every group plus committed

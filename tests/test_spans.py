@@ -1148,7 +1148,7 @@ def lowered_texts():
     from rdma_paxos_tpu.consensus.step import arg_layout
     c = SimCluster(ACCT_CFG, 3, audit=True, telemetry=True)
     inp = jnp.zeros(arg_layout(ACCT_CFG, 3).shape((3,)), jnp.int32)
-    step = c._build_step(elections=True).lower(c.state, inp)
+    step = c._program("step", elections=True)[0].lower(c.state, inp)
     fetches = {W: fn.lower(c.state.log, jnp.zeros((3,), jnp.int32))
                for W, fn in c._fetch_all.programs.items()}
     assert len(fetches) == 3
