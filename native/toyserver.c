@@ -18,6 +18,23 @@
  *   toyserver <port> -t   thread-per-connection (memcached-style) — many
  *                         reads block in the shim's commit wait
  *                         concurrently, exercising its pipelining
+ * and two options, after the port, in any order with -t:
+ *   -s <n>   the string table has 2^n slots (8..26; without it 2^17 =
+ *            131,072, which holds 131,071 keys): 320 bytes a slot,
+ *            allocated zeroed and every page of it touched at start,
+ *            once the port listens (2^21 slots are 671 MB resident, as
+ *            a store that has been loaded is: the keys hash all over
+ *            the table, so a run would otherwise spend its first
+ *            seconds taking a page fault or two a new key)
+ *   -j       the answers to the requests of ONE read are joined into
+ *            one write, as redis answers a pipelined batch (it adds
+ *            replies to the client's buffer and sends it once an event
+ *            loop turn). Without it a client that pipelines is
+ *            answered a line at a time, each with a write of its own:
+ *            by Nagle's algorithm the second answer waits for the
+ *            client's ACK of the first, so such a client asks for its
+ *            ACKs at once (TCP_QUICKACK) or waits 40 ms a batch. A
+ *            read that holds one request is answered alike either way.
  */
 #include <arpa/inet.h>
 #include <errno.h>
@@ -31,7 +48,8 @@
 #include <sys/socket.h>
 #include <unistd.h>
 
-#define MAXKV 131072            /* open-addressing table, power of two */
+#define MAXKV 131072            /* open-addressing table, power of two:
+                                 * the size without -s */
 #define MAXC 256                /* poll-mode connections; a front-end of a
                                  * G-group deployment holds its clients' and
                                  * (G - 1) groups' replayed ones */
@@ -39,18 +57,19 @@
 
 /* Open-addressing hash KVS (linear probing, tombstone-free deletes by
  * backward-shift) so benchmark-scale key counts stay O(1) per op. */
-static char keys[MAXKV][64], vals[MAXKV][256];
-static unsigned char used[MAXKV];
+static char (*keys)[64], (*vals)[256];
+static unsigned char* used;
+static unsigned kv_cap = MAXKV;         /* slots: -s sets it */
 static int nkv = 0;
 
 static unsigned kv_hash(const char* k) {
   unsigned h = 2166136261u;
   while (*k) h = (h ^ (unsigned char)*k++) * 16777619u;
-  return h & (MAXKV - 1);
+  return h & (kv_cap - 1);
 }
 static int kv_find(const char* k) {      /* slot of key, or -1 */
-  for (unsigned i = kv_hash(k), n = 0; n < MAXKV;
-       i = (i + 1) & (MAXKV - 1), n++) {
+  for (unsigned i = kv_hash(k), n = 0; n < kv_cap;
+       i = (i + 1) & (kv_cap - 1), n++) {
     if (!used[i]) return -1;
     if (!strcmp(keys[i], k)) return (int)i;
   }
@@ -61,14 +80,14 @@ static const char* kv_get(const char* k) {
   return i < 0 ? NULL : vals[i];
 }
 static int kv_set(const char* k, const char* v) {   /* 0, or -1: full */
-  for (unsigned i = kv_hash(k), n = 0; n < MAXKV;
-       i = (i + 1) & (MAXKV - 1), n++) {
+  for (unsigned i = kv_hash(k), n = 0; n < kv_cap;
+       i = (i + 1) & (kv_cap - 1), n++) {
     if (used[i] && !strcmp(keys[i], k)) {
       snprintf(vals[i], 256, "%s", v);
       return 0;
     }
     if (!used[i]) {
-      if (nkv >= MAXKV - 1) return -1;
+      if ((unsigned)nkv >= kv_cap - 1) return -1;
       used[i] = 1;
       snprintf(keys[i], 64, "%s", k);
       snprintf(vals[i], 256, "%s", v);
@@ -84,8 +103,8 @@ static void kv_del(const char* k) {
   used[i] = 0;
   nkv--;
   /* re-insert the probe chain after the hole */
-  for (unsigned j = (i + 1) & (MAXKV - 1); used[j];
-       j = (j + 1) & (MAXKV - 1)) {
+  for (unsigned j = (i + 1) & (kv_cap - 1); used[j];
+       j = (j + 1) & (kv_cap - 1)) {
     used[j] = 0;
     nkv--;
     char kk[64], vv[256];
@@ -159,6 +178,29 @@ struct conn { int fd; char buf[BUFSZ]; int len; };
 
 static pthread_mutex_t kv_mu = PTHREAD_MUTEX_INITIALIZER;
 
+/* -j: what a read's requests have been answered so far, sent by
+ * flush_replies() when the read's last line is done (or this is full) */
+static int join_replies = 0;
+static __thread char joined[BUFSZ];
+static __thread int njoined = 0;
+
+static void flush_replies(int fd) {
+  if (!njoined) return;
+  ssize_t w = write(fd, joined, (size_t)njoined);
+  (void)w;
+  njoined = 0;
+}
+static void reply(int fd, const char* out, size_t len) {
+  if (join_replies) {               /* no reply is as long as this */
+    if ((size_t)njoined + len > sizeof joined) flush_replies(fd);
+    memcpy(joined + njoined, out, len);
+    njoined += (int)len;
+    return;
+  }
+  ssize_t w = write(fd, out, len);
+  (void)w;
+}
+
 static void handle_line(int fd, char* line) {
   char out[MAXF * 290 + 8], k[64], v[256];
   int at = 0;
@@ -195,20 +237,34 @@ static void handle_line(int fd, char* line) {
      * redis BGSAVE producing an RDB: app state without event history).
      * String keys ONLY: hash records are not listed, so an app that
      * holds them is not rebuilt from this listing */
-    for (unsigned i = 0; i < MAXKV; i++) {
+    for (unsigned i = 0; i < kv_cap; i++) {
       if (!used[i]) continue;
       char lineb[512];
       int ln = snprintf(lineb, sizeof lineb, "%s %s\n", keys[i], vals[i]);
-      ssize_t w0 = write(fd, lineb, (size_t)ln);
-      (void)w0;
+      reply(fd, lineb, (size_t)ln);
     }
     snprintf(out, sizeof out, ".\n");
   } else {
     snprintf(out, sizeof out, "-ERR\n");
   }
   pthread_mutex_unlock(&kv_mu);
-  ssize_t w = write(fd, out, strlen(out));
-  (void)w;
+  reply(fd, out, strlen(out));
+}
+
+/* the lines of one read, each answered in turn; what is left of an
+ * unfinished one moves to the front */
+static void handle_read(struct conn* c) {
+  char* start = c->buf;
+  char* nl;
+  while ((nl = strchr(start, '\n'))) {
+    *nl = 0;
+    handle_line(c->fd, start);
+    start = nl + 1;
+  }
+  flush_replies(c->fd);
+  int rest = (int)(c->buf + c->len - start);
+  memmove(c->buf, start, (size_t)rest);
+  c->len = rest;
 }
 
 /* ---- thread-per-connection mode ---- */
@@ -220,16 +276,7 @@ static void* conn_main(void* arg) {
     if (n <= 0) break;
     c->len += (int)n;
     c->buf[c->len] = 0;
-    char* start = c->buf;
-    char* nl;
-    while ((nl = strchr(start, '\n'))) {
-      *nl = 0;
-      handle_line(c->fd, start);
-      start = nl + 1;
-    }
-    int rest = (int)(c->buf + c->len - start);
-    memmove(c->buf, start, (size_t)rest);
-    c->len = rest;
+    handle_read(c);
   }
   close(c->fd);
   free(c);
@@ -238,7 +285,29 @@ static void* conn_main(void* arg) {
 
 int main(int argc, char** argv) {
   int port = argc > 1 ? atoi(argv[1]) : 7000;
-  int threaded = argc > 2 && !strcmp(argv[2], "-t");
+  int threaded = 0, sized = 0;
+  for (int i = 2; i < argc; i++) {
+    if (!strcmp(argv[i], "-t")) {
+      threaded = 1;
+    } else if (!strcmp(argv[i], "-j")) {
+      join_replies = 1;
+    } else if (!strcmp(argv[i], "-s") && i + 1 < argc) {
+      int bits = atoi(argv[++i]);
+      if (bits < 8 || bits > 26) {
+        fprintf(stderr, "toyserver: -s %d: 8..26\n", bits);
+        return 2;
+      }
+      kv_cap = 1u << bits;
+      sized = 1;
+    } else {
+      fprintf(stderr, "usage: toyserver <port> [-t] [-j] [-s <bits>]\n");
+      return 2;
+    }
+  }
+  keys = calloc(kv_cap, sizeof *keys);
+  vals = calloc(kv_cap, sizeof *vals);
+  used = calloc(kv_cap, 1);
+  if (!keys || !vals || !used) { perror("calloc"); return 1; }
   /* like the servers this stands in for (redis.c setupSignalHandlers,
    * memcached sigignore): a reply written to a connection the peer — or
    * the shim's sever of a refused session — already shut down is an
@@ -253,6 +322,12 @@ int main(int argc, char** argv) {
   a.sin_port = htons((unsigned short)port);
   if (bind(ls, (struct sockaddr*)&a, sizeof a) != 0) { perror("bind"); return 1; }
   listen(ls, MAXC);
+  if (sized)                    /* who connects meanwhile waits to be accepted */
+    for (size_t i = 0; i < kv_cap; i += 4096 / sizeof *vals) {
+      ((volatile char*)vals[i])[0] = 0;
+      if (i % (4096 / sizeof *keys) == 0) ((volatile char*)keys[i])[0] = 0;
+      if (i % 4096 == 0) ((volatile unsigned char*)used)[i] = 0;
+    }
   fprintf(stderr, "toyserver listening on %d%s\n", port,
           threaded ? " (threaded)" : "");
 
@@ -301,16 +376,7 @@ int main(int argc, char** argv) {
         if (n <= 0) { close(c->fd); c->fd = -1; continue; }
         c->len += (int)n;
         c->buf[c->len] = 0;
-        char* start = c->buf;
-        char* nl;
-        while ((nl = strchr(start, '\n'))) {
-          *nl = 0;
-          handle_line(c->fd, start);
-          start = nl + 1;
-        }
-        int rest = (int)(c->buf + c->len - start);
-        memmove(c->buf, start, (size_t)rest);
-        c->len = rest;
+        handle_read(c);
       }
     }
   }

@@ -73,11 +73,16 @@ inside the optional fencing path):
     ``observe``        ``_observe_step`` + the alert/health cadences
     ``profiler``       this class's bookkeeping round the above
     ``unattributed``   the rest of the cycle
-  ``intake_to_ack``    per operation: ``PendingEvent.t0`` -> ack release
-  ``intake_queue_wait`` per operation: ``t0`` -> the pump that dispatches
-  ``replay_answer_wait`` per answer ``ReplayEngine._settle`` blocked for:
-                       the follower app's turn, of ``replay_send``
-                       (credited once a dispatch, no ring entry)
+  ``intake_to_ack``    per shim event (a pipelining client's read is
+                       one event of many operations):
+                       ``PendingEvent.t0`` -> ack release
+  ``intake_queue_wait`` per shim event: ``t0`` -> the pump that
+                       dispatches
+  ``replay_answer_wait`` per write whose answers ``ReplayEngine._settle``
+                       blocked for (all of them: sixteen where the write
+                       held sixteen requests): the follower app's turn,
+                       of ``replay_send`` (credited once a dispatch, no
+                       ring entry)
   ==================== ==============================================
 
   The two lock waits are recorded by :class:`held` only when the take
@@ -106,7 +111,13 @@ inside the optional fencing path):
   ended a request: ``replay_applies_total`` over it is the calls a
   whole request costs a follower), ``replay_order_timeouts_total``
   (``ReplayEngine.order_timeouts``: answers waited ``ORDER_WAIT_S``
-  for in vain), ``fetch_rows_total`` (rows a replica each standalone
+  for in vain), ``replay_answers_total`` (answer lines read off a
+  replay socket and matched to the write they answer: over
+  ``replay_applies_total`` it is the requests a write held, 16 under
+  a client that pipelines sixteen) and
+  ``replay_unproven_handoffs_total`` (writes that went to another
+  connection while the last one's requests were not all answered: 0
+  where the log's order was held), ``fetch_rows_total`` (rows a replica each standalone
   replay fetch asked of the device: the static width it ran at; over
   ``replay_fetch``'s count it says which width serves),
   ``input_put_calls_total``, ``input_put_buffers_total`` and
@@ -677,8 +688,9 @@ PHASE_OBSERVE = "observe"                # _observe_step + cadences
 # per operation, credited (no start/stop, no ring entry)
 OP_INTAKE_TO_ACK = "intake_to_ack"       # PendingEvent.t0 -> release
 OP_INTAKE_QUEUE_WAIT = "intake_queue_wait"  # PendingEvent.t0 -> pump
-# per answer a ReplayEngine blocked for, credited once a dispatch
-OP_REPLAY_ANSWER_WAIT = "replay_answer_wait"  # _settle's blocking recv
+# per write whose answers a ReplayEngine blocked for, credited once a
+# dispatch
+OP_REPLAY_ANSWER_WAIT = "replay_answer_wait"  # _settle's blocking recvs
 # a membership change and a replica's recovery: rare, recorded only when
 # they run. The first two nest where they run (admin_pump, or
 # post_step_rules for the auto-recovery); the last two span many cycles
@@ -751,6 +763,7 @@ class StepPhaseProfiler:
                 "replay_reconnects_total", "pruned_slots_total",
                 "append_clamped_total", "ring_wraps_total",
                 "replay_requests_total", "replay_order_timeouts_total",
+                "replay_answers_total", "replay_unproven_handoffs_total",
                 "fetch_rows_total", "input_put_calls_total",
                 "input_put_bytes_total", "input_put_buffers_total")
     # a thread waiting by design: its length counts towards no stall,

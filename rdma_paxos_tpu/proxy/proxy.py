@@ -333,31 +333,51 @@ class ReplayEngine:
     them in ITS order (toyserver and redis: by slot or fd), not in the
     order they were written. Two clients writing one key would then
     leave the replicas with different values. So before bytes go to
-    another connection than the last one, :meth:`apply` waits for the
-    app to answer on the last one (any bytes: a single-threaded app has
-    by then consumed that request; the answer is read and dropped, which
-    is also all the draining the sockets need); and before more bytes go
-    to the SAME connection too, unless the last write left a request
-    unfinished (it did not end in a newline: a fragment), so that at
-    most one whole request is ever unanswered and an answer proves it
-    done. Bytes for ANOTHER connection after an unfinished request (two
+    another connection than the last one, :meth:`apply` waits until the
+    app has answered EVERY whole request written to the last one.
+
+    What proves a write: the apps speak line protocols, a request a
+    line and an answer a line, so a write owes as many answers as it
+    holds newlines (a pipelining client's read is ONE log entry of many
+    requests, and neighbouring entries of one connection reach here
+    joined) and is proven when that many answer lines have been read
+    off its socket: the answer to its LAST request, which an app that
+    answers a line at a time, each with a ``write`` of its own, sends
+    long after the first. The answers are read and dropped, which is
+    also all the draining the sockets need. Before more bytes go to the
+    SAME connection the engine waits likewise, unless the last write
+    left a request unfinished (it did not end in a newline: a
+    fragment; TCP keeps one connection's order), so that the answers
+    owed are always ONE write's. Whole requests AHEAD of an unfinished
+    one are owed like any others: bytes for another connection (two
     groups' streams into one app: within one log a request's fragments
-    are neighbours) go out at once: nothing can answer yet, and the
-    rest of the request waits its turn like any other write. A request
-    that is never answered (``noreply``) costs
-    ``ORDER_WAIT_S`` once and is counted in ``order_timeouts``; after it
-    the order is the app's. An app that leaves ``GIVE_UP_AFTER``
-    requests in a row unanswered (a sink) is not waited for again until
-    it does answer.
+    are neighbours) wait for them, and only the fragment, which nothing
+    can answer yet, is left behind; its rest waits its turn like any
+    other write. An answer is matched to the write it answers and to no
+    other: a connection left with answers owed (below) is read empty
+    before it is written to again, so what comes late is never taken
+    for the proof of a later write.
+
+    A write whose answers do not all come within ``ORDER_WAIT_S`` costs
+    that once and is counted in ``order_timeouts``; the bytes that then
+    go to another connection go unproven (counted: an unproven
+    handoff), and there the order is the app's. An app that leaves
+    ``GIVE_UP_AFTER`` writes in a row wholly unanswered (a sink:
+    ``noreply``) is not waited for again until it does answer. One that
+    ``GIVE_UP_AFTER`` times in a row answers, but with fewer lines than
+    it was sent (memcached's ``set`` is two lines and one answer), does
+    not speak a line a line: from then on ANY answer proves a write, as
+    it does where a write holds one request, and every connection is
+    read empty before it is written to.
     """
 
-    # how long a write to another connection waits for the app's answer
-    # on the last one
+    # how long a write to another connection waits for the app's next
+    # answer on the last one
     ORDER_WAIT_S = 0.05
     GIVE_UP_AFTER = 3
     # ORDER_WAIT_S as the kernel's receive timeout (a struct timeval),
     # not Python's: the socket stays blocking, so a wait for the app's
-    # answer is ONE recv
+    # answers is ONE recv where they come together
     _RCVTIMEO = struct.pack("ll", 0, int(ORDER_WAIT_S * 1e6))
     # a port of ours is claimable from its bind until this long after
     # its socket's close (an app that accepts late reports a connection
@@ -371,20 +391,31 @@ class ReplayEngine:
     def __init__(self, app_host: str, app_port: int):
         self.addr = (app_host, app_port)
         self.conns: Dict[int, socket.socket] = {}
-        self._awaiting: Optional[socket.socket] = None
+        self._awaiting: Optional[socket.socket] = None  # last written to
+        self._owed = 0              # answers its whole requests still owe
         self._whole = False         # the last write there ended a request
+        self._by_line = True        # the app answers a line a request line
+        # connections left with answers owed: they may still come
+        self._stale: set = set()
         self._reply_bytes = 0       # app output read since the last drain
         # answers blocked for since the last take_answer_waits, and the
         # time blocked: the follower app's turn
         self._waits = 0
         self._wait_ns = 0
         self._sink = bytearray(65536)   # where that output is read to
-        self._unanswered = 0        # waits in a row that timed out
+        # waits in a row that timed out: on nothing at all, on too few
+        self._unanswered = 0
+        self._short = 0
         self.order_timeouts = 0
         # since the last take_replayed: writes that ended a request,
         # and order_timeouts as it stood then
         self._requests = 0
         self._timeouts_taken = 0
+        # since the last take_answers: answers matched to the write
+        # they answer, and writes that went to another connection while
+        # the last one's was unproven
+        self._answers = 0
+        self._unproven = 0
         # local (ephemeral) ports of our replay sockets: the driver uses
         # these to recognize its own replayed connections arriving back
         # through the app's interposition shim. port -> None while its
@@ -399,6 +430,7 @@ class ReplayEngine:
         # one — reset rather than interleave bytes into a stale socket
         old = self.conns.pop(conn_id, None)
         if old is not None:
+            self._forget(old)
             self._shut(old)
         s = self._open()
         s.setsockopt(socket.SOL_SOCKET, socket.SO_RCVTIMEO, self._RCVTIMEO)
@@ -448,40 +480,72 @@ class ReplayEngine:
         return (closed is None
                 or time.monotonic() - closed < self.CLAIM_WAIT_S)
 
+    def _forget(self, s: socket.socket) -> None:
+        """``s`` is to be closed: nothing is owed on it any more."""
+        self._stale.discard(s)
+        if self._awaiting is s:
+            self._awaiting, self._owed = None, 0
+
     def _settle(self, wait: bool = True) -> None:
-        """Read away the app's answer on the connection last written
-        to: waiting for it (at most ``ORDER_WAIT_S``) before bytes go to
-        another connection, or only if it is there already."""
+        """Read away the app's answers on the connection last written
+        to, until none is owed: waiting for them (at most
+        ``ORDER_WAIT_S`` each time the socket is empty) before bytes go
+        to another connection, or only those that are there already."""
         s = self._awaiting
-        if s is None:
+        if s is None or not self._owed:
             return
         sink = self._sink
+        flags = 0 if wait else socket.MSG_DONTWAIT
         t0 = time.perf_counter_ns() if wait else 0
+        got = 0
         try:
-            n = s.recv_into(sink, 0, 0 if wait else socket.MSG_DONTWAIT)
+            while self._owed:
+                n = s.recv_into(sink, 0, flags)
+                if not n:                       # the app closed it
+                    self._owed = 0
+                    return
+                got += n
+                self._unanswered = 0
+                self._reply_bytes += n
+                lines = (sink.count(b"\n", 0, n) if self._by_line
+                         else self._owed)
+                self._answers += min(lines, self._owed)
+                if lines > self._owed:
+                    # more than a line a request: the rest of what it
+                    # says may follow, and is not the next write's
+                    self._stale.add(s)
+                self._owed = max(self._owed - lines, 0)
+            self._short = 0
         except BlockingIOError:
-            if wait:                        # never answered
-                self.order_timeouts += 1
+            if not wait:
+                return                          # not waiting: still owed
+            self.order_timeouts += 1
+            if not got:                         # never answered
                 self._unanswered += 1
-                self._awaiting = None
-            return                          # not waiting: still owed
+            else:
+                self._short += 1
+                if self._short >= self.GIVE_UP_AFTER:
+                    self._by_line = False
         except OSError:
-            self._awaiting = None
-            return
+            self._owed = 0
         finally:
             if wait:
                 # the one place an answer is waited for, so the count
-                # holds whoever calls (or wraps) ``apply``; an answer
-                # that never comes was ``ORDER_WAIT_S`` blocked all the same
+                # holds whoever calls (or wraps) ``apply``; answers
+                # that never come were ``ORDER_WAIT_S`` blocked all the same
                 self._waits += 1
                 self._wait_ns += time.perf_counter_ns() - t0
-        self._awaiting = None
-        self._unanswered = 0
-        self._reply_bytes += n
+
+    def _read_empty(self, s: socket.socket) -> None:
+        """Drop what the app has said on ``s`` since it was last read:
+        answers that came too late to prove their own write."""
+        self._stale.discard(s)
         try:
-            while n == len(sink):           # there may be more
-                n = s.recv_into(sink, 0, socket.MSG_DONTWAIT)
+            while True:
+                n = s.recv_into(self._sink, 0, socket.MSG_DONTWAIT)
                 self._reply_bytes += n
+                if n < len(self._sink):
+                    return
         except OSError:
             pass
 
@@ -492,21 +556,39 @@ class ReplayEngine:
             s = self.conns.get(conn_id)
             if s is None:       # joined mid-stream: open lazily
                 s = self._connect(conn_id)
-            if self._awaiting is not s or self._whole:
-                # an unfinished request has no answer to wait for (a
-                # replica that follows several groups is handed another
-                # group's operation between one group's fragments)
-                self._settle(wait=self._whole
-                             and self._unanswered < self.GIVE_UP_AFTER)
+            last = self._awaiting
+            if self._owed and (last is not s or self._whole):
+                # an unfinished request has no answer to wait for, the
+                # whole ones ahead of it have (a replica that follows
+                # several groups is handed another group's operation
+                # between one group's fragments)
+                self._settle(wait=self._unanswered < self.GIVE_UP_AFTER)
+                if self._owed:              # timed out, or a sink
+                    self._stale.add(last)
+                    self._unproven += last is not s
+                    self._owed = 0
+            if last is not s and (s in self._stale or not self._by_line):
+                self._read_empty(s)
             s.sendall(payload)
             self._awaiting = s
+            self._owed += payload.count(b"\n") if self._by_line else 1
+            if self._owed > 1:
+                # an app that answers a line at a time sends the first
+                # answer and, by Nagle's algorithm, holds the others
+                # until this end has acknowledged it, which this end,
+                # with nothing to send, would put off for 40 ms: ask
+                # for the ACKs at once (until this socket next sends;
+                # the kernel then sends one whenever what was there has
+                # been read), and the rest come in a few segments
+                s.setsockopt(socket.IPPROTO_TCP, socket.TCP_QUICKACK, 1)
             self._whole = payload.endswith(b"\n")
             self._requests += self._whole
         elif etype == int(EntryType.CLOSE):
             s = self.conns.pop(conn_id, None)
             if s is not None:
                 if self._awaiting is s:
-                    self._settle()  # its last request, before the EOF
+                    self._settle()  # its last requests, before the EOF
+                self._forget(s)
                 self._shut(s)
 
     @contextlib.contextmanager
@@ -696,6 +778,14 @@ class ReplayEngine:
         and not this process's."""
         out = (self._waits, self._wait_ns)
         self._waits = self._wait_ns = 0
+        return out
+
+    def take_answers(self) -> Tuple[int, int]:
+        """-> (answers read and matched to the write they answer,
+        writes that went to another connection while the last one's
+        was unproven) since the last call."""
+        out = (self._answers, self._unproven)
+        self._answers = self._unproven = 0
         return out
 
     def take_replayed(self) -> Tuple[int, int]:

@@ -1929,6 +1929,10 @@ class ClusterDriver:
         done = [engine.take_replayed() for engine, _ in replays]
         prof.count("replay_requests_total", sum(n for n, _ in done))
         prof.count("replay_order_timeouts_total", sum(t for _, t in done))
+        proven = [engine.take_answers() for engine, _ in replays]
+        prof.count("replay_answers_total", sum(a for a, _ in proven))
+        prof.count("replay_unproven_handoffs_total",
+                   sum(u for _, u in proven))
         prof.stop("apply_replay_ack")
 
     def _replay_lost(self, engine: ReplayEngine, exc: OSError) -> None:

@@ -257,9 +257,227 @@ def test_what_is_not_waited_for_is_not_counted(slow_app):
     eng.drain_responses()
     assert eng.take_answer_waits() == (0, 0)
     deadline = time.time() + 5
-    while eng._awaiting is not None and time.time() < deadline:
+    while eng._owed and time.time() < deadline:
         time.sleep(0.01)
         eng._settle(wait=False)         # read once there, never waited for
-    assert eng._awaiting is None
+    assert not eng._owed
     assert eng.take_answer_waits() == (0, 0)
     eng.close()
+
+
+# ---------------------------------------------------------------------------
+# writes that hold several requests (a pipelining client's read is ONE
+# log entry): the proof is the answer to the LAST of them
+# ---------------------------------------------------------------------------
+
+class LineAtATimeApp(threading.Thread):
+    """A single-threaded event-loop server that serves ONE line a
+    connection a turn, the connections in REVERSE order of acceptance,
+    and answers each line ``delay`` s after it served it with a send of
+    its own: the first answer to a write of sixteen requests is out long
+    before the last request is served, and whatever another connection
+    holds by then is served in between."""
+
+    def __init__(self, delay=0.001):
+        super().__init__(daemon=True)
+        self.delay = delay
+        self.srv = socket.socket()
+        self.srv.bind(("127.0.0.1", 0))
+        self.srv.listen(64)
+        self.port = self.srv.getsockname()[1]
+        self.served = []            # lines, in the order served
+        self.stop = False
+        self.start()
+
+    def run(self):
+        sel = selectors.DefaultSelector()
+        sel.register(self.srv, selectors.EVENT_READ)
+        conns, bufs = [], {}
+        while not self.stop:
+            busy = any(b"\n" in b for b in bufs.values())
+            for key, _ in sel.select(timeout=0 if busy else 0.05):
+                c = key.fileobj
+                if c is self.srv:
+                    c, _ = self.srv.accept()
+                    conns.append(c)
+                    bufs[c] = b""
+                    sel.register(c, selectors.EVENT_READ)
+                    continue
+                try:
+                    data = c.recv(65536)
+                except OSError:
+                    data = b""
+                if not data:
+                    sel.unregister(c)
+                    conns.remove(c)
+                    continue
+                bufs[c] += data
+            for c in reversed(conns):
+                if b"\n" not in bufs[c]:
+                    continue
+                line, bufs[c] = bufs[c].split(b"\n", 1)
+                self.served.append(line)
+                time.sleep(self.delay)
+                try:
+                    c.sendall(b"+OK\n")
+                except OSError:
+                    pass
+
+
+@pytest.fixture()
+def line_app():
+    app = LineAtATimeApp()
+    yield app
+    app.stop = True
+    app.srv.close()
+
+
+def batch(conn, b, n=16):
+    """Write ``b`` of connection ``conn``: ``n`` SETs of ONE key."""
+    return [b"SET samekey c%d-b%d-%d" % (conn, b, i) for i in range(n)]
+
+
+def test_a_write_of_sixteen_requests_is_proven_by_its_last_answer(line_app):
+    """Two connections write one key, sixteen requests a write, in
+    turn: the app must serve the log's order, whatever its own. (With
+    the FIRST answer for a proof the second connection's write goes
+    out while the app is at the first one's second line, and the
+    fifteen answers left over prove that connection's next write
+    before the app has seen it.)"""
+    eng = ReplayEngine("127.0.0.1", line_app.port)
+    eng.apply(CONNECT, 1, b"")
+    eng.apply(CONNECT, 2, b"")
+    sent, writes = [], 0
+    for b in range(6):
+        for conn in (1, 2):
+            lines = batch(conn, b)
+            sent += lines
+            writes += 1
+            eng.apply(SEND, conn, b"".join(ln + b"\n" for ln in lines))
+    eng.apply(CLOSE, 1, b"")
+    eng.apply(CLOSE, 2, b"")        # waits for the last write's answers
+    assert line_app.served == sent
+    assert eng.order_timeouts == 0
+    # every answer was matched to its write, none was handed on unproven
+    assert eng.take_answers() == (16 * writes, 0)
+    assert eng.take_answers() == (0, 0)         # handed back once
+    assert eng.take_replayed() == (writes, 0)
+    waits, _ns = eng.take_answer_waits()
+    assert waits == writes          # one wait a write, not one an answer
+    assert eng.drain_responses() == 4 * 16 * writes
+    eng.close()
+
+
+def test_whole_requests_ahead_of_an_unfinished_one_are_waited_for(line_app):
+    """A read that ends mid-request: fifteen whole requests and the head
+    of a sixteenth. Another connection's entry follows it in the log:
+    the fifteen are proven first, only the fragment (which nothing can
+    answer) is left behind, and its rest waits for the other write."""
+    eng = ReplayEngine("127.0.0.1", line_app.port)
+    first, second = batch(1, 0), batch(2, 0)
+    head = b"".join(ln + b"\n" for ln in first[:15]) + first[15][:9]
+    eng.apply(SEND, 1, head)
+    eng.apply(SEND, 2, b"".join(ln + b"\n" for ln in second))
+    eng.apply(SEND, 1, first[15][9:] + b"\n")
+    eng.apply(CLOSE, 1, b"")
+    eng.apply(CLOSE, 2, b"")
+    assert line_app.served == first[:15] + second + first[15:]
+    assert eng.order_timeouts == 0
+    assert eng.take_answers() == (32, 0)
+    # the fragment's write ended no request
+    assert eng.take_replayed() == (2, 0)
+    eng.close()
+
+
+def test_a_handoff_with_answers_owed_is_counted_unproven(late_app):
+    """A sink answers nothing: every write to another connection goes
+    out with the last one's request unproven, waited for or not."""
+    app = late_app(nap=0.0, answers=False)
+    eng = ReplayEngine("127.0.0.1", app.port)
+    for i in range(6):
+        eng.apply(SEND, 500 + i % 2, b"line %d\n" % i)
+    assert eng.take_answers() == (0, 5)
+    assert eng.order_timeouts == ReplayEngine.GIVE_UP_AFTER
+    eng.close()
+
+
+class ScriptedApp(SlowApp):
+    """``SlowApp`` whose delay is the line's last word, in ms, and that
+    keeps the order in which it FINISHED the lines."""
+
+    def __init__(self):
+        self.finished = []
+        super().__init__(0)
+
+    def serve(self, c):
+        buf = b""
+        try:
+            while True:
+                data = c.recv(65536)
+                if not data:
+                    return
+                buf += data
+                while b"\n" in buf:
+                    line, buf = buf.split(b"\n", 1)
+                    time.sleep(int(line.split()[-1]) / 1e3)
+                    self.finished.append(line)
+                    c.sendall(b"+OK\n")
+        except OSError:
+            pass
+
+
+def test_an_answer_that_came_late_proves_no_later_write():
+    """Connection 1's first answer takes longer than ``ORDER_WAIT_S``:
+    that handoff goes unproven, and the answer arrives while the engine
+    is elsewhere. It must not pass for the answer to connection 1's
+    NEXT request (a slow one): connection 2's write after it waits for
+    the real one."""
+    app = ScriptedApp()
+    late = int(ReplayEngine.ORDER_WAIT_S * 1e3) + 30
+    eng = ReplayEngine("127.0.0.1", app.port)
+    eng.apply(SEND, 1, b"SET a %d\n" % late)
+    eng.apply(SEND, 2, b"SET b 0\n")        # times out on connection 1
+    assert eng.order_timeouts == 1
+    time.sleep(0.1)                         # the late answer is there
+    eng.apply(SEND, 1, b"SET c 30\n")
+    eng.apply(SEND, 2, b"SET d 0\n")        # after c is DONE, not before
+    eng.apply(CLOSE, 1, b"")
+    eng.apply(CLOSE, 2, b"")
+    assert app.finished[-2:] == [b"SET c 30", b"SET d 0"]
+    assert eng.order_timeouts == 1
+    assert eng.take_answers() == (3, 1)     # b, c, d matched; a unproven
+    assert eng.drain_responses() == 16      # the late one read and dropped
+    eng.close()
+    app.srv.close()
+
+
+def test_an_app_that_answers_fewer_lines_than_it_is_sent_falls_back():
+    """memcached's ``set`` is two lines and one answer: after
+    ``GIVE_UP_AFTER`` writes in a row answered short, any answer proves
+    a write, as before, and nothing waits ``ORDER_WAIT_S`` again."""
+
+    class TwoLinesOneAnswer(SlowApp):
+        def serve(self, c):
+            try:
+                while True:
+                    data = c.recv(65536)
+                    if not data:
+                        return
+                    for _ in range(data.count(b"\n") // 2):
+                        c.sendall(b"STORED\r\n")
+            except OSError:
+                pass
+
+    app = TwoLinesOneAnswer(0)
+    eng = ReplayEngine("127.0.0.1", app.port)
+    t0 = time.perf_counter()
+    for i in range(40):
+        eng.apply(SEND, 600 + i % 2, b"set k%d 0 0 1\r\nv\r\n" % i)
+    took = time.perf_counter() - t0
+    assert eng.order_timeouts == ReplayEngine.GIVE_UP_AFTER
+    assert took < (ReplayEngine.GIVE_UP_AFTER + 2) * ReplayEngine.ORDER_WAIT_S
+    answers, unproven = eng.take_answers()
+    assert unproven == ReplayEngine.GIVE_UP_AFTER
+    assert answers == 39                    # one a write, short or not
+    eng.close()
+    app.srv.close()
